@@ -11,7 +11,7 @@ computed once here and shared:
    ``jax.grad``/``value_and_grad``/``vmap``/``pmap``/``checkpoint``/
    ``remat``, and ``jax.lax.{scan,while_loop,fori_loop,cond,map}``
    bodies.  Aliases are normalized through each module's import table,
-   so ``from ..utils.jax_compat import shard_map`` and
+   so ``from jax import shard_map`` and
    ``from jax.experimental import pallas as pl`` both resolve.
 2. **Closure** — traced-ness propagates through resolved call edges
    (calling ``g()`` from traced ``f`` runs ``g`` at trace time) and
@@ -209,8 +209,7 @@ class _Collector(ast.NodeVisitor):
             return False
         prefix = norm.rsplit(".", 1)[0] if "." in norm else ""
         if prefix:
-            return "jax" in prefix or "jax_compat" in prefix \
-                or "pallas" in prefix
+            return "jax" in prefix or "pallas" in prefix
         return leaf in _BARE_OK
 
     def _seed_arg(self, arg: ast.AST, reason: str) -> None:

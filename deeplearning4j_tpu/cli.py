@@ -324,9 +324,7 @@ def cmd_train(args) -> int:
     heartbeat = Heartbeat.start_from_env()
     trace_path = _setup_trace(args)
     from .serving.warmcache import enable_compile_cache
-    cache_dir = enable_compile_cache(getattr(args, "compile_cache", None))
-    if cache_dir:
-        print(f"compile cache: {cache_dir}")
+    print(f"compile cache: {enable_compile_cache()}")
 
     net = _build_model(args)
     xs, ys = _load_data(args.data, train=True, num_classes=_num_classes_of(net))
@@ -611,9 +609,7 @@ def cmd_serve(args) -> int:
     from .serving.warmcache import enable_compile_cache
 
     trace_path = _setup_trace(args)
-    cache_dir = enable_compile_cache(getattr(args, "compile_cache", None))
-    if cache_dir:
-        print(f"compile cache: {cache_dir}")
+    print(f"compile cache: {enable_compile_cache()}")
     if not args.fleet and not args.model and not getattr(
             args, "models", None):
         raise SystemExit("serve needs --model/--models "
@@ -961,12 +957,11 @@ def cmd_launch(args) -> int:
         raise SystemExit(f"launch worker command must be a "
                          f"deeplearning4j_tpu subcommand, got {rest[0]!r}")
     # arm the shared compile cache BEFORE any worker exists: enable_
-    # compile_cache exports DL4J_TPU_COMPILE_CACHE, which both forked
-    # workers and the --join re-exec inherit
+    # compile_cache exports JAX_COMPILATION_CACHE_DIR, which both forked
+    # workers and the --join re-exec inherit (config only — this parent
+    # must never initialise a backend: a TPU belongs to one process)
     from .serving.warmcache import enable_compile_cache
-    cache_dir = enable_compile_cache(getattr(args, "compile_cache", None))
-    if cache_dir:
-        print(f"launch: compile cache {cache_dir}")
+    print(f"launch: compile cache {enable_compile_cache()}")
     if args.join:
         # join mode: THIS process becomes worker --process-id of an
         # existing cluster (one `launch --join` per host on a real pod)
@@ -1005,23 +1000,26 @@ def cmd_launch(args) -> int:
             path=os.path.join(trace_dir, "launcher.trace.json"),
             process_id=-1, process_name="launcher")
     chaos = _parse_chaos_worker(args.chaos_worker)
-    launcher = PodLauncher(
-        [_sys.executable, "-m", "deeplearning4j_tpu"] + rest,
-        num_workers=args.nprocs, run_dir=run_dir,
-        devices_per_worker=args.devices_per_proc,
-        chaos=chaos or None,
-        bootstrap=args.bootstrap,
-        heartbeat_timeout=args.heartbeat_timeout,
-        max_restarts=args.max_restarts,
-        deadline_s=args.deadline,
-        connect_timeout_s=args.connect_timeout,
-        megascale_slices=args.megascale_slices,
-        trace_dir=trace_dir,
-        grace_s=args.grace,
-        straggler_factor=args.straggler_factor,
-        straggler_beats=args.straggler_beats,
-        straggler_policy=args.straggler_policy,
-        serve=args.serve)
+    try:
+        launcher = PodLauncher(
+            [_sys.executable, "-m", "deeplearning4j_tpu"] + rest,
+            num_workers=args.nprocs, run_dir=run_dir,
+            devices_per_worker=args.devices_per_proc,
+            chaos=chaos or None,
+            bootstrap=args.bootstrap,
+            heartbeat_timeout=args.heartbeat_timeout,
+            max_restarts=args.max_restarts,
+            deadline_s=args.deadline,
+            connect_timeout_s=args.connect_timeout,
+            megascale_slices=args.megascale_slices,
+            trace_dir=trace_dir,
+            grace_s=args.grace,
+            straggler_factor=args.straggler_factor,
+            straggler_beats=args.straggler_beats,
+            straggler_policy=args.straggler_policy,
+            serve=args.serve)
+    except ValueError as e:   # e.g. more workers x chips than the TPU host has
+        raise SystemExit(f"launch: {e}")
     print(f"launch: {args.nprocs} worker(s) x "
           f"{args.devices_per_proc or 'default'} device(s), "
           f"bootstrap={args.bootstrap}, run dir {run_dir}"
@@ -1375,11 +1373,6 @@ def build_parser() -> argparse.ArgumentParser:
                    "trace JSON to PATH on exit (view in chrome://tracing "
                    "or ui.perfetto.dev; '{process}' expands to the worker "
                    "index; docs/OBSERVABILITY.md)")
-    t.add_argument("--compile-cache", metavar="DIR",
-                   help="persistent XLA compile cache (serving/warmcache.py): "
-                   "compiled executables are stored in DIR and later "
-                   "processes skip the compile (default: the "
-                   "DL4J_TPU_COMPILE_CACHE env var; unset = off)")
     t.add_argument("--grace", type=float, default=None, metavar="SECONDS",
                    help="preemption grace budget for --elastic-dir runs: "
                    "on SIGTERM/SIGUSR1 (a preemption notice) the next "
@@ -1399,7 +1392,8 @@ def build_parser() -> argparse.ArgumentParser:
     ln.add_argument("--devices-per-proc", type=int, default=None,
                     metavar="K", help="per-process device visibility: each "
                     "worker sees K devices (CPU: K virtual devices via "
-                    "XLA_FLAGS)")
+                    "XLA_FLAGS; TPU host: its own K chips, default 1 — "
+                    "more workers x chips than the host has is refused)")
     ln.add_argument("--bootstrap", choices=("replica", "distributed"),
                     default="replica",
                     help="'distributed' = workers form a jax.distributed "
@@ -1461,11 +1455,6 @@ def build_parser() -> argparse.ArgumentParser:
                     "across relaunch) and print the fleet endpoints — pair "
                     "with a 'serve' worker command and a `serve --fleet` "
                     "router (docs/SERVING.md 'Fleet serving')")
-    ln.add_argument("--compile-cache", metavar="DIR",
-                    help="export DL4J_TPU_COMPILE_CACHE=DIR to every worker: "
-                    "they share one persistent XLA compile cache, so a "
-                    "relaunched worker (or the whole next pod run) reuses "
-                    "executables instead of recompiling")
     ln.add_argument("--join", action="store_true",
                     help="join an existing cluster as one worker instead "
                     "of forking (one `launch --join` per host on a pod)")
@@ -1542,10 +1531,6 @@ def build_parser() -> argparse.ArgumentParser:
     v.add_argument("--breaker-threshold", type=int, default=3,
                    help="consecutive replica failures that trip its circuit "
                    "breaker (dispatch routes around it; default 3)")
-    v.add_argument("--compile-cache", metavar="DIR",
-                   help="persistent XLA compile cache (serving/warmcache.py): "
-                   "a restarted server reuses DIR's executables instead of "
-                   "cold-compiling (default: DL4J_TPU_COMPILE_CACHE env)")
     v.add_argument("--warm-bundle", metavar="PATH",
                    help="warmup bundle of serialized AOT executables to "
                    "deserialize at load (default: <checkpoint>.warm next to "

@@ -38,9 +38,9 @@ import jax
 import jax.numpy as jnp
 
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..utils.jax_compat import shard_map
 from .sequencevectors import _sg_pair_grads
 from .word2vec import Word2Vec
 

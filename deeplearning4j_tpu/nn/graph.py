@@ -957,9 +957,8 @@ class ComputationGraph:
     def _make_multi_step(self):
         """k optimizer steps fused into ONE dispatch via lax.scan over
         stacked batches — the graph-container twin of
-        MultiLayerNetwork._make_multi_step (round-4 verdict Next #5:
-        amortizes the per-step dispatch gap, the 12.6% device-IDLE bucket
-        in docs/transformer_profile.md, to 1/k).  Same rng-stream caveat
+        MultiLayerNetwork._make_multi_step (amortizes the per-step host
+        dispatch gap to 1/k).  Same rng-stream caveat
         as the MLN twin: one base split fanned to k keys, so stochastic
         runs differ from k sequential fit_batch calls."""
         def multi(params, state, opt_state, it0, inputs, labels, rng,

@@ -99,7 +99,7 @@ class Convolution2D(Layer):
         # NOTE: no checkpoint_name remat tag here — measured: the name
         # primitive blocks conv-epilogue fusion (~20% on LeNet) even with
         # no checkpoint policy active, and the save-only-conv-outputs
-        # policy itself lost to XLA's default (docs/resnet_profile.md).
+        # policy itself lost to XLA's default.
         if self.has_bias:
             y = y + params["b"].astype(x.dtype)
         return ForwardOut(self._act(y), state, mask)

@@ -66,7 +66,7 @@ class BatchNormalization(Layer):
                 # reductions.  jnp.var would upcast the whole activation
                 # and materialize (x−mean)² in f32 (and again in the
                 # transpose), doubling HBM traffic — the dominant cost of
-                # ResNet BN on TPU (docs/resnet_profile.md; +6% step).
+                # ResNet BN on TPU.
                 # Caveat: this form loses the spread when |mean|/std ≳ 1e²
                 # — but x itself carries an 8-bit mantissa here, so such
                 # channels are already unresolvable in bf16; full-precision

@@ -189,8 +189,8 @@ class Adam(Updater):
 
     ``moment_dtype`` (opt-in, e.g. "bfloat16") stores BOTH moments in a
     reduced dtype: the m/v read+write traffic is the dominant optimizer
-    HBM cost on large models (~3.9 GB/step ≈ 20 ms on the GPT-2-small
-    TransformerLM bench, docs/transformer_profile.md), and bf16 keeps
+    HBM cost on large models (~3.9 GB/step by byte count on the
+    GPT-2-small TransformerLM), and bf16 keeps
     f32's exponent range so v's dynamic range survives — only mantissa
     precision drops, quantified by tests/test_updaters_bf16.py.  The
     update math always runs in f32; only the carried state narrows."""

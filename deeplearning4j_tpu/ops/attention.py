@@ -8,7 +8,8 @@ support is therefore designed TPU-first per SURVEY §7-M5:
     oracle; XLA fuses it well at moderate sequence lengths).
   - ``flash_mha``: blockwise streaming-softmax attention as a pallas TPU
     kernel — O(T) memory instead of O(T²), tiles sized for the MXU, f32
-    accumulation.  Falls back to ``mha`` when shapes don't tile.
+    accumulation.  Falls back to ``mha`` when shapes don't tile (and
+    logs that it did when the backend is a tpu).
   - ``ring_attention`` (parallel/ring.py) reuses the same blockwise update
     rule across devices over the ``seq`` mesh axis.
 
@@ -23,19 +24,14 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..utils.jax_compat import vma_of
+from .pallas_support import fell_back, interpret
 
 Array = jax.Array
 
 _NEG_INF = -1e30  # large-finite: keeps padded/causal-masked rows NaN-free
-
-try:  # pallas ships in all jax wheels; guard anyway so mha still works
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
 
 
 # ---------------------------------------------------------------------------
@@ -114,7 +110,7 @@ def blockwise_update(acc, m, l, q, k, v, scale, bias=None):
     if q.dtype == jnp.float64:
         # f64 callers (ring-attention grad checks) run the matmuls at f32
         # with f32 statistics — the historical semantics of this function
-        # (the fused-kernel path excludes f64 entirely, _kernel_eligible)
+        # (the fused-kernel path excludes f64 entirely, _fallback_reason)
         q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
     nt = (((1,), (1,)), ((), ()))  # contract head_dim of both, no transpose
     s = jax.lax.dot_general(q, k, nt,
@@ -191,10 +187,19 @@ def _flash_kernel(q_ref, k_ref, v_ref, km_ref, o_ref, lse_ref,
         lse_ref[0, 0] = lse[:, 0].astype(lse_ref.dtype)
 
 
-def _pick_block(n: int, preferred: int = 128) -> int:
-    for b in (preferred, 64, 32, 16, 8):
-        if n % b == 0:
-            return b
+def _pick_block(n: int, dtype) -> int:
+    """Rows per tile along a sequence axis of length ``n``; 0 = no tiling.
+
+    Mosaic wants the last two dims of every block aligned to the dtype's
+    (sublane, 128) tile or equal to the array's: the [1, block, D] q/k/v
+    blocks put ``block`` on sublanes, the [1, 1, block] lse/mask rows put
+    it on LANES.  So a block is 128 rows, or — for short sequences — the
+    whole axis in one sublane-aligned tile."""
+    if n % 128 == 0:
+        return 128
+    sublane = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+    if n <= 512 and n % sublane == 0:
+        return n
     return 0
 
 
@@ -202,23 +207,20 @@ def _vma(x):
     """Varying-across-mesh-axes of ``x`` (frozenset; empty outside
     shard_map) — pallas out_shapes must carry it so the kernels trace
     under shard_map's check_vma (ulysses/pipelined attention)."""
-    return vma_of(x)
+    return jax.typeof(x).vma
 
 
 def _out_struct(shape, dtype, like):
-    """ShapeDtypeStruct carrying ``like``'s vma where the jax version
-    types it (pre-vma jax has no ``vma=`` kwarg and needs none)."""
-    try:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=_vma(like))
-    except TypeError:
-        return jax.ShapeDtypeStruct(shape, dtype)
+    """ShapeDtypeStruct carrying ``like``'s vma."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=_vma(like))
 
 
-def _kernel_eligible(q, block_q: int, block_k: int) -> bool:
-    """The kernel targets the TPU memory spaces; run it compiled on tpu,
-    interpreted on cpu (tests), and fall back to plain XLA elsewhere (gpu).
-    f64 also falls back: the kernel accumulates in f32 VMEM scratch, which
-    would silently degrade float64 gradient checks.
+def _fallback_reason(q, block_q: int, block_k: int) -> Optional[str]:
+    """Why the XLA path runs instead of the kernel (None = kernel runs).
+    The kernel targets the TPU memory spaces: compiled on tpu, interpreted
+    on cpu (tests), plain XLA elsewhere (gpu).  f64 also falls back: the
+    kernel accumulates in f32 VMEM scratch, which would silently degrade
+    float64 gradient checks.
 
     CPU + varying-across-mesh operands (inside shard_map) also fall back:
     jax 0.9's pallas HLO *interpreter* emits invariant slice indices
@@ -226,10 +228,15 @@ def _kernel_eligible(q, block_q: int, block_k: int) -> bool:
     rejects — the compiled TPU kernel carries vma through its out_shapes
     and passes the check, so only the interpreter needs the escape."""
     backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        return f"backend {backend}"
     if backend == "cpu" and _vma(q):
-        return False
-    return (_HAS_PALLAS and block_q > 0 and block_k > 0
-            and backend in ("tpu", "cpu") and q.dtype != jnp.float64)
+        return "pallas interpreter under shard_map"
+    if q.dtype == jnp.float64:
+        return "float64 inputs"
+    if not (block_q and block_k):
+        return "sequence length does not tile (see _pick_block)"
+    return None
 
 
 def _flash_forward(q: Array, k: Array, v: Array, kmask, causal: bool,
@@ -237,9 +244,11 @@ def _flash_forward(q: Array, k: Array, v: Array, kmask, causal: bool,
     """→ (o [B,H,T,D], lse [B*H,T] or None-on-fallback)."""
     B, H, T, D = q.shape
     S = k.shape[2]
-    block_q = _pick_block(T)
-    block_k = _pick_block(S)
-    if not _kernel_eligible(q, block_q, block_k):
+    block_q = _pick_block(T, q.dtype)
+    block_k = _pick_block(S, k.dtype)
+    why = _fallback_reason(q, block_q, block_k)
+    if why:
+        fell_back(f"flash_mha[T={T},S={S},{q.dtype}]", why)
         m = None if kmask is None else kmask[:, None, None, :]
         return mha(q, k, v, causal=causal, mask=m, scale=scale), None
 
@@ -256,8 +265,7 @@ def _flash_forward(q: Array, k: Array, v: Array, kmask, causal: bool,
     ]
     args = [qf, kf, vf]
     if kmask is not None:
-        # [B,1,S] row blocks (TPU pallas wants the last two block dims
-        # (8,128)-aligned or equal to the array's); batch = flat_bh // H
+        # [B,1,S] row blocks (block_k on the lane axis); batch = flat_bh // H
         in_specs.append(pl.BlockSpec((1, 1, block_k),
                                      lambda b, i, j, H=H: (b // H, 0, j)))
         args.append(kmask.astype(jnp.int32)[:, None, :])
@@ -283,7 +291,7 @@ def _flash_forward(q: Array, k: Array, v: Array, kmask, causal: bool,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        interpret=(jax.default_backend() == "cpu"),
+        interpret=interpret(),
     )(*args)
     return out.reshape(B, H, T, D), lse
 
@@ -399,17 +407,17 @@ def _flash_backward(q, k, v, kmask, o, lse, g, causal, scale):
     to the XLA recompute path when the forward did (lse is None)."""
     B, H, T, D = q.shape
     S = k.shape[2]
-    block_q = _pick_block(T)
-    block_k = _pick_block(S)
-    if lse is None or not _kernel_eligible(q, block_q, block_k):
+    if lse is None:   # the forward fell back (and said so on tpu)
         return _xla_attention_bwd(q, k, v, kmask, g, causal, scale)
+    block_q = _pick_block(T, q.dtype)
+    block_k = _pick_block(S, k.dtype)
 
     flat = lambda x: x.reshape(B * H, *x.shape[2:])
     qf, kf, vf, gf = flat(q), flat(k), flat(v), flat(g)
     # delta_i = Σ_d g_i·o_i — the softmax-jacobian row term (Dao 2023 eq. 4)
     delta = jnp.sum(gf.astype(jnp.float32) * flat(o).astype(jnp.float32),
                     axis=-1)[:, None, :]                       # [BH, 1, T]
-    interp = jax.default_backend() == "cpu"
+    interp = interpret()
 
     q_spec_i = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, j, 0))
     k_spec_o = pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, i, 0))

@@ -36,7 +36,6 @@ gradient's actual sparsity — the property the bench gate asserts.
 
 from __future__ import annotations
 
-import functools
 import math
 import os
 from typing import List, Optional
@@ -45,25 +44,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..utils.jax_compat import axis_size
-
 #: opt-in one-pass fixed-threshold encode (sort-free select+pack; see
 #: the "one-pass threshold encode" section).  Read once at import, like
 #: ops/update_kernel.ENABLED — checked at TRACE time.
 FUSED_ENCODE = os.environ.get("DL4J_TPU_FUSED_ENCODE", "0") == "1"
-#: route the one-pass encode through the pallas kernel instead of the
-#: fused-jnp streaming pass (the kernel is the TPU seam; streaming jnp
-#: is the arm the CPU A/B measures)
-FUSED_ENCODE_PALLAS = os.environ.get(
-    "DL4J_TPU_FUSED_ENCODE_PALLAS", "0") == "1"
-
-try:
-    from jax.experimental import pallas as pl
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
-
 METHODS = ("threshold", "bitmap")
 #: reference EncodingHandler default threshold (fixed-threshold mode)
 DEFAULT_THRESHOLD = 1e-3
@@ -100,11 +84,6 @@ def default_k_max(n: int) -> int:
 # largest-first selection.  Adaptive mode (threshold=None) genuinely
 # needs the k-th order statistic and always uses top_k.
 
-_ENC_LANES = 128
-#: pallas variant: single-block kernel, so cap the VMEM footprint
-_ENC_PALLAS_MAX_BYTES = 8 << 20
-
-
 def _topk_pack(g, mag, k: int, threshold):
     """The reference-exact fixed-mode pack: top_k over the masked
     magnitudes (largest-first selection under overflow)."""
@@ -125,50 +104,12 @@ def _streaming_pack(g, mag, k: int, threshold: float, n: int):
     return jnp.zeros((k,), jnp.int32).at[slot].set(payload, mode="drop")
 
 
-def _encode_kernel(g_ref, o_ref, *, k: int, k_pad: int, threshold: float,
-                   n: int):
-    g = g_ref[...].reshape(-1)          # row-major == original order
-    mag = jnp.abs(g)
-    idx = jax.lax.iota(jnp.int32, g.shape[0])
-    sel = (mag >= threshold) & (idx < n)   # zero padding never selects
-    pos = jnp.cumsum(sel.astype(jnp.int32)) - 1
-    payload = jnp.where(g >= 0, 1, -1).astype(jnp.int32) * (idx + 1)
-    slot = jnp.where(sel & (pos < k), pos, k_pad)
-    out = jnp.zeros((k_pad,), jnp.int32).at[slot].set(payload, mode="drop")
-    o_ref[...] = out.reshape(-1, _ENC_LANES)
-
-
-def _pallas_pack(g, k: int, threshold: float, n: int):
-    """Select+pack as ONE pallas pass over the whole (VMEM-resident)
-    bucket; interpret-mode on CPU.  Caller guarantees the size gate."""
-    pad = (-n) % (8 * _ENC_LANES)
-    rows = (n + pad) // _ENC_LANES
-    k_pad = k + ((-k) % _ENC_LANES)
-    out = pl.pallas_call(
-        functools.partial(_encode_kernel, k=k, k_pad=k_pad,
-                          threshold=threshold, n=n),
-        out_shape=jax.ShapeDtypeStruct((k_pad // _ENC_LANES, _ENC_LANES),
-                                       jnp.int32),
-        interpret=(jax.default_backend() == "cpu"),
-    )(jnp.pad(g, (0, pad)).reshape(rows, _ENC_LANES))
-    return out.reshape(-1)[:k]
-
-
-def _pallas_encode_ok(n: int) -> bool:
-    return (_HAS_PALLAS
-            and jax.default_backend() in ("tpu", "cpu")
-            and n >= 8 * _ENC_LANES
-            and 4 * n <= _ENC_PALLAS_MAX_BYTES)
-
-
 def _one_pass_threshold_encode(g, mag, k: int, threshold: float, n: int):
     """enc int32[k] via the sort-free path, falling back to the exact
     top_k pack inside lax.cond when more than k elements clear t."""
     count = jnp.sum((mag >= threshold).astype(jnp.int32))
 
     def fits(_):
-        if FUSED_ENCODE_PALLAS and _pallas_encode_ok(n):
-            return _pallas_pack(g, k, threshold, n)
         return _streaming_pack(g, mag, k, threshold, n)
 
     def overflow(_):
@@ -210,8 +151,8 @@ def threshold_encode(g, k_max: int, threshold: Optional[float] = None):
         if threshold <= 0:
             raise ValueError(f"threshold must be > 0, got {threshold}")
         scale = jnp.asarray(threshold, jnp.float32)
-        # one-pass path needs a static threshold (it is baked into the
-        # kernel); a traced threshold stays on the top_k path
+        # the one-pass path takes a static threshold; a traced threshold
+        # stays on the top_k path
         if FUSED_ENCODE and isinstance(threshold, (int, float)):
             # graftcheck: disable=GC101 (the isinstance guard above makes threshold a STATIC Python number here — a traced threshold takes the top_k branch)
             enc = _one_pass_threshold_encode(g, mag, k, float(threshold), n)
@@ -307,7 +248,7 @@ def compressed_pmean(g, axis_name: str, method: str = "threshold",
     if method not in METHODS:
         raise ValueError(f"method must be one of {METHODS}, got {method!r}")
     n = g.shape[0]
-    p = axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     if method == "threshold":
         k = k_max if k_max is not None else default_k_max(n)
         enc, scale = threshold_encode(g, k, threshold)

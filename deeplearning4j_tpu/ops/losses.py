@@ -241,8 +241,7 @@ def sparse_softmax_xent(logits: Array, targets: Array) -> Array:
     only per-row (max, log-sum-exp) f32 statistics (fused by XLA into
     streaming reductions over the bf16 logits) and the backward rebuilds
     ``softmax − onehot`` in the logits dtype from the saved lse — ~2.5×
-    less loss-region traffic, measured on the TransformerLM bench
-    (docs/transformer_profile.md).  No reference analog (DL4J's LossMCXENT
+    less loss-region traffic by byte count.  No reference analog (DL4J's LossMCXENT
     densifies labels; its vocab-scale path is sampled hierarchical
     softmax).
     """

@@ -29,6 +29,9 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+
+from .pallas_support import fell_back, interpret
 
 #: opt-in: XLA's scan-epilogue fusion beats the kernel at common sizes
 #: (see module docstring).  Set BEFORE the first trace of a model —
@@ -36,13 +39,8 @@ import jax.numpy as jnp
 #: keep whichever path they were traced with (clear jax caches to switch).
 ENABLED = os.environ.get("DL4J_TPU_FUSED_LSTM", "0") == "1"
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+#: rows per grid step (a multiple of the (8, 128) f32 sublane tile)
+_BLOCK_ROWS = 256
 
 
 def _plain_cell(z: jax.Array, c: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -91,22 +89,24 @@ def _bwd_kernel(z_ref, c_ref, dh_ref, dcn_ref, dz_out, dc_out, *, n: int):
 
 
 def _use_pallas(z: jax.Array, n: int) -> bool:
-    if not ENABLED or not _HAS_PALLAS or z.dtype == jnp.float64:
+    if not ENABLED or z.dtype == jnp.float64:
         return False
     if jax.default_backend() not in ("tpu", "cpu"):
         return False
-    # small widths don't fill the 128-wide VPU lanes — XLA's fused
-    # elementwise is already fine there, so keep the plain path
-    return n >= 128
+    mb = z.shape[0]
+    # the gate slices z[:, k*n:(k+1)*n] must start on a 128-lane boundary,
+    # and a row block is the whole batch or an aligned 256-row tile
+    if n % 128 or (mb > _BLOCK_ROWS and mb % _BLOCK_ROWS):
+        fell_back("fused_lstm_cell",
+                  f"width {n} / batch {mb} do not tile (need n % 128 == 0 "
+                  f"and batch <= {_BLOCK_ROWS} or a multiple of it)")
+        return False
+    return True
 
 
 def _pallas_call(kernel, z, *args, out_shapes, n):
     mb = z.shape[0]
-    bm = mb if mb <= 256 else 256
-    while mb % bm:
-        bm -= 1
-    if bm < 8:   # prime/odd batches → degenerate 1-row tiles; caller falls back
-        return None
+    bm = min(mb, _BLOCK_ROWS)      # _use_pallas: bm divides mb
     grid = (mb // bm,)
 
     def spec(width):
@@ -119,7 +119,7 @@ def _pallas_call(kernel, z, *args, out_shapes, n):
         in_specs=[spec(w) for w in widths],
         out_specs=[spec(s[1]) for s in out_shapes],
         out_shape=[jax.ShapeDtypeStruct(s, z.dtype) for s in out_shapes],
-        interpret=(jax.default_backend() == "cpu"),
+        interpret=interpret(),
     )(z, *args)
 
 
@@ -133,8 +133,6 @@ def fused_lstm_cell(z: jax.Array, c: jax.Array) -> Tuple[jax.Array, jax.Array]:
         return _plain_cell(z, c)
     out = _pallas_call(_fwd_kernel, z, c,
                        out_shapes=[(z.shape[0], n), (z.shape[0], n)], n=n)
-    if out is None:   # no viable batch tiling
-        return _plain_cell(z, c)
     return out[0], out[1]
 
 
@@ -154,8 +152,6 @@ def _cell_bwd(res, cts):
         return _bwd_math(z, c, dh, dcn)   # exact, f64-safe
     out = _pallas_call(_bwd_kernel, z, c, dh, dcn,
                        out_shapes=[z.shape, c.shape], n=n)
-    if out is None:
-        return _bwd_math(z, c, dh, dcn)
     return out[0], out[1]
 
 

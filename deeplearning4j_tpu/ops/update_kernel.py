@@ -10,8 +10,8 @@ EMAs, bias corrections, the step, and the param subtract — in one pass:
 
   pallas    one VMEM-resident kernel over (rows, 128) tiles (TPU compiled,
             interpret-mode on CPU for tests)
-  flat-jnp  the plain-jnp fallback over the same flat buffers (f64, other
-            backends, tile-unfriendly sizes, or DL4J_TPU_FUSED_UPDATE_JNP=1
+  flat-jnp  the plain-jnp fallback over the same flat buffers (other
+            backends, under one tile, or DL4J_TPU_FUSED_UPDATE_JNP=1
             — also the CPU A/B arm that isolates the flat-bucketing win
             from the kernel itself)
 
@@ -43,8 +43,11 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..obs import trace as obs_trace
+from .pallas_support import fell_back, interpret
 
 #: opt-in, read once at import (the lstm_kernel.ENABLED pattern): set
 #: BEFORE the first trace of a step — already-jitted executables keep
@@ -53,16 +56,10 @@ ENABLED = os.environ.get("DL4J_TPU_FUSED_UPDATE", "0") == "1"
 #: force the flat-jnp arm even where pallas is usable (A/B isolation).
 FORCE_JNP = os.environ.get("DL4J_TPU_FUSED_UPDATE_JNP", "0") == "1"
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
-
 _LANES = 128
-#: flat buffers are padded to a whole number of (8, 128) f32 tiles
+#: rows per grid step: 256 x 128 f32 = 128 KiB per operand block
+_BLOCK_ROWS = 256
+#: below one (8, 128) f32 tile the flat-jnp path is already one fused HLO
 _TILE = 8 * _LANES
 
 
@@ -94,31 +91,29 @@ def _kernel(p_ref, g_ref, m_ref, v_ref, sc_ref, p_out, m_out, v_out, *,
     v_out[...] = v_new
 
 
-def _use_pallas(n: int, leaves) -> bool:
-    if not _HAS_PALLAS or FORCE_JNP:
+def _use_pallas(n: int) -> bool:
+    """Caller has already excluded f64 trees (fused_apply)."""
+    if FORCE_JNP:
         return False
     if jax.default_backend() not in ("tpu", "cpu"):
         return False
-    if any(l.dtype == jnp.float64 for l in leaves):
+    if n < _TILE:
+        fell_back("fused_update", f"{n} elements is under one tile")
         return False
-    # below one tile the flat-jnp path is already a single fused HLO
-    return n >= _TILE
+    return True
 
 
 def _pallas_flat(kind: str, flat_p, flat_g, flat_m, flat_v, scalars,
                  beta1: float, beta2: float, eps: float):
     """One kernel over the padded flat buffers; returns f32 flats
-    (p_new, m_new, v_new) of the original length, or None when no viable
-    row tiling exists (caller falls back to flat-jnp)."""
+    (p_new, m_new, v_new) of the original length.  The buffers are padded
+    to a whole number of row blocks, so every block is (8, 128)-aligned
+    whatever ``n`` is (the pad lanes compute on zeros and are sliced off)."""
     n = flat_p.shape[0]
-    pad = (-n) % _TILE
-    rows = (n + pad) // _LANES
-
-    bm = rows if rows <= 256 else 256
-    while rows % bm:
-        bm -= 1
-    if bm < 8:   # degenerate tiles; caller falls back
-        return None
+    rows = -(-n // _TILE) * 8
+    bm = min(rows, _BLOCK_ROWS)
+    rows = -(-rows // bm) * bm
+    pad = rows * _LANES - n
     grid = (rows // bm,)
 
     def shape2(a):
@@ -133,7 +128,7 @@ def _pallas_flat(kind: str, flat_p, flat_g, flat_m, flat_v, scalars,
                   pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=[spec, spec, spec],
         out_shape=[jax.ShapeDtypeStruct((rows, _LANES), jnp.float32)] * 3,
-        interpret=(jax.default_backend() == "cpu"),
+        interpret=interpret(),
     )(shape2(flat_p), shape2(flat_g), shape2(flat_m), shape2(flat_v),
       scalars)
     return tuple(o.reshape(-1)[:n] for o in out)
@@ -184,12 +179,11 @@ def fused_apply(kind: str, updater, params, grads, state, it):
     flat_m, flat_v = flat(m_leaves), flat(v_leaves)
     n = flat_p.shape[0]
 
-    out = None
-    if _use_pallas(n, every):
+    if _use_pallas(n):
         scalars = jnp.stack([lr.astype(jnp.float32), bc1, bc2])
         out = _pallas_flat(kind, flat_p, flat_g, flat_m, flat_v, scalars,
                            updater.beta1, updater.beta2, updater.eps)
-    if out is None:   # flat-jnp fallback: same math, one fused flat pass
+    else:   # flat-jnp: same math, one fused flat pass
         out = _update_math(kind, flat_p, flat_g, flat_m, flat_v,
                            lr, bc1, bc2,
                            updater.beta1, updater.beta2, updater.eps)

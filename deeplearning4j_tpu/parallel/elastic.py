@@ -430,7 +430,7 @@ class ElasticTrainer:
     workers restarting in lockstep re-stampede the very storage/network
     that just failed; the jitter decorrelates them.  ``step_timeout``
     arms a wall-clock watchdog: a dispatch that neither completes nor
-    raises (hung collective, dead tunnel) is converted into a recoverable
+    raises (hung collective, lost device) is converted into a recoverable
     :class:`StepHangError` instead of blocking forever.  ``sleep_fn`` /
     ``clock`` are injectable so recovery timing is testable with a fake
     clock (tests/test_chaos.py).
@@ -611,7 +611,7 @@ class ElasticTrainer:
     def _materialize(self, loss) -> None:
         """Force the device barrier (``loss.value()``), under the watchdog
         when ``step_timeout`` is armed: a dispatch that never completes
-        (hung collective, dead device tunnel) raises neither — the read
+        (hung collective, lost device) raises neither — the read
         just blocks.  Running the read on a worker thread bounds the wait;
         on timeout the worker is abandoned (it stays parked on the dead
         dispatch) and the step surfaces as a recoverable StepHangError."""

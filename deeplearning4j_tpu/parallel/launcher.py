@@ -8,7 +8,8 @@ TPU-supercomputer retrospective frames preemption-tolerant pod training
 as THE production problem):
 
 - :class:`PodLauncher` — forks N worker processes (the CLI ``launch``
-  subcommand's engine), sets per-process device visibility and the
+  subcommand's engine), sets per-process device visibility (virtual CPU
+  devices via XLA_FLAGS; on a TPU host each worker's own chips) and the
   ``DL4J_TPU_*`` env contract, monitors liveness, and RELAUNCHES workers
   that die or hang — host leave → join, with a bounded restart budget
   and a leak check that no orphan worker survives a run.
@@ -38,6 +39,7 @@ can work: on the CPU backend it falls back to replica.
 
 from __future__ import annotations
 
+import glob
 import json
 import logging
 import os
@@ -545,6 +547,19 @@ def _with_device_count(xla_flags: str, count: int) -> str:
     return " ".join(kept)
 
 
+#: libtpu's per-process chip-grid bounds for K chips of one host
+_TPU_CHIP_BOUNDS = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1"}
+
+
+def tpu_chips() -> int:
+    """TPU chips a process on this host could open — counted from the
+    device nodes libtpu scans, WITHOUT opening one: a chip belongs to one
+    process at a time, and a launcher that initialised a backend to count
+    them would hold every chip its workers need."""
+    return (len(glob.glob("/dev/vfio/[0-9]*"))
+            or len(glob.glob("/dev/accel[0-9]*")))
+
+
 class _WorkerHandle:
     def __init__(self, process_id: int):
         self.process_id = process_id
@@ -633,6 +648,7 @@ class PodLauncher:
         self.connect_timeout_s = connect_timeout_s
         self.platform = platform
         self.megascale_slices = megascale_slices
+        self._chips_per_worker = self._plan_tpu_chips()
         # when set, workers write per-incarnation Chrome traces here (the
         # DL4J_TPU_TRACE_DIR contract) and merge_trace() stitches them —
         # plus the launcher's own membership/leave/join instants — into
@@ -691,6 +707,42 @@ class PodLauncher:
             "launcher_grace_escalations_total")
         reg.register_collector("launcher", self.stats, unique=True)
 
+    def _plan_tpu_chips(self) -> int:
+        """Chips each worker gets on a TPU host (0 = not a TPU launch).
+
+        Unrestricted, EVERY worker would claim every chip: the first wins
+        and the second fails or hangs.  So each worker is handed its own
+        ``devices_per_worker`` (default 1) chips — and a launch that
+        cannot be laid out that way is refused here, at once, instead of
+        hanging on the second worker.  (A lone worker with no explicit
+        count keeps the whole host: one process may drive every chip.)"""
+        wanted = self.platform or self.base_env.get("JAX_PLATFORMS", "")
+        chips = tpu_chips()
+        if not chips or wanted.split(",")[0] == "cpu":
+            return 0
+        if self.num_workers == 1 and not self.devices_per_worker:
+            return 0
+        per = self.devices_per_worker or 1
+        if per not in _TPU_CHIP_BOUNDS:
+            raise ValueError(
+                f"devices_per_worker={per} on a TPU host: a worker takes "
+                f"{sorted(_TPU_CHIP_BOUNDS)} chips")
+        if self.num_workers * per > chips:
+            raise ValueError(
+                f"{self.num_workers} worker(s) x {per} chip(s) need "
+                f"{self.num_workers * per} TPU chips; this host has {chips} "
+                "and a chip belongs to one process at a time — lower "
+                "--nprocs/--devices-per-proc, or pin the workers to CPU "
+                "(JAX_PLATFORMS=cpu)")
+        if self.bootstrap == "distributed" and self.num_workers > 1:
+            raise ValueError(
+                "bootstrap='distributed' with several workers on ONE TPU "
+                "host is not wired: each worker is given its own chips as "
+                "a stand-alone device set, which jax.distributed cannot "
+                "join into one mesh — drive all the host's chips from one "
+                "process (--nprocs 1), or use bootstrap='replica'")
+        return per
+
     def stats(self) -> dict:
         """Membership/fleet counters (the registry collector view — this
         is what ``/metrics`` shows under ``registry.collected.launcher``):
@@ -746,6 +798,15 @@ class PodLauncher:
         if self.devices_per_worker:
             env["XLA_FLAGS"] = _with_device_count(
                 env.get("XLA_FLAGS", ""), self.devices_per_worker)
+        if self._chips_per_worker:
+            # per-worker chip visibility (libtpu's own variables): worker
+            # i sees chips [i*K, (i+1)*K) as a stand-alone K-chip host
+            k = self._chips_per_worker
+            env["TPU_VISIBLE_CHIPS"] = ",".join(
+                str(c) for c in range(h.process_id * k,
+                                      (h.process_id + 1) * k))
+            env["TPU_CHIPS_PER_PROCESS_BOUNDS"] = _TPU_CHIP_BOUNDS[k]
+            env["TPU_PROCESS_BOUNDS"] = "1,1,1"
         if self.platform:
             env["JAX_PLATFORMS"] = self.platform
         env[ENV_GRACE_S] = str(self.grace_s)

@@ -28,14 +28,19 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..utils.jax_compat import vary_over  # noqa: F401  (re-export: the
-# historical home of vary_over; pipeline/ring import it from here)
 
 DATA_AXIS = "data"
 MODEL_AXIS = "model"
 SEQ_AXIS = "seq"
 PIPE_AXIS = "pipe"
 DCN_AXIS = "dcn"
+
+
+def vary_over(x, axes):
+    """Mark ``x`` as device-varying over the ``axes`` it isn't already
+    varying on (shard_map vma typing for zero-init scan carries)."""
+    need = tuple(a for a in axes if a not in jax.typeof(x).vma)
+    return jax.lax.pcast(x, need, to="varying") if need else x
 
 
 def build_mesh(axes: Optional[Dict[str, int]] = None,

@@ -32,8 +32,8 @@ import jax
 import jax.numpy as jnp
 
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from ..utils.jax_compat import shard_map
 
 Array = jax.Array
 

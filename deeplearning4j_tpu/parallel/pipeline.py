@@ -33,8 +33,7 @@ Both schedules compose with the other mesh axes: batch stays sharded on
 ``data``/``seq``, and block_fn may use collectives (ring attention on
 ``seq``, TP psums on ``model``).  The 1f1b backward takes ``jax.vjp`` OF
 the shard_map'd stage step — never inside it — so the shard_map
-transpose machinery inserts the data/seq/model grad collectives on every
-jax version the framework supports (utils/jax_compat.py).
+transpose machinery inserts the data/seq/model grad collectives.
 """
 
 from __future__ import annotations
@@ -44,9 +43,9 @@ from typing import Any, Callable, Dict
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..utils.jax_compat import shard_map, vma_of
 from .mesh import vary_over
 
 logger = logging.getLogger("deeplearning4j_tpu")
@@ -189,9 +188,8 @@ def _spec_axes(batch_spec):
 def _clear_extra_vma(out, batch_spec, axis):
     """Activations may be typed varying over axes block_fn reduced over
     (e.g. TP psums on "model" leave replicated-but-varying values);
-    pmean over axes absent from the output spec clears the variance
-    (no-op on jax without vma typing — the values are replicated)."""
-    extra = tuple(n for n in vma_of(out)
+    pmean over axes absent from the output spec clears the variance."""
+    extra = tuple(n for n in jax.typeof(out).vma
                   if n != axis and n not in _spec_axes(batch_spec))
     if extra:
         out = jax.lax.pmean(out, extra)
@@ -257,11 +255,13 @@ def _stage_step_fn(block_fn, mesh, axis, batch_spec, param_spec):
     [n_pipe, microbatch, ...] activation stack.  The 1f1b backward takes
     ``jax.vjp`` of THIS function, so grad collectives (data/seq psums for
     params, TP transposes inside block_fn) are inserted by the shard_map
-    transpose — correct on every supported jax."""
+    transpose."""
     hspec = P(axis, *tuple(batch_spec))
 
     def tick(params_local, h_stk):   # h_stk [1, mb_local, ...] per device
-        h = h_stk[0]
+        # the scan carry must enter with the varying-axes type block_fn
+        # returns (same reason as _gpipe_fn's zero-init buffers)
+        h = vary_over(h_stk[0], mesh.axis_names)
 
         def f(h, p):
             return block_fn(p, h), None

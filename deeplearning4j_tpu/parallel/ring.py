@@ -21,11 +21,11 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.attention import _NEG_INF, blockwise_update, causal_bias
 from .mesh import vary_over
-from ..utils.jax_compat import axis_size, shard_map, vma_of
 
 Array = jax.Array
 
@@ -43,7 +43,7 @@ def ring_attention(q: Array, k: Array, v: Array, axis_name: str,
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     b, h, t, d = q.shape
 
@@ -72,7 +72,7 @@ def ring_attention(q: Array, k: Array, v: Array, axis_name: str,
     # inputs vary on (shard_map's vma typing: the scan carry must match the
     # loop body's type) — q may additionally vary over data/model/pipe when
     # ring attention runs inside a larger manual region
-    vary = tuple(set(vma_of(q)) | {axis_name})
+    vary = tuple(set(jax.typeof(q).vma) | {axis_name})
     acc0 = vary_over(jnp.zeros((b * h, t, d), jnp.float32), vary)
     m0 = vary_over(jnp.full((b * h, t, 1), _NEG_INF, jnp.float32), vary)
     l0 = vary_over(jnp.zeros((b * h, t, 1), jnp.float32), vary)

@@ -35,11 +35,11 @@ if TYPE_CHECKING:
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, set_mesh
 
 from ..datasets.dataset import DataSet
 from ..obs import trace as obs_trace
-from ..utils.jax_compat import set_mesh, shard_map
 from ..datasets.iterators import DataSetIterator
 from .mesh import (
     DATA_AXIS, DCN_AXIS, MODEL_AXIS, build_mesh, build_two_tier_mesh,

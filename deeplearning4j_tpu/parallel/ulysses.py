@@ -25,11 +25,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.attention import flash_mha
-from ..utils.jax_compat import axis_size, shard_map
 
 Array = jax.Array
 
@@ -45,7 +44,7 @@ def ulysses_attention(q: Array, k: Array, v: Array, axis_name: str,
     local slice of the key-padding mask.  H must divide by the axis size.
     Returns [B, H, T_local, D] sharded the same way.
     """
-    p = axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     h = q.shape[1]
     if h % p:
         raise ValueError(f"n_heads {h} not divisible by '{axis_name}' axis "

@@ -28,7 +28,7 @@ remains as a thin back-compat shim over this engine).  Pieces:
                prefill/decode disaggregation (role=..., PrefillHandoff
                KV-page transfer) + tensor-parallel sharded decode
   warmcache.py zero-cold-start: process-wide JAX persistent compile
-               cache (DL4J_TPU_COMPILE_CACHE / --compile-cache) +
+               cache (JAX_COMPILATION_CACHE_DIR, else .cache/) +
                warmup bundles (serialized AOT executables next to the
                checkpoint zip; silent fallback to compile on any miss)
   autoscale.py load-driven replica autoscaling controller (hysteresis +

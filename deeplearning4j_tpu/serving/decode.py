@@ -570,7 +570,22 @@ class DecodeEngine:
                              prog.n_heads, prog.d_head,
                              kv_dtype=self._kv_dtype)
         bundle_mesh = self._mesh if getattr(prog, "tp", 1) > 1 else None
-        bundle = (load_bundle(warm_bundle, mesh=bundle_mesh)
+        if bundle_mesh is not None:
+            # the pool is head-sharded from its first byte (the program's
+            # pool spec), not parked whole on one device until a step
+            # happens to reshard it
+            from jax.sharding import NamedSharding, PartitionSpec
+            kp, vp = jax.device_put((kp, vp), NamedSharding(
+                bundle_mesh, PartitionSpec(None, None, None, "data", None)))
+        # reset/scrub return the pool where they found it: left to itself
+        # XLA parks their (input-independent) zeros on one device
+        pool_sh = jax.tree_util.tree_map(lambda a: a.sharding, (kp, vp))
+        # a one-device LM's executables are committed to ITS device,
+        # which on a multi-chip host need not be the first local one
+        bundle_devices = (None if self._mesh is None
+                          else list(self._mesh.devices.flat))
+        bundle = (load_bundle(warm_bundle, mesh=bundle_mesh,
+                              devices=bundle_devices)
                   if warm_bundle else {})
         hits = misses = 0
 
@@ -695,11 +710,12 @@ class DecodeEngine:
                 return scrub_pool(k, ids), scrub_pool(v, ids)
 
             reset_c = _get("reset", lambda: jax.jit(
-                _reset, donate_argnums=(0, 1)).lower(kp, vp).compile())
+                _reset, donate_argnums=(0, 1),
+                out_shardings=pool_sh).lower(kp, vp).compile())
             kp, vp = reset_c(kp, vp)
             self._compiled[("reset",)] = reset_c
             scrub_c = _get("scrub", lambda: jax.jit(
-                _scrub, donate_argnums=(0, 1)).lower(
+                _scrub, donate_argnums=(0, 1), out_shardings=pool_sh).lower(
                     kp, vp, np.zeros((pps,), np.int32)).compile())
             kp, vp = scrub_c(kp, vp, np.zeros((pps,), np.int32))
             self._compiled[("scrub",)] = scrub_c

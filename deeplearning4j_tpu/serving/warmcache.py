@@ -2,12 +2,12 @@
 
 Two independent mechanisms, both optional and both silent-on-miss:
 
-1. **Persistent compilation cache** — `enable_compile_cache()` points the
-   process-wide JAX compilation cache at a directory (explicit argument
-   wins, else the ``DL4J_TPU_COMPILE_CACHE`` env var).  Every
-   ``jax.jit`` compile in the process — train, serve, launch workers,
-   bench — then reads/writes XLA executables on disk, so a respawned
-   process recompiles nothing it has compiled before.
+1. **Persistent compilation cache** — `enable_compile_cache()` turns on
+   the process-wide JAX compilation cache at the directory
+   ``JAX_COMPILATION_CACHE_DIR`` names, else ``<checkout>/.cache/jax-compile``.
+   Every ``jax.jit`` compile in the process — train, serve, launch
+   workers, bench — then reads/writes XLA executables on disk, so a
+   respawned process recompiles nothing it has compiled before.
 
 2. **Warmup bundles** — explicit AOT executables serialized with
    ``jax.experimental.serialize_executable`` into a zip written next to
@@ -46,15 +46,20 @@ import os
 import pickle
 import warnings
 import zipfile
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Sequence
 
 import jax
 
-ENV_VAR = "DL4J_TPU_COMPILE_CACHE"
+#: jax's own variable: where it is set, jax has already read it and the
+#: program sets no other directory in code
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+#: the fixed fallback — never a temp name, pid or time, so a second run
+#: of the same checkout finds what the first one compiled
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".cache", "jax-compile")
 BUNDLE_FORMAT_VERSION = 1
 BUNDLE_SUFFIX = ".warm"
-
-_enabled_dir: Optional[str] = None
 
 
 def _harden_cache_writes() -> None:
@@ -69,12 +74,8 @@ def _harden_cache_writes() -> None:
     either absent or complete, never partial.  Identical concurrent
     writers are benign (same HLO key ⇒ same bytes; last rename wins).
     """
-    try:
-        from jax._src import lru_cache as _lru
-    except Exception:
-        # best-effort: a jax without this private module keeps stock
-        # writes — the cache still works, just unhardened
-        return
+    from jax._src import lru_cache as _lru
+
     if getattr(_lru.LRUCache.put, "_dl4j_atomic", False):
         return
 
@@ -92,39 +93,28 @@ def _harden_cache_writes() -> None:
     _lru.LRUCache.put = _atomic_put
 
 
-def enable_compile_cache(cache_dir: Optional[str] = None) -> Optional[str]:
-    """Enable the JAX persistent compilation cache process-wide.
+def enable_compile_cache() -> str:
+    """Use the JAX persistent compilation cache process-wide; returns
+    the directory in effect.
 
-    Explicit ``cache_dir`` wins; otherwise the ``DL4J_TPU_COMPILE_CACHE``
-    env var; if neither is set this is a no-op returning None.  The
-    min-compile-time threshold is dropped to 0 so even the small CPU
-    test executables persist.  The env var is (re)exported so forked
-    workers (``launch``) inherit the setting.  Idempotent.
+    The directory is placed from OUTSIDE: where ``JAX_COMPILATION_CACHE_DIR``
+    is set, jax read it at import and nothing here overrides it; where it
+    is not, the cache is ``<checkout>/.cache/jax-compile`` (git-ignored)
+    and the variable is exported so forked workers (``launch``) share it.
+    Whether the cache is on at all stays jax's own switch
+    (``JAX_ENABLE_COMPILATION_CACHE=false`` turns it off).  The
+    min-compile-time threshold is dropped to 0 so even small executables
+    persist.  Idempotent.
     """
-    global _enabled_dir
-    d = cache_dir or os.environ.get(ENV_VAR)
-    if not d:
-        return None
-    d = os.path.abspath(d)
-    if _enabled_dir == d:
-        return d
     _harden_cache_writes()
-    os.makedirs(d, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", d)
+    d = os.environ.get(CACHE_ENV) or DEFAULT_CACHE_DIR
+    if jax.config.jax_compilation_cache_dir != d:
+        # the fallback — or a variable exported after jax was imported,
+        # which jax has not seen yet; never a different directory
+        os.makedirs(d, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", d)
+    os.environ[CACHE_ENV] = d
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    # jax latches the cache state at the process's FIRST compile: if one
-    # already happened (e.g. the cache is enabled mid-run), the new dir
-    # is ignored until the cache re-initializes — force that here
-    try:
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _cc,
-        )
-        _cc.reset_cache()
-    # graftcheck: disable=GC404 (best-effort: a jax build without reset_cache keeps the first-compile latch; the dir is still set for up-front enables)
-    except Exception:
-        pass
-    os.environ[ENV_VAR] = d
-    _enabled_dir = d
     return d
 
 
@@ -177,7 +167,14 @@ def save_bundle(path: str, tag: str, entries: Dict[str, Any],
     names: Dict[str, str] = {}
     blobs: Dict[str, bytes] = {}
     for i, key in enumerate(sorted(entries)):
-        payload, in_tree, out_tree = _se.serialize(entries[key])
+        try:
+            payload, in_tree, out_tree = _se.serialize(entries[key])
+        except jax.errors.JaxRuntimeError as e:
+            # a backend limit, not a bundle bug: XLA:CPU cannot serialize
+            # a sort comparator (the samplers' top-k); the TPU client can
+            raise RuntimeError(
+                f"warmup bundle entry {key!r} is not serializable on the "
+                f"{jax.default_backend()} backend: {e}") from e
         ename = f"exec_{i}.bin"
         names[ename] = key
         blobs[ename] = pickle.dumps((payload, in_tree, out_tree))
@@ -203,7 +200,8 @@ class _BundleMiss(Exception):
 
 
 def load_bundle(path: Optional[str], tag: Optional[str] = None,
-                mesh: Optional[Any] = None) -> Dict[str, Any]:
+                mesh: Optional[Any] = None,
+                devices: Optional[Sequence[Any]] = None) -> Dict[str, Any]:
     """Load a warmup bundle; return {} on ANY miss, never raise.
 
     An absent file is the normal cold-start case and stays silent.  An
@@ -214,9 +212,18 @@ def load_bundle(path: Optional[str], tag: Optional[str] = None,
     match what the bundle was saved with (the fingerprint carries the
     mesh topology component) — a differently-meshed bundle falls back
     to compile under the same one-warning contract.
+
+    ``devices``: the devices the executables will run on — by default
+    the mesh's, else the first local device (the serving engines' lead
+    device).  Without it jax loads each executable for EVERY visible
+    device, and a one-device program then refuses its one-shard
+    arguments on any host with more than one device.
     """
     if not path or not os.path.exists(path):
         return {}
+    if devices is None:
+        devices = (list(mesh.devices.flat) if mesh is not None
+                   else jax.local_devices()[:1])
     from jax.experimental import serialize_executable as _se
 
     try:
@@ -244,7 +251,9 @@ def load_bundle(path: Optional[str], tag: Optional[str] = None,
                 if integrity.get(ename) != hashlib.sha256(blob).hexdigest():
                     raise _BundleMiss(f"integrity mismatch on {ename}")
                 payload, in_tree, out_tree = pickle.loads(blob)
-                out[key] = _se.deserialize_and_load(payload, in_tree, out_tree)
+                out[key] = _se.deserialize_and_load(
+                    payload, in_tree, out_tree,
+                    execution_devices=list(devices))
             return out
     except Exception as exc:  # noqa: BLE001 — fallback-to-compile contract:
         # any unusable bundle must degrade to a cold compile, not an error.

@@ -7,9 +7,8 @@ Usage: python hlo_probe.py <tree> <tag> [program]
 Programs:
   lenet (default)   the LeNet bench step (histogram only, no assertions)
   fused_update      the fused Adam update (ops/update_kernel.py)
-  one_pass_encode   the one-pass threshold encode (ops/compression.py)
 
-For the two pallas programs the probe asserts the landing actually
+For the pallas program the probe asserts the landing actually
 happened structurally — the failure mode being a silently-fallen-back
 kernel that still passes parity tests:
 
@@ -17,8 +16,7 @@ kernel that still passes parity tests:
     including lax.cond branches — interpret-mode lowering erases the op
     from compiled CPU HLO, so the jaxpr is where the claim is checkable
     on every backend);
-  * the pallas branch contains no sort (the whole point is removing it —
-    for the encode, sort may appear ONLY in the cond's overflow branch);
+  * the program contains no sort;
   * no transpose equations and no stray convert PAIRS (a convert whose
     input is itself a convert — a round trip the flat f32 layout should
     never need).
@@ -77,12 +75,10 @@ def convert_pairs(jaxpr) -> int:
     return pairs
 
 
-def assert_pallas_structure(jaxpr, out: dict, allow_sort_in_overflow: bool):
+def assert_pallas_structure(jaxpr, out: dict):
     out["pallas_calls"] = count_primitive(jaxpr, "pallas_call")
     out["transposes_jaxpr"] = count_primitive(jaxpr, "transpose")
     out["convert_pairs"] = convert_pairs(jaxpr)
-    # top_k is the sort-backed selection this work removes; count both
-    # the generic sort and the top_k primitive
     out["sorts"] = (count_primitive(jaxpr, "sort")
                     + count_primitive(jaxpr, "top_k"))
     errs = []
@@ -93,19 +89,8 @@ def assert_pallas_structure(jaxpr, out: dict, allow_sort_in_overflow: bool):
         errs.append(f"{out['transposes_jaxpr']} stray transpose(s)")
     if out["convert_pairs"]:
         errs.append(f"{out['convert_pairs']} stray convert pair(s)")
-    if out["sorts"] and not allow_sort_in_overflow:
+    if out["sorts"]:
         errs.append(f"{out['sorts']} sort(s) in a sort-free program")
-    if allow_sort_in_overflow and out["sorts"]:
-        # the sort may live ONLY in the cond's overflow branch, never
-        # alongside the pallas_call
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name != "cond":
-                continue
-            for sub in _sub_jaxprs(eqn):
-                if (count_primitive(sub, "pallas_call")
-                        and (count_primitive(sub, "sort")
-                             + count_primitive(sub, "top_k"))):
-                    errs.append("sort found in the PALLAS branch of cond")
     if errs:
         print(json.dumps({"tag": tag, "program": program,
                           "structure_ok": False, "errors": errs, **out}))
@@ -133,25 +118,7 @@ if program == "fused_update":
 
     jaxpr = jax.make_jaxpr(fn)(params, params, state, it).jaxpr
     out = {"tag": tag, "program": program}
-    assert_pallas_structure(jaxpr, out, allow_sort_in_overflow=False)
-    print(json.dumps(out))
-    raise SystemExit(0)
-
-if program == "one_pass_encode":
-    from deeplearning4j_tpu.ops import compression
-
-    compression.FUSED_ENCODE = True
-    compression.FUSED_ENCODE_PALLAS = True
-    n = 1 << 17
-    k = compression.default_k_max(n)
-    g = jnp.zeros((n,), jnp.float32)
-
-    def fn(gg):
-        return compression.threshold_encode(gg, k, threshold=1e-3)
-
-    jaxpr = jax.make_jaxpr(fn)(g).jaxpr
-    out = {"tag": tag, "program": program}
-    assert_pallas_structure(jaxpr, out, allow_sort_in_overflow=True)
+    assert_pallas_structure(jaxpr, out)
     print(json.dumps(out))
     raise SystemExit(0)
 

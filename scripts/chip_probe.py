@@ -1,0 +1,332 @@
+"""Bring-up probes for the attached TPU: one process, facts only.
+
+Run on the chip (it refuses anywhere else) to re-establish what CHANGES.md
+records about the installation:
+
+  kernels    every ``pl.pallas_call`` in ops/ compiled at its A/B script's
+             shape and checked against its XLA reference — compiled /
+             refused, with the compiler's message for a refusal
+  precision  what an f32 matmul is on the MXU at default precision
+  flash_xla  one LM train step each with attention_impl "flash" and "xla"
+             at the chip_smoke width (one run each — a finding, not a
+             benchmark)
+  bundle     whether ``serialize`` works for a decode warm bundle here, and
+             the bundle round trip on the device count at hand
+  gspmd      (>1 chip) what plain jit does with a batch-sharded flash call
+
+    python scripts/chip_probe.py [section ...]      # default: all
+
+Writes ``chiprun_out/chip_probe.json``; the last stdout line is the same
+JSON.
+"""
+import json
+import os
+import sys
+import time
+import traceback
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, _REPO)
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+
+
+def _time(fn, *args, n=10):
+    jax.block_until_ready(fn(*args))
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(n):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / n
+
+
+def _attempt(table, name, shape, fn):
+    """Run one kernel probe; a refusal is a row, not a crash."""
+    row = {"kernel": name, "shape": shape}
+    try:
+        row.update(fn())
+        # "compiled" means Mosaic compiled it, not that XLA ran instead
+        row["status"] = ("compiled" if row.get("mosaic_calls", 1)
+                         else "not used (the XLA path ran)")
+    except Exception as e:  # the compiler's message IS the finding
+        traceback.print_exc()
+        row["status"] = "refused"
+        row["message"] = f"{type(e).__name__}: {e}"[:1500]
+    print(f"chip_probe: {row}", flush=True)
+    table.append(row)
+
+
+def probe_kernels() -> list:
+    from deeplearning4j_tpu.nn.updaters import Adam, Updater
+    from deeplearning4j_tpu.ops import lstm_kernel, update_kernel
+    from deeplearning4j_tpu.ops.attention import flash_mha, mha
+
+    table = []
+    rng = np.random.default_rng(0)
+
+    def flash(B, H, T, D, dtype, masked):
+        def run():
+            mk = lambda: jnp.asarray(
+                rng.normal(size=(B, H, T, D)).astype(np.float32)).astype(dtype)
+            q, k, v = mk(), mk(), mk()
+            mask = np.ones((B, T), np.float32)
+            if masked:
+                mask[0, int(T * 0.7):] = 0.0
+            mj = jnp.asarray(mask)
+            w = mj[:, None, :, None]
+            km = mj if masked else None
+            xm = mj[:, None, None, :] if masked else None
+
+            def loss(attn):
+                return lambda q, k, v: jnp.sum(
+                    (attn(q, k, v).astype(jnp.float32) * w) ** 2)
+            gf = jax.jit(jax.value_and_grad(loss(
+                lambda q, k, v: flash_mha(q, k, v, True, kmask=km)),
+                argnums=(0, 1, 2)))
+            gx = jax.jit(jax.value_and_grad(loss(
+                lambda q, k, v: mha(q, k, v, causal=True, mask=xm)),
+                argnums=(0, 1, 2)))
+            hlo = gf.lower(q, k, v).as_text()
+            (lf, grads_f), (lx, grads_x) = gf(q, k, v), gx(q, k, v)
+            err = max(float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                                            - b.astype(jnp.float32))))
+                      for a, b in zip(grads_f, grads_x))
+            scale = max(float(jnp.max(jnp.abs(b.astype(jnp.float32))))
+                        for b in grads_x)
+            return {"mosaic_calls": hlo.count("tpu_custom_call"),
+                    "loss_rel_err": abs(float(lf) - float(lx)) / abs(float(lx)),
+                    "grad_max_abs_err": err, "grad_max_abs": scale,
+                    "fwd_bwd_ms": round(_time(gf, q, k, v) * 1e3, 3),
+                    "xla_fwd_bwd_ms": round(_time(gx, q, k, v) * 1e3, 3)}
+        name = "flash fwd+bwd" + (" +kmask" if masked else "")
+        _attempt(table, name,
+                 f"B{B} H{H} T{T} D{D} {jnp.dtype(dtype).name}", run)
+
+    flash(2, 8, 4096, 64, jnp.bfloat16, True)     # bench.py config 6
+    flash(8, 12, 1024, 64, jnp.bfloat16, False)   # the LM step's call
+    flash(2, 4, 1024, 64, jnp.float32, False)     # f32 (8,128) tiles
+    flash(2, 4, 192, 64, jnp.bfloat16, True)      # whole-axis block, T % 128 != 0
+    flash(2, 4, 64, 16, jnp.float32, True)        # the unit tests' shape
+
+    def fused_update():
+        layers, dim = 48, 256                     # scripts/fused_update_ab.py
+        params = {f"l{i}": {
+            "W": jnp.asarray(rng.normal(size=(dim, dim)), jnp.float32),
+            "b": jnp.asarray(rng.normal(size=(dim,)), jnp.float32)}
+            for i in range(layers)}
+        grads = jax.tree_util.tree_map(lambda p: p * 0.01, params)
+        upd = Adam(lr=1e-3)
+        state = {"m": jax.tree_util.tree_map(lambda p: p * 0.03, params),
+                 "v": jax.tree_util.tree_map(lambda p: p * p * 0.01, params)}
+        it = jnp.asarray(3.0, jnp.float32)
+        plain = jax.jit(lambda p, g, s, i: Updater.apply(upd, p, g, s, i))
+        update_kernel.ENABLED, update_kernel.FORCE_JNP = True, False
+        fused = jax.jit(lambda p, g, s, i: update_kernel.fused_apply(
+            "adam", upd, p, g, s, i))
+        hlo = fused.lower(params, grads, state, it).as_text()
+        ref, got = plain(params, grads, state, it), fused(params, grads, state, it)
+        err = max(float(jnp.max(jnp.abs(a - b))) for a, b in zip(
+            jax.tree_util.tree_leaves(ref), jax.tree_util.tree_leaves(got)))
+        return {"mosaic_calls": hlo.count("tpu_custom_call"),
+                "max_abs_err_vs_per_leaf": err,
+                "fused_ms": round(_time(fused, params, grads, state, it) * 1e3, 4),
+                "per_leaf_ms": round(_time(plain, params, grads, state, it) * 1e3, 4)}
+    _attempt(table, "fused Adam update", "48 layers x 256 (3.2M params)",
+             fused_update)
+
+    def lstm():
+        mb, n = 64, 512                           # docs/KERNELS.md shape
+        z = jnp.asarray(rng.normal(size=(mb, 4 * n)), jnp.float32)
+        c = jnp.asarray(rng.normal(size=(mb, n)), jnp.float32)
+        lstm_kernel.ENABLED = True
+
+        def loss(cell):
+            def f(z, c):
+                h, cn = cell(z, c)
+                return jnp.sum(h * h) + jnp.sum(jnp.tanh(cn))
+            return jax.jit(jax.value_and_grad(f, argnums=(0, 1)))
+        gf, gp = loss(lstm_kernel.fused_lstm_cell), loss(lstm_kernel._plain_cell)
+        hlo = gf.lower(z, c).as_text()
+        (_, a), (_, b) = gf(z, c), gp(z, c)
+        err = max(float(jnp.max(jnp.abs(x - y))) for x, y in zip(a, b))
+        return {"mosaic_calls": hlo.count("tpu_custom_call"),
+                "grad_max_abs_err": err,
+                "fused_ms": round(_time(gf, z, c) * 1e3, 4),
+                "plain_ms": round(_time(gp, z, c) * 1e3, 4)}
+    _attempt(table, "fused LSTM cell fwd+bwd", "mb64 n512 f32", lstm)
+
+    return table
+
+
+def probe_precision() -> dict:
+    """f32 x f32 matmul against a float64 host reference, per precision."""
+    rng = np.random.default_rng(0)
+    a = rng.normal(size=(1024, 1024)).astype(np.float32)
+    b = rng.normal(size=(1024, 1024)).astype(np.float32)
+    ref = a.astype(np.float64) @ b.astype(np.float64)
+    out = {}
+    for prec in ("default", "high", "highest"):
+        got = np.asarray(jax.jit(lambda x, y: jnp.matmul(
+            x, y, precision=prec))(a, b), np.float64)
+        out[prec] = float(np.max(np.abs(got - ref)) / np.max(np.abs(ref)))
+    bf = np.asarray(jax.jit(lambda x, y: jnp.matmul(
+        x.astype(jnp.bfloat16), y.astype(jnp.bfloat16),
+        preferred_element_type=jnp.float32))(a, b), np.float64)
+    out["bf16_operands"] = float(np.max(np.abs(bf - ref)) / np.max(np.abs(ref)))
+    return out
+
+
+def probe_flash_vs_xla() -> dict:
+    """The chip_smoke LM step, once per attention_impl."""
+    from chip_smoke import FULL as cfg
+    from deeplearning4j_tpu.nn.updaters import Adam
+    from deeplearning4j_tpu.parallel import ShardedTransformerLM, build_mesh
+
+    n_dev = len(jax.devices())
+    rng = np.random.default_rng(0)
+    toks = rng.integers(0, cfg["vocab"], (
+        cfg["batch_per_device"] * n_dev, cfg["seq"])).astype(np.int32)
+    tgts = np.roll(toks, -1, axis=1)
+    out = {}
+    for impl in ("flash", "xla"):
+        lm = ShardedTransformerLM(
+            vocab_size=cfg["vocab"], n_layers=cfg["layers"],
+            d_model=cfg["d_model"], n_heads=cfg["heads"],
+            mesh=build_mesh({"data": n_dev}), max_len=cfg["seq"],
+            n_microbatches=1, compute_dtype=jnp.bfloat16,
+            attention_impl=impl,
+            updater=Adam(lr=3e-4, moment_dtype="bfloat16"))
+        t0 = time.perf_counter()
+        float(lm.fit_batch(toks, tgts))
+        first = time.perf_counter() - t0
+        for _ in range(3):
+            lm.fit_batch(toks, tgts)
+        jax.block_until_ready(lm.params)
+        t0 = time.perf_counter()
+        for _ in range(20):
+            lm.fit_batch(toks, tgts)
+        jax.block_until_ready(lm.params)
+        out[impl] = {"step_ms": round((time.perf_counter() - t0) / 20 * 1e3, 2),
+                     "first_step_s": round(first, 2),
+                     "peak_bytes": int(jax.devices()[0].memory_stats()
+                                       ["peak_bytes_in_use"])}
+        print(f"chip_probe: LM step attention_impl={impl}: {out[impl]}",
+              flush=True)
+        del lm
+    return out
+
+
+def probe_bundle() -> dict:
+    """serialize() per decode executable, then the bundle round trip."""
+    import tempfile
+
+    from jax.experimental import serialize_executable as se
+
+    from deeplearning4j_tpu.parallel import ShardedTransformerLM, build_mesh
+    from deeplearning4j_tpu.serving import DecodeEngine
+
+    out = {}
+    meshes = {"one_device": build_mesh({"data": 1}, jax.devices()[:1])}
+    if len(jax.devices()) > 1:
+        meshes["all_devices_tp"] = build_mesh({"data": len(jax.devices())})
+    for name, mesh in meshes.items():
+        lm = ShardedTransformerLM(vocab_size=512, n_layers=2, d_model=128,
+                                  n_heads=4, mesh=mesh, max_len=64, seed=11)
+        kw = dict(max_slots=2, page_size=16, prompt_buckets=(16,),
+                  default_max_new=4)
+        cold = DecodeEngine(lm, **kw).load()
+        row = {"serialize": {}}
+        try:
+            for key, exe in cold._compiled.items():
+                try:
+                    se.serialize(exe)
+                    row["serialize"][":".join(map(str, key))] = "ok"
+                except Exception as e:
+                    row["serialize"][":".join(map(str, key))] = \
+                        f"{type(e).__name__}: {e}"[:300]
+            ref = cold.generate([1, 2, 3], max_new_tokens=4).tokens
+            n_exec = cold.compile_cache_size()
+            path = os.path.join(tempfile.mkdtemp(), "lm.zip.warm")
+            cold.save_warmup_bundle(path)
+        except Exception as e:
+            traceback.print_exc()
+            row["save_error"] = f"{type(e).__name__}: {e}"[:300]
+            out[name] = row
+            continue
+        finally:
+            cold.shutdown()
+        warm = DecodeEngine(lm, **kw).load(warm_bundle=path)
+        try:
+            row.update(
+                executables=n_exec,
+                bundle_hits=int(warm.metrics.counter_value("bundle_hits")),
+                bundle_misses=int(warm.metrics.counter_value("bundle_misses")),
+                tokens_equal=warm.generate([1, 2, 3],
+                                           max_new_tokens=4).tokens == ref)
+        finally:
+            warm.shutdown()
+        print(f"chip_probe: bundle[{name}]: {row}", flush=True)
+        out[name] = row
+    return out
+
+
+def probe_gspmd() -> dict:
+    """What plain jit (GSPMD) does with a batch-sharded flash call."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from chip_smoke import custom_call_shapes
+    from deeplearning4j_tpu.ops.attention import flash_mha
+    from deeplearning4j_tpu.parallel import build_mesh
+
+    n_dev = len(jax.devices())
+    if n_dev < 2:
+        return {"skipped": "one device"}
+    mesh = build_mesh({"data": n_dev})
+    sh = NamedSharding(mesh, P("data"))
+    q = jax.device_put(jnp.ones((2 * n_dev, 4, 1024, 64), jnp.bfloat16), sh)
+    try:
+        hlo = jax.jit(lambda q: flash_mha(q, q, q, True),
+                      out_shardings=sh).lower(q).compile().as_text()
+    except NotImplementedError as e:     # jax refuses; that is the answer
+        return {"plain_jit": f"refused — {e}"}
+    return {"global_BH": 2 * n_dev * 4, "per_chip_BH": 2 * 4,
+            "custom_call_result_shapes": custom_call_shapes(hlo),
+            "all_gathers": hlo.count(" all-gather(")
+            + hlo.count(" all-gather-start(")}
+
+
+SECTIONS = {"kernels": probe_kernels, "precision": probe_precision,
+            "flash_xla": probe_flash_vs_xla, "bundle": probe_bundle,
+            "gspmd": probe_gspmd}
+
+
+def main() -> int:
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        print(f"chip_probe: no TPU (platform {dev.platform!r})",
+              file=sys.stderr)
+        return 2
+    want = sys.argv[1:] or list(SECTIONS)
+    out = {"device": {"platform": dev.platform, "kind": dev.device_kind,
+                      "count": len(jax.devices())},
+           "jax": jax.__version__}
+    rc = 0
+    for name in want:
+        try:
+            out[name] = SECTIONS[name]()
+        except Exception as e:
+            traceback.print_exc()
+            out[name] = {"error": f"{type(e).__name__}: {e}"[:1500]}
+            rc = 1
+    os.makedirs(os.path.join(_REPO, "chiprun_out"), exist_ok=True)
+    with open(os.path.join(_REPO, "chiprun_out", "chip_probe.json"), "w") as f:
+        json.dump(out, f, indent=1)
+    print(json.dumps(out), flush=True)
+    return rc
+
+
+if __name__ == "__main__":
+    sys.exit(main())

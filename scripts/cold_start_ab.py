@@ -20,9 +20,11 @@ harness does):
      it must scale up during the burst, scale back down after idle,
      compile NOTHING new (the birth re-warms from the shared AOT set),
      and strand no future.
-  5. Persistent-compile-cache wiring check (after the arms, so it can't
-     confound the A/B): ``enable_compile_cache(tmpdir)`` + one fresh
-     jit compile must leave files in the directory.
+  5. Persistent-compile-cache wiring check (the cache is held OFF during
+     the arms, so it can't shortcut the cold arm): ``enable_compile_cache()``
+     + one fresh jit compile must leave that program's entry in the
+     directory in effect (JAX_COMPILATION_CACHE_DIR, else
+     <checkout>/.cache/jax-compile).
 
 Gates (consumed by bench.py ``cold_start_ab``):
   - speedup_ok:   cold load wall >= 3x warm load wall
@@ -31,7 +33,7 @@ Gates (consumed by bench.py ``cold_start_ab``):
   - cache_flat_ok: compile_cache_size() unchanged across serving, both arms
   - autoscale_ok: scale-up within the burst budget, scale-down after,
                   zero new compiles, every future resolved
-  - compile_cache_ok: the persistent cache directory is populated
+  - compile_cache_ok: the persistent cache directory holds the probe's entry
 
 Last stdout line is the JSON result (the bench subprocess contract).
 """
@@ -141,22 +143,24 @@ def _burst_soak(engine, n_requests: int, budget_s: float) -> dict:
 
 
 def _compile_cache_check() -> dict:
-    """Separate from the A/B arms (enabled AFTER them) so the persistent
-    cache can't shortcut the cold arm's compiles."""
+    """Separate from the A/B arms (main() holds the cache off until here)
+    so the persistent cache can't shortcut the cold arm's compiles."""
     import jax
     import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache as cc
 
     from deeplearning4j_tpu.serving.warmcache import enable_compile_cache
 
-    d = tempfile.mkdtemp(prefix="dl4j_tpu_xla_cache_")
-    enable_compile_cache(d)
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()            # jax latched "off" at the arms' compiles
+    d = enable_compile_cache()
 
     @jax.jit
     def _distinct_probe(x):
         return jnp.tanh(x) * 3.0 + 1.0
 
     np.asarray(_distinct_probe(jnp.arange(8.0)))
-    files = [f for f in os.listdir(d) if not f.startswith(".")]
+    files = [f for f in os.listdir(d) if f.startswith("jit__distinct_probe")]
     return {"dir": d, "files": len(files), "populated": bool(files)}
 
 
@@ -171,6 +175,10 @@ def main() -> int:
 
     from deeplearning4j_tpu.serving import Engine
     from deeplearning4j_tpu.serving.warmcache import device_fingerprint
+
+    # the A/B's cold arm must really compile, whatever the environment's
+    # JAX_COMPILATION_CACHE_DIR holds
+    jax.config.update("jax_enable_compilation_cache", False)
 
     n_serve = 64 if args.quick else 256
     n_burst = args.requests or (2000 if args.quick else 4000)

@@ -6,9 +6,9 @@ Arms (alternating windows, identical protocol):
   topk       the baseline fixed-mode pack: top_k over masked magnitudes
              (sort-backed selection)
   streaming  the sort-free one-pass pack: cumsum positions + one scatter
-  pallas     the single-block pallas kernel variant (compiled on TPU;
-             INTERPRET mode on CPU, absolute time meaningless there —
-             the CPU signal is streaming vs topk + the parity fields)
+
+(A single-block pallas variant of the pack was removed: Mosaic has no
+lowering for its in-kernel ``cumsum`` — CHANGES.md, PR 21.)
 
 Workload: one DCN exchange bucket (encode + decode round-trip per
 iteration, the compressed_pmean inner loop minus the collective), with
@@ -49,11 +49,10 @@ g_host[hot] = rng.normal(size=hot.size).astype(np.float32) * 10 * T
 g = jnp.asarray(g_host)
 
 
-def make_arm(fused: bool, use_pallas: bool):
-    """Trace one arm's encode+decode round trip with the module flags
-    set the way that arm needs them (flags are read at trace time)."""
+def make_arm(fused: bool):
+    """Trace one arm's encode+decode round trip with the module flag
+    set the way that arm needs it (the flag is read at trace time)."""
     compression.FUSED_ENCODE = fused
-    compression.FUSED_ENCODE_PALLAS = use_pallas
 
     @jax.jit
     def run(gg):
@@ -63,17 +62,14 @@ def make_arm(fused: bool, use_pallas: bool):
     return run, np.asarray(dec), np.asarray(enc)
 
 
-arm_topk, dec_ref, enc_ref = make_arm(False, False)
-arm_stream, dec_st, enc_st = make_arm(True, False)
-arm_pallas, dec_pl, enc_pl = make_arm(True, True)
-ARMS = {"topk": arm_topk, "streaming": arm_stream, "pallas": arm_pallas}
+arm_topk, dec_ref, enc_ref = make_arm(False)
+arm_stream, dec_st, enc_st = make_arm(True)
+ARMS = {"topk": arm_topk, "streaming": arm_stream}
 
 parity = {
     "roundtrip_bitwise_streaming": bool(np.array_equal(dec_ref, dec_st)),
-    "roundtrip_bitwise_pallas": bool(np.array_equal(dec_ref, dec_pl)),
     "selection_set_equal": bool(
-        set(enc_ref.tolist()) - {0} == set(enc_st.tolist()) - {0}
-        == set(enc_pl.tolist()) - {0}),
+        set(enc_ref.tolist()) - {0} == set(enc_st.tolist()) - {0}),
 }
 
 best = {name: float("inf") for name in ARMS}
@@ -92,9 +88,7 @@ for _ in range(WINDOWS):
 out = {"config": "one_pass_encode_ab", "n": N, "k": K,
        "topk_ms": round(best["topk"] * 1e3, 4),
        "streaming_ms": round(best["streaming"] * 1e3, 4),
-       "pallas_ms": round(best["pallas"] * 1e3, 4),
        "speedup_streaming": round(best["topk"] / best["streaming"], 3),
-       "speedup_pallas": round(best["topk"] / best["pallas"], 3),
        **parity,
        "platform": jax.devices()[0].platform, "t": round(time.time(), 1)}
 print(json.dumps(out), flush=True)

@@ -20,7 +20,7 @@ from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork, NeuralNetConfigu
 from deeplearning4j_tpu.nn.updaters import Adam, NoOp
 from deeplearning4j_tpu.ops.attention import flash_mha, mha
 from deeplearning4j_tpu.utils.gradient_check import check_gradients
-from deeplearning4j_tpu.utils.jax_compat import enable_x64
+from jax import enable_x64
 
 RNG = np.random.default_rng(7)
 

@@ -1,9 +1,10 @@
-"""Bench-harness machinery added for round 5: the regression gate, the
-matmul ceiling probe, and the measured collective microbench.
+"""Bench-harness machinery: no fallback that hides the device (peak table
+keyed by device_kind, a failed config fails the run, chip children only
+before the parent holds a backend), the matmul ceiling probe, and the
+measured collective microbench.
 
 These test the MECHANISM on CPU (the numbers themselves are produced on
-the chip by the driver run); the gate must parse real recorded artifacts,
-attach per-metric deltas, and demand notes for >20% drops.
+the chip).
 """
 
 import importlib.util
@@ -11,7 +12,6 @@ import json
 import os
 
 import jax
-import numpy as np
 import pytest
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -26,78 +26,39 @@ def bench():
     return mod
 
 
-class TestRegressionGate:
-    def test_parses_latest_artifact(self, bench):
-        prev, art = bench._load_prev_metrics()
-        assert art is not None and art.startswith("BENCH_r")
-        # every per-config line of the recorded tail must be recovered
-        assert "resnet50_train_images_per_sec_per_chip" in prev
-        assert prev["resnet50_train_images_per_sec_per_chip"] > 0
+class TestNoFallbackThatHidesTheDevice:
+    def test_unknown_device_kind_is_an_error_not_a_default(self, bench):
+        # conftest pins CPU: its device_kind is in nobody's peak table
+        assert jax.devices()[0].device_kind not in bench.PEAK_BF16_FLOPS
+        with pytest.raises(KeyError, match="PEAK_BF16_FLOPS"):
+            bench.peak_flops()
 
-    def test_deltas_and_unexplained_flagging(self, bench, monkeypatch):
-        monkeypatch.setattr(bench, "QUICK", False)
-        monkeypatch.setattr(bench, "_artifact_chain", lambda: [
-            (4, "BENCH_r04.json", {"m_ok": 98.0, "m_best": 200.0}),
-            (5, "BENCH_r05.json", {"m_ok": 100.0, "m_drop": 100.0,
-                                   "m_best": 100.0})])
-        results = [{"metric": "m_ok", "value": 95.0},
-                   {"metric": "m_drop", "value": 50.0},
-                   {"metric": "m_best", "value": 150.0},
-                   {"metric": "m_new", "value": 1.0}]
-        primary = {"metric": "m_ok", "value": 95.0}
-        bench._regression_gate(results, primary, "tpu")
-        assert results[0]["delta_vs_prev"] == pytest.approx(-0.05)
-        assert results[1]["delta_vs_prev"] == pytest.approx(-0.5)
-        # cumulative tracking: delta_vs_best spans the whole chain
-        assert results[0]["delta_vs_best"] == pytest.approx(-0.05, abs=1e-4)
-        assert results[2]["delta_vs_best"] == pytest.approx(-0.25)
-        assert results[2]["best_round"] == 4
-        assert "delta_vs_prev" not in results[3]  # no prior → no delta
-        # m_best dropped >10% below its chain best with no fresh note —
-        # the standing-note expiry gate catches what vs-prev misses
-        assert primary["unexplained_regressions"] == ["m_drop", "m_best"]
+    def test_a_failed_config_makes_the_run_exit_nonzero(self, bench,
+                                                        monkeypatch,
+                                                        tmp_path, capsys):
+        def boom():
+            raise RuntimeError("config blew up")
 
-    def test_fresh_note_satisfies_gate_stale_does_not(self, bench,
-                                                      monkeypatch, tmp_path):
-        monkeypatch.setattr(bench, "QUICK", False)
-        monkeypatch.setattr(bench, "_artifact_chain", lambda: [
-            (5, "BENCH_r05.json", {"m_drop": 100.0, "m_stale": 100.0})])
-        notes = tmp_path / "BENCH_NOTES.json"
-        notes.write_text(json.dumps({
-            "_policy": "ignored by the gate",
-            "m_drop": {"note": "fresh same-session A/B", "round": 6},
-            "m_stale": "legacy standing tenancy note"}))
         monkeypatch.setattr(bench, "_REPO", str(tmp_path))
-        results = [{"metric": "m_drop", "value": 50.0},
-                   {"metric": "m_stale", "value": 50.0}]
-        primary = {}
-        bench._regression_gate(results, primary, "tpu")
-        assert results[0]["regression_note"] == "fresh same-session A/B"
-        # the legacy note no longer excuses the drop — notes expire
-        assert primary["unexplained_regressions"] == ["m_stale"]
+        monkeypatch.setattr(bench, "_configs", lambda platform: [
+            ("good", lambda: {"metric": "m", "value": 1.0, "unit": "u"}),
+            ("bad", boom)])
+        assert bench.main() == 1
+        line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+        assert line["failed"] == ["bad"]
+        assert "value" not in line          # no made-up primary of 0.0
+        assert line["device"]["platform"] == "cpu"
+        assert [r.get("error") for r in line["results"]] == [
+            None, "RuntimeError: config blew up"]
+        monkeypatch.setattr(bench, "_configs", lambda platform: [
+            ("good", lambda: {"metric": "m", "value": 1.0, "unit": "u"})])
+        assert bench.main() == 0
 
-    def test_gate_skips_non_tpu_and_quick(self, bench, monkeypatch):
-        results = [{"metric": "m", "value": 1.0}]
-        primary = {}
-        bench._regression_gate(results, primary, "cpu")
-        monkeypatch.setattr(bench, "QUICK", True)
-        bench._regression_gate(results, primary, "tpu")
-        assert "delta_vs_prev" not in results[0]
-        assert "vs_prev_round" not in primary
-
-    def test_repo_notes_file_is_valid_json_if_present(self):
-        p = os.path.join(_REPO, "BENCH_NOTES.json")
-        if os.path.exists(p):
-            with open(p) as f:
-                notes = json.load(f)
-            assert isinstance(notes, dict)
-            for k, v in notes.items():
-                if k.startswith("_"):  # policy/bookkeeping keys
-                    continue
-                # gate-visible notes: legacy string or {note, round}
-                assert (isinstance(v, str) and v) or (
-                    isinstance(v, dict) and v.get("note")
-                    and isinstance(v.get("round"), int)), (k, v)
+    def test_chip_children_refused_once_the_parent_holds_a_backend(
+            self, bench):
+        jax.devices()                       # this process now has a backend
+        with pytest.raises(RuntimeError, match="already initialised"):
+            bench._kernel_ab("fused_update_ab.py")
 
 
 class TestCeilingProbe:

@@ -2,9 +2,8 @@
 Next #6: the reference ships 8 runnable tutorials; these are the
 equivalent user journeys, CI-tested).
 
-Each runs in its own process (examples self-configure the platform via
-DL4J_TPU_EXAMPLES_CPU; some pin device counts) and must print the final
-"OK" its internal assertions guard."""
+Each runs in its own process under JAX_PLATFORMS=cpu (some pin device
+counts) and must print the final "OK" its internal assertions guard."""
 
 import os
 import subprocess
@@ -29,8 +28,7 @@ def test_all_tutorial_numbers_present():
 @pytest.mark.parametrize("script", SCRIPTS)
 def test_example_runs(script):
     env = dict(os.environ)
-    env["DL4J_TPU_EXAMPLES_CPU"] = "1"
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     # give example 09 a multi-device mesh to shard over
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     p = subprocess.run([sys.executable, os.path.join(_EX, script)],

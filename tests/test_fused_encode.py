@@ -1,6 +1,5 @@
 """One-pass (sort-free) fixed-threshold encode: selection-set parity with
-the top_k path, bit-identical decode round-trips, overflow fallback, and
-the pallas kernel variant."""
+the top_k path, bit-identical decode round-trips, and overflow fallback."""
 
 import numpy as np
 import pytest
@@ -98,34 +97,3 @@ class TestOnePassEncode:
             # raw traced scalars hit the <=0 guard under tracing; the
             # supported contract is static thresholds
             f(g, jnp.float32(1e-3))
-
-
-class TestPallasVariant:
-    @pytest.fixture(autouse=True)
-    def enable_pallas(self, monkeypatch):
-        monkeypatch.setattr(compression, "FUSED_ENCODE_PALLAS", True)
-
-    def test_matches_streaming_bitwise(self):
-        g, t, k = grad(), 1e-3, 256
-        if not compression._pallas_encode_ok(g.shape[0]):
-            pytest.skip("pallas unavailable")
-        enc_pl = compression._pallas_pack(g, k, t, g.shape[0])
-        enc_js = compression._streaming_pack(
-            g, jnp.abs(g), k, t, g.shape[0])
-        # both pack index-ascending -> bitwise equal, not just set-equal
-        np.testing.assert_array_equal(np.asarray(enc_pl),
-                                      np.asarray(enc_js))
-
-    def test_end_to_end_roundtrip(self):
-        g, t, k = grad(seed=3), 1e-3, 256
-        enc, scale = threshold_encode(g, k, threshold=t)
-        ref = plain_encode(g, k, t)
-        np.testing.assert_array_equal(
-            np.asarray(threshold_decode(enc, scale, g.shape[0])),
-            np.asarray(threshold_decode(ref, jnp.float32(t), g.shape[0])))
-
-    def test_small_buffer_uses_streaming(self):
-        # below the pallas floor the one-pass path still works (jnp arm)
-        g = jnp.zeros((64,), jnp.float32).at[5].set(1.0)
-        enc, scale = threshold_encode(g, 4, threshold=0.5)
-        assert sorted(int(e) for e in np.asarray(enc) if e != 0) == [6]

@@ -19,7 +19,7 @@ from deeplearning4j_tpu.nn.layers import (
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork, NeuralNetConfiguration
 from deeplearning4j_tpu.nn.updaters import NoOp
 from deeplearning4j_tpu.utils.gradient_check import check_gradients
-from deeplearning4j_tpu.utils.jax_compat import enable_x64
+from jax import enable_x64
 
 RNG = np.random.default_rng(12345)
 

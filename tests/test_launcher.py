@@ -570,6 +570,72 @@ class TestPodLauncher:
                         chaos={5: "proc_kill@1"})
 
 
+    def test_tpu_host_gives_each_worker_its_own_chips(self, tmp_path,
+                                                      monkeypatch):
+        """A chip belongs to one process: on a TPU host worker i sees
+        only chips [i*K, (i+1)*K), set beside XLA_FLAGS."""
+        from deeplearning4j_tpu.parallel import launcher as lmod
+        monkeypatch.setattr(lmod, "tpu_chips", lambda: 4)
+        tpu_env = dict(os.environ, JAX_PLATFORMS="tpu,cpu")
+        one = PodLauncher(["x"], num_workers=4, run_dir=str(tmp_path),
+                          base_env=tpu_env)
+        envs = [one._env_for(h) for h in one.handles]
+        assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+        assert {e["TPU_CHIPS_PER_PROCESS_BOUNDS"] for e in envs} == {"1,1,1"}
+        assert {e["TPU_PROCESS_BOUNDS"] for e in envs} == {"1,1,1"}
+        two = PodLauncher(["x"], num_workers=2, run_dir=str(tmp_path),
+                          base_env=tpu_env, devices_per_worker=2)
+        e1 = two._env_for(two.handles[1])
+        assert e1["TPU_VISIBLE_CHIPS"] == "2,3"
+        assert e1["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,2,1"
+        # a lone worker with no explicit count keeps the whole host
+        solo = PodLauncher(["x"], num_workers=1, run_dir=str(tmp_path),
+                           base_env=tpu_env)
+        assert "TPU_VISIBLE_CHIPS" not in solo._env_for(solo.handles[0])
+        # CPU-pinned workers on the same host need no chip and get none
+        cpu = PodLauncher(["x"], num_workers=8, run_dir=str(tmp_path),
+                          base_env=dict(os.environ, JAX_PLATFORMS="cpu"))
+        assert "TPU_VISIBLE_CHIPS" not in cpu._env_for(cpu.handles[0])
+
+    def test_launch_parent_stays_off_the_backend(self):
+        """Everything `launch` runs before it forks — the imports, the
+        compile-cache set-up, building the launcher — must leave JAX's
+        backends uninitialised: on a TPU host a parent that touched a
+        backend holds every chip its workers need."""
+        import subprocess
+        import sys
+        code = (
+            "import sys; sys.path.insert(0, sys.argv[1])\n"
+            "from jax._src import xla_bridge\n"
+            "from deeplearning4j_tpu import cli\n"
+            "from deeplearning4j_tpu.parallel.launcher import PodLauncher\n"
+            "from deeplearning4j_tpu.serving.warmcache import "
+            "enable_compile_cache\n"
+            "enable_compile_cache()\n"
+            "PodLauncher(['x'], num_workers=2, run_dir=sys.argv[2])\n"
+            "assert not xla_bridge.backends_are_initialized()\n")
+        import tempfile
+        with tempfile.TemporaryDirectory() as d:
+            p = subprocess.run([sys.executable, "-c", code, _REPO, d],
+                               capture_output=True, text=True, timeout=120)
+        assert p.returncode == 0, p.stderr[-2000:]
+
+    def test_tpu_host_refuses_at_once_what_would_hang(self, tmp_path,
+                                                      monkeypatch):
+        from deeplearning4j_tpu.parallel import launcher as lmod
+        monkeypatch.setattr(lmod, "tpu_chips", lambda: 1)
+        tpu_env = dict(os.environ, JAX_PLATFORMS="tpu,cpu")
+        with pytest.raises(ValueError, match="one process at a time"):
+            PodLauncher(["x"], num_workers=2, run_dir=str(tmp_path),
+                        base_env=tpu_env)
+        monkeypatch.setattr(lmod, "tpu_chips", lambda: 4)
+        with pytest.raises(ValueError, match="distributed"):
+            PodLauncher(["x"], num_workers=2, run_dir=str(tmp_path),
+                        base_env=tpu_env, bootstrap="distributed")
+        with pytest.raises(ValueError, match="chips"):
+            PodLauncher(["x"], num_workers=1, run_dir=str(tmp_path),
+                        base_env=tpu_env, devices_per_worker=3)
+
 # ---------------------------------------------------------------------------
 # the process-scale soak itself (quick mode; heavier → slow tier)
 # ---------------------------------------------------------------------------

@@ -97,7 +97,7 @@ def _run(code, n_devices, timeout=900):
     (16, {"data": 2, "model": 2, "seq": 2, "pipe": 2}),
     # the 32/64-device configs are tier-2 (slow): each spawns a fresh
     # XLA backend + 4D compile in a subprocess, and the SAME meshes run
-    # headlessly every round in the driver's dryrun (MULTICHIP_r*.json)
+    # headlessly every round in the driver's dryrun (__graft_entry__.py)
     pytest.param(32, {"data": 4, "model": 2, "seq": 2, "pipe": 2},
                  marks=pytest.mark.slow),
     pytest.param(64, {"data": 4, "model": 2, "seq": 2, "pipe": 4},
@@ -112,7 +112,7 @@ _AXES_16 = {"data": 2, "model": 2, "seq": 2, "pipe": 2}
 
 
 @pytest.mark.slow  # the shrink direction re-runs headlessly every round
-# in the driver's dryrun (_run_elastic_shrink → MULTICHIP_r*.json); grow
+# in the driver's dryrun (__graft_entry__._run_elastic_shrink); grow
 # is only covered here, so it stays tier-1
 def test_elastic_shrink_16_to_8_continues_training():
     _run(_RESIZE.format(repo=_REPO, src_axes=_AXES_16, src_n=16,

@@ -223,26 +223,3 @@ class TestSatellites:
         assert 3 in serializer.SUPPORTED_VERSIONS
         with pytest.raises(KeyError, match="bfloat16"):
             serializer._unflatten_into({"a": jnp.zeros(2)}, {}, "")
-
-    def test_bench_notes_freshness(self):
-        """The regression gate only accepts notes citing the current
-        round; legacy strings and old rounds are stale."""
-        import bench
-        notes = {"m1": "legacy string",
-                 "m2": {"note": "fresh ab", "round": 6},
-                 "m3": {"note": "old ab", "round": 5}}
-        assert bench._note_for(notes, "m1", 6) == ("legacy string", False)
-        assert bench._note_for(notes, "m2", 6) == ("fresh ab", True)
-        assert bench._note_for(notes, "m3", 6) == ("old ab", False)
-        assert bench._note_for(notes, "absent", 6) is None
-
-    def test_artifact_metrics_structured_first(self):
-        import bench
-        art = {"parsed": {"metric": "a", "value": 1.0,
-                          "results": [{"metric": "a", "value": 2.0},
-                                      {"metric": "b", "value": 3.0}]},
-               "tail": "  a: 9.0 images/sec\n"}
-        assert bench._artifact_metrics(art) == {"a": 2.0, "b": 3.0}
-        legacy = {"parsed": {"metric": "a", "value": 1.0},
-                  "tail": "  b: 9.0 images/sec\n"}
-        assert bench._artifact_metrics(legacy) == {"a": 1.0, "b": 9.0}

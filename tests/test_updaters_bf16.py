@@ -1,8 +1,8 @@
 """Opt-in reduced-precision optimizer state (round-4 verdict Next #4).
 
 ``Adam(moment_dtype="bfloat16")`` halves the m/v HBM footprint+traffic —
-the dominant optimizer cost on the TransformerLM bench (~3.9 GB/step,
-docs/transformer_profile.md).  These tests pin the semantics: state is
+the dominant optimizer cost on the TransformerLM bench (~3.9 GB/step by
+byte count).  These tests pin the semantics: state is
 really stored narrow, update math stays f32, and the loss-curve
 divergence vs f32 moments is small and quantified.
 """

@@ -34,6 +34,8 @@ from deeplearning4j_tpu.serving import (
 )
 from deeplearning4j_tpu.serving import warmcache
 
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 def _mlp(seed=7):
     conf = (NeuralNetConfiguration.builder().seed(seed).updater(Sgd(lr=0.05))
@@ -465,34 +467,66 @@ class TestDecodeAutoscaleActuator:
 # persistent compile cache seam
 # ---------------------------------------------------------------------------
 
+_RESOLVE = r"""
+import json, os, sys
+import jax
+sys.path.insert(0, sys.argv[1])
+dir_updates = []
+_update = jax.config.update
+def spy(name, val):
+    if name == "jax_compilation_cache_dir":
+        dir_updates.append(val)
+    _update(name, val)
+jax.config.update = spy
+from deeplearning4j_tpu.serving import warmcache
+before = jax.config.jax_compilation_cache_dir
+ret = warmcache.enable_compile_cache()
+assert warmcache.enable_compile_cache() == ret          # idempotent
+jax.jit(lambda x: x * 3.0 + 1.0)(jax.numpy.arange(8.0)).block_until_ready()
+print(json.dumps({
+    "before": before, "ret": ret, "dir_updates": dir_updates,
+    "after": jax.config.jax_compilation_cache_dir,
+    "env": os.environ.get("JAX_COMPILATION_CACHE_DIR"),
+    "default": warmcache.DEFAULT_CACHE_DIR,
+    "entries": len([f for f in os.listdir(ret) if f.endswith("-cache")])}))
+"""
+
+
 class TestEnableCompileCache:
-    def _reset(self):
-        import jax
-        warmcache._enabled_dir = None
-        jax.config.update("jax_compilation_cache_dir", None)
+    """The cache directory is placed from OUTSIDE the program."""
 
-    def test_explicit_arg_wins_over_env(self, tmp_path, monkeypatch):
-        try:
-            monkeypatch.setenv(warmcache.ENV_VAR, str(tmp_path / "env_d"))
-            d = warmcache.enable_compile_cache(str(tmp_path / "arg_d"))
-            assert d == str(tmp_path / "arg_d")
-            assert os.path.isdir(d)
-            # re-exported so forked workers inherit the resolved dir
-            assert os.environ[warmcache.ENV_VAR] == d
-            assert warmcache.enable_compile_cache(d) == d  # idempotent
-        finally:
-            self._reset()
+    def _resolve(self, env_dir):
+        import subprocess
+        import sys
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   JAX_ENABLE_COMPILATION_CACHE="true")
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
+        if env_dir is not None:
+            env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+        p = subprocess.run([sys.executable, "-c", _RESOLVE, _REPO],
+                           env=env, capture_output=True, text=True,
+                           timeout=300)
+        assert p.returncode == 0, p.stderr[-2000:]
+        return json.loads(p.stdout.strip().splitlines()[-1])
 
-    def test_env_var_alone_enables(self, tmp_path, monkeypatch):
-        try:
-            monkeypatch.setenv(warmcache.ENV_VAR, str(tmp_path / "env_d"))
-            assert warmcache.enable_compile_cache() == str(tmp_path / "env_d")
-        finally:
-            self._reset()
+    def test_env_set_is_used_and_never_overridden(self, tmp_path):
+        d = str(tmp_path / "x")
+        out = self._resolve(d)
+        # jax read the variable itself; our code set no directory at all
+        assert out["before"] == out["after"] == out["ret"] == out["env"] == d
+        assert out["dir_updates"] == []
+        assert out["entries"] >= 1          # and entries land in it
 
-    def test_noop_when_nothing_configured(self, monkeypatch):
-        monkeypatch.delenv(warmcache.ENV_VAR, raising=False)
-        assert warmcache.enable_compile_cache() is None
+    def test_env_unset_falls_back_to_checkout_cache(self):
+        out = self._resolve(None)
+        want = os.path.join(_REPO, ".cache", "jax-compile")
+        assert out["default"] == want
+        assert out["before"] is None
+        assert out["after"] == out["ret"] == want
+        assert out["dir_updates"] == [want]
+        # exported so forked workers (launch) share it
+        assert out["env"] == want
+        assert out["entries"] >= 1
 
     def test_fingerprint_pins_backend_topology_and_version(self):
         import jax
