@@ -1,16 +1,27 @@
-"""Span tracing: bounded ring buffer -> Chrome trace events -> pod merge.
+"""Span tracing: one span system, two sinks.
 
 One :class:`TraceRecorder` per process records *complete spans* (name +
 start + duration), *instant events* (faults, recoveries, canary
 decisions, membership epochs), and nothing else — the two event shapes
-Chrome's trace-event format needs to render a timeline.  Design
+Chrome's trace-event format needs to render a timeline.  That ring is
+the first sink.  The second is the JAX profiler: while a profiler
+session is active (``jax.profiler.start_trace``,
+``ui.profiler.profile_trace``, anything that starts one), a span opened
+through the module-level ``span()`` is also a profiler annotation of the
+same name with the span's arguments, so it lands in the profile's
+``/host:CPU`` plane beside the device's operations, on a clock that can
+be moved onto the device's.  A span is recorded in the ring when
+``enable_tracing`` is on, in the profile when a session is on, in both
+when both are.  Whether a session is on is read from the profiler
+itself, never from a flag a caller must remember to set.  Design
 constraints, in order:
 
 1. **Low overhead when off.**  Tracing is opt-in (``enable_tracing`` /
-   CLI ``--trace``).  The module-level ``span()``/``instant()`` helpers
-   the hot paths call do ONE global read when disabled and return a
-   shared no-op context manager — no allocation, no lock, no clock
-   read.  Instrumented code is bit-identical with tracing off; the
+   CLI ``--trace``, or a profiler session).  With both sinks off the
+   module-level ``span()``/``instant()`` helpers the hot paths call read
+   the recorder global and the profiler's own flag and return a shared
+   no-op context manager — no allocation, no lock, no clock read.
+   Instrumented code is bit-identical with tracing off; the
    ``telemetry_overhead`` bench config gates both properties.
 2. **Low overhead when on.**  Recording is two monotonic clock reads
    plus one dict build plus one deque append under a lock; the ring
@@ -34,6 +45,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -71,40 +83,71 @@ class _NullSpan:
     def set(self, **args) -> "_NullSpan":
         return self
 
+    def drop(self) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
 
+def _annotation_args(args: dict) -> dict:
+    """The profiler writes an annotation's arguments into its name as
+    ``#k=v,k=v#``; a value survives that only without ``#`` and ``,``."""
+    return {k: v if isinstance(v, (int, float))
+            else str(v).replace("#", "_").replace(",", ";")
+            for k, v in args.items()}
+
+
 class _Span:
-    """An open span: records a complete ("X") event when the context
-    exits.  ``set(**args)`` attaches arguments discovered mid-span."""
+    """An open span.  With a recorder it records a complete ("X") event
+    when the context exits; with ``annotation`` (the profiler's
+    annotation class, given while a session is active) it is also a
+    profiler annotation for the same interval.  ``set(**args)`` attaches
+    arguments discovered mid-span, to both."""
 
-    __slots__ = ("_rec", "name", "cat", "args", "_t0")
+    __slots__ = ("_rec", "name", "cat", "args", "_t0", "_annotation")
 
-    def __init__(self, rec: "TraceRecorder", name: str, cat: str,
-                 args: Optional[dict]):
+    def __init__(self, rec: Optional["TraceRecorder"], name: str, cat: str,
+                 args: Optional[dict], annotation=None):
         self._rec = rec
         self.name = name
         self.cat = cat
         self.args = args
+        self._annotation = (
+            annotation(name, **_annotation_args(args or {}))
+            if annotation is not None else None)
 
     def set(self, **args) -> "_Span":
         if self.args is None:
             self.args = args
         else:
             self.args.update(args)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**_annotation_args(args))
         return self
 
+    def drop(self) -> None:
+        """Keep this span out of the ring: for a scope that turned out
+        to hold nothing (an idle turn of a polling loop).  A profiler
+        annotation, once entered, stays."""
+        self._rec = None
+
     def __enter__(self):
-        self._t0 = self._rec.clock()
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        if self._rec is not None:
+            self._t0 = self._rec.clock()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         if exc_type is not None:
             self.set(error=exc_type.__name__)
-        self._rec.complete_at(self.name, self._t0, self._rec.clock(),
-                              cat=self.cat,
-                              **(self.args or {}))
+        if self._rec is not None:
+            self._rec.complete_at(self.name, self._t0, self._rec.clock(),
+                                  cat=self.cat,
+                                  **(self.args or {}))
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -178,9 +221,6 @@ class TraceRecorder:
         if args:
             ev["args"] = args
         self._record(ev)
-
-    def span(self, name: str, cat: str = "", **args) -> _Span:
-        return _Span(self, name, cat, args or None)
 
     # -- export ------------------------------------------------------------
 
@@ -275,13 +315,32 @@ def tracing_enabled() -> bool:
     return _recorder is not None
 
 
+_annotation_class = None
+
+
+def _find_annotation_class():
+    """``jax.profiler.TraceAnnotation``, once something else has loaded
+    ``jax`` (no profiler session can be active before that); else None.
+    ``obs`` itself imports nothing of JAX."""
+    global _annotation_class
+    if "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
+        _annotation_class = TraceAnnotation
+    return _annotation_class
+
+
 def span(name: str, cat: str = "", **args):
-    """``with span("train/step", iteration=i): ...`` — a no-op when
-    tracing is disabled (one global read, shared null object)."""
+    """``with span("train/step", iteration=i): ...`` — recorded in the
+    ring when tracing is enabled, in the profile when a profiler
+    session is active (read from the profiler's own flag); the shared
+    null object when neither is."""
     r = _recorder
-    if r is None:
+    annotation = _annotation_class or _find_annotation_class()
+    if annotation is not None and not annotation.is_enabled():
+        annotation = None
+    if r is None and annotation is None:
         return _NULL_SPAN
-    return r.span(name, cat, **args)
+    return _Span(r, name, cat, args or None, annotation)
 
 
 def instant(name: str, cat: str = "", **args) -> None:
@@ -305,10 +364,7 @@ def traced(name: Optional[str] = None, cat: str = ""):
 
         @wraps(fn)
         def wrapper(*a, **kw):
-            r = _recorder
-            if r is None:
-                return fn(*a, **kw)
-            with r.span(span_name, cat):
+            with span(span_name, cat):
                 return fn(*a, **kw)
         return wrapper
     return deco

@@ -31,6 +31,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, set_mesh
 
 from ..models.transformer import block_apply, block_params
 from ..nn.updaters import Adam
+from ..obs import trace as obs_trace
 from .pipeline import SCHEDULES, pipeline_apply, stack_stage_params
 from .ring import ring_attention
 
@@ -247,12 +248,18 @@ class ShardedTransformerLM:
     def fit_batch(self, tokens: np.ndarray, targets: np.ndarray):
         if self._jit_step is None:
             self._jit_step = self._build_step()
-        tokens = jax.device_put(jnp.asarray(tokens, jnp.int32), self.token_sharding)
-        targets = jax.device_put(jnp.asarray(targets, jnp.int32), self.token_sharding)
-        with set_mesh(self.mesh):
-            self.params, self.opt_state, loss = self._jit_step(
-                self.params, self.opt_state,
-                jnp.asarray(self.iteration, jnp.int32), tokens, targets)
+        with obs_trace.span("train/step", cat="train",
+                            iteration=self.iteration + 1) as sp:
+            with obs_trace.span("train/h2d", cat="train"):
+                tokens = jax.device_put(jnp.asarray(tokens, jnp.int32), self.token_sharding)
+                targets = jax.device_put(jnp.asarray(targets, jnp.int32), self.token_sharding)
+                with set_mesh(self.mesh):   # the counter goes to every chip
+                    it = jnp.asarray(self.iteration, jnp.int32)
+            sp.set(tokens=tokens.size)
+            with obs_trace.span("train/dispatch", cat="train"), \
+                    set_mesh(self.mesh):
+                self.params, self.opt_state, loss = self._jit_step(
+                    self.params, self.opt_state, it, tokens, targets)
         self.iteration += 1
         from ..optimize.score import LazyScore
         return LazyScore(loss)
@@ -288,13 +295,19 @@ class ShardedTransformerLM:
         if self._jit_multi_step is None:
             self._jit_multi_step = self._build_multi_step()
         stacked = NamedSharding(self.mesh, P(None, "data", "seq"))
-        tokens = jax.device_put(jnp.asarray(tokens, jnp.int32), stacked)
-        targets = jax.device_put(jnp.asarray(targets, jnp.int32), stacked)
-        k = tokens.shape[0]
-        with set_mesh(self.mesh):
-            self.params, self.opt_state, losses = self._jit_multi_step(
-                self.params, self.opt_state,
-                jnp.asarray(self.iteration, jnp.int32), tokens, targets)
+        with obs_trace.span("train/step", cat="train",
+                            iteration=self.iteration + 1) as sp:
+            with obs_trace.span("train/h2d", cat="train"):
+                tokens = jax.device_put(jnp.asarray(tokens, jnp.int32), stacked)
+                targets = jax.device_put(jnp.asarray(targets, jnp.int32), stacked)
+                with set_mesh(self.mesh):   # the counter goes to every chip
+                    it = jnp.asarray(self.iteration, jnp.int32)
+            k = tokens.shape[0]
+            sp.set(steps=k, tokens=tokens.size)
+            with obs_trace.span("train/dispatch", cat="train"), \
+                    set_mesh(self.mesh):
+                self.params, self.opt_state, losses = self._jit_multi_step(
+                    self.params, self.opt_state, it, tokens, targets)
         self.iteration += k
         from ..optimize.score import LazyScore
         return [LazyScore(losses[i]) for i in range(k)]

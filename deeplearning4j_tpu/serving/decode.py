@@ -110,13 +110,17 @@ Two host-overhead eliminations ride on top (docs/SERVING.md
       chunk's logits (and every sampled token) are bit-identical to an
       unchunked prefill.
 
-TTFT and time-per-output-token are first-class (``DecodeMetrics``,
-``serve/prefill`` / ``serve/decode_step`` / ``serve/prefix_attach`` /
-``serve/spec_verify`` spans — docs/OBSERVABILITY.md).
+TTFT and time-per-output-token are first-class (``DecodeMetrics``).
+Everything the loop thread does is a live ``obs.trace`` span under
+``serve/iteration`` (``serve/admit``, ``serve/prefill``,
+``serve/decode_step`` with a child where the host builds, dispatches,
+waits and records, ``serve/finish``), and the spans of one request carry
+its ``request_id`` — docs/OBSERVABILITY.md.
 """
 
 from __future__ import annotations
 
+import itertools
 import os
 import threading
 import time
@@ -141,7 +145,9 @@ class GenerationResult:
     """One finished generation.  ``tokens`` are the GENERATED ids only
     (prompt excluded; a terminating EOS is included).  ``logits`` is
     [n_tokens, vocab] float32 when the request asked ``echo_logits``
-    (the bit-identity gate's evidence), else None."""
+    (the bit-identity gate's evidence), else None.  ``request_id`` is
+    the integer the request's spans carry (``serve/admit``,
+    ``serve/prefill``, ``serve/finish``, ``serve/request``)."""
 
     tokens: List[int]
     n_prompt: int
@@ -150,6 +156,7 @@ class GenerationResult:
     ttft_ms: float
     tpot_ms: Optional[float]
     logits: Optional[np.ndarray] = None
+    request_id: int = 0
 
 
 @dataclass(frozen=True)
@@ -163,6 +170,7 @@ class _GenSpec:
     top_p: float
     seed: int
     echo_logits: bool
+    request_id: int = 0       # drawn at submit; joins the request's spans
 
 
 @dataclass
@@ -232,6 +240,31 @@ class _Slot:
         # the cache (None once prefill completes / for unchunked slots);
         # a slot with n_prefilled set is NOT steppable yet
         self.n_prefilled: Optional[int] = None
+
+
+class _StepInputs:
+    """One decode dispatch's host-built inputs: the per-slot arrays the
+    compiled step and samplers take, and what the group holds."""
+
+    __slots__ = ("params", "group", "echo", "toks_in", "pos", "act",
+                 "temps", "tks", "tps", "seeds", "steps", "budgets",
+                 "pages_reserved", "pages_filled")
+
+    def __init__(self, n_slots: int):
+        self.params = None
+        self.group: List[int] = []      # slot indices stepped together
+        self.echo = False               # some slot wants its logits back
+        self.toks_in = np.zeros((n_slots,), np.int32)
+        self.pos = np.zeros((n_slots,), np.int32)
+        self.act = np.zeros((n_slots,), bool)
+        self.temps = np.zeros((n_slots,), np.float32)
+        self.tks = np.zeros((n_slots,), np.int32)
+        self.tps = np.ones((n_slots,), np.float32)
+        self.seeds = np.zeros((n_slots,), np.uint32)
+        self.steps = np.zeros((n_slots,), np.int32)
+        self.budgets = np.ones((n_slots,), np.int32)
+        self.pages_reserved = 0         # pages the group's slots hold
+        self.pages_filled = 0           # ... of which hold >= 1 token
 
 
 class _PrefixNode:
@@ -533,6 +566,7 @@ class DecodeEngine:
         self._shutdown = False
         self._generation = 0
         self._chunk_cursor = 0     # round-robin over chunked prefills
+        self._request_ids = itertools.count(1)
         self._crash_next = False   # test hook: raise inside the next step
         self._thread: Optional[threading.Thread] = None
         self._supervisor: Optional[threading.Thread] = None
@@ -962,7 +996,8 @@ class DecodeEngine:
         spec = _GenSpec(prompt=prompt, max_new=max_new,
                         temperature=float(temperature), top_k=int(top_k),
                         top_p=float(top_p), seed=int(seed),
-                        echo_logits=bool(echo_logits))
+                        echo_logits=bool(echo_logits),
+                        request_id=next(self._request_ids))
         return self.batcher.submit_request(spec, slo_ms=slo_ms,
                                            deadline=deadline,
                                            tenant=tenant, model=model)
@@ -1007,7 +1042,8 @@ class DecodeEngine:
             temperature=float(handoff.temperature),
             top_k=int(handoff.top_k), top_p=float(handoff.top_p),
             seed=int(handoff.seed),
-            echo_logits=bool(handoff.echo_logits), handoff=handoff)
+            echo_logits=bool(handoff.echo_logits),
+            request_id=next(self._request_ids), handoff=handoff)
         return self.batcher.submit_request(spec, slo_ms=slo_ms,
                                            deadline=deadline, tenant=tenant)
 
@@ -1262,27 +1298,33 @@ class DecodeEngine:
             with self._lock:
                 if self._shutdown or gen != self._generation:
                     return
-            try:
-                worked = self._admit_some()
-                if self.prefill_chunk is not None:
-                    # at most ONE chunk of prefill work per iteration,
-                    # so the decode dispatch below never waits behind
-                    # more than prefill_chunk prompt tokens
-                    worked = self._prefill_chunk_step() or worked
-                if self._draft_program is not None:
-                    stepped = self._spec_step_once()
-                elif self.decode_horizon > 1:
-                    stepped = self._step_fused_once()
-                else:
-                    stepped = self._step_once()
-                worked = stepped or worked
-            except Exception as e:
-                obs_trace.instant("serve/replica_crash", cat="serve",
-                                  kind="decode_step",
-                                  error=type(e).__name__)
-                self.metrics.inc("replica_crashes")
-                self._drain_crashed(e)
-                continue
+            # everything this thread does is under serve/iteration, so
+            # no device gap is left without a span of the program's
+            with obs_trace.span("serve/iteration", cat="serve") as it:
+                try:
+                    worked = self._admit_some()
+                    if self.prefill_chunk is not None:
+                        # at most ONE chunk of prefill work per
+                        # iteration, so the decode dispatch below never
+                        # waits behind more than prefill_chunk tokens
+                        worked = self._prefill_chunk_step() or worked
+                    if self._draft_program is not None:
+                        stepped = self._spec_step_once()
+                    elif self.decode_horizon > 1:
+                        stepped = self._step_fused_once()
+                    else:
+                        stepped = self._step_once()
+                    worked = stepped or worked
+                except Exception as e:
+                    obs_trace.instant("serve/replica_crash", cat="serve",
+                                      kind="decode_step",
+                                      error=type(e).__name__)
+                    self.metrics.inc("replica_crashes")
+                    self._drain_crashed(e)
+                    worked = True       # recovery was work: do not park
+                it.set(worked=bool(worked))
+                if not worked:
+                    it.drop()   # an idle engine must not fill the ring
             if not worked:
                 self.batcher.wait_for_work(0.05)
 
@@ -1438,61 +1480,69 @@ class DecodeEngine:
                     self.metrics.inc("errors")
                     _fail_safe(r.future, e)
                     continue
-            if self.role == "prefill":
-                # a prefill host never decodes — the slot only needs the
-                # prompt's pages, exported and freed at handoff
-                need_total = pages_for(int(spec.prompt.shape[0]),
-                                       prog.page_size)
-            else:
-                max_total = min(int(spec.prompt.shape[0]) + spec.max_new,
-                                prog.max_len)
-                need_total = pages_for(max_total, prog.page_size)
-            t_attach = self.clock()
-            with self._lock:
-                if not free:
-                    leftovers.append(r)
-                    continue
-                matched = (self._prefix_lookup(spec.prompt)
-                           if self._prefix_on else [])
-                m = len(matched)
-                need = need_total - m
-                if len(self._free_pages) < need:
-                    self._prefix_evict(need - len(self._free_pages))
-                if len(self._free_pages) < need:
-                    # no incref has happened yet, so a requeued request
-                    # holds nothing — re-admission matches afresh (the
-                    # no-double-decref-by-construction invariant)
-                    leftovers.append(r)
-                    continue
-                i = free.pop(0)
-                now = self.clock()
-                for nd in matched:
-                    nd.refs += 1
-                    nd.last_used = now
-                ids = [self._free_pages.popleft() for _ in range(need)]
-                self._page_table[i] = 0
-                self._page_table[i, :m] = [nd.page_id for nd in matched]
-                self._page_table[i, m:m + need] = ids
-                slot = _Slot(r, slot_tag, ids, spec.max_new)
-                slot.shared_nodes = matched
-                slot.n_matched = m
-                self._slots[i] = slot
-                self.metrics.active_slots.set(
-                    sum(1 for s in self._slots if s is not None))
-                self.metrics.pages_in_use.set(
-                    self.total_pages - 1 - len(self._free_pages))
-                self._refresh_pool_gauges_locked()
-            if self._prefix_on:
-                if m:
-                    self.metrics.inc("prefix_hits")
-                    self.metrics.inc("prefix_hit_tokens",
-                                     m * prog.page_size)
+            # one request's admission, from the page count to the slot's
+            # assignment; the prefill that follows is its sibling
+            with obs_trace.span("serve/admit", cat="serve",
+                                request_id=spec.request_id) as sp:
+                if self.role == "prefill":
+                    # a prefill host never decodes — the slot only needs
+                    # the prompt's pages, exported and freed at handoff
+                    need_total = pages_for(int(spec.prompt.shape[0]),
+                                           prog.page_size)
                 else:
-                    self.metrics.inc("prefix_misses")
-                obs_trace.complete_at(
-                    "serve/prefix_attach", t_attach, self.clock(),
-                    cat="serve", slot=i, matched_pages=m,
-                    matched_tokens=m * prog.page_size)
+                    max_total = min(
+                        int(spec.prompt.shape[0]) + spec.max_new,
+                        prog.max_len)
+                    need_total = pages_for(max_total, prog.page_size)
+                with self._lock:
+                    if not free:
+                        leftovers.append(r)
+                        sp.set(requeued=True)
+                        continue
+                    matched = (self._prefix_lookup(spec.prompt)
+                               if self._prefix_on else [])
+                    m = len(matched)
+                    need = need_total - m
+                    if len(self._free_pages) < need:
+                        self._prefix_evict(need - len(self._free_pages))
+                    if len(self._free_pages) < need:
+                        # no incref has happened yet, so a requeued
+                        # request holds nothing — re-admission matches
+                        # afresh (the no-double-decref-by-construction
+                        # invariant)
+                        leftovers.append(r)
+                        sp.set(requeued=True)
+                        continue
+                    i = free.pop(0)
+                    now = self.clock()
+                    for nd in matched:
+                        nd.refs += 1
+                        nd.last_used = now
+                    ids = [self._free_pages.popleft() for _ in range(need)]
+                    self._page_table[i] = 0
+                    self._page_table[i, :m] = [nd.page_id for nd in matched]
+                    self._page_table[i, m:m + need] = ids
+                    slot = _Slot(r, slot_tag, ids, spec.max_new)
+                    slot.shared_nodes = matched
+                    slot.n_matched = m
+                    self._slots[i] = slot
+                    self.metrics.active_slots.set(
+                        sum(1 for s in self._slots if s is not None))
+                    self.metrics.pages_in_use.set(
+                        self.total_pages - 1 - len(self._free_pages))
+                    self._refresh_pool_gauges_locked()
+                queue_wait_ms = (now - r.t_submit) * 1e3
+                self.metrics.queue_wait.record(queue_wait_ms)
+                if self._prefix_on:
+                    if m:
+                        self.metrics.inc("prefix_hits")
+                        self.metrics.inc("prefix_hit_tokens",
+                                         m * prog.page_size)
+                    else:
+                        self.metrics.inc("prefix_misses")
+                sp.set(slot=i, queue_wait_ms=round(queue_wait_ms, 3),
+                       pages_reserved=need_total, matched_pages=m,
+                       matched_tokens=m * prog.page_size)
             self.metrics.inc("requests")
             if transfer is not None:
                 self._attach_handoff(i, transfer)
@@ -1548,50 +1598,49 @@ class DecodeEngine:
         spec = s.spec
         n = s.n_prompt
         m = s.n_matched * self.program.page_size   # matched prefix tokens
-        t0 = self.clock()
-        kp, vp = self._cache
-        if m:
-            # prefix-cache hit: prefill ONLY the unmatched suffix; the
-            # shared pages already hold the prefix rows and the suffix
-            # rows attend over them (prefill_at) — same per-row math as
-            # a cold prefill, so the logits are bit-identical
-            suffix = n - m
-            bucket = self._bucket_for(suffix)
-            padded = np.zeros((bucket,), np.int32)
-            padded[:suffix] = spec.prompt[m:]
-            kp, vp, lg = self._compiled[("prefill_at", bucket)](
-                self._versions[s.tag], kp, vp, self._page_table[i], padded,
-                np.int32(suffix), np.int32(m))
-        else:
-            bucket = self._bucket_for(n)
-            padded = np.zeros((bucket,), np.int32)
-            padded[:n] = spec.prompt
-            kp, vp, lg = self._compiled[("prefill", bucket)](
-                self._versions[s.tag], kp, vp, self._page_table[i], padded,
-                np.int32(n))
-        tok, fin = self._compiled[("sample1",)](
-            lg, np.float32(spec.temperature), np.int32(spec.top_k),
-            np.float32(spec.top_p), np.uint32(spec.seed), np.int32(0))
-        self._cache = (kp, vp)
-        if self._draft_program is not None:
-            # mirror the prompt into the draft pool (same page ids, the
-            # draft's dims) so proposals start from the right state
-            dkp, dvp = self._draft_cache
+        bucket = self._bucket_for(n - m)
+        with obs_trace.span("serve/prefill", cat="serve", slot=i,
+                            bucket=bucket, prompt_tokens=n, model=s.tag,
+                            request_id=spec.request_id):
+            kp, vp = self._cache
             if m:
-                dkp, dvp, _ = self._compiled[("draft_prefill_at", bucket)](
-                    self._draft_params, dkp, dvp, self._page_table[i],
-                    padded, np.int32(n - m), np.int32(m))
+                # prefix-cache hit: prefill ONLY the unmatched suffix; the
+                # shared pages already hold the prefix rows and the suffix
+                # rows attend over them (prefill_at) — same per-row math as
+                # a cold prefill, so the logits are bit-identical
+                suffix = n - m
+                padded = np.zeros((bucket,), np.int32)
+                padded[:suffix] = spec.prompt[m:]
+                kp, vp, lg = self._compiled[("prefill_at", bucket)](
+                    self._versions[s.tag], kp, vp, self._page_table[i], padded,
+                    np.int32(suffix), np.int32(m))
             else:
-                dkp, dvp, _ = self._compiled[("draft_prefill", bucket)](
-                    self._draft_params, dkp, dvp, self._page_table[i],
-                    padded, np.int32(n))
-            self._draft_cache = (dkp, dvp)
-        tok_h = int(np.asarray(tok))
-        fin_h = bool(np.asarray(fin))
-        lg_h = np.asarray(lg) if spec.echo_logits else None
-        t1 = self.clock()
-        obs_trace.complete_at("serve/prefill", t0, t1, cat="serve", slot=i,
-                              bucket=bucket, prompt_tokens=n, model=s.tag)
+                padded = np.zeros((bucket,), np.int32)
+                padded[:n] = spec.prompt
+                kp, vp, lg = self._compiled[("prefill", bucket)](
+                    self._versions[s.tag], kp, vp, self._page_table[i], padded,
+                    np.int32(n))
+            tok, fin = self._compiled[("sample1",)](
+                lg, np.float32(spec.temperature), np.int32(spec.top_k),
+                np.float32(spec.top_p), np.uint32(spec.seed), np.int32(0))
+            self._cache = (kp, vp)
+            if self._draft_program is not None:
+                # mirror the prompt into the draft pool (same page ids, the
+                # draft's dims) so proposals start from the right state
+                dkp, dvp = self._draft_cache
+                if m:
+                    dkp, dvp, _ = self._compiled[("draft_prefill_at", bucket)](
+                        self._draft_params, dkp, dvp, self._page_table[i],
+                        padded, np.int32(n - m), np.int32(m))
+                else:
+                    dkp, dvp, _ = self._compiled[("draft_prefill", bucket)](
+                        self._draft_params, dkp, dvp, self._page_table[i],
+                        padded, np.int32(n))
+                self._draft_cache = (dkp, dvp)
+            tok_h = int(np.asarray(tok))
+            fin_h = bool(np.asarray(fin))
+            lg_h = np.asarray(lg) if spec.echo_logits else None
+            t1 = self.clock()
         self.metrics.inc("prefills")
         self.metrics.ttft.record((t1 - s.req.t_submit) * 1e3)
         s.t_first = t1
@@ -1630,31 +1679,29 @@ class DecodeEngine:
         bucket = self._bucket_for(take)
         padded = np.zeros((bucket,), np.int32)
         padded[:take] = spec.prompt[p:p + take]
-        t0 = self.clock()
-        kp, vp = self._cache
-        kp, vp, lg = self._compiled[("prefill_at", bucket)](
-            self._versions[s.tag], kp, vp, self._page_table[i], padded,
-            np.int32(take), np.int32(p))
-        self._cache = (kp, vp)
-        self.metrics.inc("prefill_chunks")
-        if p + take < n:
+        last = p + take >= n
+        with obs_trace.span("serve/prefill", cat="serve", slot=i,
+                            bucket=bucket, prompt_tokens=take, offset=p,
+                            model=s.tag, request_id=spec.request_id):
+            kp, vp = self._cache
+            kp, vp, lg = self._compiled[("prefill_at", bucket)](
+                self._versions[s.tag], kp, vp, self._page_table[i], padded,
+                np.int32(take), np.int32(p))
+            self._cache = (kp, vp)
+            if last:
+                # final chunk — the _prefill_slot tail
+                tok, fin = self._compiled[("sample1",)](
+                    lg, np.float32(spec.temperature), np.int32(spec.top_k),
+                    np.float32(spec.top_p), np.uint32(spec.seed),
+                    np.int32(0))
+                tok_h = int(np.asarray(tok))
+                fin_h = bool(np.asarray(fin))
+                lg_h = np.asarray(lg) if spec.echo_logits else None
             t1 = self.clock()
-            obs_trace.complete_at(
-                "serve/prefill", t0, t1, cat="serve", slot=i,
-                bucket=bucket, prompt_tokens=take, offset=p, model=s.tag)
+        self.metrics.inc("prefill_chunks")
+        if not last:
             s.n_prefilled = p + take
             return True
-        # final chunk — the _prefill_slot tail
-        tok, fin = self._compiled[("sample1",)](
-            lg, np.float32(spec.temperature), np.int32(spec.top_k),
-            np.float32(spec.top_p), np.uint32(spec.seed), np.int32(0))
-        tok_h = int(np.asarray(tok))
-        fin_h = bool(np.asarray(fin))
-        lg_h = np.asarray(lg) if spec.echo_logits else None
-        t1 = self.clock()
-        obs_trace.complete_at(
-            "serve/prefill", t0, t1, cat="serve", slot=i, bucket=bucket,
-            prompt_tokens=take, offset=p, model=s.tag)
         self.metrics.inc("prefills")
         if p > first_offset:
             self.metrics.inc("chunked_prefills")   # took >= 2 chunks
@@ -1680,7 +1727,6 @@ class DecodeEngine:
         pps = self.program.pages_per_slot
         m = s.n_matched
         p_pro = transfer.n_pages
-        t0 = self.clock()
         ids = np.zeros((pps,), np.int32)        # scratch: write discarded
         ids[m:p_pro] = self._page_table[i][m:p_pro]
 
@@ -1694,14 +1740,15 @@ class DecodeEngine:
                 return full
             return jax.tree_util.tree_map(one, side)
 
-        kp, vp = self._cache
-        kp, vp = self._compiled[("attach",)](
-            kp, vp, ids, _pad(transfer.k), _pad(transfer.v))
-        self._cache = (kp, vp)
-        t1 = self.clock()
-        obs_trace.complete_at("serve/prefill", t0, t1, cat="serve", slot=i,
-                              bucket=0, prompt_tokens=s.n_prompt,
-                              model=s.tag, attached_pages=p_pro - m)
+        with obs_trace.span("serve/prefill", cat="serve", slot=i, bucket=0,
+                            prompt_tokens=s.n_prompt, model=s.tag,
+                            attached_pages=p_pro - m,
+                            request_id=s.spec.request_id):
+            kp, vp = self._cache
+            kp, vp = self._compiled[("attach",)](
+                kp, vp, ids, _pad(transfer.k), _pad(transfer.v))
+            self._cache = (kp, vp)
+            t1 = self.clock()
         self.metrics.inc("prefills")
         self.metrics.inc("handoffs_in")
         self.metrics.inc("pages_attached", p_pro - m)
@@ -1732,32 +1779,31 @@ class DecodeEngine:
         spec = s.spec
         n = s.n_prompt
         m = s.n_matched * self.program.page_size
-        t0 = self.clock()
-        kp, vp = self._cache
-        if m:
-            suffix = n - m
-            bucket = self._bucket_for(suffix)
-            padded = np.zeros((bucket,), np.int32)
-            padded[:suffix] = spec.prompt[m:]
-            kp, vp, lg = self._compiled[("prefill_at", bucket)](
-                self._versions[s.tag], kp, vp, self._page_table[i], padded,
-                np.int32(suffix), np.int32(m))
-        else:
-            bucket = self._bucket_for(n)
-            padded = np.zeros((bucket,), np.int32)
-            padded[:n] = spec.prompt
-            kp, vp, lg = self._compiled[("prefill", bucket)](
-                self._versions[s.tag], kp, vp, self._page_table[i], padded,
-                np.int32(n))
-        tok, fin = self._compiled[("sample1",)](
-            lg, np.float32(spec.temperature), np.int32(spec.top_k),
-            np.float32(spec.top_p), np.uint32(spec.seed), np.int32(0))
-        self._cache = (kp, vp)
-        tok_h = int(np.asarray(tok))
-        fin_h = bool(np.asarray(fin))
-        t1 = self.clock()
-        obs_trace.complete_at("serve/prefill", t0, t1, cat="serve", slot=i,
-                              bucket=bucket, prompt_tokens=n, model=s.tag)
+        bucket = self._bucket_for(n - m)
+        with obs_trace.span("serve/prefill", cat="serve", slot=i,
+                            bucket=bucket, prompt_tokens=n, model=s.tag,
+                            request_id=spec.request_id):
+            kp, vp = self._cache
+            if m:
+                suffix = n - m
+                padded = np.zeros((bucket,), np.int32)
+                padded[:suffix] = spec.prompt[m:]
+                kp, vp, lg = self._compiled[("prefill_at", bucket)](
+                    self._versions[s.tag], kp, vp, self._page_table[i], padded,
+                    np.int32(suffix), np.int32(m))
+            else:
+                padded = np.zeros((bucket,), np.int32)
+                padded[:n] = spec.prompt
+                kp, vp, lg = self._compiled[("prefill", bucket)](
+                    self._versions[s.tag], kp, vp, self._page_table[i], padded,
+                    np.int32(n))
+            tok, fin = self._compiled[("sample1",)](
+                lg, np.float32(spec.temperature), np.int32(spec.top_k),
+                np.float32(spec.top_p), np.uint32(spec.seed), np.int32(0))
+            self._cache = (kp, vp)
+            tok_h = int(np.asarray(tok))
+            fin_h = bool(np.asarray(fin))
+            t1 = self.clock()
         self.metrics.inc("prefills")
         self.metrics.ttft.record((t1 - s.req.t_submit) * 1e3)
         s.t_first = t1
@@ -1811,13 +1857,13 @@ class DecodeEngine:
         _set_safe(s.req.future, handoff)
         obs_trace.complete_at("serve/request", s.req.t_submit, now,
                               cat="serve", kind="prefill_handoff",
-                              tokens=1, finish="handoff")
+                              tokens=1, finish="handoff",
+                              request_id=spec.request_id)
 
     def _step_once(self) -> bool:
         """One decode step per distinct active version tag (same
         executable, that tag's params, that tag's slots active) — the
         no-version-mixing hot-swap invariant lives here."""
-        s_n = self.max_slots
         with self._lock:
             tags: List[str] = []
             for s in self._slots:
@@ -1831,70 +1877,88 @@ class DecodeEngine:
         if not tags:
             return False
         for tag in tags:
-            toks_in = np.zeros((s_n,), np.int32)
-            pos = np.zeros((s_n,), np.int32)
-            act = np.zeros((s_n,), bool)
-            temps = np.zeros((s_n,), np.float32)
-            tks = np.zeros((s_n,), np.int32)
-            tps = np.ones((s_n,), np.float32)
-            seeds = np.zeros((s_n,), np.uint32)
-            steps = np.zeros((s_n,), np.int32)
-            group: List[int] = []
-            echo = False
-            with self._lock:
-                params = self._versions.get(tag)
-                if params is None:
+            with obs_trace.span("serve/decode_step", cat="serve",
+                                model=tag, tokens=1) as sp:
+                inp = self._step_inputs(tag)
+                if inp is None:
                     continue
-                for i, s in enumerate(self._slots):
-                    if (s is None or s.tag != tag
-                            or s.n_prefilled is not None):
-                        continue
-                    group.append(i)
-                    toks_in[i] = s.last_token
-                    pos[i] = s.pos
-                    act[i] = True
-                    temps[i] = s.spec.temperature
-                    tks[i] = s.spec.top_k
-                    tps[i] = s.spec.top_p
-                    seeds[i] = s.spec.seed
-                    steps[i] = s.n_out
-                    echo = echo or s.logits is not None
-            if not group:
-                continue
-            t0 = self.clock()
-            kp, vp = self._cache
-            kp, vp, lgs = self._compiled[("step",)](
-                params, kp, vp, self._page_table, toks_in, pos, act)
-            t_step = self.clock()
-            toks, fin = self._compiled[("sample",)](
-                lgs, temps, tks, tps, seeds, steps)
-            self._cache = (kp, vp)
-            toks_h = np.asarray(toks)
-            fin_h = np.asarray(fin)
-            lgs_h = np.asarray(lgs) if echo else None
-            t1 = self.clock()
-            obs_trace.complete_at("serve/decode_step", t0, t1, cat="serve",
-                                  n_active=len(group), model=tag, tokens=1,
-                                  step_ms=round((t_step - t0) * 1e3, 3),
-                                  sample_ms=round((t1 - t_step) * 1e3, 3))
-            if getattr(self.program, "tp", 1) > 1:
-                obs_trace.complete_at(
-                    "serve/shard_step", t0, t1, cat="serve",
-                    n_active=len(group), shards=int(self.program.tp),
-                    model=tag)
-            self.metrics.inc("decode_steps")
-            self.metrics.step_time.record((t1 - t0) * 1e3)
-            for i in group:
-                with self._lock:
-                    s = self._slots[i]
-                if s is not None:
-                    s.pos += 1
-                    self._record_token(
-                        i, int(toks_h[i]), bool(fin_h[i]),
-                        lgs_h[i].copy() if (lgs_h is not None
-                                            and s.logits is not None)
-                        else None, t1)
+                t0 = self.clock()
+                with obs_trace.span("serve/step_dispatch", cat="serve"):
+                    kp, vp = self._cache
+                    kp, vp, lgs = self._compiled[("step",)](
+                        inp.params, kp, vp, self._page_table, inp.toks_in,
+                        inp.pos, inp.act)
+                t_step = self.clock()
+                with obs_trace.span("serve/sample_dispatch", cat="serve"):
+                    toks, fin = self._compiled[("sample",)](
+                        lgs, inp.temps, inp.tks, inp.tps, inp.seeds,
+                        inp.steps)
+                    self._cache = (kp, vp)
+                # the blocking read-back: the device works inside it
+                with obs_trace.span("serve/step_wait", cat="serve"):
+                    toks_h = np.asarray(toks)
+                    fin_h = np.asarray(fin)
+                    lgs_h = np.asarray(lgs) if inp.echo else None
+                t1 = self.clock()
+                self._set_step_args(sp, inp, step_ms=(t_step - t0) * 1e3,
+                                    sample_ms=(t1 - t_step) * 1e3)
+                self.metrics.inc("decode_steps")
+                self.metrics.step_time.record((t1 - t0) * 1e3)
+                with obs_trace.span("serve/step_record", cat="serve"):
+                    for i in inp.group:
+                        with self._lock:
+                            s = self._slots[i]
+                        if s is not None:
+                            s.pos += 1
+                            self._record_token(
+                                i, int(toks_h[i]), bool(fin_h[i]),
+                                lgs_h[i].copy() if (lgs_h is not None
+                                                    and s.logits is not None)
+                                else None, t1)
         return True
+
+    def _step_inputs(self, tag: str) -> Optional[_StepInputs]:
+        """Assemble, under the lock, the arrays one dispatch takes for
+        the steppable slots serving ``tag``; None when the version is
+        gone or no slot is left to step."""
+        inp = _StepInputs(self.max_slots)
+        with obs_trace.span("serve/step_build", cat="serve"), self._lock:
+            inp.params = self._versions.get(tag)
+            if inp.params is None:
+                return None
+            filled_all = 0
+            for i, s in enumerate(self._slots):
+                if s is None:
+                    continue
+                filled = self._pages_filled(s)
+                filled_all += filled
+                if s.tag != tag or s.n_prefilled is not None:
+                    continue
+                inp.group.append(i)
+                inp.toks_in[i] = s.last_token
+                inp.pos[i] = s.pos
+                inp.act[i] = True
+                inp.temps[i] = s.spec.temperature
+                inp.tks[i] = s.spec.top_k
+                inp.tps[i] = s.spec.top_p
+                inp.seeds[i] = s.spec.seed
+                inp.steps[i] = s.n_out
+                inp.budgets[i] = max(1, s.max_new - s.n_out)
+                inp.echo = inp.echo or s.logits is not None
+                inp.pages_reserved += len(s.page_ids) + len(s.shared_nodes)
+                inp.pages_filled += filled
+            self.metrics.pages_filled.set(filled_all)
+        return inp if inp.group else None
+
+    def _set_step_args(self, sp, inp: _StepInputs, step_ms: float,
+                       sample_ms: float) -> None:
+        """The arguments every ``serve/decode_step`` span carries."""
+        tp = int(getattr(self.program, "tp", 1))
+        sp.set(n_active=len(inp.group), step_ms=round(step_ms, 3),
+               sample_ms=round(sample_ms, 3), queued=self.batcher.qsize(),
+               pages_reserved=inp.pages_reserved,
+               pages_filled=inp.pages_filled,
+               **({"shards": tp} if tp > 1 else {}))
 
     def _step_fused_once(self) -> bool:
         """One FUSED dispatch per distinct active version tag: H =
@@ -1910,7 +1974,6 @@ class DecodeEngine:
         anywhere inside the horizon retries from the last committed
         token and regenerates identical bits (seeded counter-based
         sampling)."""
-        s_n = self.max_slots
         H = self.decode_horizon
         with self._lock:
             tags: List[str] = []
@@ -1926,84 +1989,56 @@ class DecodeEngine:
             return False
         eos = np.int32(self.eos_id if self.eos_id is not None else -1)
         for tag in tags:
-            toks_in = np.zeros((s_n,), np.int32)
-            pos = np.zeros((s_n,), np.int32)
-            act = np.zeros((s_n,), bool)
-            temps = np.zeros((s_n,), np.float32)
-            tks = np.zeros((s_n,), np.int32)
-            tps = np.ones((s_n,), np.float32)
-            seeds = np.zeros((s_n,), np.uint32)
-            steps = np.zeros((s_n,), np.int32)
-            budgets = np.ones((s_n,), np.int32)
-            group: List[int] = []
-            echo = False
-            with self._lock:
-                params = self._versions.get(tag)
-                if params is None:
+            with obs_trace.span("serve/decode_step", cat="serve",
+                                model=tag, tokens=H) as sp:
+                inp = self._step_inputs(tag)
+                if inp is None:
                     continue
-                for i, s in enumerate(self._slots):
-                    if (s is None or s.tag != tag
-                            or s.n_prefilled is not None):
-                        continue
-                    group.append(i)
-                    toks_in[i] = s.last_token
-                    pos[i] = s.pos
-                    act[i] = True
-                    temps[i] = s.spec.temperature
-                    tks[i] = s.spec.top_k
-                    tps[i] = s.spec.top_p
-                    seeds[i] = s.spec.seed
-                    steps[i] = s.n_out
-                    budgets[i] = max(1, s.max_new - s.n_out)
-                    echo = echo or s.logits is not None
-            if not group:
-                continue
-            t0 = self.clock()
-            kp, vp = self._cache
-            kp, vp, toks, fins, lgs = self._compiled[("step_multi", H)](
-                params, kp, vp, self._page_table, toks_in, pos, act,
-                temps, tks, tps, seeds, steps, budgets, eos,
-                np.arange(H, dtype=np.int32))
-            self._cache = (kp, vp)
-            toks_h = np.asarray(toks)      # [H, S]
-            fins_h = np.asarray(fins)
-            lgs_h = np.asarray(lgs) if echo else None
-            t1 = self.clock()
-            if crash:
-                # "mid-horizon" from the host's view: the device has
-                # advanced H tokens but NONE are committed — recovery
-                # must retry from the last committed token
-                raise ReplicaCrashError(
-                    "injected decode-batch crash (test hook)")
-            obs_trace.complete_at("serve/decode_step", t0, t1, cat="serve",
-                                  n_active=len(group), model=tag, tokens=H,
-                                  step_ms=round((t1 - t0) * 1e3, 3),
-                                  sample_ms=0.0)
-            if getattr(self.program, "tp", 1) > 1:
-                obs_trace.complete_at(
-                    "serve/shard_step", t0, t1, cat="serve",
-                    n_active=len(group), shards=int(self.program.tp),
-                    model=tag)
-            self.metrics.inc("decode_steps")
-            self.metrics.inc("fused_dispatches")
-            self.metrics.step_time.record((t1 - t0) * 1e3)
-            committed = 0
-            for i in group:
-                for j in range(H):
-                    with self._lock:
-                        s = self._slots[i]
-                    if s is None:
-                        break       # stopped mid-horizon; drop overrun
-                    s.pos += 1
-                    fin_j = bool(fins_h[j, i])
-                    self._record_token(
-                        i, int(toks_h[j, i]), fin_j,
-                        lgs_h[j, i].copy() if (lgs_h is not None
-                                               and s.logits is not None)
-                        else None, t1)
-                    if fin_j:
-                        committed += 1
-            self.metrics.inc("tokens_per_dispatch", committed)
+                t0 = self.clock()
+                with obs_trace.span("serve/step_dispatch", cat="serve"):
+                    kp, vp = self._cache
+                    kp, vp, toks, fins, lgs = \
+                        self._compiled[("step_multi", H)](
+                            inp.params, kp, vp, self._page_table,
+                            inp.toks_in, inp.pos, inp.act, inp.temps,
+                            inp.tks, inp.tps, inp.seeds, inp.steps,
+                            inp.budgets, eos, np.arange(H, dtype=np.int32))
+                    self._cache = (kp, vp)
+                with obs_trace.span("serve/step_wait", cat="serve"):
+                    toks_h = np.asarray(toks)      # [H, S]
+                    fins_h = np.asarray(fins)
+                    lgs_h = np.asarray(lgs) if inp.echo else None
+                t1 = self.clock()
+                if crash:
+                    # "mid-horizon" from the host's view: the device has
+                    # advanced H tokens but NONE are committed — recovery
+                    # must retry from the last committed token
+                    raise ReplicaCrashError(
+                        "injected decode-batch crash (test hook)")
+                self._set_step_args(sp, inp, step_ms=(t1 - t0) * 1e3,
+                                    sample_ms=0.0)
+                self.metrics.inc("decode_steps")
+                self.metrics.inc("fused_dispatches")
+                self.metrics.step_time.record((t1 - t0) * 1e3)
+                committed = 0
+                with obs_trace.span("serve/step_record", cat="serve"):
+                    for i in inp.group:
+                        for j in range(H):
+                            with self._lock:
+                                s = self._slots[i]
+                            if s is None:
+                                break   # stopped mid-horizon; drop overrun
+                            s.pos += 1
+                            fin_j = bool(fins_h[j, i])
+                            self._record_token(
+                                i, int(toks_h[j, i]), fin_j,
+                                lgs_h[j, i].copy()
+                                if (lgs_h is not None
+                                    and s.logits is not None) else None,
+                                t1)
+                            if fin_j:
+                                committed += 1
+                self.metrics.inc("tokens_per_dispatch", committed)
         return True
 
     def _spec_step_once(self) -> bool:
@@ -2031,68 +2066,44 @@ class DecodeEngine:
         if not tags:
             return False
         for tag in tags:
-            toks_in = np.zeros((s_n,), np.int32)
-            pos = np.zeros((s_n,), np.int32)
-            act = np.zeros((s_n,), bool)
-            temps = np.zeros((s_n,), np.float32)
-            tks = np.zeros((s_n,), np.int32)
-            tps = np.ones((s_n,), np.float32)
-            seeds = np.zeros((s_n,), np.uint32)
-            steps = np.zeros((s_n,), np.int32)
-            group: List[int] = []
-            echo = False
-            with self._lock:
-                params = self._versions.get(tag)
-                if params is None:
-                    continue
-                for i, s in enumerate(self._slots):
-                    if s is None or s.tag != tag:
-                        continue
-                    group.append(i)
-                    toks_in[i] = s.last_token
-                    pos[i] = s.pos
-                    act[i] = True
-                    temps[i] = s.spec.temperature
-                    tks[i] = s.spec.top_k
-                    tps[i] = s.spec.top_p
-                    seeds[i] = s.spec.seed
-                    steps[i] = s.n_out
-                    echo = echo or s.logits is not None
-            if not group:
+            inp = self._step_inputs(tag)
+            if inp is None:
                 continue
+            group = inp.group
             t0 = self.clock()
             dkp, dvp = self._draft_cache
-            cur = toks_in
+            cur = inp.toks_in
             d_toks_dev, d_probs_dev = [], []
             for j in range(k):
                 dkp, dvp, dlgs = self._compiled[("draft_step",)](
                     self._draft_params, dkp, dvp, self._page_table, cur,
-                    pos + j, act)
+                    inp.pos + j, inp.act)
                 d_tok, d_prob = self._compiled[("propose",)](
-                    dlgs, temps, tks, tps, seeds, steps + j)
+                    dlgs, inp.temps, inp.tks, inp.tps, inp.seeds,
+                    inp.steps + j)
                 d_toks_dev.append(d_tok)
                 d_probs_dev.append(d_prob)
                 cur = d_tok
             self._draft_cache = (dkp, dvp)
             d_toks = np.stack([np.asarray(t) for t in d_toks_dev],
                               1).astype(np.int32)          # [S, k]
-            spec_tokens = np.concatenate([toks_in[:, None], d_toks], 1)
-            kp, vp = self._cache
-            tv0 = self.clock()
-            kp, vp, lgs = self._compiled[("spec_step",)](
-                params, kp, vp, self._page_table, spec_tokens, pos, act)
-            n_commit, commit, fin = self._compiled[("spec_accept",)](
-                lgs, d_toks,
-                np.stack([np.asarray(p) for p in d_probs_dev], 1),
-                temps, tks, tps, seeds, steps)
-            self._cache = (kp, vp)
-            nc_h = np.asarray(n_commit)
-            cm_h = np.asarray(commit)
-            fin_h = np.asarray(fin)
-            lgs_h = np.asarray(lgs) if echo else None
-            t1 = self.clock()
-            obs_trace.complete_at("serve/spec_verify", tv0, t1, cat="serve",
-                                  n_active=len(group), k=k, model=tag)
+            spec_tokens = np.concatenate([inp.toks_in[:, None], d_toks], 1)
+            with obs_trace.span("serve/spec_verify", cat="serve",
+                                n_active=len(group), k=k, model=tag):
+                kp, vp = self._cache
+                kp, vp, lgs = self._compiled[("spec_step",)](
+                    inp.params, kp, vp, self._page_table, spec_tokens,
+                    inp.pos, inp.act)
+                n_commit, commit, fin = self._compiled[("spec_accept",)](
+                    lgs, d_toks,
+                    np.stack([np.asarray(p) for p in d_probs_dev], 1),
+                    inp.temps, inp.tks, inp.tps, inp.seeds, inp.steps)
+                self._cache = (kp, vp)
+                nc_h = np.asarray(n_commit)
+                cm_h = np.asarray(commit)
+                fin_h = np.asarray(fin)
+                lgs_h = np.asarray(lgs) if inp.echo else None
+                t1 = self.clock()
             self.metrics.inc("decode_steps")
             self.metrics.step_time.record((t1 - t0) * 1e3)
             self.metrics.inc("spec_steps")
@@ -2100,32 +2111,33 @@ class DecodeEngine:
             committed = 0
             catchup = np.zeros((s_n,), bool)
             cu_tok = np.zeros((s_n,), np.int32)
-            for i in group:
-                c = int(nc_h[i])
-                self.metrics.inc("spec_accepted", c - 1)
-                for j in range(c):
+            with obs_trace.span("serve/step_record", cat="serve"):
+                for i in group:
+                    c = int(nc_h[i])
+                    self.metrics.inc("spec_accepted", c - 1)
+                    for j in range(c):
+                        with self._lock:
+                            s = self._slots[i]
+                        if s is None:   # stopped mid-commit (eos/max/...)
+                            break
+                        s.pos += 1
+                        committed += 1
+                        self._record_token(
+                            i, int(cm_h[i, j]), bool(fin_h[i]),
+                            lgs_h[i, j].copy()
+                            if (lgs_h is not None and s.logits is not None)
+                            else None, t1)
                     with self._lock:
-                        s = self._slots[i]
-                    if s is None:      # stopped mid-commit (eos/max/...)
-                        break
-                    s.pos += 1
-                    committed += 1
-                    self._record_token(
-                        i, int(cm_h[i, j]), bool(fin_h[i]),
-                        lgs_h[i, j].copy() if (lgs_h is not None
-                                               and s.logits is not None)
-                        else None, t1)
-                with self._lock:
-                    alive = self._slots[i] is not None
-                if alive and c == k + 1:
-                    catchup[i] = True
-                    cu_tok[i] = d_toks[i, k - 1]
+                        alive = self._slots[i] is not None
+                    if alive and c == k + 1:
+                        catchup[i] = True
+                        cu_tok[i] = d_toks[i, k - 1]
             self.metrics.inc("spec_committed", committed)
             if catchup.any():
                 dkp, dvp = self._draft_cache
                 dkp, dvp, _ = self._compiled[("draft_step",)](
                     self._draft_params, dkp, dvp, self._page_table, cu_tok,
-                    pos + k, catchup)
+                    inp.pos + k, catchup)
                 self._draft_cache = (dkp, dvp)
         return True
 
@@ -2179,49 +2191,63 @@ class DecodeEngine:
 
     def _finish(self, i: int, now: float, reason: Optional[str] = None,
                 error: Optional[BaseException] = None) -> None:
-        with self._lock:
-            s = self._slots[i]
-            if s is None:
-                return
-            self._slots[i] = None
-            self._free_pages.extend(s.page_ids)
-            for nd in reversed(s.shared_nodes):
-                # decref, never free: trie pages stay resident for the
-                # next shared-prefix request until LRU eviction
-                nd.refs -= 1
-                nd.last_used = now
-            s.shared_nodes = []
-            self._page_table[i] = 0
-            live_tags = {sl.tag for sl in self._slots if sl is not None}
-            live_tags.add(self._serve_tag)
-            live_tags.update(self._model_tags.values())
-            for t in [t for t in self._versions if t not in live_tags]:
-                del self._versions[t]
-            self.metrics.active_slots.set(
-                sum(1 for sl in self._slots if sl is not None))
-            self.metrics.pages_in_use.set(
-                self.total_pages - 1 - len(self._free_pages))
-            self._refresh_pool_gauges_locked()
-        if error is not None:
-            self.metrics.inc("errors")
-            _fail_safe(s.req.future, error)
-        else:
-            self.metrics.inc({"eos": "eos_stops",
-                              "max_tokens": "max_token_stops",
-                              "deadline": "deadline_stops"}[reason])
-            tpot = ((s.t_last - s.t_first) * 1e3 / (s.n_out - 1)
-                    if s.n_out > 1 else None)
-            if tpot is not None:
-                self.metrics.tpot.record(tpot)
-            _set_safe(s.req.future, GenerationResult(
-                tokens=list(s.tokens), n_prompt=s.n_prompt,
-                finish_reason=reason, model_tag=s.tag,
-                ttft_ms=round((s.t_first - s.req.t_submit) * 1e3, 3),
-                tpot_ms=round(tpot, 3) if tpot is not None else None,
-                logits=np.stack(s.logits) if s.logits else None))
+        if self._slots[i] is None:
+            return
+        with obs_trace.span("serve/finish", cat="serve",
+                            reason=reason or "error") as sp:
+            with self._lock:
+                s = self._slots[i]
+                if s is None:
+                    return
+                self._slots[i] = None
+                self._free_pages.extend(s.page_ids)
+                for nd in reversed(s.shared_nodes):
+                    # decref, never free: trie pages stay resident for
+                    # the next shared-prefix request until LRU eviction
+                    nd.refs -= 1
+                    nd.last_used = now
+                s.shared_nodes = []
+                self._page_table[i] = 0
+                live_tags = {sl.tag for sl in self._slots if sl is not None}
+                live_tags.add(self._serve_tag)
+                live_tags.update(self._model_tags.values())
+                for t in [t for t in self._versions if t not in live_tags]:
+                    del self._versions[t]
+                self.metrics.active_slots.set(
+                    sum(1 for sl in self._slots if sl is not None))
+                self.metrics.pages_in_use.set(
+                    self.total_pages - 1 - len(self._free_pages))
+                self._refresh_pool_gauges_locked()
+            request_id = s.spec.request_id
+            # an error before the first token leaves no time to it
+            ttft_ms = (round((s.t_first - s.req.t_submit) * 1e3, 3)
+                       if s.t_first else None)
+            if error is not None:
+                self.metrics.inc("errors")
+                _fail_safe(s.req.future, error)
+            else:
+                self.metrics.inc({"eos": "eos_stops",
+                                  "max_tokens": "max_token_stops",
+                                  "deadline": "deadline_stops"}[reason])
+                tpot = ((s.t_last - s.t_first) * 1e3 / (s.n_out - 1)
+                        if s.n_out > 1 else None)
+                if tpot is not None:
+                    self.metrics.tpot.record(tpot)
+                _set_safe(s.req.future, GenerationResult(
+                    tokens=list(s.tokens), n_prompt=s.n_prompt,
+                    finish_reason=reason, model_tag=s.tag, ttft_ms=ttft_ms,
+                    tpot_ms=round(tpot, 3) if tpot is not None else None,
+                    logits=np.stack(s.logits) if s.logits else None,
+                    request_id=request_id))
+            sp.set(request_id=request_id, tokens=s.n_out,
+                   request_ms=round((now - s.req.t_submit) * 1e3, 3))
+            if ttft_ms is not None:
+                sp.set(ttft_ms=ttft_ms)
+        # submit -> result spans threads, so it cannot be a live scope
         obs_trace.complete_at("serve/request", s.req.t_submit, now,
                               cat="serve", kind="generate", tokens=s.n_out,
-                              finish=reason or "error")
+                              finish=reason or "error",
+                              request_id=request_id)
 
     # -- crash recovery ----------------------------------------------------
 
@@ -2276,6 +2302,13 @@ class DecodeEngine:
         self.metrics.free_pages.set(len(self._free_pages))
         self.metrics.free_slots.set(
             sum(1 for s in self._slots if s is None))
+        self.metrics.pages_filled.set(
+            sum(self._pages_filled(s) for s in self._slots if s is not None))
+
+    def _pages_filled(self, s: _Slot) -> int:
+        """Pages of ``s`` that hold at least one token."""
+        held = s.pos if s.n_prefilled is None else s.n_prefilled
+        return -(-held // self.program.page_size)
 
     def metrics_snapshot(self) -> dict:
         snap = self.metrics.snapshot()

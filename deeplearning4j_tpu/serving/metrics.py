@@ -293,6 +293,9 @@ class DecodeMetrics:
             LatencyHistogram(buckets_ms, name="tpot_ms"))
         self.step_time = self.registry.register(
             LatencyHistogram(buckets_ms, name="decode_step_ms"))
+        # submit -> slot assignment, recorded at admission
+        self.queue_wait = self.registry.register(
+            LatencyHistogram(buckets_ms, name="queue_wait_ms"))
         self._counters = {k: self.registry.counter(k)
                           for k in _DECODE_COUNTER_KEYS}
         self._lock = threading.Lock()
@@ -300,6 +303,10 @@ class DecodeMetrics:
         self.active_slots.set(0)
         self.pages_in_use = self.registry.gauge("pages_in_use")
         self.pages_in_use.set(0)
+        # pages of the active slots that hold at least one token
+        # (pages_in_use counts pages RESERVED: total - 1 - free)
+        self.pages_filled = self.registry.gauge("pages_filled")
+        self.pages_filled.set(0)
         self.shared_pages = self.registry.gauge("shared_pages")
         self.shared_pages.set(0)
         self.free_pages = self.registry.gauge("free_pages")
@@ -340,6 +347,7 @@ class DecodeMetrics:
             "counters": c,
             "active_slots": int(self.active_slots.value()),
             "pages_in_use": int(self.pages_in_use.value()),
+            "pages_filled": int(self.pages_filled.value()),
             "shared_pages": int(self.shared_pages.value()),
             "free_pages": int(self.free_pages.value()),
             "free_slots": int(self.free_slots.value()),
@@ -352,4 +360,5 @@ class DecodeMetrics:
             "ttft_ms": self.ttft.snapshot(),
             "tpot_ms": self.tpot.snapshot(),
             "decode_step_ms": self.step_time.snapshot(),
+            "queue_wait_ms": self.queue_wait.snapshot(),
         }

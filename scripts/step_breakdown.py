@@ -35,11 +35,13 @@ into the four buckets the fused-step work amortizes:
                    decode executable itself (H steps fused for H > 1)
     sampling       sample_ms arg / tokens — the separate sampling
                    dispatch (0 for fused: sampling runs in-program)
-    host_dispatch  span dur minus step_ms+sample_ms, / tokens — sync +
-                   token readback inside the dispatch window
+    host_dispatch  span dur minus step_ms+sample_ms, / tokens — the
+                   host work inside the span round the dispatches: the
+                   input arrays (serve/step_build) and the
+                   _record_token replay (serve/step_record)
     bookkeeping    gap to the previous decode_step span / tokens — the
-                   host Python between dispatches (locks, _record_token
-                   replay, admission checks)
+                   host Python between steps (the loop's own checks,
+                   admission, prefill)
 
 and prints the amortization ratio (per-token total at H=1 over H) for
 each horizon — the measured host-overhead elimination.

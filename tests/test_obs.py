@@ -472,6 +472,240 @@ class TestGoldenSpanTrees:
 
 
 # ---------------------------------------------------------------------------
+# one span system, two sinks: the decode engine's and the sharded LM's
+# live spans in the ring and in a profiler session
+# ---------------------------------------------------------------------------
+
+STEP_CHILDREN = ["serve/step_build", "serve/step_dispatch",
+                 "serve/sample_dispatch", "serve/step_wait",
+                 "serve/step_record"]
+PROMPT = [3, 1, 4, 1, 5]
+
+
+def tiny_lm():
+    import jax
+
+    from deeplearning4j_tpu.parallel import ShardedTransformerLM, build_mesh
+    return ShardedTransformerLM(
+        vocab_size=64, n_layers=2, d_model=32, n_heads=2, d_ff=64,
+        mesh=build_mesh({"data": 1}, devices=jax.devices()[:1]),
+        max_len=32, seed=11)
+
+
+def lm_batch():
+    t = np.random.default_rng(3).integers(0, 64, (2, 16))
+    return t, np.roll(t, -1, axis=1)
+
+
+@pytest.fixture(scope="module")
+def tiny_engine():
+    from deeplearning4j_tpu.serving import DecodeEngine
+    eng = DecodeEngine(tiny_lm(), max_slots=2, page_size=8, max_len=32,
+                       prompt_buckets=(8, 16)).load()
+    try:
+        yield eng
+    finally:
+        eng.shutdown()
+
+
+def served(eng, **kw):
+    """One request's result, with the engine's thread past the turn
+    that finished it: a live span is recorded when it closes, which is
+    after the future resolves, and the one-token request sent behind is
+    admitted only in a later turn."""
+    res = eng.generate(PROMPT, **kw)
+    eng.generate(PROMPT, max_new_tokens=1)
+    return res
+
+
+@pytest.fixture
+def profiler_session(tmp_path):
+    """A JAX profiler session on the CPU, the ring OFF; yields a reader
+    of the program's spans ``(name, stats)`` in the written profile.
+    Skipped where no session can start."""
+    import glob
+
+    import jax
+
+    obs_trace.disable_tracing()
+    try:
+        jax.profiler.start_trace(str(tmp_path))
+    except Exception as e:
+        pytest.skip(f"no profiler session can start here: {e}")
+    stopped = []
+
+    def spans():
+        if not stopped:
+            jax.profiler.stop_trace()
+            stopped.append(True)
+        path = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                             / "*.xplane.pb"))[-1]
+        out = []
+        for plane in jax.profiler.ProfileData.from_file(path).planes:
+            if plane.name == "/host:CPU":
+                out += [(e.name, dict(e.stats), e.start_ns, e.duration_ns)
+                        for line in plane.lines for e in line.events
+                        if e.name.startswith(("serve/", "train/"))]
+        return sorted(out, key=lambda e: e[2])
+
+    try:
+        yield spans
+    finally:
+        if not stopped:
+            jax.profiler.stop_trace()
+
+
+class TestOneSpanSystemTwoSinks:
+    def test_both_sinks_off_is_the_shared_null_span(self):
+        import jax  # noqa: F401  (loaded: the profiler's flag is read)
+        obs_trace.disable_tracing()
+        s = obs_trace.span("serve/decode_step", cat="serve", n_active=1)
+        assert s is obs_trace._NULL_SPAN
+        assert s.set(x=1) is s and s.drop() is None
+
+    def test_generate_golden_span_tree_in_the_ring(self, tiny_engine,
+                                                   recorder):
+        res = served(tiny_engine, max_new_tokens=3)
+        tree = span_tree(recorder.export())
+        turns = find_spans(tree, "serve/iteration")
+        assert turns and all(t["event"]["args"]["worked"] for t in turns)
+        steps = find_spans(tree, "serve/decode_step")
+        assert len(steps) == 2            # token 1 comes from the prefill
+        by_turn = [s for t in turns for s in t["children"]
+                   if s["name"] == "serve/decode_step"]
+        assert len(by_turn) == 2          # each directly under a turn
+        for st in steps:
+            assert [c["name"] for c in st["children"]] == STEP_CHILDREN
+            a = st["event"]["args"]
+            assert a["tokens"] == 1 and a["n_active"] == 1
+            assert {"model", "step_ms", "sample_ms", "queued"} <= set(a)
+            assert 1 <= a["pages_filled"] <= a["pages_reserved"]
+            assert "shards" not in a      # one device: not tensor-parallel
+        # admit and prefill are siblings in the first turn, in that order
+        first = [c["name"] for c in turns[0]["children"]]
+        assert first[:3] == ["serve/admit", "serve/prefill",
+                             "serve/decode_step"]
+        # the spans of one request share its identifier
+        assert res.request_id > 0
+        mine = lambda name: [
+            f for f in find_spans(tree, name)
+            if f["event"]["args"]["request_id"] == res.request_id]
+        for name in ("serve/admit", "serve/prefill", "serve/finish",
+                     "serve/request"):
+            assert len(mine(name)) == 1, name
+        admit = mine("serve/admit")[0]["event"]["args"]
+        assert admit["queue_wait_ms"] >= 0 and admit["pages_reserved"] == 1
+        assert admit["matched_pages"] == 0 and admit["slot"] in (0, 1)
+        fin = mine("serve/finish")[0]
+        assert fin["event"]["args"]["reason"] == "max_tokens"
+        assert fin["event"]["args"]["tokens"] == 3
+        assert {"ttft_ms", "request_ms"} <= set(fin["event"]["args"])
+        # the last token's finish happens while the step is recorded
+        assert fin in find_spans(steps[-1]["children"], "serve/finish")
+        for gone in ("serve/shard_step", "serve/prefix_attach"):
+            assert not find_spans(tree, gone)
+        assert validate_chrome_trace(recorder.export()) == []
+
+    def test_idle_turns_stay_out_of_the_ring(self, tiny_engine, recorder):
+        import time
+        served(tiny_engine, max_new_tokens=2)
+        time.sleep(0.2)                   # the loop polls every 50 ms
+        turns = find_spans(span_tree(recorder.export()), "serve/iteration")
+        assert turns and all(t["event"]["args"]["worked"] for t in turns)
+
+    def test_fit_batch_golden_span_tree_in_the_ring(self, recorder):
+        lm = tiny_lm()
+        t, y = lm_batch()
+        lm.fit_batch(t, y)
+        lm.fit_batches(np.stack([t, t]), np.stack([y, y]))
+        steps = find_spans(span_tree(recorder.export()), "train/step")
+        assert len(steps) == 2
+        for st in steps:
+            assert [c["name"] for c in st["children"]] == [
+                "train/h2d", "train/dispatch"]
+        one, many = (st["event"]["args"] for st in steps)
+        assert one == {"iteration": 1, "tokens": 32}
+        assert many == {"iteration": 2, "steps": 2, "tokens": 64}
+
+    def test_same_names_and_arguments_in_a_profile_ring_off(
+            self, tiny_engine, profiler_session):
+        res = served(tiny_engine, max_new_tokens=3)
+        lm = tiny_lm()
+        lm.fit_batch(*lm_batch())
+        assert not obs_trace.tracing_enabled()
+        spans = profiler_session()
+        names = [n for n, *_ in spans]
+        for name in ["serve/iteration", "serve/admit", "serve/prefill",
+                     "serve/decode_step", "serve/finish", "train/step",
+                     "train/h2d", "train/dispatch"] + STEP_CHILDREN:
+            assert name in names, name
+        assert "serve/request" not in names      # post-hoc: ring only
+        stats = lambda name: [s for n, s, *_ in spans if n == name]
+        assert len(stats("serve/decode_step")) == 2
+        for a in stats("serve/decode_step"):
+            assert a["tokens"] == 1 and a["n_active"] == 1
+            assert a["model"] == tiny_engine.current_tag
+            assert 1 <= a["pages_filled"] <= a["pages_reserved"]
+            assert {"step_ms", "sample_ms", "queued"} <= set(a)
+        for name in ("serve/admit", "serve/prefill", "serve/finish"):
+            assert [a["request_id"] for a in stats(name)].count(
+                res.request_id) == 1, name
+        assert stats("serve/finish")[0]["reason"] == "max_tokens"
+        assert stats("train/step") == [{"iteration": 1, "tokens": 32}]
+        # nesting by containment, as in the ring: the step's children
+        step = next(e for e in spans if e[0] == "serve/decode_step")
+        inside = [n for n, _, t0, d in spans
+                  if n != "serve/decode_step" and step[2] <= t0
+                  and t0 + d <= step[2] + step[3] and n in STEP_CHILDREN]
+        assert inside == STEP_CHILDREN
+
+    def test_instrumented_paths_are_bit_identical_in_every_mode(
+            self, tiny_engine, profiler_session):
+        def run():
+            r = tiny_engine.generate(PROMPT, max_new_tokens=4,
+                                     echo_logits=True)
+            s = tiny_engine.generate(PROMPT, max_new_tokens=4,
+                                     temperature=0.8, top_k=8, seed=5)
+            lm = tiny_lm()
+            losses = [float(lm.fit_batch(*lm_batch())) for _ in range(2)]
+            return r.tokens, r.logits.tobytes(), s.tokens, losses
+
+        with_profiler = run()             # the session is on, the ring off
+        profiler_session()                # stops it
+        obs_trace.disable_tracing()
+        off = run()
+        obs_trace.enable_tracing()
+        try:
+            with_ring = run()
+        finally:
+            obs_trace.disable_tracing()
+        assert off == with_ring == with_profiler
+
+    def test_queue_wait_and_pages_filled_counters(self, tiny_engine,
+                                                  recorder):
+        before = tiny_engine.metrics.snapshot()["queue_wait_ms"]["count"]
+        futs = [tiny_engine.generate_async(PROMPT, max_new_tokens=6)
+                for _ in range(3)]        # three requests on two slots
+        filled = []
+        while not all(f.done() for f in futs):
+            filled.append(tiny_engine.metrics.snapshot()["pages_filled"])
+        for f in futs:
+            f.result(timeout=60)
+        snap = tiny_engine.metrics_snapshot()
+        assert snap["queue_wait_ms"]["count"] == before + 3
+        assert snap["queue_wait_ms"]["max_ms"] >= 0
+        assert max(filled) >= 1           # the gauge moved while serving
+        assert snap["pages_filled"] == 0 == snap["pages_in_use"]
+        steps = find_spans(span_tree(recorder.export()), "serve/decode_step")
+        assert len(steps) >= 5
+        for st in steps:
+            a = st["event"]["args"]
+            assert 1 <= a["pages_filled"] <= a["pages_reserved"]
+            assert a["pages_reserved"] == 2 * a["n_active"]   # 11 tokens
+        assert any(st["event"]["args"]["queued"] == 1 for st in steps)
+
+
+# ---------------------------------------------------------------------------
 # HTTP surface: /metrics carries the registry, /trace dumps the ring
 # ---------------------------------------------------------------------------
 
