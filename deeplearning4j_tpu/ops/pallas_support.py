@@ -26,3 +26,16 @@ def fell_back(kernel: str, why: str) -> None:
         # graftcheck: disable=GC102 (shape-static dispatch notice: firing ONCE at trace time is the intended behavior)
         logger.warning("%s: pallas kernel not used on tpu — %s; running "
                        "the XLA path", kernel, why)
+
+
+_ENGAGED: set = set()
+
+
+def engaged(kernel: str, what: str) -> None:
+    """Call at TRACE time where ``kernel`` is what runs: says once per
+    distinct ``kernel`` (its name carries the shapes) what it engaged
+    with — the opposite case of ``fell_back``."""
+    if kernel not in _ENGAGED:
+        _ENGAGED.add(kernel)
+        # graftcheck: disable=GC102 (shape-static dispatch notice: firing ONCE at trace time is the intended behavior)
+        logger.info("%s: pallas kernel engaged — %s", kernel, what)

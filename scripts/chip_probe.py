@@ -5,7 +5,11 @@ records about the installation:
 
   kernels    every ``pl.pallas_call`` in ops/ compiled at its A/B script's
              shape and checked against its XLA reference — compiled /
-             refused, with the compiler's message for a refusal
+             refused, with the compiler's message for a refusal; the flash
+             rows name the tile geometry ``flash_tiles`` chose
+  tiles      the three flash kernels alone at the benchmark's training
+             shape over a handful of tile geometries (what the preferences
+             in ``ops/attention.py`` were read from); not in the default run
   precision  what an f32 matmul is on the MXU at default precision
   flash_xla  one LM train step each with attention_impl "flash" and "xla"
              at the chip_smoke width (one run each — a finding, not a
@@ -19,6 +23,7 @@ records about the installation:
 Writes ``chiprun_out/chip_probe.json``; the last stdout line is the same
 JSON.
 """
+import functools
 import json
 import os
 import sys
@@ -59,6 +64,19 @@ def _attempt(table, name, shape, fn):
     table.append(row)
 
 
+CELL_CALL = (12, 16, 1024, 64)   # B, H, T, D of gpt2-medium.train-1k's flash call
+
+
+def _flash_geometry(T, S, D, dtype, BH) -> str:
+    """The tiles the code chooses for a flash call, as the row prints it."""
+    from deeplearning4j_tpu.ops import attention
+    pick = getattr(attention, "flash_tiles", None)
+    if pick is None:       # a tree from before PR 26
+        return "128 x 128, one tile a grid step"
+    t = pick(T, S, D, jnp.dtype(dtype).itemsize, BH)
+    return "none (XLA path)" if t is None else f"{t} steps={t.grid_steps}"
+
+
 def probe_kernels() -> list:
     from deeplearning4j_tpu.nn.updaters import Adam, Updater
     from deeplearning4j_tpu.ops import lstm_kernel, update_kernel
@@ -89,6 +107,7 @@ def probe_kernels() -> list:
             gx = jax.jit(jax.value_and_grad(loss(
                 lambda q, k, v: mha(q, k, v, causal=True, mask=xm)),
                 argnums=(0, 1, 2)))
+            fwd = jax.jit(lambda q, k, v: flash_mha(q, k, v, True, kmask=km))
             hlo = gf.lower(q, k, v).as_text()
             (lf, grads_f), (lx, grads_x) = gf(q, k, v), gx(q, k, v)
             err = max(float(jnp.max(jnp.abs(a.astype(jnp.float32)
@@ -96,9 +115,11 @@ def probe_kernels() -> list:
                       for a, b in zip(grads_f, grads_x))
             scale = max(float(jnp.max(jnp.abs(b.astype(jnp.float32))))
                         for b in grads_x)
-            return {"mosaic_calls": hlo.count("tpu_custom_call"),
+            return {"tiles": _flash_geometry(T, T, D, dtype, B * H),
+                    "mosaic_calls": hlo.count("tpu_custom_call"),
                     "loss_rel_err": abs(float(lf) - float(lx)) / abs(float(lx)),
                     "grad_max_abs_err": err, "grad_max_abs": scale,
+                    "fwd_ms": round(_time(fwd, q, k, v) * 1e3, 3),
                     "fwd_bwd_ms": round(_time(gf, q, k, v) * 1e3, 3),
                     "xla_fwd_bwd_ms": round(_time(gx, q, k, v) * 1e3, 3)}
         name = "flash fwd+bwd" + (" +kmask" if masked else "")
@@ -106,7 +127,9 @@ def probe_kernels() -> list:
                  f"B{B} H{H} T{T} D{D} {jnp.dtype(dtype).name}", run)
 
     flash(2, 8, 4096, 64, jnp.bfloat16, True)     # bench.py config 6
-    flash(8, 12, 1024, 64, jnp.bfloat16, False)   # the LM step's call
+    flash(*CELL_CALL, jnp.bfloat16, False)        # gpt2-medium.train-1k's call
+    flash(2, 8, 2048, 64, jnp.bfloat16, False)
+    flash(8, 12, 1024, 64, jnp.bfloat16, False)   # chip_smoke's LM step
     flash(2, 4, 1024, 64, jnp.float32, False)     # f32 (8,128) tiles
     flash(2, 4, 192, 64, jnp.bfloat16, True)      # whole-axis block, T % 128 != 0
     flash(2, 4, 64, 16, jnp.float32, True)        # the unit tests' shape
@@ -158,6 +181,69 @@ def probe_kernels() -> list:
                 "plain_ms": round(_time(gp, z, c) * 1e3, 4)}
     _attempt(table, "fused LSTM cell fwd+bwd", "mb64 n512 f32", lstm)
 
+    return table
+
+
+def probe_tiles() -> list:
+    """Forward, dk/dv and dq alone (bf16, D 64, causal), each over tile
+    geometries put in place of ``flash_tiles``' choice: at the training
+    cells' call (B·H 192, T 1024) and at the other lengths the rule has to
+    serve.  A result nobody reads is dead code to XLA, so returning dq
+    alone times the dq kernel alone."""
+    from deeplearning4j_tpu.ops import attention
+
+    chosen = attention.flash_tiles
+    D = CELL_CALL[3]
+    scale = D ** -0.5
+    rng = np.random.default_rng(0)
+    table = []
+
+    def sweep(BH, T, S, geometries):
+        mk = lambda n: jnp.asarray(rng.normal(size=(1, BH, n, D)), jnp.bfloat16)
+        q, k, v, g = mk(T), mk(S), mk(S), mk(T)
+        o, lse = jax.jit(lambda q, k, v: attention._flash_forward(
+            q, k, v, None, True, scale))(q, k, v)
+        bwd = lambda pick: (lambda q, k, v, o, lse, g: pick(
+            attention._flash_backward(q, k, v, None, o, lse, g, True, scale)))
+        calls = {
+            "fwd_ms": (lambda q, k, v, o, lse, g: attention._flash_forward(
+                q, k, v, None, True, scale)),
+            "dkdv_ms": bwd(lambda grads: grads[1:]),
+            "dq_ms": bwd(lambda grads: grads[0]),
+        }
+        for geo in [None] + geometries:
+            row = {"BH": BH, "T": T, "S": S}
+            if geo is None:
+                row["tiles"] = "chosen: " + _flash_geometry(T, S, D, q.dtype, BH)
+            else:
+                r, c, h = geo
+                row.update(rows=r, chunk=c, heads=h)
+                # (n_blocked, n_walked): dk/dv asks with the axes swapped
+                attention.flash_tiles = lambda nb, nw, D, isz, BH: \
+                    attention.FlashTiles(min(r, nb), min(c, nw), nw, h,
+                                         (BH // h, nb // min(r, nb), 1), 0)
+            try:
+                for name, fn in calls.items():
+                    # a new function object: jit's trace cache is keyed by it
+                    row[name] = round(_time(jax.jit(functools.partial(fn)),
+                                            q, k, v, o, lse, g, n=20) * 1e3, 4)
+            except Exception as e:   # a geometry Mosaic refuses is a row too
+                row["refused"] = f"{type(e).__name__}: {e}"[:300]
+            finally:
+                attention.flash_tiles = chosen
+            print(f"chip_probe: {row}", flush=True)
+            table.append(row)
+
+    B, H, T, _ = CELL_CALL
+    sweep(B * H, T, T, [(128, 128, 1), (256, 512, 1), (256, 512, 4),
+                        (512, 512, 1), (512, 1024, 1), (512, 1024, 2),
+                        (1024, 1024, 1)])
+    sweep(1536, 128, 128, [(128, 128, h) for h in (1, 4, 8)])
+    sweep(384, 512, 512, [(256, 256, 1), (256, 512, 1), (512, 512, 1),
+                          (512, 512, 2)])
+    for n in (2048, 4096):
+        sweep(16, n, n, [(256, 512, 1), (512, 512, 1), (512, 1024, 1),
+                         (1024, 1024, 1), (1024, 2048, 1)])
     return table
 
 
@@ -301,6 +387,7 @@ def probe_gspmd() -> dict:
 SECTIONS = {"kernels": probe_kernels, "precision": probe_precision,
             "flash_xla": probe_flash_vs_xla, "bundle": probe_bundle,
             "gspmd": probe_gspmd}
+ON_REQUEST = {"tiles": probe_tiles}      # run only when named
 
 
 def main() -> int:
@@ -316,7 +403,7 @@ def main() -> int:
     rc = 0
     for name in want:
         try:
-            out[name] = SECTIONS[name]()
+            out[name] = {**SECTIONS, **ON_REQUEST}[name]()
         except Exception as e:
             traceback.print_exc()
             out[name] = {"error": f"{type(e).__name__}: {e}"[:1500]}

@@ -18,7 +18,8 @@ from deeplearning4j_tpu.nn.layers import (
 )
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork, NeuralNetConfiguration
 from deeplearning4j_tpu.nn.updaters import Adam, NoOp
-from deeplearning4j_tpu.ops.attention import flash_mha, mha
+from deeplearning4j_tpu.ops import attention
+from deeplearning4j_tpu.ops.attention import FlashTiles, flash_mha, flash_tiles, mha
 from deeplearning4j_tpu.utils.gradient_check import check_gradients
 from jax import enable_x64
 
@@ -126,6 +127,198 @@ class TestFlashKernel:
             assert np.all(np.isfinite(np.asarray(a)))
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-4, atol=1e-5)
+
+
+def _max_rel(a, b):
+    """Largest absolute difference over the reference's largest entry."""
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
+
+
+def _parity(q, k, v, causal, kmask=None, out_w=None):
+    """(worst forward gap, worst gradient gap) of flash_mha against mha,
+    each over the reference's largest entry; ``out_w`` [B,1,T,1] weights
+    the outputs the loss sees (the attention layer's output mask)."""
+    xm = None if kmask is None else kmask[:, None, None, :]
+    w = 1.0 if out_w is None else out_w
+    fl = lambda q, k, v: flash_mha(q, k, v, causal, kmask=kmask)
+    ref = lambda q, k, v: mha(q, k, v, causal=causal, mask=xm)
+    loss = lambda f: (lambda q, k, v: jnp.sum(
+        (f(q, k, v).astype(jnp.float32) * w) ** 2))
+    fwd = _max_rel(fl(q, k, v) * w, ref(q, k, v) * w)
+    g_fl = jax.grad(loss(fl), argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    for g in g_fl:
+        assert np.all(np.isfinite(np.asarray(g, np.float32)))
+    return fwd, max(_max_rel(a, b) for a, b in zip(g_fl, g_ref))
+
+
+# f32: the two paths differ by summation order only; bf16: both round p and
+# the operands to 8 bits, in different places
+_TOL = {"float32": 2e-5, "bfloat16": 3e-2}
+
+
+class TestFlashTiledShapes:
+    """The shapes where a grid step walks several chunks (T >= 512): what
+    the benchmark's training cells run, beside masks and cross-attention."""
+
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("t", [512, 1024, 2048])
+    def test_forward_and_grad_match_xla(self, t, dtype, causal):
+        q, k, v = (x.astype(dtype) for x in _qkv(b=1, h=2, t=t, d=64, seed=t))
+        tiles = flash_tiles(t, t, 64, q.dtype.itemsize, 2)
+        assert tiles is not None and tiles.span == t
+        fwd, grad = _parity(q, k, v, causal)
+        assert fwd < _TOL[dtype] and grad < _TOL[dtype], (fwd, grad)
+
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_padding_mask_multi_chunk(self, causal):
+        q, k, v = _qkv(b=2, h=1, t=1024, d=64, seed=11)
+        mask = np.ones((2, 1024), np.float32)
+        mask[0, 700:] = 0.0      # ends inside a chunk
+        mask[1, 512:] = 0.0      # ends on a chunk's edge
+        mj = jnp.asarray(mask)
+        fwd, grad = _parity(q, k, v, causal, kmask=mj,
+                            out_w=mj[:, None, :, None])
+        assert fwd < 2e-5 and grad < 2e-5, (fwd, grad)
+
+    @pytest.mark.parametrize("t,s", [(512, 1024), (1024, 512), (192, 1024)])
+    def test_cross_attention_multi_chunk(self, t, s):
+        q, _, _ = _qkv(b=1, h=2, t=t, d=64, seed=12)
+        _, k, v = _qkv(b=1, h=2, t=s, d=64, seed=13)
+        mask = np.ones((1, s), np.float32)
+        mask[0, s - 100:] = 0.0
+        fwd, grad = _parity(q, k, v, False, kmask=jnp.asarray(mask))
+        assert fwd < 2e-5 and grad < 2e-5, (fwd, grad)
+
+    @pytest.mark.parametrize("t,s", [(256, 512), (512, 256), (1024, 384),
+                                     (2048, 1024), (1024, 2048)])
+    def test_causal_cross_attention(self, t, s):
+        """Key blocks past the last query see no query at all (S > T), and
+        query blocks past the last key see every key (T > S)."""
+        q, _, _ = _qkv(b=1, h=2, t=t, d=64, seed=16)
+        _, k, v = _qkv(b=1, h=2, t=s, d=64, seed=17)
+        fwd, grad = _parity(q, k, v, True)
+        assert fwd < 2e-5 and grad < 2e-5, (fwd, grad)
+
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_fully_masked_rows_multi_chunk(self, causal):
+        """Slice 0 has no live key at all; under the causal mask the first
+        three queries of slice 1 have none either.  The zero-gradient
+        convention holds across chunks: finite everywhere, and equal to
+        mha under the output mask."""
+        q, k, v = _qkv(b=2, h=1, t=1024, d=64, seed=14)
+        mask = np.ones((2, 1024), np.float32)
+        mask[0, :] = 0.0
+        mask[1, :3] = 0.0
+        mask[1, 900:] = 0.0
+        w = mask.copy()
+        fwd, grad = _parity(q, k, v, causal, kmask=jnp.asarray(mask),
+                            out_w=jnp.asarray(w)[:, None, :, None])
+        assert fwd < 2e-5 and grad < 2e-5, (fwd, grad)
+
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("rows,chunk,span,heads", [
+        (128, 128, 256, 2),      # four outer key blocks of two chunks
+        (256, 128, 128, 1),      # span == chunk: one chunk a step
+        (128, 256, 512, 2),
+    ])
+    def test_walked_axis_in_parts(self, monkeypatch, rows, chunk, span, heads,
+                                  causal):
+        """A walked axis too long for VMEM stays an outer grid dimension;
+        reached here by putting a small ``span`` in place of the choice."""
+        def small(T, S, D, itemsize, BH):
+            c, sp = min(chunk, S), min(span, S)
+            return FlashTiles(rows, c, sp, heads,
+                              (BH // heads, T // rows, S // sp), 0)
+        monkeypatch.setattr(attention, "flash_tiles", small)
+        q, k, v = _qkv(b=1, h=2, t=512, d=32, seed=15)
+        mask = np.ones((1, 512), np.float32)
+        mask[0, 400:] = 0.0
+        mj = jnp.asarray(mask)
+        fwd, grad = _parity(q, k, v, causal, kmask=mj,
+                            out_w=mj[:, None, :, None])
+        assert fwd < 2e-5 and grad < 2e-5, (fwd, grad)
+        # S != T under the causal mask: dead outer blocks on either side
+        fwd, grad = _parity(q[:, :, :256], k, v, causal)
+        assert fwd < 2e-5 and grad < 2e-5, (fwd, grad)
+        fwd, grad = _parity(q, k[:, :, :256], v[:, :, :256], causal)
+        assert fwd < 2e-5 and grad < 2e-5, (fwd, grad)
+
+
+class TestFlashTiles:
+    """The tile function alone."""
+
+    def test_training_cell_shape(self):
+        t = flash_tiles(1024, 1024, 64, 2, 192)       # gpt2-medium.train-1k
+        assert t.grid_steps <= 1000, t
+        assert t.vmem_bytes <= attention._VMEM_BUDGET, t
+        assert t.span == 1024                          # K and V resident
+        assert t.grid == (192 // t.heads, 1024 // t.rows, 1)
+        assert 1024 % t.rows == 0 and 1024 % t.chunk == 0 and 192 % t.heads == 0
+
+    @pytest.mark.parametrize("t,s,d,itemsize,bh", [
+        (128, 128, 64, 2, 1536), (512, 512, 64, 4, 8), (2048, 2048, 64, 2, 16),
+        (4096, 4096, 64, 2, 16), (1024, 1024, 128, 4, 6), (640, 384, 64, 2, 3),
+        (192, 1024, 64, 4, 2), (16384, 16384, 128, 4, 4), (512, 65536, 128, 4, 1),
+        (8192, 8192, 128, 2, 16), (1536, 768, 64, 2, 4), (64, 64, 16, 4, 8),
+    ])
+    def test_geometry_is_consistent_and_fits(self, t, s, d, itemsize, bh):
+        g = flash_tiles(t, s, d, itemsize, bh)
+        assert t % g.rows == 0 and s % g.span == 0 and g.span % g.chunk == 0
+        assert bh % g.heads == 0 and 1 <= g.heads <= attention._MAX_HEADS
+        assert g.heads == 1 or g.rows * g.heads <= attention._STEP_ROWS
+        assert g.grid == (bh // g.heads, t // g.rows, s // g.span)
+        assert g.vmem_bytes <= attention._VMEM_BUDGET, g
+        for n, block in ((t, g.rows), (s, g.chunk)):   # Mosaic's alignment
+            assert block % 128 == 0 or block == n
+
+    def test_long_walked_axis_is_held_in_parts(self):
+        g = flash_tiles(512, 65536, 128, 4, 1)
+        assert g.span < 65536 and g.grid[2] == 65536 // g.span > 1
+
+    @pytest.mark.parametrize("n,itemsize,block", [
+        (64, 4, 64), (192, 2, 192), (384, 2, 128), (512, 4, 128),
+        (100, 4, 0), (24, 2, 0), (520, 4, 0), (640, 4, 128), (1000, 2, 0),
+    ])
+    def test_lengths_that_do_not_tile_keep_their_answers(self, n, itemsize,
+                                                         block):
+        """What ``_pick_block`` answered before PR 26 for the blocked axis:
+        0 = the XLA path, n = the whole axis in one tile, 128 = tiled (now
+        in multiples of 128)."""
+        g = flash_tiles(n, n, 64, itemsize, 4)
+        if block == 0:
+            assert g is None
+        elif block == n:
+            assert (g.rows, g.chunk, g.span) == (n, n, n)
+        else:
+            assert g.rows % 128 == 0 and g.chunk % 128 == 0
+
+    def test_one_layer_holds_three_calls_with_the_shapes_the_benchmark_reads(self):
+        """``benchmarks/kernels/flash_attention.classify`` tells the three
+        Mosaic calls of a layer apart by their results: forward
+        ([BH,T,D], f32[BH,1,T]), dk/dv ([BH,S,D] x 2), dq ([BH,T,D])."""
+        b, h, t, s, d = 2, 3, 256, 512, 64
+        q = jnp.zeros((b, h, t, d), jnp.bfloat16)
+        k = v = jnp.zeros((b, h, s, d), jnp.bfloat16)
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda q, k, v: jnp.sum(flash_mha(q, k, v).astype(jnp.float32)),
+            argnums=(0, 1, 2)))(q, k, v)
+
+        def calls(jp):
+            for eqn in jp.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    yield [(o.aval.shape, str(o.aval.dtype))
+                           for o in eqn.outvars]
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from calls(sub)
+        bh = b * h
+        assert list(calls(jaxpr.jaxpr)) == [
+            [((bh, t, d), "bfloat16"), ((bh, 1, t), "float32")],
+            [((bh, s, d), "bfloat16"), ((bh, s, d), "bfloat16")],
+            [((bh, t, d), "bfloat16")],
+        ]
 
 
 def _seq_data(n=4, t=8, f=6, c=3):
