@@ -1,0 +1,200 @@
+"""LMArch — the one description of a decoder-only LM's architecture.
+
+``ShardedTransformerLM`` is built from this (ROADMAP M0): it can say the
+GPT-2 block the repo has always run (pre-LN with bias, learned
+positions, full multi-head attention, biased GELU feed-forward) and the
+latent-attention / sparse-expert block of DeepSeek-V3's modelling code
+(``models/latent_moe.py``): RMSNorm, YaRN-scaled rotary positions on
+part of each head, low-rank query and key/value projections whose
+cached row is a latent, gated SiLU feed-forwards, a leading dense layer
+followed by layers of routed experts with a shared expert beside them.
+
+``from_config`` reads a configuration file's keys (the published
+``config.json`` names of either family), so a model is a data file and
+not a constructor call.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Dict
+
+BLOCKS = ("gpt2", "latent_moe")
+
+
+@dataclasses.dataclass(frozen=True)
+class LMArch:
+    vocab_size: int
+    n_layers: int
+    d_model: int
+    n_heads: int
+    d_ff: int = 0                  # 0 -> 4 * d_model (GPT-2's convention)
+    max_len: int = 512             # learned table's rows / rotary table's rows
+    block: str = "gpt2"
+    # -- latent attention (block == "latent_moe") --------------------------
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rms_eps: float = 1e-6
+    rope_theta: float = 10000.0
+    rope_factor: float = 1.0       # YaRN; 1.0 = plain rotary
+    rope_original_max_len: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    # -- experts ------------------------------------------------------------
+    n_dense_layers: int = 0        # leading layers with a dense feed-forward
+    moe_d_ff: int = 0
+    n_experts: int = 0             # the router's width (all experts)
+    experts_held: int = 0          # how many of them live here ...
+    first_expert: int = 0          # ... the range [first, first + held)
+    experts_per_token: int = 0
+    n_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
+    init_std: float = 0.02
+    param_dtype: str = "float32"
+
+    def __post_init__(self):
+        if self.block not in BLOCKS:
+            raise ValueError(f"block must be one of {BLOCKS}, got "
+                             f"{self.block!r}")
+        if not self.d_ff:
+            object.__setattr__(self, "d_ff", 4 * self.d_model)
+        if self.block == "latent_moe":
+            for k in ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                      "qk_rope_head_dim", "v_head_dim"):
+                if getattr(self, k) < 1:
+                    raise ValueError(f"latent_moe needs {k} >= 1")
+            if self.qk_rope_head_dim % 2:
+                raise ValueError("qk_rope_head_dim must be even")
+            if not 0 <= self.n_dense_layers <= self.n_layers:
+                raise ValueError("n_dense_layers must lie in [0, n_layers]")
+            if self.n_moe_layers:
+                if not (0 < self.experts_per_token <= self.n_experts):
+                    raise ValueError("experts_per_token must lie in "
+                                     "(0, n_experts]")
+                if not (0 < self.experts_held
+                        and 0 <= self.first_expert
+                        and self.first_expert + self.experts_held
+                        <= self.n_experts):
+                    raise ValueError(
+                        f"held experts [{self.first_expert}, "
+                        f"{self.first_expert + self.experts_held}) must be "
+                        f"a non-empty range inside [0, {self.n_experts})")
+                if self.moe_d_ff < 1:
+                    raise ValueError("moe_d_ff must be >= 1")
+
+    # -- derived ------------------------------------------------------------
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layers - self.n_dense_layers \
+            if self.block == "latent_moe" else 0
+
+    @property
+    def latent_width(self) -> int:
+        """Values one token leaves in one layer's cache."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_lanes(self) -> int:
+        """Lanes one cached row takes in the pool: ``latent_width``
+        rounded up to the chip's 128-lane tile (models/latent_moe.py
+        says why)."""
+        return -(-self.latent_width // 128) * 128
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def softmax_scale(self) -> float:
+        s = self.qk_head_dim ** -0.5
+        if self.rope_factor > 1.0:
+            m = yarn_mscale(self.rope_factor, self.rope_mscale_all_dim)
+            s *= m * m
+        return s
+
+    # -- construction ---------------------------------------------------------
+
+    @classmethod
+    def gpt2(cls, vocab_size: int, n_layers: int, d_model: int, n_heads: int,
+             d_ff: int = 0, max_len: int = 512) -> "LMArch":
+        return cls(vocab_size=vocab_size, n_layers=n_layers, d_model=d_model,
+                   n_heads=n_heads, d_ff=d_ff, max_len=max_len)
+
+    @classmethod
+    def from_config(cls, cfg: Dict[str, Any], **over) -> "LMArch":
+        """From a configuration file's keys.  A file with ``n_embd`` is
+        GPT-2's; one with ``kv_lora_rank`` is the latent/expert family
+        (DeepSeek-V3's names, which ``model_type: kimi_k2`` reuses).
+        ``n_routed_experts`` there counts the experts HELD (the chip's
+        share); the router's width is ``n_routed_experts_published``
+        when the file states one.  ``over`` replaces any field."""
+        if "n_embd" in cfg:
+            kw = dict(vocab_size=cfg["vocab_size"], n_layers=cfg["n_layer"],
+                      d_model=cfg["n_embd"], n_heads=cfg["n_head"],
+                      d_ff=cfg.get("n_inner") or 0,
+                      max_len=cfg["n_positions"])
+        elif "kv_lora_rank" in cfg:
+            rs = cfg.get("rope_scaling") or {}
+            held = int(cfg["n_routed_experts"])
+            kw = dict(
+                block="latent_moe", vocab_size=cfg["vocab_size"],
+                n_layers=cfg["num_hidden_layers"],
+                d_model=cfg["hidden_size"],
+                n_heads=cfg["num_attention_heads"],
+                d_ff=cfg["intermediate_size"],
+                max_len=cfg["max_position_embeddings"],
+                q_lora_rank=cfg["q_lora_rank"],
+                kv_lora_rank=cfg["kv_lora_rank"],
+                qk_nope_head_dim=cfg["qk_nope_head_dim"],
+                qk_rope_head_dim=cfg["qk_rope_head_dim"],
+                v_head_dim=cfg["v_head_dim"],
+                rms_eps=cfg.get("rms_norm_eps", 1e-6),
+                rope_theta=cfg.get("rope_theta", 10000.0),
+                rope_factor=float(rs.get("factor", 1.0)),
+                rope_original_max_len=rs.get(
+                    "original_max_position_embeddings", 4096),
+                rope_beta_fast=rs.get("beta_fast", 32.0),
+                rope_beta_slow=rs.get("beta_slow", 1.0),
+                rope_mscale=rs.get("mscale", 1.0),
+                rope_mscale_all_dim=rs.get("mscale_all_dim", 0.0),
+                n_dense_layers=min(cfg.get("first_k_dense_replace", 0),
+                                   cfg["num_hidden_layers"]),
+                moe_d_ff=cfg["moe_intermediate_size"],
+                n_experts=int(cfg.get("n_routed_experts_published", held)),
+                experts_held=held,
+                first_expert=int(cfg.get("first_expert", 0)),
+                experts_per_token=cfg["num_experts_per_tok"],
+                n_shared_experts=cfg.get("n_shared_experts", 0),
+                routed_scaling_factor=cfg.get("routed_scaling_factor", 1.0),
+                init_std=cfg.get("initializer_range", 0.02))
+            unsupported = {
+                "attention_bias": (False,), "hidden_act": ("silu",),
+                "scoring_func": ("sigmoid",), "topk_method": ("noaux_tc",),
+                "n_group": (1,), "topk_group": (1,),
+                "norm_topk_prob": (True,), "moe_layer_freq": (1,),
+                "tie_word_embeddings": (False,)}
+            for k, ok in unsupported.items():
+                if k in cfg and cfg[k] not in ok:
+                    raise ValueError(
+                        f"config key {k}={cfg[k]!r} is not expressible by "
+                        f"the latent_moe block (supported: {ok})")
+        else:
+            raise ValueError("configuration names neither a GPT-2 block "
+                             "(n_embd) nor a latent/expert block "
+                             "(kv_lora_rank)")
+        kw.update(over)
+        return cls(**kw)
+
+
+def yarn_mscale(scale: float, mscale: float) -> float:
+    """``yarn_get_mscale`` of the published modelling code."""
+    if scale <= 1.0:
+        return 1.0
+    return 0.1 * mscale * math.log(scale) + 1.0

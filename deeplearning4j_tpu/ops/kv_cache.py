@@ -109,6 +109,24 @@ def alloc_cache(n_layers: int, n_pages: int, page_size: int, n_heads: int,
     return KVCache(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
 
 
+def alloc_pools(prog: "DecodeProgram", n_pages: int,
+                kv_dtype: Optional[str] = None) -> KVCache:
+    """The pools ``prog`` threads, zero-filled: shape and dtype are the
+    program's own (``pool_row`` / ``pool_dtype`` / ``pool_sides``)."""
+    if prog.pool_row is None and prog.pool_sides == 2:
+        return alloc_cache(prog.n_layers, n_pages, prog.page_size,
+                           prog.n_heads, prog.d_head,
+                           dtype=prog.pool_dtype or jnp.float32,
+                           kv_dtype=kv_dtype)
+    if kv_dtype in ("int8", "i8"):
+        raise ValueError("int8 KV is not carried by this decode program")
+    row = prog.pool_row or (prog.n_heads, prog.d_head)
+    shape = (prog.n_layers, n_pages, prog.page_size) + tuple(row)
+    dtype = prog.pool_dtype or jnp.float32
+    return KVCache(*(jnp.zeros(shape, dtype) if i < prog.pool_sides else ()
+                     for i in range(2)))
+
+
 def pool_nbytes(cache) -> int:
     """Resident bytes of a cache (pool values + any quant scales) — the
     sessions-at-fixed-HBM arithmetic in bench ``decode_speed_ab``."""
@@ -432,3 +450,17 @@ class DecodeProgram(NamedTuple):
     # fns are shard_map'd over the mesh's "data" axis (heads + page pool
     # sharded, logits replicated) — see parallel/transformer.py
     tp: int = 1
+    # what one cached row is, for a program whose row is not [H, d]: the
+    # trailing dims of a pool after [layers, pages, page] (None =
+    # (n_heads, d_head)) and the pool's dtype (None = float32).  A
+    # program with ``pool_sides == 1`` keeps ONE pool (a latent cache:
+    # models/latent_moe.py) and the engine threads an empty tree where
+    # the second would go.
+    pool_row: Optional[tuple] = None
+    pool_dtype: Any = None
+    pool_sides: int = 2
+    # True: prefill / prefill_at / step return a fourth value, a dict of
+    # small arrays the engine reads back with the tokens
+    # (``expert_stats`` int32 [len(parallel.moe.EXPERT_STATS)],
+    # ``expert_picks`` [..., expert layers, k])
+    aux: bool = False

@@ -25,6 +25,7 @@ gate-weighted input) single-device path used for parity tests and the
 from __future__ import annotations
 
 import dataclasses
+import functools
 from functools import partial
 from typing import Dict, Optional, Tuple
 
@@ -158,6 +159,134 @@ def moe_forward_ep(params: Dict[str, Array], x: Array, mesh: Mesh,
     fn = shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs)
     return fn(params, x)
+
+
+# ---------------------------------------------------------------------------
+# dropless routed experts, told which experts live here (ROADMAP M4)
+# ---------------------------------------------------------------------------
+#
+# The Switch path above (softmax router, biased ReLU experts, a fixed
+# capacity that DROPS overflow) stays for its own users (the ``MoE``
+# layer, ``moe_forward_ep``).  The layer below is what an LM block of the
+# DeepSeek-V3 family needs and what expert parallelism asks of a chip:
+# route over ALL ``n_experts``, compute the part of the result that the
+# experts held here give, never drop a token.  On one chip it runs
+# without its exchange; nothing stands in for the absent chips.
+
+#: what ``moe_forward_held`` counts, in this order (int32 [4])
+EXPERT_STATS = ("expert_picks", "expert_picks_held", "expert_load_max",
+                "experts_hit")
+
+
+def init_held_experts(rng: Array, d_model: int, d_ff: int, n_experts: int,
+                      experts_held: int, n_shared: int = 1,
+                      std: float = 0.02, bias_std: float = 0.1,
+                      dtype=jnp.float32) -> Dict[str, Array]:
+    """Router over all ``n_experts`` (weights and the correction bias,
+    drawn non-zero so the choice-only path is worked), gated-SiLU
+    experts for the ``experts_held`` that live here, and the shared
+    expert (``n_shared`` experts' width in one)."""
+    kg, kb, k1, k2, k3, k4, k5, k6 = jax.random.split(rng, 8)
+
+    def normal(key, shape, s=std):
+        return (s * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
+
+    p = {
+        "router_w": normal(kg, (d_model, n_experts)),
+        "router_b": (bias_std * jax.random.normal(
+            kb, (n_experts,), jnp.float32)),
+        "e_gate": normal(k1, (experts_held, d_model, d_ff)),
+        "e_up": normal(k2, (experts_held, d_model, d_ff)),
+        "e_down": normal(k3, (experts_held, d_ff, d_model)),
+    }
+    if n_shared:
+        f = n_shared * d_ff
+        p.update(s_gate=normal(k4, (d_model, f)), s_up=normal(k5, (d_model, f)),
+                 s_down=normal(k6, (f, d_model)))
+    return p
+
+
+def route_noaux_tc(x: Array, router_w: Array, router_b: Array, k: int,
+                   scaling: float) -> Tuple[Array, Array]:
+    """The ``noaux_tc`` gate with one group, in float32: scores are
+    ``sigmoid(x W)``; the ``k`` experts are the top k of ``scores +
+    bias``; their weights are the SCORES (without the bias) of those k,
+    divided by their sum (+1e-20), times ``scaling``.
+    ``x`` [N, d] -> (expert ids [N, k] int32, weights [N, k] f32)."""
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(scores + router_b.astype(jnp.float32), k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * scaling
+    return idx.astype(jnp.int32), w
+
+
+def gated_silu(x: Array, w_gate: Array, w_up: Array, w_down: Array) -> Array:
+    """``W_down(silu(W_gate x) * (W_up x))`` with the products' operands
+    in the weights' type and float32 accumulation; float32 out."""
+    xc = x.astype(w_gate.dtype)
+    g = jnp.dot(xc, w_gate, preferred_element_type=jnp.float32)
+    u = jnp.dot(xc, w_up, preferred_element_type=jnp.float32)
+    a = (jax.nn.silu(g) * u).astype(w_down.dtype)
+    return jnp.dot(a, w_down, preferred_element_type=jnp.float32)
+
+
+def moe_forward_held(p: Dict[str, Array], x: Array, *, first_expert: int,
+                     k: int, scaling: float, valid: Optional[Array] = None,
+                     shared: bool = True):
+    """The part of a routed-expert layer that THIS chip gives.
+
+    ``x`` [N, d].  Routes every row over all experts (``p["router_w"]``
+    is [d, n_experts]), keeps the picks that fall on the experts held
+    here (``p["e_gate"]`` is [held, d, f]; they are experts
+    ``first_expert .. first_expert + held - 1``), sorts those picks by
+    expert and runs ONE grouped product per projection over them
+    (``jax.lax.ragged_dot``: work follows the rows really routed, an
+    expert nobody picked is not read).  A pick on an expert that lives
+    elsewhere contributes nothing.  No capacity: every held pick is
+    computed whatever the skew, the shapes are static (N*k rows).  The
+    shared expert, when the tree has one and ``shared`` is set, is added
+    once.  ``valid`` [N] bool marks the rows that are real tokens: the
+    others (padding, idle slots) are routed nowhere and not counted.
+
+    Returns ``(y [N, d] float32, picks [N, k] int32 sorted by id,
+    stats int32 [4])`` with stats in the order of ``EXPERT_STATS``:
+    picks made by valid rows, those that fell on held experts, the
+    fullest held expert's picks, held experts with at least one pick.
+    """
+    n, d = x.shape
+    held = p["e_gate"].shape[0]
+    idx, w = route_noaux_tc(x, p["router_w"], p["router_b"], k, scaling)
+    if valid is None:
+        valid = jnp.ones((n,), bool)
+    local = idx - first_expert
+    on_held = (local >= 0) & (local < held) & valid[:, None]
+    # picks elsewhere go to a last, empty-weighted group `held`
+    flat_e = jnp.where(on_held, local, held).reshape(-1)
+    order = jnp.argsort(flat_e, stable=True)
+    tok = order // k
+    load = jnp.zeros((held + 1,), jnp.int32).at[flat_e].add(1)[:held]
+    n_held = jnp.sum(load)
+    in_group = jnp.arange(n * k) < n_held
+    cd = p["e_gate"].dtype
+    xs = x.astype(cd)[tok]
+    rd = functools.partial(jax.lax.ragged_dot, group_sizes=load,
+                           preferred_element_type=jnp.float32)
+    a = (jax.nn.silu(rd(xs, p["e_gate"])) * rd(xs, p["e_up"])).astype(cd)
+    o = rd(a, p["e_down"])
+    # rows past the last group hold nothing of any expert's
+    o = jnp.where(in_group[:, None], o * w.reshape(-1)[order][:, None], 0.0)
+    # back to the picks' own order by a gather (a scatter-add of N*k rows
+    # is the slow way on this chip), then each token's k picks summed
+    back = jnp.zeros((n * k,), jnp.int32).at[order].set(
+        jnp.arange(n * k, dtype=jnp.int32))
+    y = jnp.sum(o[back].reshape(n, k, d), axis=1)
+    if shared and "s_gate" in p:
+        y = y + gated_silu(x, p["s_gate"], p["s_up"], p["s_down"])
+    stats = jnp.stack([jnp.sum(valid) * k, n_held, jnp.max(load),
+                       jnp.sum(load > 0)]).astype(jnp.int32)
+    return y, jnp.sort(idx, axis=-1), stats
 
 
 # ---------------------------------------------------------------------------

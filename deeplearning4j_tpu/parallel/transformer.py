@@ -29,6 +29,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, set_mesh
 
+from ..models.arch import LMArch
 from ..models.transformer import block_apply, block_params
 from ..nn.updaters import Adam
 from ..obs import trace as obs_trace
@@ -59,14 +60,37 @@ class ShardedTransformerLM:
     >>> lm = ShardedTransformerLM(vocab_size=256, n_layers=4, d_model=128,
     ...                           n_heads=8, mesh=mesh)
     >>> loss = lm.fit_batch(tokens, targets)   # [B,T] int32 each
+
+    The architecture is an :class:`~..models.arch.LMArch`: pass ``arch=``
+    (e.g. ``LMArch.from_config(json.load(f))``) or the GPT-2 sizes by
+    name as before, which build the same description.  ``params=`` hands
+    in a ready tree (made elsewhere, e.g. on the device from a seed) in
+    place of the constructor's own draw.  A ``latent_moe`` architecture
+    (models/latent_moe.py) is SERVED (``decode_program``, ``logits``);
+    its train step does not exist yet and ``fit_batch`` says so.
     """
 
-    def __init__(self, vocab_size: int, n_layers: int, d_model: int,
-                 n_heads: int, mesh: Mesh, d_ff: int = 0, max_len: int = 512,
+    def __init__(self, vocab_size: Optional[int] = None,
+                 n_layers: Optional[int] = None,
+                 d_model: Optional[int] = None,
+                 n_heads: Optional[int] = None, mesh: Optional[Mesh] = None,
+                 d_ff: int = 0, max_len: int = 512,
                  n_microbatches: int = 2, seed: int = 0, updater=None,
                  compute_dtype=None, seq_parallel: str = "ring",
-                 attention_impl: str = "flash", schedule: str = "gpipe"):
-        d_ff = d_ff or 4 * d_model
+                 attention_impl: str = "flash", schedule: str = "gpipe",
+                 arch: Optional[LMArch] = None, params=None):
+        if mesh is None:
+            raise ValueError("ShardedTransformerLM needs a mesh")
+        if arch is None:
+            if None in (vocab_size, n_layers, d_model, n_heads):
+                raise ValueError("give arch= or vocab_size, n_layers, "
+                                 "d_model and n_heads")
+            arch = LMArch.gpt2(vocab_size, n_layers, d_model, n_heads,
+                               d_ff=d_ff, max_len=max_len)
+        self.arch = arch
+        vocab_size, n_layers = arch.vocab_size, arch.n_layers
+        d_model, n_heads = arch.d_model, arch.n_heads
+        d_ff, max_len = arch.d_ff, arch.max_len
         # normalize to the canonical 4-axis mesh (absent axes = size 1) so
         # specs/collectives can reference every axis unconditionally
         canonical = ("data", "model", "seq", "pipe")
@@ -120,17 +144,26 @@ class ShardedTransformerLM:
         self.updater = updater or Adam(lr=3e-4)
         self.iteration = 0
 
+        self._jit_step = None
+        self._jit_multi_step = None
+        self._jit_logits = None
+        self.token_sharding = NamedSharding(mesh, P("data", "seq"))
+        if arch.block == "latent_moe":
+            self._init_latent_moe(seed, params)
+            return
+
         rng = jax.random.PRNGKey(seed)
-        ke, kp, kh, *kb = jax.random.split(rng, 3 + n_layers)
-        blocks = stack_stage_params(
-            [block_params(k, d_model, n_heads, d_ff) for k in kb])
-        params = {
-            "embed": 0.02 * jax.random.normal(ke, (vocab_size, d_model)),
-            "pos": 0.02 * jax.random.normal(kp, (max_len, d_model)),
-            "blocks": blocks,
-            "lnf_g": jnp.ones((d_model,)), "lnf_b": jnp.zeros((d_model,)),
-            "head": 0.02 * jax.random.normal(kh, (d_model, vocab_size)),
-        }
+        if params is None:
+            ke, kp, kh, *kb = jax.random.split(rng, 3 + n_layers)
+            blocks = stack_stage_params(
+                [block_params(k, d_model, n_heads, d_ff) for k in kb])
+            params = {
+                "embed": 0.02 * jax.random.normal(ke, (vocab_size, d_model)),
+                "pos": 0.02 * jax.random.normal(kp, (max_len, d_model)),
+                "blocks": blocks,
+                "lnf_g": jnp.ones((d_model,)), "lnf_b": jnp.zeros((d_model,)),
+                "head": 0.02 * jax.random.normal(kh, (d_model, vocab_size)),
+            }
         self.block_specs = _block_tp_specs()
         shardings = {
             "embed": NamedSharding(mesh, P(None, None)),
@@ -144,10 +177,37 @@ class ShardedTransformerLM:
         # optimizer state mirrors params structurally → same shardings
         opt = self.updater.init_state(params)
         self.opt_state = jax.device_put(opt, self._opt_shardings(opt, shardings))
-        self.token_sharding = NamedSharding(mesh, P("data", "seq"))
-        self._jit_step = None
-        self._jit_multi_step = None
-        self._jit_logits = None
+
+    def _init_latent_moe(self, seed: int, params) -> None:
+        """A latent/expert architecture: replicated parameters in the
+        architecture's own type (bf16 weights are SERVED as bf16), no
+        optimizer state — nothing here trains it yet."""
+        from ..models import latent_moe
+
+        arch, mesh = self.arch, self.mesh
+        if any(mesh.shape.get(a, 1) > 1 for a in ("model", "seq", "pipe")):
+            raise NotImplementedError(
+                "a latent_moe architecture is not sharded over model / seq "
+                f"/ pipe yet (got {dict(mesh.shape)}): the expert exchange "
+                "across chips is ROADMAP M4's open half")
+        if self.compute_dtype is not None:
+            raise NotImplementedError(
+                "compute_dtype does not apply to a latent_moe architecture: "
+                "state param_dtype in its LMArch")
+        if params is None:
+            params = latent_moe.init_params(
+                jax.random.PRNGKey(seed), arch, jnp.dtype(arch.param_dtype))
+        self.params = jax.device_put(params, NamedSharding(mesh, P()))
+        self.opt_state = None
+        self.block_specs = None
+
+    def _refuse_training(self) -> None:
+        if self.arch.block == "latent_moe":
+            raise NotImplementedError(
+                "training a latent_moe architecture is not implemented: "
+                "the train step of latent attention and of routed experts "
+                "(ROADMAP M4, M5) is open; this LM is served "
+                "(decode_program, logits)")
 
     def _opt_shardings(self, opt, param_shardings):
         """Each opt-state subtree ('m'/'v'/...) mirrors the params tree."""
@@ -162,6 +222,9 @@ class ShardedTransformerLM:
     # -- forward -----------------------------------------------------------
 
     def _forward(self, params, tokens):
+        if self.arch.block == "latent_moe":
+            from ..models import latent_moe
+            return latent_moe.forward(params, tokens, self.arch)
         cd = self.compute_dtype
         embed = params["embed"] if cd is None else params["embed"].astype(cd)
         pos = params["pos"] if cd is None else params["pos"].astype(cd)
@@ -246,6 +309,7 @@ class ShardedTransformerLM:
         return jax.jit(step, donate_argnums=(0, 1))
 
     def fit_batch(self, tokens: np.ndarray, targets: np.ndarray):
+        self._refuse_training()
         if self._jit_step is None:
             self._jit_step = self._build_step()
         with obs_trace.span("train/step", cat="train",
@@ -292,6 +356,7 @@ class ShardedTransformerLM:
     def fit_batches(self, tokens: np.ndarray, targets: np.ndarray):
         """k steps in ONE dispatch: ``tokens``/``targets`` are [k, B, T]
         (k stacked minibatches).  Returns [k] LazyScores."""
+        self._refuse_training()
         if self._jit_multi_step is None:
             self._jit_multi_step = self._build_multi_step()
         stacked = NamedSharding(self.mesh, P(None, "data", "seq"))
@@ -346,6 +411,14 @@ class ShardedTransformerLM:
         quantization scale is an amax over ALL heads, which a head
         shard cannot compute locally (the engine enforces this).
         """
+        if self.arch.block == "latent_moe":
+            from ..models import latent_moe
+            if int(np.prod(list(self.mesh.shape.values()))) != 1:
+                raise NotImplementedError(
+                    "tensor-parallel decode is not carried by the "
+                    "latent_moe decode program: serve it on a one-device "
+                    "mesh")
+            return latent_moe.decode_program(self.arch, page_size, max_len)
         from ..models.transformer import block_finish, block_kv_project
         from ..nn.layers.normalization import layer_norm
         from ..ops.kv_cache import (

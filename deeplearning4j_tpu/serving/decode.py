@@ -108,7 +108,12 @@ Two host-overhead eliminations ride on top (docs/SERVING.md
       token-budget admission rule paces a wall of prompts to the same
       chunk budget.  Per-row attention math is unchanged, so the final
       chunk's logits (and every sampled token) are bit-identical to an
-      unchunked prefill.
+      unchunked prefill.  Under a fused horizon the iteration's chunk
+      is queued behind the step's dispatch, so the device runs it while
+      the host reads back and records the step's tokens, and a fused
+      dispatch's echoed logits are copied out one dispatch late
+      (``_step_fused_once``, ``_flush_echo``): between two programs the
+      host then only reads tokens, records them and dispatches.
 
 TTFT and time-per-output-token are first-class (``DecodeMetrics``).
 Everything the loop thread does is a live ``obs.trace`` span under
@@ -140,6 +145,11 @@ from .metrics import DecodeMetrics
 FINISH_REASONS = ("eos", "max_tokens", "deadline")
 
 
+def _leaves(tree) -> list:
+    import jax
+    return jax.tree_util.tree_leaves(tree)
+
+
 @dataclass
 class GenerationResult:
     """One finished generation.  ``tokens`` are the GENERATED ids only
@@ -157,6 +167,10 @@ class GenerationResult:
     tpot_ms: Optional[float]
     logits: Optional[np.ndarray] = None
     request_id: int = 0
+    # [n_tokens, expert layers, k] int32: the experts (ids ascending) the
+    # router chose at the position each token was taken from, where the
+    # decode program has routed experts (models/latent_moe.py); else None
+    expert_picks: Optional[np.ndarray] = None
 
 
 @dataclass(frozen=True)
@@ -216,7 +230,7 @@ class _Slot:
     __slots__ = ("req", "spec", "tag", "page_ids", "n_prompt", "pos",
                  "last_token", "tokens", "n_out", "max_new", "deadline",
                  "t_first", "t_last", "logits", "shared_nodes", "n_matched",
-                 "n_prefilled")
+                 "n_prefilled", "picks", "logit_buf")
 
     def __init__(self, req, tag: str, page_ids: List[int], max_new: int):
         self.req = req
@@ -234,12 +248,24 @@ class _Slot:
         self.t_last = 0.0
         self.logits: Optional[List[np.ndarray]] = \
             [] if self.spec.echo_logits else None
+        self.logit_buf: Optional[np.ndarray] = None   # the rows' storage
+        # the experts each token's layers chose, where the program says
+        self.picks: List[np.ndarray] = []
         self.shared_nodes: List["_PrefixNode"] = []
         self.n_matched = 0
         # chunked prefill progress: prompt tokens already resident in
         # the cache (None once prefill completes / for unchunked slots);
         # a slot with n_prefilled set is NOT steppable yet
         self.n_prefilled: Optional[int] = None
+
+
+class _Chunk:
+    """One chunk of a chunked prefill between its pick and its commit:
+    the slot, the tokens, and what the dispatch left on the device."""
+
+    __slots__ = ("i", "slot", "offset", "take", "bucket", "padded", "last",
+                 "lg", "aux", "tok", "fin", "picks_h", "tok_h", "fin_h",
+                 "lg_h", "t1")
 
 
 class _StepInputs:
@@ -477,6 +503,20 @@ class DecodeEngine:
                 "int8 KV + tensor-parallel decode is unsupported: the "
                 "per-row quantization scale is an amax over ALL heads "
                 "and cannot be computed inside one head shard")
+        if getattr(prog, "pool_sides", 2) != 2 or getattr(prog, "aux", False):
+            # a program with its own pool layout (one latent pool) carries
+            # the plain path and chunked prefill; the rest is not ported
+            asked = [name for name, on in (
+                ("int8 KV (kv_dtype)", kv_dtype in ("int8", "i8")),
+                ("page transfer between hosts (role)", role != "unified"),
+                ("speculation (draft_model)", draft_model is not None),
+                ("the prefix cache (prefix_cache)", bool(prefix_cache)),
+                ("tensor-parallel decode", getattr(prog, "tp", 1) > 1),
+            ) if on]
+            if asked:
+                raise ValueError(
+                    "this decode program keeps one latent pool and does "
+                    "not carry " + ", ".join(asked) + " yet")
         self._prefix_on = bool(prefix_cache)
         if self._prefix_on and prog.prefill_at is None:
             raise ValueError(
@@ -545,6 +585,11 @@ class DecodeEngine:
             raise ValueError("no prompt bucket <= max_len "
                              f"{prog.max_len}: {buckets}")
         self.max_prompt = min(self.prompt_buckets[-1], prog.max_len - 1)
+        if (self.prefill_chunk is not None
+                and self.prefill_chunk <= self.prompt_buckets[-1]):
+            # chunks go through the buckets, not whole prompts: a prompt
+            # may be as long as the slot holds
+            self.max_prompt = prog.max_len - 1
 
         params = getattr(model, "params", model)
         self._versions: Dict[str, Any] = {tag: params}
@@ -566,6 +611,15 @@ class DecodeEngine:
         self._shutdown = False
         self._generation = 0
         self._chunk_cursor = 0     # round-robin over chunked prefills
+        # echoed logits of the last fused dispatch, not copied out yet:
+        # the device array, (buffer, first row, rows, slot) per request,
+        # and the finished answers that wait for those rows
+        self._echo_lgs = None
+        self._echo_rows: List[tuple] = []
+        self._echo_results: List[tuple] = []
+        self._echo_defer = False   # True while a fused step is recorded
+        # a chunk queued behind a fused step and not read back yet
+        self._chunk_inflight: Optional[_Chunk] = None
         self._request_ids = itertools.count(1)
         self._crash_next = False   # test hook: raise inside the next step
         self._thread: Optional[threading.Thread] = None
@@ -594,15 +648,15 @@ class DecodeEngine:
         cold load."""
         import jax
 
-        from ..ops.kv_cache import alloc_cache
+        from ..ops.kv_cache import alloc_pools, pool_nbytes
         from .warmcache import load_bundle
 
         prog = self.program
         params = self._versions[self._serve_tag]
         s_n, pps, v_n = self.max_slots, prog.pages_per_slot, prog.vocab_size
-        kp, vp = alloc_cache(prog.n_layers, self.total_pages, prog.page_size,
-                             prog.n_heads, prog.d_head,
-                             kv_dtype=self._kv_dtype)
+        kp, vp = alloc_pools(prog, self.total_pages, self._kv_dtype)
+        self.metrics.kv_bytes_per_token.set(
+            pool_nbytes((kp, vp)) / (self.total_pages * prog.page_size))
         bundle_mesh = self._mesh if getattr(prog, "tp", 1) > 1 else None
         if bundle_mesh is not None:
             # the pool is head-sharded from its first byte (the program's
@@ -648,7 +702,7 @@ class DecodeEngine:
                 kp, vp, lgs = step_c(
                     params, kp, vp, np.zeros((s_n, pps), np.int32),
                     np.zeros((s_n,), np.int32), np.zeros((s_n,), np.int32),
-                    np.zeros((s_n,), bool))
+                    np.zeros((s_n,), bool))[:3]
                 self._compiled[("step",)] = step_c
 
                 if self.decode_horizon > 1:
@@ -666,26 +720,30 @@ class DecodeEngine:
                             np.zeros((s_n,), np.uint32), zs_i,
                             np.ones((s_n,), np.int32), np.int32(-1),
                             np.arange(H, dtype=np.int32)).compile())
-                    kp, vp, _, _, _ = sm_c(
+                    kp, vp = sm_c(
                         params, kp, vp, np.zeros((s_n, pps), np.int32),
                         zs_i, zs_i, np.zeros((s_n,), bool),
                         np.zeros((s_n,), np.float32), zs_i,
                         np.ones((s_n,), np.float32),
                         np.zeros((s_n,), np.uint32), zs_i,
                         np.ones((s_n,), np.int32), np.int32(-1),
-                        np.arange(H, dtype=np.int32))
+                        np.arange(H, dtype=np.int32))[:2]
                     self._compiled[("step_multi", H)] = sm_c
 
             lg1 = None
             if self.role != "decode":
                 prefill_jit = jax.jit(prog.prefill, donate_argnums=(1, 2))
-                for b in self.prompt_buckets:
+                # chunked prefill sends every prompt through prefill_at:
+                # the whole-prompt programs would never run
+                for b in (self.prompt_buckets
+                          if self.prefill_chunk is None else ()):
                     pf = _get(f"prefill:{b}", lambda b=b: prefill_jit.lower(
                         params, kp, vp, np.zeros((pps,), np.int32),
                         np.zeros((b,), np.int32), np.int32(1)).compile())
                     kp, vp, lg1 = pf(params, kp, vp,
                                      np.zeros((pps,), np.int32),
-                                     np.zeros((b,), np.int32), np.int32(1))
+                                     np.zeros((b,), np.int32),
+                                     np.int32(1))[:3]
                     self._compiled[("prefill", b)] = pf
 
                 if self._prefix_on or self.prefill_chunk is not None:
@@ -704,7 +762,7 @@ class DecodeEngine:
                         kp, vp, lg1 = pf(params, kp, vp,
                                          np.zeros((pps,), np.int32),
                                          np.zeros((b,), np.int32),
-                                         np.int32(1), np.int32(0))
+                                         np.int32(1), np.int32(0))[:3]
                         self._compiled[("prefill_at", b)] = pf
 
             one, batch = _make_samplers(v_n)
@@ -810,15 +868,13 @@ class DecodeEngine:
         Returns the threaded target pool (spec_step donates it)."""
         import jax
 
-        from ..ops.kv_cache import alloc_cache, scrub_pool
+        from ..ops.kv_cache import alloc_pools, scrub_pool
 
         prog, dprog = self.program, self._draft_program
         dparams = self._draft_params
         s_n, pps, v_n = self.max_slots, prog.pages_per_slot, prog.vocab_size
         k = self.speculate_k
-        dkp, dvp = alloc_cache(dprog.n_layers, self.total_pages,
-                               dprog.page_size, dprog.n_heads, dprog.d_head,
-                               kv_dtype=self._kv_dtype)
+        dkp, dvp = alloc_pools(dprog, self.total_pages, self._kv_dtype)
 
         dp_jit = jax.jit(dprog.prefill, donate_argnums=(1, 2))
         for b in self.prompt_buckets:
@@ -1296,24 +1352,38 @@ class DecodeEngine:
                 pass
         while True:
             with self._lock:
-                if self._shutdown or gen != self._generation:
-                    return
+                leave = self._shutdown or gen != self._generation
+            if leave:
+                self._chunk_inflight = None
+                self._flush_echo()          # answers already finished
+                return
             # everything this thread does is under serve/iteration, so
             # no device gap is left without a span of the program's
             with obs_trace.span("serve/iteration", cat="serve") as it:
                 try:
                     worked = self._admit_some()
-                    if self.prefill_chunk is not None:
-                        # at most ONE chunk of prefill work per
-                        # iteration, so the decode dispatch below never
-                        # waits behind more than prefill_chunk tokens
-                        worked = self._prefill_chunk_step() or worked
-                    if self._draft_program is not None:
-                        stepped = self._spec_step_once()
-                    elif self.decode_horizon > 1:
+                    # at most ONE chunk of prefill work per iteration,
+                    # so a decode dispatch never waits behind more than
+                    # prefill_chunk tokens
+                    if self.decode_horizon > 1:
+                        # the fused step queues the chunk behind itself
+                        # and does its host work while the chunk runs
                         stepped = self._step_fused_once()
+                        if not stepped:
+                            # no slot to step: nothing else will cover
+                            # the rows and results still owed
+                            self._chunk_settle()
+                            self._flush_echo()
+                            if self.prefill_chunk is not None:
+                                worked = (self._prefill_chunk_step()
+                                          or worked)
                     else:
-                        stepped = self._step_once()
+                        if self.prefill_chunk is not None:
+                            worked = self._prefill_chunk_step() or worked
+                        if self._draft_program is not None:
+                            stepped = self._spec_step_once()
+                        else:
+                            stepped = self._step_once()
                     worked = stepped or worked
                 except Exception as e:
                     obs_trace.instant("serve/replica_crash", cat="serve",
@@ -1601,7 +1671,7 @@ class DecodeEngine:
         bucket = self._bucket_for(n - m)
         with obs_trace.span("serve/prefill", cat="serve", slot=i,
                             bucket=bucket, prompt_tokens=n, model=s.tag,
-                            request_id=spec.request_id):
+                            request_id=spec.request_id) as sp:
             kp, vp = self._cache
             if m:
                 # prefix-cache hit: prefill ONLY the unmatched suffix; the
@@ -1611,13 +1681,13 @@ class DecodeEngine:
                 suffix = n - m
                 padded = np.zeros((bucket,), np.int32)
                 padded[:suffix] = spec.prompt[m:]
-                kp, vp, lg = self._compiled[("prefill_at", bucket)](
+                kp, vp, lg, *aux = self._compiled[("prefill_at", bucket)](
                     self._versions[s.tag], kp, vp, self._page_table[i], padded,
                     np.int32(suffix), np.int32(m))
             else:
                 padded = np.zeros((bucket,), np.int32)
                 padded[:n] = spec.prompt
-                kp, vp, lg = self._compiled[("prefill", bucket)](
+                kp, vp, lg, *aux = self._compiled[("prefill", bucket)](
                     self._versions[s.tag], kp, vp, self._page_table[i], padded,
                     np.int32(n))
             tok, fin = self._compiled[("sample1",)](
@@ -1640,6 +1710,7 @@ class DecodeEngine:
             tok_h = int(np.asarray(tok))
             fin_h = bool(np.asarray(fin))
             lg_h = np.asarray(lg) if spec.echo_logits else None
+            picks_h = self._read_aux(sp, aux)
             t1 = self.clock()
         self.metrics.inc("prefills")
         self.metrics.ttft.record((t1 - s.req.t_submit) * 1e3)
@@ -1650,6 +1721,8 @@ class DecodeEngine:
             # poisoned prefill's rows never enter the trie
             with self._lock:
                 self._prefix_insert(s, t1)
+        if picks_h is not None:
+            s.picks.append(picks_h)
         self._record_token(i, tok_h, fin_h, lg_h, t1)
 
     def _prefill_chunk_step(self) -> bool:
@@ -1661,58 +1734,91 @@ class DecodeEngine:
         and the slot becomes steppable.  Chunk rows attend over all
         earlier rows already in the pool (same per-row math as a cold
         prefill), so the final logits are bit-identical to an unchunked
-        prefill of the whole prompt."""
+        prefill of the whole prompt.
+
+        Pick, dispatch, wait and commit are separate so that the fused
+        decode step can put the chunk's dispatch BEHIND its own on the
+        device and do its host work while the chunk runs
+        (``_step_fused_once``); here they run back to back."""
+        c = self._chunk_pick()
+        if c is None:
+            return False
+        with self._chunk_span(c) as sp:
+            self._chunk_dispatch(c)
+            self._chunk_wait(c, sp)
+        self._chunk_commit(c)
+        return True
+
+    def _chunk_pick(self) -> Optional[_Chunk]:
+        """The next chunk of the round-robin, with its padded tokens."""
         with self._lock:
             pending = [i for i, s in enumerate(self._slots)
                        if s is not None and s.n_prefilled is not None]
             if not pending:
-                return False
+                return None
             start = self._chunk_cursor
             i = min(pending, key=lambda x: (x - start) % self.max_slots)
             self._chunk_cursor = (i + 1) % self.max_slots
             s = self._slots[i]
-        spec = s.spec
-        n = s.n_prompt
-        p = s.n_prefilled
-        first_offset = s.n_matched * self.program.page_size
-        take = min(self.prefill_chunk, n - p)
-        bucket = self._bucket_for(take)
-        padded = np.zeros((bucket,), np.int32)
-        padded[:take] = spec.prompt[p:p + take]
-        last = p + take >= n
-        with obs_trace.span("serve/prefill", cat="serve", slot=i,
-                            bucket=bucket, prompt_tokens=take, offset=p,
-                            model=s.tag, request_id=spec.request_id):
-            kp, vp = self._cache
-            kp, vp, lg = self._compiled[("prefill_at", bucket)](
-                self._versions[s.tag], kp, vp, self._page_table[i], padded,
-                np.int32(take), np.int32(p))
-            self._cache = (kp, vp)
-            if last:
-                # final chunk — the _prefill_slot tail
-                tok, fin = self._compiled[("sample1",)](
-                    lg, np.float32(spec.temperature), np.int32(spec.top_k),
-                    np.float32(spec.top_p), np.uint32(spec.seed),
-                    np.int32(0))
-                tok_h = int(np.asarray(tok))
-                fin_h = bool(np.asarray(fin))
-                lg_h = np.asarray(lg) if spec.echo_logits else None
-            t1 = self.clock()
+        c = _Chunk()
+        c.i, c.slot, c.offset = i, s, s.n_prefilled
+        c.take = min(self.prefill_chunk, s.n_prompt - c.offset)
+        c.bucket = self._bucket_for(c.take)
+        c.padded = np.zeros((c.bucket,), np.int32)
+        c.padded[:c.take] = s.spec.prompt[c.offset:c.offset + c.take]
+        c.last = c.offset + c.take >= s.n_prompt
+        return c
+
+    def _chunk_span(self, c: _Chunk):
+        s = c.slot
+        return obs_trace.span("serve/prefill", cat="serve", slot=c.i,
+                              bucket=c.bucket, prompt_tokens=c.take,
+                              offset=c.offset, model=s.tag,
+                              request_id=s.spec.request_id)
+
+    def _chunk_dispatch(self, c: _Chunk) -> None:
+        """Queue the chunk's program (and, after a final chunk, the
+        first token's sampler) on the device; nothing is read back."""
+        s, spec = c.slot, c.slot.spec
+        kp, vp = self._cache
+        kp, vp, c.lg, *c.aux = self._compiled[("prefill_at", c.bucket)](
+            self._versions[s.tag], kp, vp, self._page_table[c.i], c.padded,
+            np.int32(c.take), np.int32(c.offset))
+        self._cache = (kp, vp)
+        if c.last:
+            # final chunk — the _prefill_slot tail
+            c.tok, c.fin = self._compiled[("sample1",)](
+                c.lg, np.float32(spec.temperature), np.int32(spec.top_k),
+                np.float32(spec.top_p), np.uint32(spec.seed), np.int32(0))
+
+    def _chunk_wait(self, c: _Chunk, sp) -> None:
+        """The blocking read-back of what the chunk left."""
+        c.picks_h = self._read_aux(sp, c.aux)
+        if c.last:
+            c.tok_h = int(np.asarray(c.tok))
+            c.fin_h = bool(np.asarray(c.fin))
+            c.lg_h = (np.asarray(c.lg) if c.slot.spec.echo_logits
+                      else None)
+        c.t1 = self.clock()
+
+    def _chunk_commit(self, c: _Chunk) -> None:
+        s, i, t1 = c.slot, c.i, c.t1
         self.metrics.inc("prefill_chunks")
-        if not last:
-            s.n_prefilled = p + take
-            return True
+        if not c.last:
+            s.n_prefilled = c.offset + c.take
+            return
         self.metrics.inc("prefills")
-        if p > first_offset:
+        if c.offset > s.n_matched * self.program.page_size:
             self.metrics.inc("chunked_prefills")   # took >= 2 chunks
         self.metrics.ttft.record((t1 - s.req.t_submit) * 1e3)
         s.t_first = t1
         s.n_prefilled = None
-        if self._prefix_on and fin_h:
+        if self._prefix_on and c.fin_h:
             with self._lock:
                 self._prefix_insert(s, t1)
-        self._record_token(i, tok_h, fin_h, lg_h, t1)
-        return True
+        if c.picks_h is not None:
+            s.picks.append(c.picks_h)
+        self._record_token(i, c.tok_h, c.fin_h, c.lg_h, t1)
 
     def _attach_handoff(self, i: int, transfer) -> None:
         """Decode-stage admission: scatter the prefill host's exported
@@ -1782,7 +1888,7 @@ class DecodeEngine:
         bucket = self._bucket_for(n - m)
         with obs_trace.span("serve/prefill", cat="serve", slot=i,
                             bucket=bucket, prompt_tokens=n, model=s.tag,
-                            request_id=spec.request_id):
+                            request_id=spec.request_id) as sp:
             kp, vp = self._cache
             if m:
                 suffix = n - m
@@ -1885,7 +1991,7 @@ class DecodeEngine:
                 t0 = self.clock()
                 with obs_trace.span("serve/step_dispatch", cat="serve"):
                     kp, vp = self._cache
-                    kp, vp, lgs = self._compiled[("step",)](
+                    kp, vp, lgs, *aux = self._compiled[("step",)](
                         inp.params, kp, vp, self._page_table, inp.toks_in,
                         inp.pos, inp.act)
                 t_step = self.clock()
@@ -1899,6 +2005,7 @@ class DecodeEngine:
                     toks_h = np.asarray(toks)
                     fin_h = np.asarray(fin)
                     lgs_h = np.asarray(lgs) if inp.echo else None
+                    picks_h = self._read_aux(sp, aux)
                 t1 = self.clock()
                 self._set_step_args(sp, inp, step_ms=(t_step - t0) * 1e3,
                                     sample_ms=(t1 - t_step) * 1e3)
@@ -1910,12 +2017,30 @@ class DecodeEngine:
                             s = self._slots[i]
                         if s is not None:
                             s.pos += 1
+                            if picks_h is not None:
+                                s.picks.append(picks_h[i])
                             self._record_token(
                                 i, int(toks_h[i]), bool(fin_h[i]),
                                 lgs_h[i].copy() if (lgs_h is not None
                                                     and s.logits is not None)
                                 else None, t1)
         return True
+
+    def _read_aux(self, sp, aux) -> Optional[np.ndarray]:
+        """What a program with ``aux`` reports beside its logits, read
+        back with the tokens: the expert counts go onto the span and
+        the counters of the same names; the chosen experts are returned
+        for the requests' results.  None for any other program."""
+        if not aux:
+            return None
+        from ..parallel.moe import EXPERT_STATS
+        import jax
+        host = jax.device_get(aux[0])       # both arrays in one round trip
+        counts = dict(zip(EXPERT_STATS, host["expert_stats"].tolist()))
+        for name, v in counts.items():
+            self.metrics.inc(name, v)
+        sp.set(**counts)
+        return host["expert_picks"]
 
     def _step_inputs(self, tag: str) -> Optional[_StepInputs]:
         """Assemble, under the lock, the arrays one dispatch takes for
@@ -1973,7 +2098,23 @@ class DecodeEngine:
         state is only mutated AFTER the dispatch returns, so a crash
         anywhere inside the horizon retries from the last committed
         token and regenerates identical bits (seeded counter-based
-        sampling)."""
+        sampling).
+
+        The host's work is kept off the device's critical path where it
+        can be.  With chunked prefill the iteration's one chunk is
+        queued BEHIND the first dispatch, so the device goes from the
+        steps into the chunk while the host reads the tokens back and
+        records them.  A final chunk is then waited for (its first token
+        joins the next step); any other is left running, the next
+        turn's dispatch is queued behind it, and its counts are read
+        after that turn's steps, when it is long done.  Echoed logits (``[H, slots, vocab]`` float32, the
+        bulk of what a dispatch returns) ride one dispatch behind: their
+        transfer is started at the dispatch, and the rows are copied to
+        the requests' buffers after the NEXT dispatch is queued (or as
+        soon as a chunk keeps the device busy, or the loop has nothing
+        to step); an answer that echoes logits and finishes meanwhile is
+        handed to its caller right after its last rows land
+        (``_flush_echo``)."""
         H = self.decode_horizon
         with self._lock:
             tags: List[str] = []
@@ -1988,6 +2129,8 @@ class DecodeEngine:
         if not tags:
             return False
         eos = np.int32(self.eos_id if self.eos_id is not None else -1)
+        chunk = None
+        chunk_due = self.prefill_chunk is not None
         for tag in tags:
             with obs_trace.span("serve/decode_step", cat="serve",
                                 model=tag, tokens=H) as sp:
@@ -1997,17 +2140,31 @@ class DecodeEngine:
                 t0 = self.clock()
                 with obs_trace.span("serve/step_dispatch", cat="serve"):
                     kp, vp = self._cache
-                    kp, vp, toks, fins, lgs = \
+                    kp, vp, toks, fins, lgs, *aux = \
                         self._compiled[("step_multi", H)](
                             inp.params, kp, vp, self._page_table,
                             inp.toks_in, inp.pos, inp.act, inp.temps,
                             inp.tks, inp.tps, inp.seeds, inp.steps,
                             inp.budgets, eos, np.arange(H, dtype=np.int32))
                     self._cache = (kp, vp)
+                    # what the host waits for goes first, the bulk last
+                    for a in (toks, fins, *_leaves(aux)):
+                        a.copy_to_host_async()
+                    if inp.echo:
+                        lgs.copy_to_host_async()
+                if chunk_due:
+                    chunk_due = False
+                    chunk = self._chunk_pick()
+                    if chunk is not None:
+                        with obs_trace.span("serve/prefill_dispatch",
+                                            cat="serve", slot=chunk.i):
+                            self._chunk_dispatch(chunk)
+                # the device is busy: the last dispatch's rows can land
+                self._flush_echo()
                 with obs_trace.span("serve/step_wait", cat="serve"):
                     toks_h = np.asarray(toks)      # [H, S]
                     fins_h = np.asarray(fins)
-                    lgs_h = np.asarray(lgs) if inp.echo else None
+                    picks_h = self._read_aux(sp, aux)   # [H, S, ...]
                 t1 = self.clock()
                 if crash:
                     # "mid-horizon" from the host's view: the device has
@@ -2022,24 +2179,83 @@ class DecodeEngine:
                 self.metrics.step_time.record((t1 - t0) * 1e3)
                 committed = 0
                 with obs_trace.span("serve/step_record", cat="serve"):
-                    for i in inp.group:
-                        for j in range(H):
+                    self._echo_defer = inp.echo
+                    try:
+                        for i in inp.group:
                             with self._lock:
                                 s = self._slots[i]
                             if s is None:
-                                break   # stopped mid-horizon; drop overrun
-                            s.pos += 1
-                            fin_j = bool(fins_h[j, i])
-                            self._record_token(
-                                i, int(toks_h[j, i]), fin_j,
-                                lgs_h[j, i].copy()
-                                if (lgs_h is not None
-                                    and s.logits is not None) else None,
-                                t1)
-                            if fin_j:
-                                committed += 1
+                                continue
+                            n0 = len(s.logits) if s.logits is not None else 0
+                            for j in range(H):
+                                if self._slots[i] is None:
+                                    break   # stopped mid-horizon; drop overrun
+                                s.pos += 1
+                                fin_j = bool(fins_h[j, i])
+                                if picks_h is not None:
+                                    s.picks.append(picks_h[j, i])
+                                self._record_token(i, int(toks_h[j, i]),
+                                                   fin_j, None, t1)
+                                if fin_j:
+                                    committed += 1
+                            if s.logits is not None and len(s.logits) > n0:
+                                # rows n0.. of its buffer are column i of
+                                # this dispatch's logits
+                                self._echo_rows.append(
+                                    (s.logit_buf, n0, len(s.logits) - n0, i))
+                    finally:
+                        self._echo_defer = False
+                        if inp.echo:
+                            self._echo_lgs = lgs
                 self.metrics.inc("tokens_per_dispatch", committed)
+        # a chunk an earlier turn left running lies before this turn's
+        # steps on the device: it is done, and reading it costs no wait
+        self._chunk_settle()
+        if chunk is not None:
+            # the chunk is still running: this dispatch's rows land now
+            self._flush_echo()
+            if chunk.last:
+                # its first token joins the next step: wait for it
+                self._chunk_inflight = chunk
+                self._chunk_settle()
+            else:
+                # nothing of it is needed before the next dispatch, so
+                # that is queued behind it with the device still busy;
+                # the slot's next chunk starts where this one ends
+                chunk.slot.n_prefilled = chunk.offset + chunk.take
+                self._chunk_inflight = chunk
+        elif chunk_due:             # no dispatch to queue it behind
+            self._prefill_chunk_step()
         return True
+
+    def _chunk_settle(self) -> None:
+        """Read back and commit the chunk left on the device, if any."""
+        c, self._chunk_inflight = self._chunk_inflight, None
+        if c is not None:
+            with self._chunk_span(c) as sp:
+                self._chunk_wait(c, sp)
+            self._chunk_commit(c)
+
+    def _flush_echo(self) -> None:
+        """Copy the echoed logits of the last fused dispatch into the
+        requests' buffers and hand over the answers that waited for
+        them.  Loop thread only; a no-op when nothing is owed."""
+        lgs, rows, results = self._echo_lgs, self._echo_rows, \
+            self._echo_results
+        if lgs is None and not results:
+            return
+        self._echo_lgs, self._echo_rows, self._echo_results = None, [], []
+        try:
+            if rows:
+                lgs_h = np.asarray(lgs)                 # [H, S, V]
+                for buf, n0, n, i in rows:
+                    buf[n0:n0 + n] = lgs_h[:n, i]
+        except Exception as e:      # the device is gone: say so
+            for fut, _ in results:
+                _fail_safe(fut, e)
+            raise
+        for fut, res in results:
+            _set_safe(fut, res)
 
     def _spec_step_once(self) -> bool:
         """One speculative round per distinct active version tag: k
@@ -2160,8 +2376,22 @@ class DecodeEngine:
         s.n_out += 1
         s.last_token = token
         s.t_last = now
-        if s.logits is not None and logits_row is not None:
-            s.logits.append(logits_row)
+        if s.logits is not None and (logits_row is not None
+                                     or self._echo_defer):
+            # rows land in one buffer sized for the whole answer, so that
+            # finishing hands it over without stacking (tens of MB copied
+            # with the device idle, once a request: PERF.md §5)
+            if s.logit_buf is None:
+                s.logit_buf = np.empty(
+                    (s.max_new, self.program.vocab_size), np.float32)
+            n = len(s.logits)
+            if n == len(s.logit_buf):       # more rows than the budget
+                s.logit_buf = np.concatenate(
+                    [s.logit_buf, np.empty_like(s.logit_buf)])
+            if logits_row is not None:
+                s.logit_buf[n] = logits_row
+            # else the fused step's row, which ``_flush_echo`` copies in
+            s.logits.append(s.logit_buf[n])
         self.metrics.inc("tokens_out")
         if self.eos_id is not None and token == self.eos_id:
             self._finish(i, now, reason="eos")
@@ -2233,12 +2463,19 @@ class DecodeEngine:
                         if s.n_out > 1 else None)
                 if tpot is not None:
                     self.metrics.tpot.record(tpot)
-                _set_safe(s.req.future, GenerationResult(
+                result = GenerationResult(
                     tokens=list(s.tokens), n_prompt=s.n_prompt,
                     finish_reason=reason, model_tag=s.tag, ttft_ms=ttft_ms,
                     tpot_ms=round(tpot, 3) if tpot is not None else None,
-                    logits=np.stack(s.logits) if s.logits else None,
-                    request_id=request_id))
+                    logits=s.logit_buf[:len(s.logits)] if s.logits
+                    else None,
+                    request_id=request_id,
+                    expert_picks=np.stack(s.picks) if s.picks else None)
+                if s.logits and self._echo_defer:
+                    # its last rows are still on the device
+                    self._echo_results.append((s.req.future, result))
+                else:
+                    _set_safe(s.req.future, result)
             sp.set(request_id=request_id, tokens=s.n_out,
                    request_ms=round((now - s.req.t_submit) * 1e3, 3))
             if ttft_ms is not None:
@@ -2256,6 +2493,12 @@ class DecodeEngine:
         crash, reset the pool, keep serving.  Retries regenerate the
         identical sequence (seeded counter-based sampling), so a retry
         is indistinguishable from a slow first attempt."""
+        try:
+            self._flush_echo()      # answers that had finished before it
+        except Exception as e:      # their callers have been told
+            obs_trace.instant("serve/replica_crash", cat="serve",
+                              kind="echo_flush", error=type(e).__name__)
+        self._chunk_inflight = None     # its slot is wiped with the rest
         with self._lock:
             in_flight = [s for s in self._slots if s is not None]
             self._slots = [None] * self.max_slots

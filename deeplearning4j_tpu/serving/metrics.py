@@ -271,6 +271,11 @@ _DECODE_COUNTER_KEYS = (
     # prompt/chunk counts
     "fused_dispatches", "tokens_per_dispatch",
     "chunked_prefills", "prefill_chunks",
+    # routed experts (parallel/moe.EXPERT_STATS; zero for a program
+    # without them): picks made by real tokens, those that fell on
+    # experts held here, the fullest held expert's picks (summed over
+    # layers and calls), held experts with at least one pick
+    "expert_picks", "expert_picks_held", "expert_load_max", "experts_hit",
 )
 
 
@@ -313,6 +318,9 @@ class DecodeMetrics:
         self.free_pages.set(0)
         self.free_slots = self.registry.gauge("free_slots")
         self.free_slots.set(0)
+        # bytes one cached token holds in the pool, all layers (set at load)
+        self.kv_bytes_per_token = self.registry.gauge("kv_bytes_per_token")
+        self.kv_bytes_per_token.set(0)
         self._t0 = time.monotonic()
         self.global_name = get_registry().register_collector(
             "decode", self.snapshot, unique=True)
@@ -351,6 +359,7 @@ class DecodeMetrics:
             "shared_pages": int(self.shared_pages.value()),
             "free_pages": int(self.free_pages.value()),
             "free_slots": int(self.free_slots.value()),
+            "kv_bytes_per_token": int(self.kv_bytes_per_token.value()),
             "accepted_tokens_per_step": round(
                 c["spec_committed"] / c["spec_steps"], 4)
             if c.get("spec_steps") else None,

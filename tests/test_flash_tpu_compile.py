@@ -75,3 +75,64 @@ def test_forward_and_backward_compile_for_v5e(one_chip, as_on_tpu, case):
 
     hlo = jax.jit(grads).lower(q, k, k, km).compile().as_text()
     assert hlo.count("tpu_custom_call") == 3
+
+
+# -- the latent decode programs at Kimi-K2's widths -------------------------------
+
+@pytest.mark.parametrize("entry", ["step", "step_multi", "prefill_at"])
+def test_latent_decode_program_compiles_for_v5e_without_a_pool_copy(
+        one_chip, entry):
+    """The decode step and a 512-token prefill chunk of the benchmark's
+    ``kimi-k2-instruct`` configuration (16 slots, 4,096 positions, bf16)
+    compile for one v5e chip, fit its memory, update the donated pool in
+    place, and keep less in temporaries than one pool holds, so no copy
+    of the pool is among them: a 576-lane row, ``pool[layer][table]``
+    and a scatter a layer each cost whole-pool copies a call (PERF.md
+    §6, PR 27)."""
+    import json
+
+    from deeplearning4j_tpu.models import latent_moe
+    from deeplearning4j_tpu.models.arch import LMArch
+    from deeplearning4j_tpu.ops.kv_cache import alloc_pools
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "kimi-k2-instruct.json")) as f:
+        cfg = json.load(f)
+    slots, page = cfg["program"]["max_slots"], cfg["program"]["page_size"]
+    arch = LMArch.from_config(cfg, max_len=cfg["program"]["max_len"],
+                              param_dtype="bfloat16")
+    prog = latent_moe.decode_program(arch, page, arch.max_len)
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                             sharding=one_chip)
+    params = jax.tree_util.tree_map(on_chip, jax.eval_shape(
+        lambda: latent_moe.init_params(jax.random.PRNGKey(0), arch,
+                                       jnp.bfloat16)))
+    pool, none = jax.tree_util.tree_map(on_chip, jax.eval_shape(
+        lambda: tuple(alloc_pools(prog, 1 + slots * prog.pages_per_slot))))
+    assert pool.shape == (8, 4097, 16, 640) and pool.dtype == jnp.bfloat16
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    if entry == "step":
+        args = (i32(slots, prog.pages_per_slot), i32(slots), i32(slots),
+                jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip))
+        fn = prog.step
+    elif entry == "step_multi":
+        f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32,
+                                              sharding=one_chip)
+        args = (i32(slots, prog.pages_per_slot), i32(slots), i32(slots),
+                jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip),
+                f32(slots), i32(slots), f32(slots),
+                jax.ShapeDtypeStruct((slots,), jnp.uint32, sharding=one_chip),
+                i32(slots), i32(slots), i32(),
+                i32(cfg["program"]["decode_horizon"]))
+        fn = prog.step_multi
+    else:
+        args = (i32(prog.pages_per_slot), i32(512), i32(), i32())
+        fn = prog.prefill_at
+    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
+        params, pool, none, *args).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
+    assert mem.alias_size_in_bytes >= 8 * 4097 * 16 * 640 * 2   # in place
+    # the program's temporaries are smaller than the pool: no copy of it
+    assert mem.temp_size_in_bytes < 8 * 4097 * 16 * 640 * 2

@@ -24,6 +24,7 @@ The key contracts tested here:
 """
 
 import json
+import time
 import urllib.request
 from types import SimpleNamespace
 
@@ -250,6 +251,124 @@ class TestFusedSpans:
         exp = rec.export()
         assert exp["metadata"]["dropped"] == 12
         assert exp["metadata"]["events"] == 8
+
+
+# -- fused steps with chunked prefill and echoed logits -----------------------
+
+class TestFusedChunkedEcho:
+    """The fused step queues the iteration's chunk behind its own
+    dispatch and copies echoed logits out one dispatch late: nothing a
+    client sees may change, and no answer may come back before its rows."""
+
+    CLOCK_OFFSET = [0.0]
+
+    @classmethod
+    def _clock(cls):
+        return time.monotonic() + cls.CLOCK_OFFSET[0]
+
+    @pytest.fixture(scope="class")
+    def both(self, lm):
+        engines = [_make(lm, prefill_chunk=CHUNK, decode_horizon=h,
+                         clock=self._clock) for h in (1, H)]
+        yield engines
+        for e in engines:
+            e.shutdown()
+
+    @staticmethod
+    def _requests():
+        out = []
+        for k, (n, new) in enumerate([(5, 9), (30, 6), (21, 1), (16, 11),
+                                      (3, 2), (27, 7), (12, 5)]):
+            p = [1 + (i * (3 + k)) % (VOCAB - 1) for i in range(n)]
+            kw = {"max_new_tokens": new, "echo_logits": k % 3 != 2}
+            if k % 2:
+                kw.update(temperature=0.8, top_k=5, seed=k)
+            out.append((p, kw))
+        return out
+
+    def test_concurrent_answers_identical_to_step_by_step(self, both):
+        got = []
+        for eng in both:
+            n0 = eng.compile_cache_size()
+            futs = [eng.generate_async(p, **kw) for p, kw in self._requests()]
+            got.append([f.result(timeout=120) for f in futs])
+            assert eng.compile_cache_size() == n0
+        for (p, kw), one, four in zip(self._requests(), *got):
+            assert four.tokens == one.tokens
+            assert four.finish_reason == one.finish_reason
+            if kw["echo_logits"]:
+                assert four.logits.shape == (len(four.tokens), VOCAB)
+                np.testing.assert_allclose(four.logits, one.logits,
+                                           rtol=1e-5, atol=1e-6)
+            else:
+                assert four.logits is None
+        assert _partition_ok(both[1])
+
+    def test_chunk_is_queued_inside_the_step_and_waited_for_after_it(
+            self, both):
+        eng = both[1]
+        c0 = eng.metrics_snapshot()["counters"]
+        rec = obs_trace.TraceRecorder()
+        old = obs_trace.set_recorder(rec)
+        try:
+            first = eng.generate_async([1, 2, 3], max_new_tokens=30)
+            while (eng.metrics_snapshot()["counters"]["tokens_out"]
+                   == c0["tokens_out"]):
+                time.sleep(0.0005)
+            eng.generate(list(range(1, 31)), max_new_tokens=2)  # 2 chunks
+            first.result(timeout=120)
+        finally:
+            obs_trace.set_recorder(old)
+        ev = [e for e in rec.export()["traceEvents"] if e.get("ph") == "X"]
+
+        def within(inner, outer):
+            return (outer["ts"] <= inner["ts"] and inner["ts"] + inner["dur"]
+                    <= outer["ts"] + outer["dur"])
+        steps = [e for e in ev if e["name"] == "serve/decode_step"]
+        queued = [e for e in ev if e["name"] == "serve/prefill_dispatch"]
+        assert queued, "no chunk was queued behind a fused dispatch"
+        assert all(any(within(q, s) for s in steps) for q in queued)
+        # every chunk is read back, after a step and never inside one
+        waits = [e for e in ev if e["name"] == "serve/prefill"
+                 and "offset" in e["args"]]
+        assert len(waits) >= len(queued)
+        assert not any(within(w, s) for w in waits for s in steps)
+        assert eng._chunk_inflight is None
+        c = eng.metrics_snapshot()["counters"]
+        assert c["prefill_chunks"] - c0["prefill_chunks"] == len(waits) == 3
+
+    def test_answers_cut_at_the_deadline_come_back_with_every_row(
+            self, both):
+        eng, full_eng = both[1], both[0]
+        p = [2, 2, 7]
+        whole = full_eng.generate(p, max_new_tokens=40, echo_logits=True)
+        t0 = eng.metrics_snapshot()["counters"]["tokens_out"]
+        fut = eng.generate_async(p, max_new_tokens=40, echo_logits=True,
+                                 slo_ms=3_600_000.0)
+        limit = time.monotonic() + 30
+        while eng.metrics_snapshot()["counters"]["tokens_out"] <= t0:
+            assert time.monotonic() < limit, "prefill never landed"
+            time.sleep(0.0005)
+        try:
+            self.CLOCK_OFFSET[0] = 7200.0
+            res = fut.result(timeout=60)
+        finally:
+            self.CLOCK_OFFSET[0] = 0.0
+        assert res.finish_reason == "deadline"
+        n = len(res.tokens)
+        assert 1 <= n < 40 and res.logits.shape == (n, VOCAB)
+        assert res.tokens == whole.tokens[:n]
+        np.testing.assert_allclose(res.logits, whole.logits[:n],
+                                   rtol=1e-5, atol=1e-6)
+
+    def test_shutdown_hands_over_what_had_finished(self, lm):
+        eng = _make(lm, prefill_chunk=CHUNK, decode_horizon=H)
+        futs = [eng.generate_async([1 + k, 5], max_new_tokens=3 + k,
+                                   echo_logits=True) for k in range(3)]
+        done = [f.result(timeout=120) for f in futs]
+        eng.shutdown()
+        assert all(len(r.logits) == len(r.tokens) for r in done)
+        assert eng._echo_lgs is None and not eng._echo_results
 
 
 # -- chunked prefill -------------------------------------------------------
