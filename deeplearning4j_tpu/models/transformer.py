@@ -270,6 +270,7 @@ class TransformerDecodeAdapter:
         n_layers = self._n_blocks
         embed_lay, out_lay = self._embed_lay, self._out_lay
         d_model = int(self.params["embed"]["W"].shape[1])
+        heads = (n_heads, d_model // n_heads)   # what a cached row holds
 
         def tok_embed(params, idx):
             y = params["embed"]["W"][idx]
@@ -297,8 +298,10 @@ class TransformerDecodeAdapter:
                                         k.transpose(0, 2, 1, 3)[0])
                 v_pages = write_prefill(v_pages, i, page_table_row,
                                         v.transpose(0, 2, 1, 3)[0])
-                k_all = gather_layer(k_pages, i, pt).transpose(0, 2, 1, 3)
-                v_all = gather_layer(v_pages, i, pt).transpose(0, 2, 1, 3)
+                k_all = gather_layer(
+                    k_pages, i, pt, heads).transpose(0, 2, 1, 3)
+                v_all = gather_layer(
+                    v_pages, i, pt, heads).transpose(0, 2, 1, 3)
                 h = block_finish(bp, h, det_attention(q, k_all, v_all, bias))
             return k_pages, v_pages, head(params, h)[0, n_real - 1]
 
@@ -314,8 +317,10 @@ class TransformerDecodeAdapter:
                 q, k, v = block_kv_project(bp, h, n_heads)
                 k_pages = write_step(k_pages, i, pt, positions, k[:, :, 0])
                 v_pages = write_step(v_pages, i, pt, positions, v[:, :, 0])
-                k_all = gather_layer(k_pages, i, pt).transpose(0, 2, 1, 3)
-                v_all = gather_layer(v_pages, i, pt).transpose(0, 2, 1, 3)
+                k_all = gather_layer(
+                    k_pages, i, pt, heads).transpose(0, 2, 1, 3)
+                v_all = gather_layer(
+                    v_pages, i, pt, heads).transpose(0, 2, 1, 3)
                 h = block_finish(bp, h, det_attention(q, k_all, v_all, bias))
             return k_pages, v_pages, head(params, h)[:, 0]
 
@@ -340,8 +345,10 @@ class TransformerDecodeAdapter:
                                         k.transpose(0, 2, 1, 3)[0], offset)
                 v_pages = write_prefill(v_pages, i, page_table_row,
                                         v.transpose(0, 2, 1, 3)[0], offset)
-                k_all = gather_layer(k_pages, i, pt).transpose(0, 2, 1, 3)
-                v_all = gather_layer(v_pages, i, pt).transpose(0, 2, 1, 3)
+                k_all = gather_layer(
+                    k_pages, i, pt, heads).transpose(0, 2, 1, 3)
+                v_all = gather_layer(
+                    v_pages, i, pt, heads).transpose(0, 2, 1, 3)
                 h = block_finish(bp, h, det_attention(q, k_all, v_all, bias))
             return k_pages, v_pages, head(params, h)[0, n_real - 1]
 
@@ -366,8 +373,10 @@ class TransformerDecodeAdapter:
                                        k.transpose(0, 2, 1, 3))
                 v_pages = write_tokens(v_pages, i, pt, positions,
                                        v.transpose(0, 2, 1, 3))
-                k_all = gather_layer(k_pages, i, pt).transpose(0, 2, 1, 3)
-                v_all = gather_layer(v_pages, i, pt).transpose(0, 2, 1, 3)
+                k_all = gather_layer(
+                    k_pages, i, pt, heads).transpose(0, 2, 1, 3)
+                v_all = gather_layer(
+                    v_pages, i, pt, heads).transpose(0, 2, 1, 3)
                 h = block_finish(bp, h, det_attention(q, k_all, v_all, bias))
             return k_pages, v_pages, head(params, h)
 
@@ -396,8 +405,10 @@ class TransformerDecodeAdapter:
                     q, k, v = block_kv_project(bp, h, n_heads)
                     k_pages = write_step(k_pages, i, pt, pos_j, k[:, :, 0])
                     v_pages = write_step(v_pages, i, pt, pos_j, v[:, :, 0])
-                    k_all = gather_layer(k_pages, i, pt).transpose(0, 2, 1, 3)
-                    v_all = gather_layer(v_pages, i, pt).transpose(0, 2, 1, 3)
+                    k_all = gather_layer(
+                        k_pages, i, pt, heads).transpose(0, 2, 1, 3)
+                    v_all = gather_layer(
+                        v_pages, i, pt, heads).transpose(0, 2, 1, 3)
                     h = block_finish(bp, h,
                                      det_attention(q, k_all, v_all, bias))
                 lgs = head(params, h)[:, 0]

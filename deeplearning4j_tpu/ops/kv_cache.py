@@ -12,6 +12,18 @@ early); the paged layout additionally lets a pool smaller than
 vary, returning a finished request's pages to the free list the moment
 it stops (EOS / max-tokens / deadline).
 
+A pool is ``[n_layers, n_pages, page_size, row_lanes]``: the ``n_heads *
+d_head`` cached values of one position laid head-major in ONE minor axis
+(``row_lanes``; ``head_lanes`` says how wide a head sits in it).  On a
+TPU an array's minor axis is tiled in 128 lanes, and where a pool's
+minor axis is no multiple of 128 (a 64-wide ``d_head`` behind an
+``n_heads`` axis, a 576-wide latent row) the chip's default layout puts
+the PAGES minor-most instead: every program that takes the pool then
+transposes all of it on the way in and again on the way out (four 758 MB
+copies a decode step for GPT-2 large, half the step: PERF.md section 6,
+PR 28).  With the row one lane-aligned axis the default layout is the
+natural one and the per-layer scatters update the donated pool in place.
+
 Page id 0 is the **scratch page** by convention: inactive slots' page-
 table rows are all-zero, so the fixed-shape decode step can write every
 slot unconditionally (no dynamic shapes, zero recompiles) while masked
@@ -40,7 +52,7 @@ flash/mha kernels.
 from __future__ import annotations
 
 import math
-from typing import Any, Callable, NamedTuple, Optional, Union
+from typing import Any, Callable, NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -54,17 +66,32 @@ NEG_INF = _NEG_INF  # shared masking convention with ops/attention.py
 
 SCRATCH_PAGE = 0    # pool page 0: write target for masked-out slots
 
+LANES = 128         # the TPU's minor-axis tile
+
+
+def head_lanes(n_heads: int, d_head: int) -> int:
+    """Lanes one head takes in a cached row: ``d_head`` widened (zeros,
+    never read) to the least width that makes the row, ``n_heads`` of
+    them, a multiple of 128 lanes.  64-wide heads need none at 16 or 20
+    heads (1024 and 1280 lanes); one 576-wide latent head sits in 640.
+    The padding is per head and not at the row's end so that any split
+    of the row by whole heads (tensor-parallel decode shards the lane
+    axis) and any page transfer look the same whatever the split."""
+    step = LANES // math.gcd(n_heads, LANES)
+    return -(-d_head // step) * step
+
 
 class QuantPages(NamedTuple):
     """Int8 page pool: symmetrically quantized values plus the f32
     scales that ride alongside (``kv_dtype="int8"``).
 
-    ``q``: [n_layers, n_pages, page_size, n_heads, d_head] int8;
-    ``scale``: [n_layers, n_pages, page_size] f32 — one scale per cached
-    row.  Pages fill append-only (prefill writes a range, each decode
-    step appends one row), so the symmetric scale is computed per ROW at
-    write time: a page-wide amax would change as rows arrive and force
-    requantizing rows already stored.  Row granularity is the
+    ``q``: [n_layers, n_pages, page_size, row_lanes] int8 (the row as
+    in an f32 pool: module docstring); ``scale``: [n_layers, n_pages,
+    page_size] f32 — one scale per cached row.  Pages fill append-only
+    (prefill writes a range, each decode step appends one row), so the
+    symmetric scale is computed per ROW at write time: a page-wide amax
+    would change as rows arrive and force requantizing rows already
+    stored.  Row granularity is the
     page-aligned refinement of per-page quantization that append-only
     writes admit, and every scale lives in the page-indexed side arrays
     so pages still share/free/scrub as a unit.  Dequantization happens
@@ -83,10 +110,12 @@ KVPool = Union[Array, QuantPages]
 class KVCache(NamedTuple):
     """Device carry state: the page pools for K and V.
 
-    ``k_pages`` / ``v_pages``: [n_layers, n_pages, page_size, n_heads,
-    d_head] (or :class:`QuantPages` when ``kv_dtype="int8"``).  Page
-    tables and sequence positions live host-side in the decode engine
-    (tiny int arrays passed per call).
+    ``k_pages`` / ``v_pages``: [n_layers, n_pages, page_size, row_lanes]
+    with ``row_lanes = n_heads * head_lanes(n_heads, d_head)``, a row's
+    heads side by side in one lane-aligned axis (module docstring says
+    why), or :class:`QuantPages` when ``kv_dtype="int8"``.  Page tables
+    and sequence positions live host-side in the decode engine (tiny
+    int arrays passed per call).
     """
 
     k_pages: KVPool
@@ -100,7 +129,8 @@ def alloc_cache(n_layers: int, n_pages: int, page_size: int, n_heads: int,
     ``kv_dtype="int8"`` allocates int8 value pools with f32 row scales
     (a zero scale dequantizes untouched rows to the same 0.0 an f32
     pool starts with)."""
-    shape = (n_layers, n_pages, page_size, n_heads, d_head)
+    shape = (n_layers, n_pages, page_size,
+             n_heads * head_lanes(n_heads, d_head))
     if kv_dtype in ("int8", "i8"):
         def pool():
             return QuantPages(jnp.zeros(shape, jnp.int8),
@@ -120,7 +150,8 @@ def alloc_pools(prog: "DecodeProgram", n_pages: int,
                            kv_dtype=kv_dtype)
     if kv_dtype in ("int8", "i8"):
         raise ValueError("int8 KV is not carried by this decode program")
-    row = prog.pool_row or (prog.n_heads, prog.d_head)
+    row = prog.pool_row or (
+        prog.n_heads * head_lanes(prog.n_heads, prog.d_head),)
     shape = (prog.n_layers, n_pages, prog.page_size) + tuple(row)
     dtype = prog.pool_dtype or jnp.float32
     return KVCache(*(jnp.zeros(shape, dtype) if i < prog.pool_sides else ()
@@ -159,13 +190,26 @@ def _pool_values(pages: KVPool) -> Array:
     return pages.q if isinstance(pages, QuantPages) else pages
 
 
+def _as_rows(kv: Array, row_lanes: int) -> Array:
+    """[..., H, d] -> [..., row_lanes]: each head zero-filled to its
+    ``head_lanes``, the heads side by side."""
+    h, d = kv.shape[-2:]
+    pad = row_lanes // h - d
+    if pad:
+        kv = jnp.pad(kv, [(0, 0)] * (kv.ndim - 1) + [(0, pad)])
+    return kv.reshape(kv.shape[:-2] + (row_lanes,))
+
+
 def _pool_set(pages: KVPool, layer, page_idx, slot_idx, kv: Array) -> KVPool:
-    """Scatter f32 rows into an f32 or int8 pool (quantizing on write)."""
+    """Scatter f32 rows ``kv`` [..., H, d] into an f32 or int8 pool
+    (quantizing on write)."""
+    lanes = _pool_values(pages).shape[3]
     if isinstance(pages, QuantPages):
         q, sc = _quantize_rows(kv)
-        return QuantPages(pages.q.at[layer, page_idx, slot_idx].set(q),
-                          pages.scale.at[layer, page_idx, slot_idx].set(sc))
-    return pages.at[layer, page_idx, slot_idx].set(kv)
+        return QuantPages(
+            pages.q.at[layer, page_idx, slot_idx].set(_as_rows(q, lanes)),
+            pages.scale.at[layer, page_idx, slot_idx].set(sc))
+    return pages.at[layer, page_idx, slot_idx].set(_as_rows(kv, lanes))
 
 
 def write_prefill(pages: KVPool, layer: int, page_table_row: Array,
@@ -224,18 +268,25 @@ def write_tokens(pages: KVPool, layer: int, page_table: Array,
     return _pool_set(pages, layer, page_idx, pos % page_size, kv)
 
 
-def gather_layer(pages: KVPool, layer: int, page_table: Array) -> Array:
+def gather_layer(pages: KVPool, layer: int, page_table: Array,
+                 heads: Tuple[int, int]) -> Array:
     """[S, pages_per_slot] table -> [S, L, H, d] contiguous f32 view of
-    one layer's cached rows (L = pages_per_slot * page_size).  Int8
-    pools dequantize here — ``det_scores``/``det_weighted_sum`` always
-    see f32, so the attention math is dtype-agnostic."""
+    one layer's cached rows (L = pages_per_slot * page_size; ``heads`` =
+    (H, d) of the rows this pool holds, which its lanes alone do not
+    tell).  Int8 pools dequantize here — ``det_scores`` /
+    ``det_weighted_sum`` always see f32, so the attention math is
+    dtype-agnostic."""
+    h, d = heads
+    # the layer as an index of ONE gather: ``pages[layer][page_table]``
+    # copies the layer's whole slice of the pool before it gathers
+    at = (jnp.full_like(page_table, layer), page_table)
     if isinstance(pages, QuantPages):
-        g = (pages.q[layer][page_table].astype(jnp.float32)
-             * pages.scale[layer][page_table][..., None, None])
+        g = (pages.q[at].astype(jnp.float32)
+             * pages.scale[at][..., None])
     else:
-        g = pages[layer][page_table]      # [S, pps, page, H, d]
-    s, pps, page, h, d = g.shape
-    return g.reshape(s, pps * page, h, d)
+        g = pages[at]                     # [S, pps, page, row_lanes]
+    s, pps, page, lanes = g.shape
+    return g.reshape(s, pps * page, h, lanes // h)[..., :d]
 
 
 def scrub_pool(pages: KVPool, ids: Array) -> KVPool:
@@ -273,7 +324,8 @@ class PageTransfer(NamedTuple):
 
     ``n_pages`` real pages (payload rows beyond it, if any, are
     padding); ``k`` / ``v`` are numpy payloads shaped
-    [n_layers, n_pages, page_size, n_heads, d_head] — plain f32 arrays,
+    [n_layers, n_pages, page_size, row_lanes], the pool's own rows (any
+    lane padding rides along, zero) — plain f32 arrays,
     or :class:`QuantPages` of numpy arrays (int8 values + f32 row
     scales) when the pool is int8.  ``pack_transfer`` /
     ``unpack_transfer`` give the wire form; the round trip is bitwise
@@ -450,12 +502,12 @@ class DecodeProgram(NamedTuple):
     # fns are shard_map'd over the mesh's "data" axis (heads + page pool
     # sharded, logits replicated) — see parallel/transformer.py
     tp: int = 1
-    # what one cached row is, for a program whose row is not [H, d]: the
-    # trailing dims of a pool after [layers, pages, page] (None =
-    # (n_heads, d_head)) and the pool's dtype (None = float32).  A
-    # program with ``pool_sides == 1`` keeps ONE pool (a latent cache:
-    # models/latent_moe.py) and the engine threads an empty tree where
-    # the second would go.
+    # what one cached row is, for a program whose row is not n_heads
+    # heads of d_head: the trailing dims of a pool after [layers, pages,
+    # page] (None = (n_heads * head_lanes(n_heads, d_head),)) and the
+    # pool's dtype (None = float32).  A program with ``pool_sides == 1``
+    # keeps ONE pool (a latent cache: models/latent_moe.py) and the
+    # engine threads an empty tree where the second would go.
     pool_row: Optional[tuple] = None
     pool_dtype: Any = None
     pool_sides: int = 2

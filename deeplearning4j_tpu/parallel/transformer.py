@@ -401,7 +401,8 @@ class ShardedTransformerLM:
         On a multi-device mesh (all devices folded into the ``data``
         axis) the program is TENSOR-PARALLEL: every entry point is
         shard_map'd with attention heads split over ``data``, the page
-        pool sharded to match (each device holds 1/n of the KV bytes),
+        pool's lane axis sharded to match (a row's heads lie side by
+        side, so each device holds whole heads, 1/n of the KV bytes),
         an explicit psum after the row-parallel output projection, and
         logits replicated so the samplers see the full vocabulary.  All
         shards run the identical psum in both the incremental and
@@ -457,6 +458,7 @@ class ShardedTransformerLM:
         n_layers = int(jax.tree_util.tree_leaves(
             self.params["blocks"])[0].shape[0])
         d_model = int(self.params["embed"].shape[1])
+        heads = (n_heads, d_model // n_heads)   # what a cached row holds
 
         def _blocks(params):
             return [jax.tree_util.tree_map(lambda a: a[i], params["blocks"])
@@ -480,8 +482,10 @@ class ShardedTransformerLM:
                                         k.transpose(0, 2, 1, 3)[0])
                 v_pages = write_prefill(v_pages, i, page_table_row,
                                         v.transpose(0, 2, 1, 3)[0])
-                k_all = gather_layer(k_pages, i, pt).transpose(0, 2, 1, 3)
-                v_all = gather_layer(v_pages, i, pt).transpose(0, 2, 1, 3)
+                k_all = gather_layer(
+                    k_pages, i, pt, heads).transpose(0, 2, 1, 3)
+                v_all = gather_layer(
+                    v_pages, i, pt, heads).transpose(0, 2, 1, 3)
                 h = block_finish(bp, h, det_attention(q, k_all, v_all, bias))
             h = layer_norm(h, params["lnf_g"], params["lnf_b"])
             return k_pages, v_pages, (h @ params["head"])[0, n_real - 1]
@@ -503,8 +507,10 @@ class ShardedTransformerLM:
                 q, k, v = block_kv_project(bp, h, n_heads)  # [S,H,1,dh]
                 k_pages = write_step(k_pages, i, pt, positions, k[:, :, 0])
                 v_pages = write_step(v_pages, i, pt, positions, v[:, :, 0])
-                k_all = gather_layer(k_pages, i, pt).transpose(0, 2, 1, 3)
-                v_all = gather_layer(v_pages, i, pt).transpose(0, 2, 1, 3)
+                k_all = gather_layer(
+                    k_pages, i, pt, heads).transpose(0, 2, 1, 3)
+                v_all = gather_layer(
+                    v_pages, i, pt, heads).transpose(0, 2, 1, 3)
                 h = block_finish(bp, h, det_attention(q, k_all, v_all, bias))
             h = layer_norm(h, params["lnf_g"], params["lnf_b"])
             return k_pages, v_pages, (h @ params["head"])[:, 0]
@@ -531,8 +537,10 @@ class ShardedTransformerLM:
                                         k.transpose(0, 2, 1, 3)[0], offset)
                 v_pages = write_prefill(v_pages, i, page_table_row,
                                         v.transpose(0, 2, 1, 3)[0], offset)
-                k_all = gather_layer(k_pages, i, pt).transpose(0, 2, 1, 3)
-                v_all = gather_layer(v_pages, i, pt).transpose(0, 2, 1, 3)
+                k_all = gather_layer(
+                    k_pages, i, pt, heads).transpose(0, 2, 1, 3)
+                v_all = gather_layer(
+                    v_pages, i, pt, heads).transpose(0, 2, 1, 3)
                 h = block_finish(bp, h, det_attention(q, k_all, v_all, bias))
             h = layer_norm(h, params["lnf_g"], params["lnf_b"])
             return k_pages, v_pages, (h @ params["head"])[0, n_real - 1]
@@ -561,8 +569,10 @@ class ShardedTransformerLM:
                                        k.transpose(0, 2, 1, 3))
                 v_pages = write_tokens(v_pages, i, pt, positions,
                                        v.transpose(0, 2, 1, 3))
-                k_all = gather_layer(k_pages, i, pt).transpose(0, 2, 1, 3)
-                v_all = gather_layer(v_pages, i, pt).transpose(0, 2, 1, 3)
+                k_all = gather_layer(
+                    k_pages, i, pt, heads).transpose(0, 2, 1, 3)
+                v_all = gather_layer(
+                    v_pages, i, pt, heads).transpose(0, 2, 1, 3)
                 h = block_finish(bp, h, det_attention(q, k_all, v_all, bias))
             h = layer_norm(h, params["lnf_g"], params["lnf_b"])
             return k_pages, v_pages, h @ params["head"]
@@ -623,9 +633,9 @@ class ShardedTransformerLM:
                     k_pages = write_step(k_pages, i, pt, pos_j, k[:, :, 0])
                     v_pages = write_step(v_pages, i, pt, pos_j, v[:, :, 0])
                     k_all = gather_layer(
-                        k_pages, i, pt).transpose(0, 2, 1, 3)
+                        k_pages, i, pt, heads).transpose(0, 2, 1, 3)
                     v_all = gather_layer(
-                        v_pages, i, pt).transpose(0, 2, 1, 3)
+                        v_pages, i, pt, heads).transpose(0, 2, 1, 3)
                     h = block_finish(bp, h,
                                      det_attention(q, k_all, v_all, bias))
                 h = layer_norm(h, params["lnf_g"], params["lnf_b"])
@@ -655,10 +665,13 @@ class ShardedTransformerLM:
             mesh = self.mesh
             hl = n_heads // tp
             dh = d_model // n_heads
+            heads_l = (hl, dh)
             rep = PartitionSpec()
 
             def _pool_spec(pool):
-                full = PartitionSpec(None, None, None, "data", None)
+                # heads lie side by side in a row, so a shard of the
+                # lane axis is whole heads
+                full = PartitionSpec(None, None, None, "data")
                 if isinstance(pool, QuantPages):
                     return QuantPages(full, rep)
                 return full
@@ -692,9 +705,9 @@ class ShardedTransformerLM:
                     v_pages = write_prefill(v_pages, i, page_table_row,
                                             v.transpose(0, 2, 1, 3)[0])
                     k_all = gather_layer(
-                        k_pages, i, pt).transpose(0, 2, 1, 3)
+                        k_pages, i, pt, heads_l).transpose(0, 2, 1, 3)
                     v_all = gather_layer(
-                        v_pages, i, pt).transpose(0, 2, 1, 3)
+                        v_pages, i, pt, heads_l).transpose(0, 2, 1, 3)
                     h = block_finish(bp, h,
                                      det_attention(q, k_all, v_all, bias),
                                      psum_axis="data")
@@ -716,9 +729,9 @@ class ShardedTransformerLM:
                     v_pages = write_step(v_pages, i, pt, positions,
                                          v[:, :, 0])
                     k_all = gather_layer(
-                        k_pages, i, pt).transpose(0, 2, 1, 3)
+                        k_pages, i, pt, heads_l).transpose(0, 2, 1, 3)
                     v_all = gather_layer(
-                        v_pages, i, pt).transpose(0, 2, 1, 3)
+                        v_pages, i, pt, heads_l).transpose(0, 2, 1, 3)
                     h = block_finish(bp, h,
                                      det_attention(q, k_all, v_all, bias),
                                      psum_axis="data")
@@ -745,9 +758,9 @@ class ShardedTransformerLM:
                                             v.transpose(0, 2, 1, 3)[0],
                                             offset)
                     k_all = gather_layer(
-                        k_pages, i, pt).transpose(0, 2, 1, 3)
+                        k_pages, i, pt, heads_l).transpose(0, 2, 1, 3)
                     v_all = gather_layer(
-                        v_pages, i, pt).transpose(0, 2, 1, 3)
+                        v_pages, i, pt, heads_l).transpose(0, 2, 1, 3)
                     h = block_finish(bp, h,
                                      det_attention(q, k_all, v_all, bias),
                                      psum_axis="data")
@@ -772,9 +785,9 @@ class ShardedTransformerLM:
                     v_pages = write_tokens(v_pages, i, pt, positions,
                                            v.transpose(0, 2, 1, 3))
                     k_all = gather_layer(
-                        k_pages, i, pt).transpose(0, 2, 1, 3)
+                        k_pages, i, pt, heads_l).transpose(0, 2, 1, 3)
                     v_all = gather_layer(
-                        v_pages, i, pt).transpose(0, 2, 1, 3)
+                        v_pages, i, pt, heads_l).transpose(0, 2, 1, 3)
                     h = block_finish(bp, h,
                                      det_attention(q, k_all, v_all, bias),
                                      psum_axis="data")
@@ -820,9 +833,9 @@ class ShardedTransformerLM:
                         v_pages = write_step(v_pages, i, pt, pos_j,
                                              v[:, :, 0])
                         k_all = gather_layer(
-                            k_pages, i, pt).transpose(0, 2, 1, 3)
+                            k_pages, i, pt, heads_l).transpose(0, 2, 1, 3)
                         v_all = gather_layer(
-                            v_pages, i, pt).transpose(0, 2, 1, 3)
+                            v_pages, i, pt, heads_l).transpose(0, 2, 1, 3)
                         h = block_finish(
                             bp, h, det_attention(q, k_all, v_all, bias),
                             psum_axis="data")

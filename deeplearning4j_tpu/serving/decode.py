@@ -664,7 +664,7 @@ class DecodeEngine:
             # happens to reshard it
             from jax.sharding import NamedSharding, PartitionSpec
             kp, vp = jax.device_put((kp, vp), NamedSharding(
-                bundle_mesh, PartitionSpec(None, None, None, "data", None)))
+                bundle_mesh, PartitionSpec(None, None, None, "data")))
         # reset/scrub return the pool where they found it: left to itself
         # XLA parks their (input-independent) zeros on one device
         pool_sh = jax.tree_util.tree_map(lambda a: a.sharding, (kp, vp))
@@ -801,8 +801,11 @@ class DecodeEngine:
                 # int8 pools zero values AND scales)
                 return scrub_pool(k, ids), scrub_pool(v, ids)
 
+            # keep_unused: the zeros do not read the pools, and an argument
+            # the program drops is not donated: the reset then builds a
+            # second pair of pools beside the first (12 GB at 16 slots)
             reset_c = _get("reset", lambda: jax.jit(
-                _reset, donate_argnums=(0, 1),
+                _reset, donate_argnums=(0, 1), keep_unused=True,
                 out_shardings=pool_sh).lower(kp, vp).compile())
             kp, vp = reset_c(kp, vp)
             self._compiled[("reset",)] = reset_c
@@ -950,7 +953,8 @@ class DecodeEngine:
             return scrub_pool(dk, ids), scrub_pool(dv, ids)
 
         dreset_c = _get("draft_reset", lambda: jax.jit(
-            _dreset, donate_argnums=(0, 1)).lower(dkp, dvp).compile())
+            _dreset, donate_argnums=(0, 1),
+            keep_unused=True).lower(dkp, dvp).compile())
         dkp, dvp = dreset_c(dkp, dvp)
         self._compiled[("draft_reset",)] = dreset_c
         dscrub_c = _get("draft_scrub", lambda: jax.jit(

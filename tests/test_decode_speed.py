@@ -28,8 +28,8 @@ import numpy as np
 import pytest
 
 from deeplearning4j_tpu.ops.kv_cache import (
-    QuantPages, _quantize_rows, alloc_cache, pool_nbytes, scrub_pool,
-    write_tokens,
+    QuantPages, _quantize_rows, alloc_cache, gather_layer, head_lanes,
+    pool_nbytes, scrub_pool, write_prefill, write_step, write_tokens,
 )
 from deeplearning4j_tpu.parallel.mesh import build_mesh
 from deeplearning4j_tpu.parallel.transformer import ShardedTransformerLM
@@ -294,23 +294,107 @@ class TestInt8KV:
 
     def test_scrub_zeroes_values_and_scales(self):
         kp, _ = alloc_cache(1, 4, PAGE, 2, 4, kv_dtype="int8")
-        kv = np.full((1, PAGE, 2, 4), 3.0, np.float32)
-        import jax.numpy as jnp
-
-        q, sc = _quantize_rows(jnp.asarray(kv[0]))
-        kp = QuantPages(kp.q.at[0, 2].set(q), kp.scale.at[0, 2].set(sc))
+        kv = np.full((PAGE, 2, 4), 3.0, np.float32)
+        kp = write_prefill(kp, 0, np.array([2], np.int32), kv)
+        assert np.asarray(kp.q[0, 2]).any() and np.asarray(kp.scale[0, 2]).all()
         kp = scrub_pool(kp, np.array([2], np.int32))
         assert not np.asarray(kp.q[0, 2]).any()
         assert not np.asarray(kp.scale[0, 2]).any()
 
     def test_write_tokens_overflow_routes_to_scratch(self):
         kp, _ = alloc_cache(1, 3, PAGE, 2, 4)
+        assert kp.shape == (1, 3, PAGE, 128)           # one lane-aligned row
         table = np.array([[1, 2]], np.int32)          # 2 pages = 16 rows
         kv = np.ones((1, 4, 2, 4), np.float32)
         out = write_tokens(kp, 0, table, np.array([14], np.int32), kv)
         assert np.asarray(out[0, 2, 6]).any()          # row 14 lands
         assert np.asarray(out[0, 0]).any()             # 16.. -> scratch
         assert not np.asarray(out[0, 1, :6]).any()     # rows < 14 clean
+
+
+# -- the cached row: [H, d] values in one lane-aligned axis -------------------
+# (n_heads, d_head): 2 x 64 and 20 x 64 fill their 128 / 1280 lanes, the
+# others are widened per head (2 x 16 -> 2 x 64, 3 x 8 -> 3 x 128, and one
+# 576-wide head -> 640 as the latent pool's)
+ROWS = [(2, 64), (20, 64), (2, 16), (3, 8), (1, 576)]
+
+
+def _real_lanes(h, d):
+    w = head_lanes(h, d)
+    return (np.arange(h * w) % w) < d
+
+
+class TestPoolRow:
+    @pytest.mark.parametrize("h,d", ROWS)
+    def test_row_is_a_multiple_of_128_lanes(self, h, d):
+        w = head_lanes(h, d)
+        assert w >= d and (h * w) % 128 == 0
+        assert not any((h * n) % 128 == 0 for n in range(d, w))   # least
+        kp, vp = alloc_cache(2, 3, PAGE, h, d)
+        assert kp.shape == vp.shape == (2, 3, PAGE, h * w)
+        q, _ = alloc_cache(2, 3, PAGE, h, d, kv_dtype="int8")
+        assert q.q.shape == (2, 3, PAGE, h * w) and q.q.dtype == np.int8
+        assert q.scale.shape == (2, 3, PAGE)
+
+    @pytest.mark.parametrize("kv_dtype", [None, "int8"])
+    @pytest.mark.parametrize("writer", ["prefill", "step", "tokens"])
+    @pytest.mark.parametrize("h,d", ROWS[:4])
+    def test_written_rows_come_back_bit_for_bit(self, h, d, writer,
+                                                kv_dtype):
+        """What ``write_prefill`` / ``write_step`` / ``write_tokens``
+        store, ``gather_layer`` hands back as [S, L, H, d]: the f32
+        rows themselves, or for int8 exactly ``q * scale`` of
+        ``_quantize_rows``; the padding lanes stay zero, garbage put
+        there is never read, and ``scrub_pool`` zeroes them too."""
+        rng = np.random.default_rng([h, d])
+        kp, _ = alloc_cache(2, 7, PAGE, h, d, kv_dtype=kv_dtype)
+        table = np.array([[1, 2, 3], [4, 5, 6]], np.int32)   # 2 slots
+        layer, L = 1, 3 * PAGE
+        want = np.zeros((2, L, h, d), np.float32)
+        if writer == "prefill":
+            kv = rng.standard_normal((13, h, d)).astype(np.float32)
+            kp = write_prefill(kp, layer, table[1], kv, offset=PAGE)
+            want[1, PAGE:PAGE + 13] = kv
+        elif writer == "step":
+            kv = rng.standard_normal((2, h, d)).astype(np.float32)
+            pos = np.array([5, 17], np.int32)
+            kp = write_step(kp, layer, table, pos, kv)
+            want[0, 5], want[1, 17] = kv
+        else:
+            kv = rng.standard_normal((2, 4, h, d)).astype(np.float32)
+            pos = np.array([6, 11], np.int32)
+            kp = write_tokens(kp, layer, table, pos, kv)
+            want[0, 6:10], want[1, 11:15] = kv
+        if kv_dtype:
+            q, sc = _quantize_rows(want)
+            want = (np.asarray(q, np.float32)
+                    * np.asarray(sc)[..., None, None])
+        got = np.asarray(gather_layer(kp, layer, table, (h, d)))
+        assert got.shape == (2, L, h, d) and got.dtype == np.float32
+        assert np.array_equal(got, want)
+        other = np.asarray(gather_layer(kp, 0, table, (h, d)))
+        assert not other.any()                        # the layer asked for
+        # padding: zero as written, never read, scrubbed with the page
+        real = _real_lanes(h, d)
+        values = kp.q if kv_dtype else kp
+        assert not np.asarray(values)[..., ~real].any()
+        junk = values.at[..., ~real].set(7)
+        dirty = (QuantPages(junk, kp.scale) if kv_dtype else junk)
+        assert np.array_equal(
+            np.asarray(gather_layer(dirty, layer, table, (h, d))), want)
+        clean = scrub_pool(dirty, np.arange(7, dtype=np.int32))
+        assert not any(np.asarray(a).any()
+                       for a in (clean if kv_dtype else (clean,)))
+
+    @pytest.mark.parametrize("which", ["plain", "i8"])
+    def test_kv_bytes_per_token_is_resident_bytes(self, which, request):
+        eng = request.getfixturevalue(which)
+        rows = eng.total_pages * eng.program.page_size
+        lanes = 2 * head_lanes(2, 16)                 # the toy LM: 128
+        per_row = 2 * 2 * {"plain": lanes * 4,        # layers x (K, V)
+                           "i8": lanes + 4}[which]
+        assert pool_nbytes(eng._cache) == rows * per_row
+        assert eng.metrics_snapshot()["kv_bytes_per_token"] == per_row
 
 
 class TestMetricsAndFlags:

@@ -95,7 +95,7 @@ def _partition_ok(engine):
 class TestPageTransferWire:
     def _f32(self, n_pages=3):
         rng = np.random.default_rng(0)
-        shape = (2, n_pages, 8, 2, 16)
+        shape = (2, n_pages, 8, 128)       # [layers, pages, page, row]
         return PageTransfer(
             n_pages=n_pages,
             k=rng.standard_normal(shape).astype(np.float32),
@@ -111,7 +111,7 @@ class TestPageTransferWire:
 
     def test_int8_round_trip_exact(self):
         rng = np.random.default_rng(1)
-        q = rng.integers(-128, 128, size=(2, 2, 8, 2, 16), dtype=np.int8)
+        q = rng.integers(-128, 128, size=(2, 2, 8, 128), dtype=np.int8)
         scale = rng.random((2, 2, 8), dtype=np.float32)
         t = PageTransfer(n_pages=2, k=QuantPages(q, scale),
                          v=QuantPages(q[::-1].copy(), scale * 2))
@@ -125,6 +125,38 @@ class TestPageTransferWire:
     def test_nbytes_matches_payload(self):
         t = self._f32()
         assert transfer_nbytes(t) == t.k.nbytes + t.v.nbytes
+
+    @pytest.mark.parametrize("kv_dtype", [None, "int8"])
+    @pytest.mark.parametrize("h,d", [(2, 64), (2, 16)])
+    def test_pages_packed_by_one_pool_attach_to_another(self, h, d,
+                                                        kv_dtype):
+        """Rows one host's pool holds, extracted, sent over the wire and
+        attached at OTHER page ids of another host's pool, read back
+        there bit for bit (f32) or exactly as quantized (int8), with a
+        row that fills its 128 lanes and one widened to them."""
+        import jax
+
+        from deeplearning4j_tpu.ops.kv_cache import (
+            alloc_cache, gather_layer, gather_pages, set_pages,
+            write_prefill,
+        )
+
+        rng = np.random.default_rng([h, d])
+        src, _ = alloc_cache(2, 6, 8, h, d, kv_dtype=kv_dtype)
+        dst, _ = alloc_cache(2, 9, 8, h, d, kv_dtype=kv_dtype)
+        there, here = np.array([2, 5, 1], np.int32), np.array([7, 3, 4],
+                                                               np.int32)
+        for layer in range(2):
+            src = write_prefill(src, layer, there, rng.standard_normal(
+                (20, h, d)).astype(np.float32))
+        sent = jax.tree_util.tree_map(np.asarray, gather_pages(src, there))
+        back = unpack_transfer(pack_transfer(
+            PageTransfer(n_pages=3, k=sent, v=sent)))
+        dst = set_pages(dst, here, back.k)
+        for layer in range(2):
+            a = np.asarray(gather_layer(src, layer, there[None], (h, d)))
+            b = np.asarray(gather_layer(dst, layer, here[None], (h, d)))
+            assert a[0, :20].any() and np.array_equal(a, b)
 
     @pytest.mark.parametrize("cut", [0, 4, 10, 40, -1])
     def test_truncated_raises(self, cut):
@@ -153,6 +185,8 @@ class TestDisaggEngine:
             h = pre.generate(prompt, max_new_tokens=6, seed=i)
             assert isinstance(h, PrefillHandoff)
             assert h.n_pages == pages_for(len(prompt), 8)
+            sent = unpack_transfer(h.pages)       # the pool's own rows
+            assert sent.k.shape[0] == 2 and sent.k.shape[2:] == (8, 128)
             got = dec.continue_async(h).result(timeout=60)
             assert got.tokens == ref.tokens
 

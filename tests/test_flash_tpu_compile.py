@@ -136,3 +136,101 @@ def test_latent_decode_program_compiles_for_v5e_without_a_pool_copy(
     assert mem.alias_size_in_bytes >= 8 * 4097 * 16 * 640 * 2   # in place
     # the program's temporaries are smaller than the pool: no copy of it
     assert mem.temp_size_in_bytes < 8 * 4097 * 16 * 640 * 2
+
+
+# -- the GPT-2 decode programs at gpt2-large's widths -----------------------------
+
+@pytest.fixture(scope="module")
+def gpt2_large():
+    """``ShardedTransformerLM`` as the benchmark's ``serve_lm`` builds it
+    from ``gpt2-large.json``, its 3.1 GB of weights never drawn (the
+    constructor runs under ``jax.eval_shape``), and the file's
+    ``program`` (its ``max_slots`` is 4; the cases bring their own)."""
+    import json
+
+    from deeplearning4j_tpu.nn.updaters import Sgd
+    from deeplearning4j_tpu.parallel import ShardedTransformerLM, build_mesh
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "gpt2-large.json")) as f:
+        cfg = json.load(f)
+    built = []
+
+    def build():
+        built.append(ShardedTransformerLM(
+            vocab_size=cfg["vocab_size"], n_layers=cfg["n_layer"],
+            d_model=cfg["n_embd"], n_heads=cfg["n_head"],
+            d_ff=cfg["n_inner"],
+            mesh=build_mesh({"data": 1}, devices=jax.devices()[:1]),
+            max_len=cfg["n_positions"], updater=Sgd(lr=0.0)))
+        return built[0].params
+
+    shapes = jax.eval_shape(build)
+    lm, = built
+    lm.params = shapes
+    return lm, cfg["program"]
+
+
+GPT2_DECODE_CASES = [("step", 4), ("step", 16), ("prefill", 16),
+                     ("prefill_at", 16), ("spec_step", 16),
+                     ("step_multi", 16)]
+
+
+@pytest.mark.parametrize("entry,slots", GPT2_DECODE_CASES)
+def test_gpt2_decode_program_compiles_for_v5e_without_a_pool_copy(
+        one_chip, gpt2_large, entry, slots):
+    """Every entry point of ``ShardedTransformerLM.decode_program`` at
+    GPT-2 large's widths (f32, 1,024 positions, pages of 16) compiles
+    for one v5e chip at 4 and at 16 slots, fits its memory, gives both
+    donated pools back in place and keeps less in temporaries than one
+    pool holds, with no ``copy`` of a pool-shaped array: a pool stored
+    ``[..., 20, 64]`` got the chip's pages-minor layout and was
+    transposed in and out of every call, four 758 MB copies a step at 4
+    slots and out of memory at 8 (PERF.md section 6, PR 28)."""
+    import re
+
+    from deeplearning4j_tpu.ops.kv_cache import alloc_pools
+
+    lm, program = gpt2_large
+    prog = lm.decode_program(page_size=program["page_size"],
+                             max_len=program["max_len"])
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                             sharding=one_chip)
+    params = jax.tree_util.tree_map(on_chip, lm.params)
+    pps = prog.pages_per_slot
+    kp, vp = jax.tree_util.tree_map(on_chip, jax.eval_shape(
+        lambda: tuple(alloc_pools(prog, 1 + slots * pps))))
+    assert kp.shape == vp.shape == (36, 1 + slots * 64, 16, 1280)
+    pool_bytes = 4 * kp.size
+
+    def arr(dtype, *s):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    i32 = lambda *s: arr(jnp.int32, *s)
+    batch = (i32(slots, pps), i32(slots), i32(slots), arr(jnp.bool_, slots))
+    args = {
+        "step": batch,
+        "prefill": (i32(pps), i32(128), i32()),
+        "prefill_at": (i32(pps), i32(128), i32(), i32()),
+        "spec_step": (i32(slots, pps), i32(slots, 4), i32(slots),
+                      arr(jnp.bool_, slots)),
+        "step_multi": batch + (
+            arr(jnp.float32, slots), i32(slots), arr(jnp.float32, slots),
+            arr(jnp.uint32, slots), i32(slots), i32(slots), i32(), i32(4)),
+    }[entry]
+    compiled = jax.jit(getattr(prog, entry), donate_argnums=(1, 2)).lower(
+        params, kp, vp, *args).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes      # both in place
+    assert mem.temp_size_in_bytes < pool_bytes
+    hlo = compiled.as_text()
+    pool_copy = re.compile(
+        r"= f32\[%s\]\S* copy\(" % ",".join(map(str, kp.shape)))
+    assert not pool_copy.findall(hlo)
+    # nor is one layer's slice of a pool taken out before the gather, as
+    # ``pool[layer][table]`` did 72 times a step (a third of the 16-slot
+    # step on the chip): nothing has that shape
+    layer_slice = "= f32[%s]" % ",".join(map(str, kp.shape[1:]))
+    assert layer_slice not in hlo
