@@ -16,6 +16,7 @@ the two is structural rather than tested-for.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Callable, Dict, Optional
 
@@ -114,6 +115,181 @@ def block_finish(p: Dict[str, Array], h: Array, att_heads: Array, *,
     return h + f @ p["W2"] + p["b2"]
 
 
+def paged_decode_program(*, embed: Callable, pos: Callable, blocks: Callable,
+                         head: Callable, heads: tuple, n_layers: int,
+                         vocab_size: int, pos_rows: int, page_size: int,
+                         max_len: Optional[int] = None,
+                         psum_axis: Optional[str] = None):
+    """The ``ops/kv_cache.DecodeProgram`` (its docstring has each entry
+    point's contract) of a model made of this block, built ONCE for all
+    of them.  A model describes itself with callables and numbers:
+    ``embed(params, ids) -> rows``, ``pos(params) -> [pos_rows, D]``,
+    ``blocks(params) -> [block_params trees]`` (cut to the heads this
+    device holds), ``head(params, h) -> logits``, ``heads = (H, d_head)``
+    of a cached row, ``psum_axis`` for ``block_finish``.  ``max_len``
+    (default: the position table, whole pages) is the FIXED key length L.
+
+    Each entry point forms its first hidden state, the causal bias at
+    its query positions and its page table, names the writer of its
+    rows and calls ``layers``: the same per-position ops over L keys in
+    all of them.  What that gives against ``reencode`` of the same tokens:
+    equal tokens always; equal logit BITS wherever XLA:CPU computes two
+    row counts alike (tests/test_decode.py::TestBitIdentity holds them),
+    and within 2e-6 where it does not (the four comparisons named in
+    tests/_decode_checks.py; ROADMAP C1)."""
+    from ..ops.kv_cache import (
+        NEG_INF, DecodeProgram, det_attention, gather_layer,
+        write_prefill, write_step, write_tokens,
+    )
+    from ..ops.sampling import sample_token
+
+    if max_len is None:
+        max_len = (pos_rows // page_size) * page_size
+    if max_len % page_size or not (0 < max_len <= pos_rows):
+        raise ValueError(
+            f"max_len {max_len} must be a positive multiple of "
+            f"page_size {page_size} and <= the position table "
+            f"({pos_rows})")
+    L = int(max_len)
+    n_heads = heads[0]
+
+    def layers(params, k_pages, v_pages, h, bias, pt, write):
+        # each layer stores its new rows (``write(pages, layer, kv
+        # [B,H,T,d])``) BEFORE it attends over the gathered window
+        for i, bp in enumerate(blocks(params)):
+            q, k, v = block_kv_project(bp, h, n_heads)
+            k_pages = write(k_pages, i, k)
+            v_pages = write(v_pages, i, v)
+            k_all = gather_layer(k_pages, i, pt, heads).transpose(0, 2, 1, 3)
+            v_all = gather_layer(v_pages, i, pt, heads).transpose(0, 2, 1, 3)
+            h = block_finish(bp, h, det_attention(q, k_all, v_all, bias),
+                             psum_axis=psum_axis)
+        return k_pages, v_pages, head(params, h)
+
+    def one_slot(params, k_pages, v_pages, page_table_row, h, bias, n_real,
+                 offset):
+        # rows at offset..offset+Tb-1 -> the last REAL one's logits; pad
+        # rows' K/V are garbage-but-finite, masked until overwritten
+        k_pages, v_pages, lgs = layers(
+            params, k_pages, v_pages, h, bias, page_table_row[None],
+            lambda pages, i, kv: write_prefill(
+                pages, i, page_table_row, kv.transpose(0, 2, 1, 3)[0],
+                offset))
+        return k_pages, v_pages, lgs[0, n_real - 1]
+
+    def prefill(params, k_pages, v_pages, page_table_row, tokens, n_real):
+        tb = tokens.shape[0]
+        h = (embed(params, tokens) + pos(params)[:tb])[None]
+        bias = jnp.where(
+            jnp.arange(L, dtype=jnp.int32)[None, :]
+            <= jnp.arange(tb, dtype=jnp.int32)[:, None],
+            0.0, NEG_INF)[None, None]                   # [1,1,Tb,L]
+        return one_slot(params, k_pages, v_pages, page_table_row, h, bias,
+                        n_real, 0)
+
+    def prefill_at(params, k_pages, v_pages, page_table_row, tokens, n_real,
+                   offset):
+        # same per-row ops as ``prefill``: the position gather reads the
+        # rows its slice does
+        tb = tokens.shape[0]
+        pos_abs = offset + jnp.arange(tb, dtype=jnp.int32)
+        h = (embed(params, tokens)
+             + pos(params)[jnp.clip(pos_abs, 0, pos_rows - 1)])[None]
+        bias = jnp.where(
+            jnp.arange(L, dtype=jnp.int32)[None, :]
+            <= pos_abs[:, None], 0.0, NEG_INF)[None, None]
+        return one_slot(params, k_pages, v_pages, page_table_row, h, bias,
+                        n_real, offset)
+
+    def step(params, k_pages, v_pages, page_table, tokens, positions,
+             active):
+        # a masked slot's table row is zeroed, so its write goes to the
+        # scratch page and ONE program serves any active subset
+        h = (embed(params, tokens) + pos(params)[positions])[:, None]
+        bias = jnp.where(
+            jnp.arange(L, dtype=jnp.int32)[None, :]
+            <= positions[:, None], 0.0, NEG_INF)[:, None, None, :]
+        pt = jnp.where(active[:, None], page_table, 0)
+        k_pages, v_pages, lgs = layers(
+            params, k_pages, v_pages, h, bias, pt,
+            lambda pages, i, kv: write_step(pages, i, pt, positions,
+                                            kv[:, :, 0]))
+        return k_pages, v_pages, lgs[:, 0]
+
+    def spec_step(params, k_pages, v_pages, page_table, tokens, positions,
+                  active):
+        # rejected rows are garbage-but-finite and stay masked until the
+        # next round overwrites them
+        pos_abs = positions[:, None] + jnp.arange(tokens.shape[1],
+                                                  dtype=jnp.int32)
+        h = (embed(params, tokens)
+             + pos(params)[jnp.clip(pos_abs, 0, pos_rows - 1)])
+        bias = jnp.where(
+            jnp.arange(L, dtype=jnp.int32)[None, None, :]
+            <= pos_abs[:, :, None], 0.0, NEG_INF)[:, None]
+        pt = jnp.where(active[:, None], page_table, 0)
+        return layers(
+            params, k_pages, v_pages, h, bias, pt,
+            lambda pages, i, kv: write_tokens(pages, i, pt, positions,
+                                              kv.transpose(0, 2, 1, 3)))
+
+    sample_rows = jax.vmap(
+        functools.partial(sample_token, vocab_size=vocab_size))
+
+    def step_multi(params, k_pages, v_pages, page_table, tokens, positions,
+                   active, temps, top_ks, top_ps, seeds, steps, budgets,
+                   eos_id, horizon):
+        # a scan of ``step``'s work and the engine's own sampler, keyed
+        # ``fold_in(seed, steps + j)`` as its per-step sampler is.  A
+        # slot that stops leaves ``alive``: its table row zeroes, so
+        # live slots' bits match H plain steps.  Under tensor
+        # parallelism post-psum h is replicated: every shard draws the
+        # SAME token
+        def body(carry, j):
+            k_pages, v_pages, tok, alive = carry
+            pos_j = positions + j
+            h = (embed(params, tok)
+                 + pos(params)[jnp.clip(pos_j, 0, pos_rows - 1)])[:, None]
+            bias = jnp.where(
+                jnp.arange(L, dtype=jnp.int32)[None, :]
+                <= pos_j[:, None], 0.0, NEG_INF)[:, None, None, :]
+            pt = jnp.where(alive[:, None], page_table, 0)
+            k_pages, v_pages, lgs = layers(
+                params, k_pages, v_pages, h, bias, pt,
+                lambda pages, i, kv: write_step(pages, i, pt, pos_j,
+                                                kv[:, :, 0]))
+            lgs = lgs[:, 0]
+            nxt, fin = sample_rows(lgs, temps, top_ks, top_ps, seeds,
+                                   steps + j)
+            alive = alive & fin & (nxt != eos_id) & (j + 1 < budgets)
+            return (k_pages, v_pages, nxt, alive), (nxt, fin, lgs)
+
+        (k_pages, v_pages, _, _), (toks, fins, lgs) = jax.lax.scan(
+            body, (k_pages, v_pages, tokens, active), horizon)
+        return k_pages, v_pages, toks, fins, lgs
+
+    def reencode(params, tokens):
+        # the layers with no pool: the bit-identity oracle
+        t = tokens.shape[1]
+        h = embed(params, tokens) + pos(params)[:t]
+        bias = jnp.where(
+            jnp.arange(t, dtype=jnp.int32)[None, :]
+            <= jnp.arange(t, dtype=jnp.int32)[:, None],
+            0.0, NEG_INF)[None, None]
+        for bp in blocks(params):
+            q, k, v = block_kv_project(bp, h, n_heads)
+            h = block_finish(bp, h, det_attention(q, k, v, bias),
+                             psum_axis=psum_axis)
+        return head(params, h)
+
+    return DecodeProgram(
+        prefill=prefill, step=step, reencode=reencode,
+        n_layers=n_layers, n_heads=n_heads, d_head=heads[1],
+        vocab_size=vocab_size, max_len=L, page_size=page_size,
+        pages_per_slot=L // page_size,
+        prefill_at=prefill_at, spec_step=spec_step, step_multi=step_multi)
+
+
 @register_layer
 @dataclasses.dataclass
 class TransformerBlock(Layer):
@@ -210,14 +386,9 @@ class TransformerDecodeAdapter:
     ``serving.DecodeEngine``: the same ``params`` + ``decode_program()``
     surface ShardedTransformerLM exposes, built from the MLN layer stack
     (EmbeddingSequence, PositionalEmbedding, TransformerBlock × N,
-    RnnOutputLayer).  The program's three closures (prefill / step /
-    re-encode) share every per-position op — embedding lookup, position
-    add, block_kv_project/block_finish, the pre-softmax head — and
-    ops/kv_cache.det_attention, so incremental logits are BIT-identical
-    to re-encoding the same tokens.  The wrapped network itself is
-    untouched: its one-shot ``output``/``predict`` path keeps its own
-    jit programs (the no-behavior-change regression in
-    tests/test_decode.py)."""
+    RnnOutputLayer).  The wrapped network itself is untouched: its
+    one-shot ``output``/``predict`` path keeps its own jit programs (the
+    no-behavior-change regression in tests/test_decode.py)."""
 
     def __init__(self, net: MultiLayerNetwork):
         layers = net.conf.layers
@@ -251,28 +422,13 @@ class TransformerDecodeAdapter:
 
     def decode_program(self, page_size: int = 16,
                        max_len: Optional[int] = None):
-        from ..ops.kv_cache import (
-            NEG_INF, DecodeProgram, det_attention, gather_layer,
-            write_prefill, write_step, write_tokens,
-        )
-        from ..ops.sampling import sample_token
-
-        pos_rows = int(self.params["pos"]["P"].shape[0])
-        if max_len is None:
-            max_len = (pos_rows // page_size) * page_size
-        if max_len % page_size or not (0 < max_len <= pos_rows):
-            raise ValueError(
-                f"max_len {max_len} must be a positive multiple of "
-                f"page_size {page_size} and <= the position table "
-                f"({pos_rows})")
-        L = int(max_len)
-        n_heads = self.n_heads
-        n_layers = self._n_blocks
+        """``paged_decode_program`` of the MLN layer stack: the embedding
+        layer's own activation and bias, a list of block trees, and the
+        output layer's pre-softmax head (no final norm)."""
         embed_lay, out_lay = self._embed_lay, self._out_lay
         d_model = int(self.params["embed"]["W"].shape[1])
-        heads = (n_heads, d_model // n_heads)   # what a cached row holds
 
-        def tok_embed(params, idx):
+        def embed(params, idx):
             y = params["embed"]["W"][idx]
             if embed_lay.has_bias:
                 y = y + params["embed"]["b"]
@@ -284,162 +440,10 @@ class TransformerDecodeAdapter:
                 y = y + params["head"]["b"]
             return y          # pre-softmax logits (RnnOutputLayer._pre)
 
-        def prefill(params, k_pages, v_pages, page_table_row, tokens, n_real):
-            tb = tokens.shape[0]
-            h = (tok_embed(params, tokens) + params["pos"]["P"][:tb])[None]
-            bias = jnp.where(
-                jnp.arange(L, dtype=jnp.int32)[None, :]
-                <= jnp.arange(tb, dtype=jnp.int32)[:, None],
-                0.0, NEG_INF)[None, None]
-            pt = page_table_row[None]
-            for i, bp in enumerate(params["blocks"]):
-                q, k, v = block_kv_project(bp, h, n_heads)
-                k_pages = write_prefill(k_pages, i, page_table_row,
-                                        k.transpose(0, 2, 1, 3)[0])
-                v_pages = write_prefill(v_pages, i, page_table_row,
-                                        v.transpose(0, 2, 1, 3)[0])
-                k_all = gather_layer(
-                    k_pages, i, pt, heads).transpose(0, 2, 1, 3)
-                v_all = gather_layer(
-                    v_pages, i, pt, heads).transpose(0, 2, 1, 3)
-                h = block_finish(bp, h, det_attention(q, k_all, v_all, bias))
-            return k_pages, v_pages, head(params, h)[0, n_real - 1]
-
-        def step(params, k_pages, v_pages, page_table, tokens, positions,
-                 active):
-            h = (tok_embed(params, tokens)
-                 + params["pos"]["P"][positions])[:, None]
-            bias = jnp.where(
-                jnp.arange(L, dtype=jnp.int32)[None, :]
-                <= positions[:, None], 0.0, NEG_INF)[:, None, None, :]
-            pt = jnp.where(active[:, None], page_table, 0)
-            for i, bp in enumerate(params["blocks"]):
-                q, k, v = block_kv_project(bp, h, n_heads)
-                k_pages = write_step(k_pages, i, pt, positions, k[:, :, 0])
-                v_pages = write_step(v_pages, i, pt, positions, v[:, :, 0])
-                k_all = gather_layer(
-                    k_pages, i, pt, heads).transpose(0, 2, 1, 3)
-                v_all = gather_layer(
-                    v_pages, i, pt, heads).transpose(0, 2, 1, 3)
-                h = block_finish(bp, h, det_attention(q, k_all, v_all, bias))
-            return k_pages, v_pages, head(params, h)[:, 0]
-
-        def prefill_at(params, k_pages, v_pages, page_table_row, tokens,
-                       n_real, offset):
-            # suffix prefill for a prefix-cache hit: rows occupy absolute
-            # positions offset..offset+tb-1 and attend over the shared
-            # prefix rows already resident in the attached pages.  Same
-            # per-row ops as prefill, so logits stay bit-identical.
-            tb = tokens.shape[0]
-            pos_abs = offset + jnp.arange(tb, dtype=jnp.int32)
-            h = (tok_embed(params, tokens)
-                 + params["pos"]["P"][jnp.clip(pos_abs, 0, pos_rows - 1)]
-                 )[None]
-            bias = jnp.where(
-                jnp.arange(L, dtype=jnp.int32)[None, :]
-                <= pos_abs[:, None], 0.0, NEG_INF)[None, None]
-            pt = page_table_row[None]
-            for i, bp in enumerate(params["blocks"]):
-                q, k, v = block_kv_project(bp, h, n_heads)
-                k_pages = write_prefill(k_pages, i, page_table_row,
-                                        k.transpose(0, 2, 1, 3)[0], offset)
-                v_pages = write_prefill(v_pages, i, page_table_row,
-                                        v.transpose(0, 2, 1, 3)[0], offset)
-                k_all = gather_layer(
-                    k_pages, i, pt, heads).transpose(0, 2, 1, 3)
-                v_all = gather_layer(
-                    v_pages, i, pt, heads).transpose(0, 2, 1, 3)
-                h = block_finish(bp, h, det_attention(q, k_all, v_all, bias))
-            return k_pages, v_pages, head(params, h)[0, n_real - 1]
-
-        def spec_step(params, k_pages, v_pages, page_table, tokens,
-                      positions, active):
-            # speculative verify: score tokens [S, T] at absolute
-            # positions positions[s]..positions[s]+T-1 in ONE call,
-            # writing their K/V rows (overflow rows route to scratch in
-            # write_tokens).  Rejected rows are garbage-but-finite and
-            # stay masked until overwritten by the next round.
-            s_n, t_n = tokens.shape
-            pos_abs = positions[:, None] + jnp.arange(t_n, dtype=jnp.int32)
-            h = (tok_embed(params, tokens)
-                 + params["pos"]["P"][jnp.clip(pos_abs, 0, pos_rows - 1)])
-            bias = jnp.where(
-                jnp.arange(L, dtype=jnp.int32)[None, None, :]
-                <= pos_abs[:, :, None], 0.0, NEG_INF)[:, None]
-            pt = jnp.where(active[:, None], page_table, 0)
-            for i, bp in enumerate(params["blocks"]):
-                q, k, v = block_kv_project(bp, h, n_heads)
-                k_pages = write_tokens(k_pages, i, pt, positions,
-                                       k.transpose(0, 2, 1, 3))
-                v_pages = write_tokens(v_pages, i, pt, positions,
-                                       v.transpose(0, 2, 1, 3))
-                k_all = gather_layer(
-                    k_pages, i, pt, heads).transpose(0, 2, 1, 3)
-                v_all = gather_layer(
-                    v_pages, i, pt, heads).transpose(0, 2, 1, 3)
-                h = block_finish(bp, h, det_attention(q, k_all, v_all, bias))
-            return k_pages, v_pages, head(params, h)
-
-        vocab = self.vocab_size
-
-        def step_multi(params, k_pages, v_pages, page_table, tokens,
-                       positions, active, temps, top_ks, top_ps, seeds,
-                       steps, budgets, eos_id, horizon):
-            # H = horizon.shape[0] consecutive decode steps in ONE
-            # program: scan of the step body with device-resident
-            # sampling.  A slot that hits EOS / its token budget /
-            # non-finite logits drops out of ``alive``; its page-table
-            # row zeroes, so the remaining iterations write to scratch
-            # and live slots' bits match H plain steps exactly.
-            def body(carry, j):
-                k_pages, v_pages, tok, alive = carry
-                pos_j = positions + j
-                h = (tok_embed(params, tok)
-                     + params["pos"]["P"][jnp.clip(pos_j, 0, pos_rows - 1)]
-                     )[:, None]
-                bias = jnp.where(
-                    jnp.arange(L, dtype=jnp.int32)[None, :]
-                    <= pos_j[:, None], 0.0, NEG_INF)[:, None, None, :]
-                pt = jnp.where(alive[:, None], page_table, 0)
-                for i, bp in enumerate(params["blocks"]):
-                    q, k, v = block_kv_project(bp, h, n_heads)
-                    k_pages = write_step(k_pages, i, pt, pos_j, k[:, :, 0])
-                    v_pages = write_step(v_pages, i, pt, pos_j, v[:, :, 0])
-                    k_all = gather_layer(
-                        k_pages, i, pt, heads).transpose(0, 2, 1, 3)
-                    v_all = gather_layer(
-                        v_pages, i, pt, heads).transpose(0, 2, 1, 3)
-                    h = block_finish(bp, h,
-                                     det_attention(q, k_all, v_all, bias))
-                lgs = head(params, h)[:, 0]
-                nxt, fin = jax.vmap(
-                    lambda l, t, kk, pp, sd, st:
-                        sample_token(l, t, kk, pp, sd, st, vocab)
-                )(lgs, temps, top_ks, top_ps, seeds, steps + j)
-                alive = (alive & fin & (nxt != eos_id)
-                         & (j + 1 < budgets))
-                return (k_pages, v_pages, nxt, alive), (nxt, fin, lgs)
-
-            (k_pages, v_pages, _, _), (toks, fins, lgs) = jax.lax.scan(
-                body, (k_pages, v_pages, tokens, active), horizon)
-            return k_pages, v_pages, toks, fins, lgs
-
-        def reencode(params, tokens):
-            b, t = tokens.shape
-            h = tok_embed(params, tokens) + params["pos"]["P"][:t]
-            bias = jnp.where(
-                jnp.arange(t, dtype=jnp.int32)[None, :]
-                <= jnp.arange(t, dtype=jnp.int32)[:, None],
-                0.0, NEG_INF)[None, None]
-            for bp in params["blocks"]:
-                q, k, v = block_kv_project(bp, h, n_heads)
-                h = block_finish(bp, h, det_attention(q, k, v, bias))
-            return head(params, h)
-
-        return DecodeProgram(
-            prefill=prefill, step=step, reencode=reencode,
-            n_layers=n_layers, n_heads=n_heads, d_head=d_model // n_heads,
-            vocab_size=self.vocab_size, max_len=L, page_size=page_size,
-            pages_per_slot=L // page_size,
-            prefill_at=prefill_at, spec_step=spec_step,
-            step_multi=step_multi)
+        return paged_decode_program(
+            embed=embed, pos=lambda params: params["pos"]["P"],
+            blocks=lambda params: params["blocks"], head=head,
+            heads=(self.n_heads, d_model // self.n_heads),
+            n_layers=self._n_blocks, vocab_size=self.vocab_size,
+            pos_rows=int(self.params["pos"]["P"].shape[0]),
+            page_size=page_size, max_len=max_len)

@@ -389,26 +389,32 @@ class ShardedTransformerLM:
     def decode_program(self, page_size: int = 16,
                        max_len: Optional[int] = None):
         """Pure prefill / decode-step / re-encode functions over the
-        paged KV-cache (ops/kv_cache.py) for the serving decode engine.
+        paged KV-cache (ops/kv_cache.py) for the serving decode engine:
+        ``models/transformer.paged_decode_program`` of this LM's stacked
+        block tree, with the final layer norm before the head.
 
         The decode path is a different execution mode from training —
         stateful, one query row per step — but shares the block weights
-        and the block math split (models/transformer.block_kv_project /
-        block_finish), and uses ops/kv_cache.det_attention so the
-        incremental logits are BIT-identical to ``reencode`` of the same
-        tokens (the ``continuous_batching_ab`` gate).
+        and the block math split (block_kv_project / block_finish), and
+        uses ops/kv_cache.det_attention: the incremental path runs the
+        ops ``reencode`` runs, so tokens are equal and logits equal in
+        bits or, where XLA:CPU computes two row counts differently,
+        within 2e-6 (``paged_decode_program``'s docstring names the
+        tests that hold each).
 
         On a multi-device mesh (all devices folded into the ``data``
-        axis) the program is TENSOR-PARALLEL: every entry point is
-        shard_map'd with attention heads split over ``data``, the page
+        axis) the program is TENSOR-PARALLEL: the same entry points,
+        built over each device's head group (column-slices of Wq/Wk/Wv,
+        the matching row-slice of Wo) and shard_map'd with the page
         pool's lane axis sharded to match (a row's heads lie side by
         side, so each device holds whole heads, 1/n of the KV bytes),
-        an explicit psum after the row-parallel output projection, and
-        logits replicated so the samplers see the full vocabulary.  All
+        ONE psum per layer after the row-parallel output projection,
+        and the FFN, the head and so the logits replicated: the
+        samplers see the full vocabulary on every shard.  All
         shards run the identical psum in both the incremental and
-        re-encode paths, so the bit-identity contract holds PER SHARD
-        LAYOUT (an n-way program's bits match its own re-encode, not a
-        1-way program's).  Int8 KV stays single-device: its per-row
+        re-encode paths, so that contract holds PER SHARD LAYOUT (an
+        n-way program is held to its own re-encode, not to a 1-way
+        program's).  Int8 KV stays single-device: its per-row
         quantization scale is an amax over ALL heads, which a head
         shard cannot compute locally (the engine enforces this).
         """
@@ -420,13 +426,9 @@ class ShardedTransformerLM:
                     "latent_moe decode program: serve it on a one-device "
                     "mesh")
             return latent_moe.decode_program(self.arch, page_size, max_len)
-        from ..models.transformer import block_finish, block_kv_project
+        from ..models.transformer import paged_decode_program
         from ..nn.layers.normalization import layer_norm
-        from ..ops.kv_cache import (
-            NEG_INF, DecodeProgram, det_attention, gather_layer,
-            write_prefill, write_step, write_tokens,
-        )
-        from ..ops.sampling import sample_token
+        from ..ops.kv_cache import QuantPages
 
         n_dev = int(np.prod(list(self.mesh.shape.values())))
         tp = 1
@@ -445,441 +447,76 @@ class ShardedTransformerLM:
             raise NotImplementedError(
                 "decode_program serves the f32 params path; compute_dtype "
                 "casting would break the re-encode bit-identity contract")
-        pos_rows = int(self.params["pos"].shape[0])
-        if max_len is None:
-            max_len = (pos_rows // page_size) * page_size
-        if max_len % page_size or not (0 < max_len <= pos_rows):
-            raise ValueError(
-                f"max_len {max_len} must be a positive multiple of "
-                f"page_size {page_size} and <= the position table "
-                f"({pos_rows})")
-        L = int(max_len)
         n_heads = self.n_heads
         n_layers = int(jax.tree_util.tree_leaves(
             self.params["blocks"])[0].shape[0])
         d_model = int(self.params["embed"].shape[1])
-        heads = (n_heads, d_model // n_heads)   # what a cached row holds
+        hl, dh = n_heads // tp, d_model // n_heads   # a device's heads
 
-        def _blocks(params):
-            return [jax.tree_util.tree_map(lambda a: a[i], params["blocks"])
-                    for i in range(n_layers)]
+        def layer(params, i):
+            return jax.tree_util.tree_map(lambda a: a[i], params["blocks"])
 
-        def prefill(params, k_pages, v_pages, page_table_row, tokens, n_real):
-            """One slot's prompt (bucket length Tb) -> cache writes for
-            positions 0..Tb-1 plus the last REAL position's logits.
-            Pad-position K/V rows are garbage-but-finite; the step bias
-            masks them until a decode step overwrites each one."""
-            tb = tokens.shape[0]
-            h = (params["embed"][tokens] + params["pos"][:tb])[None]
-            bias = jnp.where(
-                jnp.arange(L, dtype=jnp.int32)[None, :]
-                <= jnp.arange(tb, dtype=jnp.int32)[:, None],
-                0.0, NEG_INF)[None, None]              # [1,1,Tb,L]
-            pt = page_table_row[None]
-            for i, bp in enumerate(_blocks(params)):
-                q, k, v = block_kv_project(bp, h, n_heads)  # [1,H,Tb,dh]
-                k_pages = write_prefill(k_pages, i, page_table_row,
-                                        k.transpose(0, 2, 1, 3)[0])
-                v_pages = write_prefill(v_pages, i, page_table_row,
-                                        v.transpose(0, 2, 1, 3)[0])
-                k_all = gather_layer(
-                    k_pages, i, pt, heads).transpose(0, 2, 1, 3)
-                v_all = gather_layer(
-                    v_pages, i, pt, heads).transpose(0, 2, 1, 3)
-                h = block_finish(bp, h, det_attention(q, k_all, v_all, bias))
-            h = layer_norm(h, params["lnf_g"], params["lnf_b"])
-            return k_pages, v_pages, (h @ params["head"])[0, n_real - 1]
+        def blocks(params):
+            return [layer(params, i) for i in range(n_layers)]
 
-        def step(params, k_pages, v_pages, page_table, tokens, positions,
-                 active):
-            """One fixed-shape decode step over ALL slots ([S] inputs):
-            masked slots' writes are routed to the scratch page (their
-            table rows are zeroed here), so one compiled program serves
-            any active subset — the zero-recompile contract continuous
-            batching rides on."""
-            h = (params["embed"][tokens]
-                 + params["pos"][positions])[:, None]   # [S,1,D]
-            bias = jnp.where(
-                jnp.arange(L, dtype=jnp.int32)[None, :]
-                <= positions[:, None], 0.0, NEG_INF)[:, None, None, :]
-            pt = jnp.where(active[:, None], page_table, 0)
-            for i, bp in enumerate(_blocks(params)):
-                q, k, v = block_kv_project(bp, h, n_heads)  # [S,H,1,dh]
-                k_pages = write_step(k_pages, i, pt, positions, k[:, :, 0])
-                v_pages = write_step(v_pages, i, pt, positions, v[:, :, 0])
-                k_all = gather_layer(
-                    k_pages, i, pt, heads).transpose(0, 2, 1, 3)
-                v_all = gather_layer(
-                    v_pages, i, pt, heads).transpose(0, 2, 1, 3)
-                h = block_finish(bp, h, det_attention(q, k_all, v_all, bias))
-            h = layer_norm(h, params["lnf_g"], params["lnf_b"])
-            return k_pages, v_pages, (h @ params["head"])[:, 0]
+        def local_blocks(params):
+            idx = jax.lax.axis_index("data")
+            out = []
+            for i in range(n_layers):
+                bp = layer(params, i)
+                lb = dict(bp)
+                for w in ("Wq", "Wk", "Wv"):
+                    lb[w] = bp[w].reshape(d_model, tp, hl * dh)[:, idx]
+                lb["Wo"] = bp["Wo"].reshape(tp, hl * dh, d_model)[idx]
+                out.append(lb)
+            return out
 
-        def prefill_at(params, k_pages, v_pages, page_table_row, tokens,
-                       n_real, offset):
-            """Suffix prefill for a prefix-cache hit: the bucket's rows
-            land at absolute positions offset..offset+Tb-1 and attend
-            over the shared prefix rows already resident in the attached
-            pages.  Same per-row ops as ``prefill`` (position gather vs
-            slice reads the same table rows), so the last-real-position
-            logits stay bit-identical to a cold full prefill."""
-            tb = tokens.shape[0]
-            pos_abs = offset + jnp.arange(tb, dtype=jnp.int32)
-            h = (params["embed"][tokens]
-                 + params["pos"][jnp.clip(pos_abs, 0, pos_rows - 1)])[None]
-            bias = jnp.where(
-                jnp.arange(L, dtype=jnp.int32)[None, :]
-                <= pos_abs[:, None], 0.0, NEG_INF)[None, None]
-            pt = page_table_row[None]
-            for i, bp in enumerate(_blocks(params)):
-                q, k, v = block_kv_project(bp, h, n_heads)
-                k_pages = write_prefill(k_pages, i, page_table_row,
-                                        k.transpose(0, 2, 1, 3)[0], offset)
-                v_pages = write_prefill(v_pages, i, page_table_row,
-                                        v.transpose(0, 2, 1, 3)[0], offset)
-                k_all = gather_layer(
-                    k_pages, i, pt, heads).transpose(0, 2, 1, 3)
-                v_all = gather_layer(
-                    v_pages, i, pt, heads).transpose(0, 2, 1, 3)
-                h = block_finish(bp, h, det_attention(q, k_all, v_all, bias))
-            h = layer_norm(h, params["lnf_g"], params["lnf_b"])
-            return k_pages, v_pages, (h @ params["head"])[0, n_real - 1]
-
-        def spec_step(params, k_pages, v_pages, page_table, tokens,
-                      positions, active):
-            """Speculative verify: score ``tokens`` [S, T] at absolute
-            positions positions[s]..positions[s]+T-1 in ONE fixed-shape
-            call, writing their K/V rows (overflow rows route to the
-            scratch page inside write_tokens).  Rejected rows are
-            garbage-but-finite and stay masked until the next round
-            overwrites them.  Per-row math matches ``step``, so each
-            row's logits are bit-identical to stepping tokens one at a
-            time — the greedy temp-0 identity gate rides on this."""
-            s_n, t_n = tokens.shape
-            pos_abs = positions[:, None] + jnp.arange(t_n, dtype=jnp.int32)
-            h = (params["embed"][tokens]
-                 + params["pos"][jnp.clip(pos_abs, 0, pos_rows - 1)])
-            bias = jnp.where(
-                jnp.arange(L, dtype=jnp.int32)[None, None, :]
-                <= pos_abs[:, :, None], 0.0, NEG_INF)[:, None]
-            pt = jnp.where(active[:, None], page_table, 0)
-            for i, bp in enumerate(_blocks(params)):
-                q, k, v = block_kv_project(bp, h, n_heads)  # [S,H,T,dh]
-                k_pages = write_tokens(k_pages, i, pt, positions,
-                                       k.transpose(0, 2, 1, 3))
-                v_pages = write_tokens(v_pages, i, pt, positions,
-                                       v.transpose(0, 2, 1, 3))
-                k_all = gather_layer(
-                    k_pages, i, pt, heads).transpose(0, 2, 1, 3)
-                v_all = gather_layer(
-                    v_pages, i, pt, heads).transpose(0, 2, 1, 3)
-                h = block_finish(bp, h, det_attention(q, k_all, v_all, bias))
-            h = layer_norm(h, params["lnf_g"], params["lnf_b"])
-            return k_pages, v_pages, h @ params["head"]
-
-        def reencode(params, tokens):
-            """Full forward at the SAME fixed length L with the SAME
-            deterministic attention — the naive-baseline arm and the
-            bit-identity oracle.  ``tokens`` [B, L]; row p of the output
-            is the next-token logits after position p."""
-            b, t = tokens.shape
-            h = params["embed"][tokens] + params["pos"][:t]
-            bias = jnp.where(
-                jnp.arange(t, dtype=jnp.int32)[None, :]
-                <= jnp.arange(t, dtype=jnp.int32)[:, None],
-                0.0, NEG_INF)[None, None]
-            for bp in _blocks(params):
-                q, k, v = block_kv_project(bp, h, n_heads)
-                h = block_finish(bp, h, det_attention(q, k, v, bias))
+        def head(params, h):
             h = layer_norm(h, params["lnf_g"], params["lnf_b"])
             return h @ params["head"]
 
-        vocab = self.vocab_size
+        prog = paged_decode_program(
+            embed=lambda params, ids: params["embed"][ids],
+            pos=lambda params: params["pos"],
+            blocks=blocks if tp == 1 else local_blocks, head=head,
+            heads=(hl, dh), n_layers=n_layers, vocab_size=self.vocab_size,
+            pos_rows=int(self.params["pos"].shape[0]), page_size=page_size,
+            max_len=max_len, psum_axis=None if tp == 1 else "data")
+        if tp == 1:
+            return prog
 
-        def _sample_rows(lgs, temps, top_ks, top_ps, seeds, steps):
-            return jax.vmap(
-                lambda l, t, k, p, sd, st:
-                    sample_token(l, t, k, p, sd, st, vocab)
-            )(lgs, temps, top_ks, top_ps, seeds, steps)
+        mesh = self.mesh
+        rep = P()
 
-        def step_multi(params, k_pages, v_pages, page_table, tokens,
-                       positions, active, temps, top_ks, top_ps, seeds,
-                       steps, budgets, eos_id, horizon):
-            """H = horizon.shape[0] consecutive decode steps in ONE
-            program: ``lax.scan`` of the ``step`` body with sampling
-            device-resident (ops/sampling.sample_token keyed
-            ``fold_in(seed, steps + j)`` — the identical key schedule
-            the engine's per-step sampler uses, which is what makes
-            horizon fusion bit-identical to step-by-step).  Per-slot
-            EOS (``eos_id``; pass -1 to disable) / token-budget /
-            poison masking runs on device: a finished slot leaves
-            ``alive``, its page-table row zeroes, and its remaining
-            writes route to the scratch page, so live slots' bits match
-            H plain steps exactly.  Returns stacked per-iteration
-            (tokens, finite, logits); the host records tokens up to
-            each slot's stop and discards the device overrun."""
-            def body(carry, j):
-                k_pages, v_pages, tok, alive = carry
-                pos_j = positions + j
-                h = (params["embed"][tok]
-                     + params["pos"][jnp.clip(pos_j, 0, pos_rows - 1)]
-                     )[:, None]
-                bias = jnp.where(
-                    jnp.arange(L, dtype=jnp.int32)[None, :]
-                    <= pos_j[:, None], 0.0, NEG_INF)[:, None, None, :]
-                pt = jnp.where(alive[:, None], page_table, 0)
-                for i, bp in enumerate(_blocks(params)):
-                    q, k, v = block_kv_project(bp, h, n_heads)
-                    k_pages = write_step(k_pages, i, pt, pos_j, k[:, :, 0])
-                    v_pages = write_step(v_pages, i, pt, pos_j, v[:, :, 0])
-                    k_all = gather_layer(
-                        k_pages, i, pt, heads).transpose(0, 2, 1, 3)
-                    v_all = gather_layer(
-                        v_pages, i, pt, heads).transpose(0, 2, 1, 3)
-                    h = block_finish(bp, h,
-                                     det_attention(q, k_all, v_all, bias))
-                h = layer_norm(h, params["lnf_g"], params["lnf_b"])
-                lgs = (h @ params["head"])[:, 0]
-                nxt, fin = _sample_rows(lgs, temps, top_ks, top_ps,
-                                        seeds, steps + j)
-                alive = (alive & fin & (nxt != eos_id)
-                         & (j + 1 < budgets))
-                return (k_pages, v_pages, nxt, alive), (nxt, fin, lgs)
+        def pool_spec(pool):
+            # heads lie side by side in a row, so a shard of the lane
+            # axis is whole heads
+            full = P(None, None, None, "data")
+            if isinstance(pool, QuantPages):
+                return QuantPages(full, rep)
+            return full
 
-            (k_pages, v_pages, _, _), (toks, fins, lgs) = jax.lax.scan(
-                body, (k_pages, v_pages, tokens, active), horizon)
-            return k_pages, v_pages, toks, fins, lgs
+        def wrap(body, n_rep=1):
+            # the pool specs depend on the pool KIND, so the shard_map is
+            # built at trace time (inside the engine's jit) where the
+            # pytree is known; n_rep = number of replicated outputs after
+            # the two pool sides.  The wrapper keeps the entry point's
+            # name: the engine's executables are called after it
+            @functools.wraps(body)
+            def fn(params, k_pages, v_pages, *rest):
+                ks, vs = pool_spec(k_pages), pool_spec(v_pages)
+                sm = shard_map(
+                    body, mesh=mesh,
+                    in_specs=(rep, ks, vs) + (rep,) * len(rest),
+                    out_specs=(ks, vs) + (rep,) * n_rep)
+                return sm(params, k_pages, v_pages, *rest)
+            return fn
 
-        if tp > 1:
-            # tensor-parallel twins of the five entry points: identical
-            # per-row math, but each shard projects only its local head
-            # group (column-slices of Wq/Wk/Wv, the matching row-slice
-            # of Wo) against a pool shard holding those heads' pages,
-            # with ONE psum per layer restoring the full residual.  The
-            # FFN and the vocab head run replicated — post-psum h is
-            # identical on every shard, so the samplers' "gathered"
-            # logits come for free.
-            from jax.sharding import PartitionSpec
-            from ..ops.kv_cache import QuantPages
-
-            mesh = self.mesh
-            hl = n_heads // tp
-            dh = d_model // n_heads
-            heads_l = (hl, dh)
-            rep = PartitionSpec()
-
-            def _pool_spec(pool):
-                # heads lie side by side in a row, so a shard of the
-                # lane axis is whole heads
-                full = PartitionSpec(None, None, None, "data")
-                if isinstance(pool, QuantPages):
-                    return QuantPages(full, rep)
-                return full
-
-            def _local_blocks(params):
-                idx = jax.lax.axis_index("data")
-                out = []
-                for i in range(n_layers):
-                    bp = jax.tree_util.tree_map(
-                        lambda a: a[i], params["blocks"])
-                    lb = dict(bp)
-                    for w in ("Wq", "Wk", "Wv"):
-                        lb[w] = bp[w].reshape(d_model, tp, hl * dh)[:, idx]
-                    lb["Wo"] = bp["Wo"].reshape(tp, hl * dh, d_model)[idx]
-                    out.append(lb)
-                return out
-
-            def _prefill_sh(params, k_pages, v_pages, page_table_row,
-                            tokens, n_real):
-                tb = tokens.shape[0]
-                h = (params["embed"][tokens] + params["pos"][:tb])[None]
-                bias = jnp.where(
-                    jnp.arange(L, dtype=jnp.int32)[None, :]
-                    <= jnp.arange(tb, dtype=jnp.int32)[:, None],
-                    0.0, NEG_INF)[None, None]
-                pt = page_table_row[None]
-                for i, bp in enumerate(_local_blocks(params)):
-                    q, k, v = block_kv_project(bp, h, hl)
-                    k_pages = write_prefill(k_pages, i, page_table_row,
-                                            k.transpose(0, 2, 1, 3)[0])
-                    v_pages = write_prefill(v_pages, i, page_table_row,
-                                            v.transpose(0, 2, 1, 3)[0])
-                    k_all = gather_layer(
-                        k_pages, i, pt, heads_l).transpose(0, 2, 1, 3)
-                    v_all = gather_layer(
-                        v_pages, i, pt, heads_l).transpose(0, 2, 1, 3)
-                    h = block_finish(bp, h,
-                                     det_attention(q, k_all, v_all, bias),
-                                     psum_axis="data")
-                h = layer_norm(h, params["lnf_g"], params["lnf_b"])
-                return k_pages, v_pages, (h @ params["head"])[0, n_real - 1]
-
-            def _step_sh(params, k_pages, v_pages, page_table, tokens,
-                         positions, active):
-                h = (params["embed"][tokens]
-                     + params["pos"][positions])[:, None]
-                bias = jnp.where(
-                    jnp.arange(L, dtype=jnp.int32)[None, :]
-                    <= positions[:, None], 0.0, NEG_INF)[:, None, None, :]
-                pt = jnp.where(active[:, None], page_table, 0)
-                for i, bp in enumerate(_local_blocks(params)):
-                    q, k, v = block_kv_project(bp, h, hl)
-                    k_pages = write_step(k_pages, i, pt, positions,
-                                         k[:, :, 0])
-                    v_pages = write_step(v_pages, i, pt, positions,
-                                         v[:, :, 0])
-                    k_all = gather_layer(
-                        k_pages, i, pt, heads_l).transpose(0, 2, 1, 3)
-                    v_all = gather_layer(
-                        v_pages, i, pt, heads_l).transpose(0, 2, 1, 3)
-                    h = block_finish(bp, h,
-                                     det_attention(q, k_all, v_all, bias),
-                                     psum_axis="data")
-                h = layer_norm(h, params["lnf_g"], params["lnf_b"])
-                return k_pages, v_pages, (h @ params["head"])[:, 0]
-
-            def _prefill_at_sh(params, k_pages, v_pages, page_table_row,
-                               tokens, n_real, offset):
-                tb = tokens.shape[0]
-                pos_abs = offset + jnp.arange(tb, dtype=jnp.int32)
-                h = (params["embed"][tokens]
-                     + params["pos"][jnp.clip(pos_abs, 0,
-                                              pos_rows - 1)])[None]
-                bias = jnp.where(
-                    jnp.arange(L, dtype=jnp.int32)[None, :]
-                    <= pos_abs[:, None], 0.0, NEG_INF)[None, None]
-                pt = page_table_row[None]
-                for i, bp in enumerate(_local_blocks(params)):
-                    q, k, v = block_kv_project(bp, h, hl)
-                    k_pages = write_prefill(k_pages, i, page_table_row,
-                                            k.transpose(0, 2, 1, 3)[0],
-                                            offset)
-                    v_pages = write_prefill(v_pages, i, page_table_row,
-                                            v.transpose(0, 2, 1, 3)[0],
-                                            offset)
-                    k_all = gather_layer(
-                        k_pages, i, pt, heads_l).transpose(0, 2, 1, 3)
-                    v_all = gather_layer(
-                        v_pages, i, pt, heads_l).transpose(0, 2, 1, 3)
-                    h = block_finish(bp, h,
-                                     det_attention(q, k_all, v_all, bias),
-                                     psum_axis="data")
-                h = layer_norm(h, params["lnf_g"], params["lnf_b"])
-                return k_pages, v_pages, (h @ params["head"])[0, n_real - 1]
-
-            def _spec_step_sh(params, k_pages, v_pages, page_table, tokens,
-                              positions, active):
-                s_n, t_n = tokens.shape
-                pos_abs = positions[:, None] + jnp.arange(t_n,
-                                                          dtype=jnp.int32)
-                h = (params["embed"][tokens]
-                     + params["pos"][jnp.clip(pos_abs, 0, pos_rows - 1)])
-                bias = jnp.where(
-                    jnp.arange(L, dtype=jnp.int32)[None, None, :]
-                    <= pos_abs[:, :, None], 0.0, NEG_INF)[:, None]
-                pt = jnp.where(active[:, None], page_table, 0)
-                for i, bp in enumerate(_local_blocks(params)):
-                    q, k, v = block_kv_project(bp, h, hl)
-                    k_pages = write_tokens(k_pages, i, pt, positions,
-                                           k.transpose(0, 2, 1, 3))
-                    v_pages = write_tokens(v_pages, i, pt, positions,
-                                           v.transpose(0, 2, 1, 3))
-                    k_all = gather_layer(
-                        k_pages, i, pt, heads_l).transpose(0, 2, 1, 3)
-                    v_all = gather_layer(
-                        v_pages, i, pt, heads_l).transpose(0, 2, 1, 3)
-                    h = block_finish(bp, h,
-                                     det_attention(q, k_all, v_all, bias),
-                                     psum_axis="data")
-                h = layer_norm(h, params["lnf_g"], params["lnf_b"])
-                return k_pages, v_pages, h @ params["head"]
-
-            def _reencode_sh(params, tokens):
-                b, t = tokens.shape
-                h = params["embed"][tokens] + params["pos"][:t]
-                bias = jnp.where(
-                    jnp.arange(t, dtype=jnp.int32)[None, :]
-                    <= jnp.arange(t, dtype=jnp.int32)[:, None],
-                    0.0, NEG_INF)[None, None]
-                for bp in _local_blocks(params):
-                    q, k, v = block_kv_project(bp, h, hl)
-                    h = block_finish(bp, h, det_attention(q, k, v, bias),
-                                     psum_axis="data")
-                h = layer_norm(h, params["lnf_g"], params["lnf_b"])
-                return h @ params["head"]
-
-            def _step_multi_sh(params, k_pages, v_pages, page_table,
-                               tokens, positions, active, temps, top_ks,
-                               top_ps, seeds, steps, budgets, eos_id,
-                               horizon):
-                # fused scan of _step_sh's body; post-psum h is
-                # replicated, so every shard samples the SAME token from
-                # the same deterministic key — no gather needed
-                def body(carry, j):
-                    k_pages, v_pages, tok, alive = carry
-                    pos_j = positions + j
-                    h = (params["embed"][tok]
-                         + params["pos"][jnp.clip(pos_j, 0, pos_rows - 1)]
-                         )[:, None]
-                    bias = jnp.where(
-                        jnp.arange(L, dtype=jnp.int32)[None, :]
-                        <= pos_j[:, None], 0.0,
-                        NEG_INF)[:, None, None, :]
-                    pt = jnp.where(alive[:, None], page_table, 0)
-                    for i, bp in enumerate(_local_blocks(params)):
-                        q, k, v = block_kv_project(bp, h, hl)
-                        k_pages = write_step(k_pages, i, pt, pos_j,
-                                             k[:, :, 0])
-                        v_pages = write_step(v_pages, i, pt, pos_j,
-                                             v[:, :, 0])
-                        k_all = gather_layer(
-                            k_pages, i, pt, heads_l).transpose(0, 2, 1, 3)
-                        v_all = gather_layer(
-                            v_pages, i, pt, heads_l).transpose(0, 2, 1, 3)
-                        h = block_finish(
-                            bp, h, det_attention(q, k_all, v_all, bias),
-                            psum_axis="data")
-                    h = layer_norm(h, params["lnf_g"], params["lnf_b"])
-                    lgs = (h @ params["head"])[:, 0]
-                    nxt, fin = _sample_rows(lgs, temps, top_ks, top_ps,
-                                            seeds, steps + j)
-                    alive = (alive & fin & (nxt != eos_id)
-                             & (j + 1 < budgets))
-                    return (k_pages, v_pages, nxt, alive), (nxt, fin, lgs)
-
-                (k_pages, v_pages, _, _), (toks, fins, lgs) = jax.lax.scan(
-                    body, (k_pages, v_pages, tokens, active), horizon)
-                return k_pages, v_pages, toks, fins, lgs
-
-            def _wrap(body, n_rep=1):
-                # the pool specs depend on the pool KIND, so the
-                # shard_map is built at trace time (inside the engine's
-                # jit) where the pytree is known; n_rep = number of
-                # replicated outputs after the two pool sides
-                def fn(params, k_pages, v_pages, *rest):
-                    ks, vs = _pool_spec(k_pages), _pool_spec(v_pages)
-                    sm = shard_map(
-                        body, mesh=mesh,
-                        in_specs=(rep, ks, vs) + (rep,) * len(rest),
-                        out_specs=(ks, vs) + (rep,) * n_rep)
-                    return sm(params, k_pages, v_pages, *rest)
-                return fn
-
-            prefill = _wrap(_prefill_sh)
-            step = _wrap(_step_sh)
-            prefill_at = _wrap(_prefill_at_sh)
-            spec_step = _wrap(_spec_step_sh)
-            step_multi = _wrap(_step_multi_sh, n_rep=3)
-
-            def reencode(params, tokens):
-                return shard_map(_reencode_sh, mesh=mesh,
-                                 in_specs=(rep, rep),
-                                 out_specs=rep)(params, tokens)
-
-        return DecodeProgram(
-            prefill=prefill, step=step, reencode=reencode,
-            n_layers=n_layers, n_heads=n_heads, d_head=d_model // n_heads,
-            vocab_size=self.vocab_size, max_len=L, page_size=page_size,
-            pages_per_slot=L // page_size,
-            prefill_at=prefill_at, spec_step=spec_step,
-            step_multi=step_multi, tp=tp)
+        return prog._replace(
+            prefill=wrap(prog.prefill), step=wrap(prog.step),
+            prefill_at=wrap(prog.prefill_at),
+            spec_step=wrap(prog.spec_step),
+            step_multi=wrap(prog.step_multi, n_rep=3),
+            reencode=shard_map(prog.reencode, mesh=mesh,
+                               in_specs=(rep, rep), out_specs=rep),
+            n_heads=n_heads, tp=tp)

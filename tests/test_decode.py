@@ -508,6 +508,77 @@ class TestHttpGenerate:
         assert "ttft_ms" in snap and "tpot_ms" in snap
 
 
+class TestOneBuilder:
+    """models/transformer.paged_decode_program makes the program of all
+    three families; the engine's executables (and the benchmark's
+    readers: ``^jit_step$``, ``jit_prefill*``) are called after the
+    functions it hands back."""
+
+    ENTRY_POINTS = ("step", "prefill", "prefill_at", "spec_step",
+                    "step_multi")
+
+    @staticmethod
+    def _model(family):
+        import jax
+
+        if family == "adapter":
+            from deeplearning4j_tpu.models import TransformerLM
+            from deeplearning4j_tpu.models.transformer import (
+                TransformerDecodeAdapter,
+            )
+            return TransformerDecodeAdapter(TransformerLM(
+                vocab_size=VOCAB, n_layers=2, d_model=32, n_heads=4,
+                max_len=MAXLEN, seed=3, kernel="xla"))
+        n = 4 if family == "sharded_tp4" else 1
+        if len(jax.devices()) < n:
+            pytest.skip(f"needs >= {n} devices")
+        mesh = build_mesh({"data": n, "model": 1, "seq": 1, "pipe": 1},
+                          jax.devices()[:n])
+        return ShardedTransformerLM(vocab_size=VOCAB, n_layers=2, d_model=32,
+                                    n_heads=4, max_len=MAXLEN, mesh=mesh,
+                                    seed=11)
+
+    @pytest.mark.parametrize("family", ["adapter", "sharded", "sharded_tp4"])
+    def test_names_and_one_step_horizon(self, family):
+        import jax
+        from _decode_checks import assert_logits_close
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        from deeplearning4j_tpu.ops.kv_cache import alloc_pools
+
+        model = self._model(family)
+        prog = model.decode_program(page_size=8)
+        assert prog.tp == (4 if family == "sharded_tp4" else 1)
+        for name in self.ENTRY_POINTS:
+            assert getattr(prog, name).__name__ == name
+        pps, s_n = prog.pages_per_slot, 2
+        pools = alloc_pools(prog, 1 + s_n * pps)
+        if prog.tp > 1:
+            pools = jax.device_put(pools, NamedSharding(
+                model.mesh, PartitionSpec(None, None, None, "data")))
+        table = 1 + np.arange(s_n * pps, dtype=np.int32).reshape(s_n, pps)
+        prompt = np.array([3, 1, 4, 1, 5, 0, 0, 0], np.int32)   # 5 real
+        kp, vp, lg = jax.jit(prog.prefill)(
+            model.params, *pools, table[0], prompt, np.int32(5))
+        args = (model.params, kp, vp, table,
+                np.array([int(np.argmax(lg)), 0], np.int32),
+                np.array([5, 0], np.int32), np.array([True, False]))
+        step = jax.jit(prog.step)
+        assert step.lower(*args).as_text().startswith("module @jit_step ")
+        lgs = np.asarray(step(*args)[2])
+        zi = np.zeros((s_n,), np.int32)
+        _, _, toks, fins, lgm = jax.jit(prog.step_multi)(
+            *args, np.zeros((s_n,), np.float32), zi,
+            np.ones((s_n,), np.float32), np.zeros((s_n,), np.uint32), zi,
+            np.ones((s_n,), np.int32), np.int32(-1),
+            np.arange(1, dtype=np.int32))
+        assert toks.shape == (1, s_n) and bool(fins[0, 0])
+        assert int(toks[0, 0]) == int(np.argmax(lgs[0]))
+        # a scan and a plain program need not agree in the last bit on
+        # XLA:CPU (test_fused_decode.py::test_greedy_bitwise_identical)
+        assert_logits_close(np.asarray(lgm)[0, 0], lgs[0])
+
+
 class TestOneShotPredictRegression:
     def test_mln_output_bitwise_unchanged_by_decode_engine(self):
         import jax

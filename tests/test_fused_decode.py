@@ -21,6 +21,12 @@ The key contracts tested here:
   - the new DecodeMetrics keys are zero-keyed in every snapshot with the
     features off (HTTP /metrics included) and advance when on; the fused
     executable is covered by the warmup bundle
+
+Tokens are held exactly everywhere.  The three tests here that compare
+ECHOED LOGITS with the re-encode oracle (``test_greedy_bitwise_identical``,
+``test_echo_logits_bitwise``, ``test_interacts_with_prefix_cache``) hold
+them to ``_decode_checks.LOGIT_ATOL`` (2e-6), not to equal bits, whatever
+their names say: the oracle is a program of another row count (see there).
 """
 
 import json
@@ -30,6 +36,7 @@ from types import SimpleNamespace
 
 import numpy as np
 import pytest
+from _decode_checks import assert_greedy_echo, save_bundle_or_skip
 
 from deeplearning4j_tpu.obs import trace as obs_trace
 from deeplearning4j_tpu.parallel.mesh import build_mesh
@@ -99,12 +106,6 @@ def oracle(lm, plain):
     return rows
 
 
-def _bits_match(oracle, prompt, res) -> bool:
-    ref = oracle(prompt, res.tokens)
-    return all(np.array_equal(ref[len(prompt) + j - 1], res.logits[j])
-               for j in range(len(res.tokens)))
-
-
 def _partition_ok(engine) -> bool:
     st = engine._debug_page_state()
     all_ids = st["free"] + st["private"] + st["trie"]
@@ -148,11 +149,14 @@ class TestConstruction:
 
 class TestFusedIdentity:
     def test_greedy_bitwise_identical(self, fused, plain, oracle):
+        """Tokens equal the plain loop's exactly; echoed logits within
+        ``LOGIT_ATOL`` (2e-6) of the re-encode's, NOT bit for bit: a scan
+        and a plain program differ in a logit's last bit on XLA:CPU."""
         for p in PROMPTS:
             ref = plain.generate(p, max_new_tokens=8)
             res = fused.generate(p, max_new_tokens=8, echo_logits=True)
             assert res.tokens == ref.tokens
-            assert _bits_match(oracle, p, res)
+            assert_greedy_echo(p, res, oracle(p, res.tokens))
 
     def test_seeded_sampling_identical(self, fused, plain):
         kw = dict(max_new_tokens=8, temperature=0.8, top_k=5, seed=123)
@@ -382,9 +386,12 @@ class TestChunkedPrefill:
                 == plain.generate(p, max_new_tokens=8).tokens)
 
     def test_echo_logits_bitwise(self, chunk, oracle):
+        """Echoed logits within ``LOGIT_ATOL`` (2e-6) of the re-encode's
+        and each token its row's argmax, NOT bit for bit: a 16-row chunk
+        and the whole window differ in a logit's last bit on XLA:CPU."""
         p = list(range(1, 31))          # 2 chunks: 16 + 14
         res = chunk.generate(p, max_new_tokens=6, echo_logits=True)
-        assert _bits_match(oracle, p, res)
+        assert_greedy_echo(p, res, oracle(p, res.tokens))
 
     def test_counters_advance(self, chunk):
         c0 = chunk.metrics_snapshot()["counters"]
@@ -410,7 +417,8 @@ class TestChunkedPrefill:
     def test_interacts_with_prefix_cache(self, lm, plain, oracle):
         # a prefix hit resumes the chunk walk at matched-pages (24 =
         # 3 pages), which is NOT a chunk boundary (16) — the suffix
-        # chunks must pick up exactly there, bitwise
+        # chunks must pick up exactly there: tokens equal, echoed logits
+        # within LOGIT_ATOL (2e-6) of the re-encode's
         eng = _make(lm, prefill_chunk=CHUNK, prefix_cache=True,
                     max_slots=3)
         try:
@@ -423,7 +431,7 @@ class TestChunkedPrefill:
                 == hits0 + 1
             assert res.tokens == plain.generate(p,
                                                 max_new_tokens=6).tokens
-            assert _bits_match(oracle, p, res)
+            assert_greedy_echo(p, res, oracle(p, res.tokens))
             assert _partition_ok(eng)
         finally:
             eng.shutdown()
@@ -550,7 +558,8 @@ class TestMetricsAndBundle:
     def test_warm_bundle_covers_fused_executable(self, lm, fused,
                                                  tmp_path):
         path = str(tmp_path / "fused.warmup")
-        fused.save_warmup_bundle(path)
+        assert ("step_multi", H) in fused._compiled
+        save_bundle_or_skip(fused, path)
         warmed = DecodeEngine(lm, max_slots=3, page_size=PAGE,
                               default_max_new=8, prompt_buckets=(16, 32),
                               decode_horizon=H).load(warm_bundle=path)
