@@ -129,18 +129,22 @@ def paged_decode_program(*, embed: Callable, pos: Callable, blocks: Callable,
     of a cached row, ``psum_axis`` for ``block_finish``.  ``max_len``
     (default: the position table, whole pages) is the FIXED key length L.
 
-    Each entry point forms its first hidden state, the causal bias at
-    its query positions and its page table, names the writer of its
-    rows and calls ``layers``: the same per-position ops over L keys in
-    all of them.  What that gives against ``reencode`` of the same tokens:
-    equal tokens always; equal logit BITS wherever XLA:CPU computes two
-    row counts alike (tests/test_decode.py::TestBitIdentity holds them),
-    and within 2e-6 where it does not (the four comparisons named in
-    tests/_decode_checks.py; ROADMAP C1)."""
+    Each entry point forms its first hidden state and its page table,
+    names the writer of its rows and how they attend, and calls
+    ``layers``.  ``prefill`` / ``prefill_at`` (a bucket of query rows)
+    attend as ``reencode`` does, ``det_attention`` over all L keys under
+    a causal bias; ``step`` / ``spec_step`` / ``step_multi`` (one to a
+    few rows a slot) through ``ops/paged_attention.py``, which reads only
+    the pages a slot holds and sums in another order.  What that gives
+    against ``reencode`` of the same tokens: equal tokens, and logits
+    within ``tests/_decode_checks.py``'s limit (ROADMAP C1); one entry
+    point against itself (co-batched, retried, fused, ``spec_step``
+    against ``step``): equal bits."""
     from ..ops.kv_cache import (
-        NEG_INF, DecodeProgram, det_attention, gather_layer,
-        write_prefill, write_step, write_tokens,
+        NEG_INF, DecodeProgram, det_attention, write_prefill, write_step,
+        write_tokens,
     )
+    from ..ops.paged_attention import paged_attention, window_attention
     from ..ops.sampling import sample_token
 
     if max_len is None:
@@ -153,28 +157,35 @@ def paged_decode_program(*, embed: Callable, pos: Callable, blocks: Callable,
     L = int(max_len)
     n_heads = heads[0]
 
-    def layers(params, k_pages, v_pages, h, bias, pt, write):
+    def layers(params, k_pages, v_pages, h, write, attend):
         # each layer stores its new rows (``write(pages, layer, kv
-        # [B,H,T,d])``) BEFORE it attends over the gathered window
+        # [B,H,T,d])``) BEFORE ``attend(q, k_pages, v_pages, layer)``
+        # reads them back among the slot's rows
         for i, bp in enumerate(blocks(params)):
             q, k, v = block_kv_project(bp, h, n_heads)
             k_pages = write(k_pages, i, k)
             v_pages = write(v_pages, i, v)
-            k_all = gather_layer(k_pages, i, pt, heads).transpose(0, 2, 1, 3)
-            v_all = gather_layer(v_pages, i, pt, heads).transpose(0, 2, 1, 3)
-            h = block_finish(bp, h, det_attention(q, k_all, v_all, bias),
+            h = block_finish(bp, h, attend(q, k_pages, v_pages, i),
                              psum_axis=psum_axis)
         return k_pages, v_pages, head(params, h)
+
+    def held(pt, lens):
+        # few query rows a slot: over the ``lens`` rows each slot holds
+        # (the new ones counted; 0 for a masked slot)
+        return lambda q, k_pages, v_pages, i: paged_attention(
+            q, k_pages, v_pages, i, pt, lens, heads)
 
     def one_slot(params, k_pages, v_pages, page_table_row, h, bias, n_real,
                  offset):
         # rows at offset..offset+Tb-1 -> the last REAL one's logits; pad
         # rows' K/V are garbage-but-finite, masked until overwritten
         k_pages, v_pages, lgs = layers(
-            params, k_pages, v_pages, h, bias, page_table_row[None],
+            params, k_pages, v_pages, h,
             lambda pages, i, kv: write_prefill(
                 pages, i, page_table_row, kv.transpose(0, 2, 1, 3)[0],
-                offset))
+                offset),
+            lambda q, k_pages, v_pages, i: window_attention(
+                q, k_pages, v_pages, i, page_table_row[None], bias, heads))
         return k_pages, v_pages, lgs[0, n_real - 1]
 
     def prefill(params, k_pages, v_pages, page_table_row, tokens, n_real):
@@ -206,14 +217,12 @@ def paged_decode_program(*, embed: Callable, pos: Callable, blocks: Callable,
         # a masked slot's table row is zeroed, so its write goes to the
         # scratch page and ONE program serves any active subset
         h = (embed(params, tokens) + pos(params)[positions])[:, None]
-        bias = jnp.where(
-            jnp.arange(L, dtype=jnp.int32)[None, :]
-            <= positions[:, None], 0.0, NEG_INF)[:, None, None, :]
         pt = jnp.where(active[:, None], page_table, 0)
         k_pages, v_pages, lgs = layers(
-            params, k_pages, v_pages, h, bias, pt,
+            params, k_pages, v_pages, h,
             lambda pages, i, kv: write_step(pages, i, pt, positions,
-                                            kv[:, :, 0]))
+                                            kv[:, :, 0]),
+            held(pt, jnp.where(active, positions + 1, 0)))
         return k_pages, v_pages, lgs[:, 0]
 
     def spec_step(params, k_pages, v_pages, page_table, tokens, positions,
@@ -224,14 +233,12 @@ def paged_decode_program(*, embed: Callable, pos: Callable, blocks: Callable,
                                                   dtype=jnp.int32)
         h = (embed(params, tokens)
              + pos(params)[jnp.clip(pos_abs, 0, pos_rows - 1)])
-        bias = jnp.where(
-            jnp.arange(L, dtype=jnp.int32)[None, None, :]
-            <= pos_abs[:, :, None], 0.0, NEG_INF)[:, None]
         pt = jnp.where(active[:, None], page_table, 0)
         return layers(
-            params, k_pages, v_pages, h, bias, pt,
+            params, k_pages, v_pages, h,
             lambda pages, i, kv: write_tokens(pages, i, pt, positions,
-                                              kv.transpose(0, 2, 1, 3)))
+                                              kv.transpose(0, 2, 1, 3)),
+            held(pt, jnp.where(active, positions + tokens.shape[1], 0)))
 
     sample_rows = jax.vmap(
         functools.partial(sample_token, vocab_size=vocab_size))
@@ -250,14 +257,12 @@ def paged_decode_program(*, embed: Callable, pos: Callable, blocks: Callable,
             pos_j = positions + j
             h = (embed(params, tok)
                  + pos(params)[jnp.clip(pos_j, 0, pos_rows - 1)])[:, None]
-            bias = jnp.where(
-                jnp.arange(L, dtype=jnp.int32)[None, :]
-                <= pos_j[:, None], 0.0, NEG_INF)[:, None, None, :]
             pt = jnp.where(alive[:, None], page_table, 0)
             k_pages, v_pages, lgs = layers(
-                params, k_pages, v_pages, h, bias, pt,
+                params, k_pages, v_pages, h,
                 lambda pages, i, kv: write_step(pages, i, pt, pos_j,
-                                                kv[:, :, 0]))
+                                                kv[:, :, 0]),
+                held(pt, jnp.where(alive, pos_j + 1, 0)))
             lgs = lgs[:, 0]
             nxt, fin = sample_rows(lgs, temps, top_ks, top_ps, seeds,
                                    steps + j)
@@ -287,7 +292,8 @@ def paged_decode_program(*, embed: Callable, pos: Callable, blocks: Callable,
         n_layers=n_layers, n_heads=n_heads, d_head=heads[1],
         vocab_size=vocab_size, max_len=L, page_size=page_size,
         pages_per_slot=L // page_size,
-        prefill_at=prefill_at, spec_step=spec_step, step_multi=step_multi)
+        prefill_at=prefill_at, spec_step=spec_step, step_multi=step_multi,
+        held_pages=True)
 
 
 @register_layer

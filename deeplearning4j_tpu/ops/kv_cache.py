@@ -30,11 +30,9 @@ slot unconditionally (no dynamic shapes, zero recompiles) while masked
 slots' writes land in scratch and are never read unmasked.
 
 Why a dedicated attention formulation instead of ops/attention.mha:
-the A/B contract (bench ``continuous_batching_ab``) requires per-token
-logits **bit-identical** between the incremental decode path (one query
-row against the cache) and a full re-encode (all rows at once).  On
-XLA, ``X @ W`` against a shared 2D weight is bitwise independent of the
-number of rows — but dot-general attention scores are NOT: lowering
+a served token's logits must not depend on how its row was batched.
+On XLA, ``X @ W`` against a shared 2D weight is bitwise independent of
+the number of rows — but dot-general attention scores are NOT: lowering
 changes with the query count, so row k of a [T,L] score matrix differs
 in final ulps from the same row computed alone.  ``det_attention``
 therefore computes scores and the weighted sum as broadcast-multiply +
@@ -42,11 +40,25 @@ reduce over a trailing axis, whose per-element reduction is independent
 of the leading (query) shape, and always attends over the same fixed
 key length ``L`` (the slot capacity) with additive ``NEG_INF`` masking
 — exp underflows to exact 0.0 for masked keys, and ``0.0 * v`` terms
-cannot perturb the sum.  Both the decode path and the re-encode
-reference use these functions, so bit-identity is structural.  The
-price is an O(T·L·d) materialized product instead of an MXU dot — the
-right trade for correctness-gated decode; the training path keeps the
-flash/mha kernels.
+cannot perturb the sum.  The price is an O(T·L·d) materialized product
+instead of an MXU dot.
+
+Who attends how (since PR 30).  ``prefill`` / ``prefill_at`` (a bucket
+of query rows over one slot's window) and the ``reencode`` reference
+use ``det_attention``, so a prompt's rows agree with the re-encode's
+wherever XLA computes two row counts alike.  ``step`` / ``spec_step``
+/ ``step_multi`` (one to a few rows a slot, every layer of every token)
+do NOT: gathering, relayouting and multiplying every slot's whole
+window for them was half the decode step on the chip (PERF.md section
+6, PR 30).  They call ``ops/paged_attention.py``, one Mosaic kernel a
+layer that reads the pool's rows as stored and only the pages a slot
+holds, in float32, summing in the order of the slot's own pages.  Its
+result depends on the slot's own rows and length only, so a decode
+program compared with itself (co-batched, retried, handed off, fused)
+stays bitwise equal; against ``reencode`` tokens are equal and logits
+agree to rounding (ROADMAP C1; tests/_decode_checks.py holds the
+limit).  An int8 pool, and the Pallas interpreter under ``shard_map``
+on cpu, keep the gathered window there too.
 """
 
 from __future__ import annotations
@@ -96,7 +108,8 @@ class QuantPages(NamedTuple):
     writes admit, and every scale lives in the page-indexed side arrays
     so pages still share/free/scrub as a unit.  Dequantization happens
     in ``gather_layer`` (feeding ``det_scores``/``det_weighted_sum``
-    f32), so attention math is unchanged — int8 trades bits for HBM and
+    f32; an int8 pool keeps the gathered window in the decode step
+    too), so attention math is unchanged — int8 trades bits for HBM and
     is gated behind an accuracy envelope (bench ``decode_speed_ab``).
     """
 
@@ -516,3 +529,8 @@ class DecodeProgram(NamedTuple):
     # (``expert_stats`` int32 [len(parallel.moe.EXPERT_STATS)],
     # ``expert_picks`` [..., expert layers, k])
     aux: bool = False
+    # True: step / spec_step / step_multi attend through
+    # ops/paged_attention.py, which reads only the pages a slot holds
+    # wherever its kernel takes the pool (``kept_path``); False:
+    # they read every slot's whole window
+    held_pages: bool = False

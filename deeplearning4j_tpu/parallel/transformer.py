@@ -395,12 +395,12 @@ class ShardedTransformerLM:
 
         The decode path is a different execution mode from training —
         stateful, one query row per step — but shares the block weights
-        and the block math split (block_kv_project / block_finish), and
-        uses ops/kv_cache.det_attention: the incremental path runs the
-        ops ``reencode`` runs, so tokens are equal and logits equal in
-        bits or, where XLA:CPU computes two row counts differently,
-        within 2e-6 (``paged_decode_program``'s docstring names the
-        tests that hold each).
+        and the block math split (block_kv_project / block_finish).
+        Prefill attends as ``reencode`` does (ops/kv_cache.det_attention);
+        a decode step through ops/paged_attention.py, over the pages a
+        slot holds: tokens are equal and logits agree to rounding
+        (``paged_decode_program``'s docstring; tests/_decode_checks.py
+        holds the limit).
 
         On a multi-device mesh (all devices folded into the ``data``
         axis) the program is TENSOR-PARALLEL: the same entry points,

@@ -604,6 +604,7 @@ class DecodeEngine:
             (self.max_slots, prog.pages_per_slot), np.int32)
         self._free_pages = deque(range(1, self.total_pages))
         self._cache = None
+        self._reads_held_pages = False
         self._compiled: Dict[tuple, Any] = {}
         self._lock = threading.RLock()
         self._stop = threading.Event()
@@ -649,12 +650,17 @@ class DecodeEngine:
         import jax
 
         from ..ops.kv_cache import alloc_pools, pool_nbytes
+        from ..ops.paged_attention import kept_path
         from .warmcache import load_bundle
 
         prog = self.program
         params = self._versions[self._serve_tag]
         s_n, pps, v_n = self.max_slots, prog.pages_per_slot, prog.vocab_size
         kp, vp = alloc_pools(prog, self.total_pages, self._kv_dtype)
+        # what ``kv_pages_read`` counts: the program says it attends over
+        # the pages held, the kernel's own rule whether it takes this pool
+        self._reads_held_pages = (
+            prog.held_pages and kept_path(kp, pps, prog.tp) is None)
         self.metrics.kv_bytes_per_token.set(
             pool_nbytes((kp, vp)) / (self.total_pages * prog.page_size))
         bundle_mesh = self._mesh if getattr(prog, "tp", 1) > 1 else None
@@ -2080,13 +2086,22 @@ class DecodeEngine:
         return inp if inp.group else None
 
     def _set_step_args(self, sp, inp: _StepInputs, step_ms: float,
-                       sample_ms: float) -> None:
-        """The arguments every ``serve/decode_step`` span carries."""
-        tp = int(getattr(self.program, "tp", 1))
+                       sample_ms: float, steps: int = 1) -> None:
+        """The arguments every ``serve/decode_step`` span carries
+        (``steps``: decode steps in the dispatch)."""
+        prog = self.program
+        tp = int(getattr(prog, "tp", 1))
+        if self._reads_held_pages:
+            # each stepped slot's pages up to its new row, every step
+            rows = (inp.pos[inp.group][:, None] + 1
+                    + np.arange(steps, dtype=np.int64))
+            pages_read = int((-(-rows // prog.page_size)).sum())
+        else:
+            pages_read = steps * self.max_slots * prog.pages_per_slot
         sp.set(n_active=len(inp.group), step_ms=round(step_ms, 3),
                sample_ms=round(sample_ms, 3), queued=self.batcher.qsize(),
                pages_reserved=inp.pages_reserved,
-               pages_filled=inp.pages_filled,
+               pages_filled=inp.pages_filled, kv_pages_read=pages_read,
                **({"shards": tp} if tp > 1 else {}))
 
     def _step_fused_once(self) -> bool:
@@ -2177,7 +2192,7 @@ class DecodeEngine:
                     raise ReplicaCrashError(
                         "injected decode-batch crash (test hook)")
                 self._set_step_args(sp, inp, step_ms=(t1 - t0) * 1e3,
-                                    sample_ms=0.0)
+                                    sample_ms=0.0, steps=H)
                 self.metrics.inc("decode_steps")
                 self.metrics.inc("fused_dispatches")
                 self.metrics.step_time.record((t1 - t0) * 1e3)

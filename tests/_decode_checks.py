@@ -1,23 +1,40 @@
-"""What four decode tests hold an engine to on XLA:CPU, in one place.
+"""What the decode tests hold an engine's echoed logits to against
+``reencode``, in one place (ROADMAP C1).
 
 An engine's echoed logits come from programs of other row counts than
 ``reencode``'s (one row a step, a bucket a prefill, the whole window a
-re-encode).  The decode contract (ROADMAP C1) is equal bits, and the
-tests that see equal bits on the driver's host still assert them
-(``np.array_equal``: test_decode.py::TestBitIdentity, test_decode_speed.py
-``_bits_match``).  Four comparisons do not get equal bits from XLA:CPU
-under jax 0.9 there: every token agrees and a logit's last bits differ
-(test_fused_decode.py ``test_greedy_bitwise_identical``,
-``test_echo_logits_bitwise``, ``test_interacts_with_prefix_cache``;
-test_disagg.py ``test_decode_bitwise_vs_sharded_reencode``).  Those four
-hold: equal tokens, and logits within ``LOGIT_ATOL`` of the reference's.
-Widest gap measured over their 49 rows (PR 29, this sandbox's XLA:CPU; a
-row's largest logit 0.19 to 0.37): 1.79e-07, at most 11 units in the
-last place of that largest logit.  The limit is 11 x that gap and 500 x
+re-encode), and since PR 30 a decode step's attention sums over the
+pages a slot holds (``ops/paged_attention.py``) where ``reencode`` sums
+over all ``L`` keys at once: the same float32 mathematics in another
+order, so the last bits differ by construction.  The contract: every
+token equal (a greedy token is the argmax of the reference's row), and
+every echoed logit within ``LOGIT_ATOL`` of the reference's.  Eight
+comparisons hold it: test_decode.py
+``TestBitIdentity::test_decode_logits_match_full_reencode`` and
+``test_mln_output_bitwise_unchanged_by_decode_engine`` (the adapter's
+echo), test_decode_speed.py ``test_hit_is_bitwise_identical_and_counts``
+and ``test_temp0_bitwise_identical_to_plain`` (these four asserted equal
+bits until PR 30, and failed in this sandbox on any tree: XLA:CPU's
+own row-count differences), test_fused_decode.py
+``test_greedy_bitwise_identical``, ``test_echo_logits_bitwise``,
+``test_interacts_with_prefix_cache`` and test_disagg.py
+``test_decode_bitwise_vs_sharded_reencode`` (under this limit since PR
+29), whatever their names say.  test_decode.py::TestOneBuilder holds
+``step_multi`` at a horizon of 1 to ``step`` under the same limit: a scan
+and a plain program.
+
+What stays ``np.array_equal``: a decode program against ITSELF
+(co-batched against solo, crash-retry, hand-off, fused against plain
+tokens, a slot alone against co-batched and ``spec_step``'s rows against
+``step``'s in tests/test_paged_attention.py): the kernel reads a slot's
+own rows in a fixed order.
+
+Widest gap measured over the eight's 100 rows (PR 30, this sandbox's
+XLA:CPU; a row's largest logit 0.19 to 2.85): 7.15e-07, three units in
+the last place of a logit of 2.02 (1.79e-07 over the four of PR 29,
+before the kernel).  The limit stays PR 29's: 2.8 x that gap and 500 x
 under the 1e-3 a changed operation shows
 (benchmarks/configs/gpt2-large.json's bf16 control reads 1e-2).
-test_decode.py::TestOneBuilder (new in PR 29) holds ``step_multi`` at a
-horizon of 1 to ``step`` under the same limit: a scan and a plain program.
 """
 
 import numpy as np

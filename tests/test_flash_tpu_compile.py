@@ -1,4 +1,5 @@
-"""The three flash kernels compiled for a DESCRIBED TPU v5e, without one.
+"""The Mosaic kernels (flash attention's three, the decode step's paged
+attention) compiled for a DESCRIBED TPU v5e, without one.
 
 Interpret mode (tests/test_attention.py) cannot see what Mosaic refuses: a
 slice not aligned to the tiling, more VMEM than a kernel may use.  The
@@ -18,7 +19,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from deeplearning4j_tpu.ops import attention
+from deeplearning4j_tpu.ops import attention, paged_attention
 
 
 @pytest.fixture(scope="module")
@@ -38,6 +39,7 @@ def as_on_tpu(monkeypatch):
     """The code asks ``jax.default_backend()`` and would take its CPU
     branch (the interpreter) here: steer it in the test."""
     monkeypatch.setattr(attention, "interpret", lambda: False)
+    monkeypatch.setattr(paged_attention, "interpret", lambda: False)
 
 
 # B, H, T, S, D, dtype, causal, kmask
@@ -175,11 +177,14 @@ def gpt2_large():
 GPT2_DECODE_CASES = [("step", 4), ("step", 16), ("prefill", 16),
                      ("prefill_at", 16), ("spec_step", 16),
                      ("step_multi", 16)]
+#: temporaries of ``step`` while it gathered every slot's window (PERF.md
+#: section 6, PR 28), by slots: what the kernel may not exceed
+STEP_TEMPORARIES_BEFORE = {4: 127e6, 16: 421e6}
 
 
 @pytest.mark.parametrize("entry,slots", GPT2_DECODE_CASES)
 def test_gpt2_decode_program_compiles_for_v5e_without_a_pool_copy(
-        one_chip, gpt2_large, entry, slots):
+        one_chip, as_on_tpu, gpt2_large, entry, slots):
     """Every entry point of ``ShardedTransformerLM.decode_program`` at
     GPT-2 large's widths (f32, 1,024 positions, pages of 16) compiles
     for one v5e chip at 4 and at 16 slots, fits its memory, gives both
@@ -187,7 +192,13 @@ def test_gpt2_decode_program_compiles_for_v5e_without_a_pool_copy(
     pool holds, with no ``copy`` of a pool-shaped array: a pool stored
     ``[..., 20, 64]`` got the chip's pages-minor layout and was
     transposed in and out of every call, four 758 MB copies a step at 4
-    slots and out of memory at 8 (PERF.md section 6, PR 28)."""
+    slots and out of memory at 8 (PERF.md section 6, PR 28).
+
+    The entry points of a few query rows a slot hold ONE Mosaic call a
+    layer (ops/paged_attention.py), which reads the pool in place right
+    after the layer's scatter wrote into it, and nothing shaped like the
+    gathered window or its ``[.., 20, 64]`` relayout (PERF.md section 6,
+    PR 30); the prefill forms keep the window and hold no Mosaic call."""
     import re
 
     from deeplearning4j_tpu.ops.kv_cache import alloc_pools
@@ -234,3 +245,16 @@ def test_gpt2_decode_program_compiles_for_v5e_without_a_pool_copy(
     # step on the chip): nothing has that shape
     layer_slice = "= f32[%s]" % ",".join(map(str, kp.shape[1:]))
     assert layer_slice not in hlo
+    mosaic_calls = hlo.count('custom_call_target="tpu_custom_call"')
+    if entry in ("prefill", "prefill_at"):
+        assert mosaic_calls == 0
+        return
+    assert mosaic_calls == prog.n_layers == 36
+    window = prog.max_len
+    for gathered in ("f32[%d,%d,20,64]" % (slots, window),
+                     "f32[%d,20,%d,64]" % (slots, window),
+                     "f32[%d,%d,1280]" % (slots, window),
+                     "f32[%d,16,1280]" % (slots * pps)):
+        assert gathered not in hlo, gathered
+    if entry == "step":
+        assert mem.temp_size_in_bytes < STEP_TEMPORARIES_BEFORE[slots] / 4

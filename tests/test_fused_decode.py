@@ -25,7 +25,7 @@ The key contracts tested here:
 Tokens are held exactly everywhere.  The three tests here that compare
 ECHOED LOGITS with the re-encode oracle (``test_greedy_bitwise_identical``,
 ``test_echo_logits_bitwise``, ``test_interacts_with_prefix_cache``) hold
-them to ``_decode_checks.LOGIT_ATOL`` (2e-6), not to equal bits, whatever
+them to ``_decode_checks.LOGIT_ATOL``, not to equal bits, whatever
 their names say: the oracle is a program of another row count (see there).
 """
 
@@ -150,7 +150,7 @@ class TestConstruction:
 class TestFusedIdentity:
     def test_greedy_bitwise_identical(self, fused, plain, oracle):
         """Tokens equal the plain loop's exactly; echoed logits within
-        ``LOGIT_ATOL`` (2e-6) of the re-encode's, NOT bit for bit: a scan
+        ``LOGIT_ATOL`` of the re-encode's, NOT bit for bit: a scan
         and a plain program differ in a logit's last bit on XLA:CPU."""
         for p in PROMPTS:
             ref = plain.generate(p, max_new_tokens=8)
@@ -386,7 +386,7 @@ class TestChunkedPrefill:
                 == plain.generate(p, max_new_tokens=8).tokens)
 
     def test_echo_logits_bitwise(self, chunk, oracle):
-        """Echoed logits within ``LOGIT_ATOL`` (2e-6) of the re-encode's
+        """Echoed logits within ``LOGIT_ATOL`` of the re-encode's
         and each token its row's argmax, NOT bit for bit: a 16-row chunk
         and the whole window differ in a logit's last bit on XLA:CPU."""
         p = list(range(1, 31))          # 2 chunks: 16 + 14
@@ -418,7 +418,7 @@ class TestChunkedPrefill:
         # a prefix hit resumes the chunk walk at matched-pages (24 =
         # 3 pages), which is NOT a chunk boundary (16) — the suffix
         # chunks must pick up exactly there: tokens equal, echoed logits
-        # within LOGIT_ATOL (2e-6) of the re-encode's
+        # within LOGIT_ATOL of the re-encode's
         eng = _make(lm, prefill_chunk=CHUNK, prefix_cache=True,
                     max_slots=3)
         try:

@@ -580,6 +580,9 @@ class TestOneSpanSystemTwoSinks:
             assert a["tokens"] == 1 and a["n_active"] == 1
             assert {"model", "step_ms", "sample_ms", "queued"} <= set(a)
             assert 1 <= a["pages_filled"] <= a["pages_reserved"]
+            # the step's attention read the pages held, the new row's too
+            assert (a["pages_filled"] <= a["kv_pages_read"]
+                    <= a["pages_filled"] + a["n_active"])
             assert "shards" not in a      # one device: not tensor-parallel
         # admit and prefill are siblings in the first turn, in that order
         first = [c["name"] for c in turns[0]["children"]]
