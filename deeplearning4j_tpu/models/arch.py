@@ -7,10 +7,16 @@ latent-attention / sparse-expert block of DeepSeek-V3's modelling code
 (``models/latent_moe.py``): RMSNorm, YaRN-scaled rotary positions on
 part of each head, low-rank query and key/value projections whose
 cached row is a latent, gated SiLU feed-forwards, a leading dense layer
-followed by layers of routed experts with a shared expert beside them.
+followed by layers of routed experts with a shared expert beside them;
+and the grouped-query block with a learned sparse selection
+(``models/sparse_gqa.py``): query heads over fewer key/value heads of a
+free ``head_dim``, RMSNorm on each head's query and key, plain rotary
+over three position streams, an indexer that scores the context and
+keeps ``index_topk`` rows of it, every layer an expert layer behind a
+softmax router.
 
 ``from_config`` reads a configuration file's keys (the published
-``config.json`` names of either family), so a model is a data file and
+``config.json`` names of each family), so a model is a data file and
 not a constructor call.
 """
 
@@ -20,7 +26,11 @@ import dataclasses
 import math
 from typing import Any, Dict
 
-BLOCKS = ("gpt2", "latent_moe")
+BLOCKS = ("gpt2", "latent_moe", "sparse_gqa")
+#: the blocks of the expert family (``models/<block>.py``): served through
+#: one decode-program builder, not trained yet
+EXPERT_BLOCKS = ("latent_moe", "sparse_gqa")
+ROUTERS = ("noaux_tc", "softmax_topk")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -46,6 +56,13 @@ class LMArch:
     rope_beta_slow: float = 1.0
     rope_mscale: float = 1.0
     rope_mscale_all_dim: float = 0.0
+    # -- grouped-query attention over a learned selection (sparse_gqa) ----
+    n_kv_heads: int = 0            # query head a reads KV head a // (H / KV)
+    head_dim: int = 0              # free: n_heads * head_dim need not be d_model
+    mrope_section: tuple = ()      # rotary frequencies per position stream
+    index_n_heads: int = 0         # the indexer's query heads ...
+    index_head_dim: int = 0        # ... over ONE index key a token
+    index_topk: int = 0            # context rows a query keeps
     # -- experts ------------------------------------------------------------
     n_dense_layers: int = 0        # leading layers with a dense feed-forward
     moe_d_ff: int = 0
@@ -55,6 +72,7 @@ class LMArch:
     experts_per_token: int = 0
     n_shared_experts: int = 0
     routed_scaling_factor: float = 1.0
+    router: str = "noaux_tc"       # parallel/moe.py ROUTERS
     init_std: float = 0.02
     param_dtype: str = "float32"
 
@@ -73,27 +91,45 @@ class LMArch:
                 raise ValueError("qk_rope_head_dim must be even")
             if not 0 <= self.n_dense_layers <= self.n_layers:
                 raise ValueError("n_dense_layers must lie in [0, n_layers]")
-            if self.n_moe_layers:
-                if not (0 < self.experts_per_token <= self.n_experts):
-                    raise ValueError("experts_per_token must lie in "
-                                     "(0, n_experts]")
-                if not (0 < self.experts_held
-                        and 0 <= self.first_expert
-                        and self.first_expert + self.experts_held
-                        <= self.n_experts):
-                    raise ValueError(
-                        f"held experts [{self.first_expert}, "
-                        f"{self.first_expert + self.experts_held}) must be "
-                        f"a non-empty range inside [0, {self.n_experts})")
-                if self.moe_d_ff < 1:
-                    raise ValueError("moe_d_ff must be >= 1")
+        if self.block == "sparse_gqa":
+            for k in ("n_kv_heads", "head_dim", "index_n_heads",
+                      "index_head_dim", "index_topk"):
+                if getattr(self, k) < 1:
+                    raise ValueError(f"sparse_gqa needs {k} >= 1")
+            if self.n_heads % self.n_kv_heads:
+                raise ValueError("n_heads must be a multiple of n_kv_heads")
+            if self.head_dim % 2 or self.index_head_dim % 2:
+                raise ValueError("head_dim and index_head_dim must be even")
+            if sum(self.mrope_section) != self.head_dim // 2:
+                raise ValueError(
+                    f"mrope_section {self.mrope_section} must sum to "
+                    f"head_dim / 2 = {self.head_dim // 2}")
+            if self.n_dense_layers:
+                raise ValueError("every sparse_gqa layer is an expert layer")
+        if self.router not in ROUTERS:
+            raise ValueError(f"router must be one of {ROUTERS}, got "
+                             f"{self.router!r}")
+        if self.n_moe_layers:
+            if not (0 < self.experts_per_token <= self.n_experts):
+                raise ValueError("experts_per_token must lie in "
+                                 "(0, n_experts]")
+            if not (0 < self.experts_held
+                    and 0 <= self.first_expert
+                    and self.first_expert + self.experts_held
+                    <= self.n_experts):
+                raise ValueError(
+                    f"held experts [{self.first_expert}, "
+                    f"{self.first_expert + self.experts_held}) must be "
+                    f"a non-empty range inside [0, {self.n_experts})")
+            if self.moe_d_ff < 1:
+                raise ValueError("moe_d_ff must be >= 1")
 
     # -- derived ------------------------------------------------------------
 
     @property
     def n_moe_layers(self) -> int:
         return self.n_layers - self.n_dense_layers \
-            if self.block == "latent_moe" else 0
+            if self.block in EXPERT_BLOCKS else 0
 
     @property
     def latent_width(self) -> int:
@@ -134,7 +170,12 @@ class LMArch:
         (DeepSeek-V3's names, which ``model_type: kimi_k2`` reuses).
         ``n_routed_experts`` there counts the experts HELD (the chip's
         share); the router's width is ``n_routed_experts_published``
-        when the file states one.  ``over`` replaces any field."""
+        when the file states one.  One with ``sa_config`` beside
+        ``num_key_value_heads`` is the grouped-query block over a
+        learned selection (Qwen3-MoE's names plus the indexer's):
+        ``num_experts`` is the router's width, and ``n_routed_experts``,
+        when stated, the count held from ``first_expert`` on.  ``over``
+        replaces any field."""
         if "n_embd" in cfg:
             kw = dict(vocab_size=cfg["vocab_size"], n_layers=cfg["n_layer"],
                       d_model=cfg["n_embd"], n_heads=cfg["n_head"],
@@ -185,10 +226,60 @@ class LMArch:
                     raise ValueError(
                         f"config key {k}={cfg[k]!r} is not expressible by "
                         f"the latent_moe block (supported: {ok})")
+        elif "sa_config" in cfg and "num_key_value_heads" in cfg:
+            sa = cfg["sa_config"]
+            rs = cfg.get("rope_scaling") or {}
+            head_dim = int(cfg.get("head_dim") or cfg["hidden_size"]
+                           // cfg["num_attention_heads"])
+            kw = dict(
+                block="sparse_gqa", vocab_size=cfg["vocab_size"],
+                n_layers=cfg["num_hidden_layers"],
+                d_model=cfg["hidden_size"],
+                n_heads=cfg["num_attention_heads"],
+                n_kv_heads=cfg["num_key_value_heads"], head_dim=head_dim,
+                d_ff=cfg.get("intermediate_size") or 0,
+                max_len=cfg["max_position_embeddings"],
+                rms_eps=cfg.get("rms_norm_eps", 1e-6),
+                rope_theta=cfg.get("rope_theta", 10000.0),
+                mrope_section=tuple(rs.get("mrope_section")
+                                    or (head_dim // 2,)),
+                index_n_heads=sa["indexer_num_heads"],
+                index_head_dim=sa["indexer_head_dim"],
+                index_topk=sa["topk"],
+                moe_d_ff=cfg["moe_intermediate_size"],
+                n_experts=int(cfg["num_experts"]),
+                experts_held=int(cfg.get("n_routed_experts",
+                                         cfg["num_experts"])),
+                first_expert=int(cfg.get("first_expert", 0)),
+                experts_per_token=cfg["num_experts_per_tok"],
+                router="softmax_topk",
+                init_std=cfg.get("initializer_range", 0.02))
+            unsupported = {
+                "attention_bias": (False,), "hidden_act": ("silu",),
+                "norm_topk_prob": (True,), "tie_word_embeddings": (False,),
+                "decoder_sparse_step": (1,), "mlp_only_layers": ([],),
+                "use_sliding_window": (False,), "sliding_window": (None,)}
+            for k, ok in unsupported.items():
+                if k in cfg and cfg[k] not in ok:
+                    raise ValueError(
+                        f"config key {k}={cfg[k]!r} is not expressible by "
+                        f"the sparse_gqa block (supported: {ok})")
+            if sa.get("indexer_num_kv_heads", 1) != 1:
+                raise ValueError(
+                    "config key sa_config.indexer_num_kv_heads="
+                    f"{sa['indexer_num_kv_heads']!r} is not expressible by "
+                    "the sparse_gqa block (supported: one index key a token)")
+            if rs.get("rope_type", rs.get("type", "default")) != "default":
+                raise ValueError(
+                    f"config key rope_scaling={rs!r} is not expressible by "
+                    "the sparse_gqa block (supported: plain rotary, "
+                    "rope_type default)")
         else:
-            raise ValueError("configuration names neither a GPT-2 block "
-                             "(n_embd) nor a latent/expert block "
-                             "(kv_lora_rank)")
+            raise ValueError(
+                "configuration names neither a GPT-2 block (n_embd), a "
+                "latent/expert block (kv_lora_rank) nor a grouped-query "
+                "block over a learned selection (sa_config with "
+                "num_key_value_heads)")
         kw.update(over)
         return cls(**kw)
 

@@ -32,8 +32,13 @@ The feed-forward of the leading ``n_dense_layers`` is a gated SiLU
 ``parallel/moe.moe_forward_held``: a router over all experts, the
 experts held here, the shared expert.
 
-The decode program threads ONE latent pool ``[layers, pages, page,
-latent_lanes]`` through ``prefill`` / ``prefill_at`` / ``step``: a row is
+The decode program is the expert family's one builder
+(``expert_decode_program``, which ``models/sparse_gqa.py`` shares: the
+entry points, the layer loop, the write of the new rows, ``layer_finish``
+and the aux read-back), around the attention a block hands it
+(:class:`CachedAttention`).  This block's threads ONE latent pool
+``[layers, pages, page, latent_lanes]`` through ``prefill`` /
+``prefill_at`` / ``step``: a row is
 the ``latent_width`` cached values in the next multiple of 128 lanes,
 the rest zero.  (At a minor size that is no multiple of its 128-lane
 tile the chip's default layout of the array puts the PAGES minor-most,
@@ -48,7 +53,7 @@ updated in place and never copied.
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -323,7 +328,7 @@ def layer_finish(p, h: Array, att: Array, arch: LMArch,
             None, None
     y, picks, stats = moe_forward_held(
         p, u, first_expert=arch.first_expert, k=arch.experts_per_token,
-        scaling=arch.routed_scaling_factor, valid=valid)
+        scaling=arch.routed_scaling_factor, valid=valid, router=arch.router)
     return h + y, picks, stats
 
 
@@ -373,15 +378,105 @@ def forward(params, tokens: Array, arch: LMArch, with_aux: bool = False):
 
 # -- the decode program -----------------------------------------------------------
 
+class CachedAttention(NamedTuple):
+    """What a block of the expert family hands ``expert_decode_program``:
+    its attention over a paged cache, and nothing else of the program.
+
+    ``pool_rows``: the trailing dims of each pool it threads, one entry
+    a row kind (``ops/kv_cache.DecodeProgram.pool_rows``).
+    ``tables``: a tree of per-position rotary tables ``[L, ...]``; the
+    builder hands ``project`` their rows at the call's positions.
+    ``project(p, h, rope) -> (query side, rows)``: first half of the
+    layer for rows ``h`` [N, d]; ``rows`` is one ``[N, lanes]`` array a
+    pool, what the cache will hold of these positions.
+    ``attend_chunk(p, pools, layer, page_table_row, q, rows, offset,
+    n_real) -> (att [T, .], extra)``: one slot's ``T`` new rows over
+    themselves and the ``offset`` rows the pools hold.
+    ``attend_step(p, pools, layer, table, q, rows, positions, active)
+    -> (att [S, .], extra)``: one new row a slot over its cache.
+    ``extra`` is None or a dict with the keys ``stats`` and ``extras``
+    name: a ``stats`` entry (``(key, names of its counts)``) is summed
+    over the layers (and a fused horizon's steps) and read onto the
+    engine's spans and counters under those names, an ``extras`` entry
+    is stacked on a layer axis before its last."""
+    pool_rows: tuple
+    tables: Any
+    project: Callable
+    attend_chunk: Callable
+    attend_step: Callable
+    d_head: int
+    stats: tuple = ()
+    extras: tuple = ()
+    # what the step reads of a slot's window
+    # (``ops/kv_cache.DecodeProgram.held_pages``)
+    held_pages: Optional[bool] = False
+
+
+def mla_attention(arch: LMArch, page_size: int, pps: int) -> CachedAttention:
+    """Latent attention over ONE latent pool ``[layers, pages, page,
+    latent_lanes]``: chunks by the expanded path a block of pages at a
+    time, the step by the absorbed path over the gathered window."""
+    L = pps * page_size
+
+    def gather(pool, layer, table):
+        # the layer is an index beside the pages: ``pool[layer][table]``
+        # makes XLA copy the layer's whole slice of the pool first
+        g = pool[jnp.full_like(table, layer), table]  # [..., pps, page, w]
+        return g.reshape(g.shape[:-3] + (L, g.shape[-1]))
+
+    # a chunk reads the rows before it a block of pages at a time
+    block_pages = next(d for d in range(max(1, pps // 8), 0, -1)
+                       if pps % d == 0)          # whole blocks tile a slot
+    block_rows = block_pages * page_size
+
+    def project(p, h, rope):
+        qn, qp, row = mla_project(p, h, *rope, arch)
+        return (qn, qp), (row,)
+
+    def attend_chunk(p, pools, layer, page_table_row, q, rows, offset,
+                     n_real):
+        (pool,), (row,) = pools, rows
+
+        def read_old(j):
+            pages = jax.lax.dynamic_slice(page_table_row,
+                                          (j * block_pages,), (block_pages,))
+            g = pool[jnp.full_like(pages, layer), pages]
+            return g.reshape(block_rows, g.shape[-1])
+        return attend_expanded(p, *q, row, arch, read_old, offset,
+                               block_rows), None
+
+    def attend_step(p, pools, layer, table, q, rows, positions, active):
+        return attend_absorbed(p, *q, rows[0], gather(pools[0], layer, table),
+                               positions, arch), None
+
+    return CachedAttention(
+        pool_rows=((arch.latent_lanes,),), tables=rope_tables(arch, L),
+        project=project, attend_chunk=attend_chunk, attend_step=attend_step,
+        d_head=arch.qk_head_dim)
+
+
 def decode_program(arch: LMArch, page_size: int, max_len: Optional[int]):
-    """``ops/kv_cache.DecodeProgram`` over one latent pool.
+    """``ops/kv_cache.DecodeProgram`` over one latent pool."""
+    return expert_decode_program(arch, page_size, max_len, mla_attention)
+
+
+def expert_decode_program(arch: LMArch, page_size: int,
+                          max_len: Optional[int], attention):
+    """The ONE decode program of the expert family: the entry points,
+    the loop over the layers, the write of the new rows, ``layer_finish``
+    and what is read back, around the attention ``attention(arch,
+    page_size, pages_per_slot)`` gives (a :class:`CachedAttention`).
 
     ``prefill`` / ``prefill_at`` / ``step`` keep the signature the
     engine calls (``params, k_pages, v_pages, ...``): ``k_pages`` is the
-    latent pool ``[layers, pages, page, latent_lanes]`` in the weights'
-    type, ``v_pages`` an empty tree (there is no second pool).  Each
-    returns one value more than the engine's contract names, the aux
-    tree of ``_join_aux``; ``step_multi`` fuses steps and sampling.
+    attention's first pool ``[layers, pages, page, lanes]`` in the
+    weights' type, ``v_pages`` the tuple of its others (empty where
+    there is one pool).  A call only READS the pools while its layers
+    run (the rows of earlier positions) and attends to its own new rows
+    directly; all layers' new rows are written at the end, one scatter a
+    pool, so the donated pools are updated in place and never copied.
+    Each returns one value more than the engine's contract names, the
+    aux tree of ``_join_aux``; ``step_multi`` fuses steps and sampling.
     """
     from ..ops.kv_cache import SCRATCH_PAGE, DecodeProgram
 
@@ -393,58 +488,53 @@ def decode_program(arch: LMArch, page_size: int, max_len: Optional[int]):
             f"{page_size} and <= the rotary table ({arch.max_len})")
     L = int(max_len)
     pps = L // page_size
-    cos_t, sin_t = rope_tables(arch, L)
+    att = attention(arch, page_size, pps)
     n_layers = arch.n_layers
 
-    def gather(pool, layer, table):
-        # the layer is an index beside the pages: ``pool[layer][table]``
-        # makes XLA copy the layer's whole slice of the pool first
-        g = pool[jnp.full_like(table, layer), table]  # [..., pps, page, w]
-        return g.reshape(g.shape[:-3] + (L, g.shape[-1]))
+    def write_rows(pools, page_idx, in_page, rows_all):
+        """Every layer's new rows into the pools, after the last read of
+        them, by ONE scatter of whole rows a pool with the layer an
+        index like the page (a slice over the layers makes XLA transpose
+        the whole pool and back; a scatter a layer makes it split the
+        pool into its layers and copy each)."""
+        out = []
+        for j, pool in enumerate(pools):
+            rows = jnp.stack([r[j] for r in rows_all]).astype(pool.dtype)
+            layer = jnp.arange(rows.shape[0], dtype=jnp.int32)[:, None]
+            out.append(pool.at[layer, page_idx[None, :],
+                               in_page[None, :]].set(rows))
+        return out[0], tuple(out[1:])
 
-    def write_rows(pool, page_idx, in_page, rows_all):
-        """Every layer's new rows into the pool, after the last read of
-        it, by ONE scatter of whole rows with the layer an index like
-        the page (a slice over the layers makes XLA transpose the whole
-        pool and back; a scatter a layer makes it split the pool into
-        its layers and copy each)."""
-        rows = jnp.stack(rows_all).astype(pool.dtype)     # [layers, N, w]
-        layer = jnp.arange(rows.shape[0], dtype=jnp.int32)[:, None]
-        return pool.at[layer, page_idx[None, :], in_page[None, :]].set(rows)
+    def join(picks, stats, extras, lead):
+        aux = _join_aux(picks, stats, arch, lead)
+        for name, _ in att.stats:
+            per_layer = [e[name] for e in extras]
+            aux[name] = sum(per_layer[1:], per_layer[0])
+        for name in att.extras:
+            aux[name] = jnp.stack([e[name] for e in extras], axis=-2)
+        return aux
 
-    # a chunk reads the rows before it a block of pages at a time
-    block_pages = next(d for d in range(max(1, pps // 8), 0, -1)
-                       if pps % d == 0)          # whole blocks tile a slot
-    block_rows = block_pages * page_size
-
-    def attend_chunk(p, pool, layer, page_table_row, qn, qp, rows, offset):
-        def read_old(j):
-            pages = jax.lax.dynamic_slice(page_table_row,
-                                          (j * block_pages,), (block_pages,))
-            g = pool[jnp.full_like(pages, layer), pages]
-            return g.reshape(block_rows, g.shape[-1])
-        return attend_expanded(p, qn, qp, rows, arch, read_old, offset,
-                               block_rows)
-
-    def prefill_at(params, pool, none, page_table_row, tokens, n_real,
+    def prefill_at(params, first, rest, page_table_row, tokens, n_real,
                    offset):
         """One slot's rows at positions offset..offset+Tb-1 (the first
-        ``n_real`` real) attending over the ``offset`` rows the pool
-        already holds and over themselves; their cache rows are written
-        by one scatter; the last real position's logits."""
+        ``n_real`` real) attending over the ``offset`` rows the pools
+        already hold and over themselves; their cache rows are written
+        by one scatter a pool; the last real position's logits."""
+        pools = (first,) + tuple(rest)
         tb = tokens.shape[0]
         pos = offset + jnp.arange(tb, dtype=jnp.int32)
         at = jnp.clip(pos, 0, L - 1)
-        cos, sin = cos_t[at], sin_t[at]
+        rope = jax.tree_util.tree_map(lambda t: t[at], att.tables)
         valid = jnp.arange(tb) < n_real
         h = _embed(params, tokens)
-        rows_all, picks, stats = [], [], []
+        rows_all, picks, stats, extras = [], [], [], []
         for i, p in enumerate(params["blocks"]):
-            qn, qp, rows = mla_project(p, h, cos, sin, arch)
-            att = attend_chunk(p, pool, i, page_table_row, qn, qp, rows,
-                               offset)
-            h, pk, st = layer_finish(p, h, att, arch, valid)
+            q, rows = att.project(p, h, rope)
+            a, extra = att.attend_chunk(p, pools, i, page_table_row, q, rows,
+                                        offset, n_real)
+            h, pk, st = layer_finish(p, h, a, arch, valid)
             rows_all.append(rows)
+            extras.append(extra)
             if pk is not None:
                 picks.append(pk[n_real - 1])
                 stats.append(st)
@@ -452,74 +542,88 @@ def decode_program(arch: LMArch, page_size: int, max_len: Optional[int]):
         page_idx = jnp.where(idx < pps,
                              page_table_row[jnp.clip(idx, 0, pps - 1)],
                              SCRATCH_PAGE)
-        pool = write_rows(pool, page_idx, pos % page_size, rows_all)
-        return pool, none, _logits(params, h[n_real - 1], arch), \
-            _join_aux(picks, stats, arch, ())
+        first, rest = write_rows(pools, page_idx, pos % page_size, rows_all)
+        return first, rest, _logits(params, h[n_real - 1], arch), \
+            join(picks, stats, extras, ())
 
-    def prefill(params, pool, none, page_table_row, tokens, n_real):
-        return prefill_at(params, pool, none, page_table_row, tokens,
+    def prefill(params, first, rest, page_table_row, tokens, n_real):
+        return prefill_at(params, first, rest, page_table_row, tokens,
                           n_real, jnp.int32(0))
 
-    def step(params, pool, none, page_table, tokens, positions, active):
-        """One token for every slot by the absorbed path.  Idle slots'
-        rows go to the scratch page and their picks are not counted."""
+    def step(params, first, rest, page_table, tokens, positions, active):
+        """One token for every slot.  Idle slots' rows go to the scratch
+        page and their picks are not counted."""
+        pools = (first,) + tuple(rest)
         s_n = tokens.shape[0]
         at = jnp.clip(positions, 0, L - 1)
-        cos, sin = cos_t[at], sin_t[at]
+        rope = jax.tree_util.tree_map(lambda t: t[at], att.tables)
         table = jnp.where(active[:, None], page_table, SCRATCH_PAGE)
         h = _embed(params, tokens)
-        rows_all, picks, stats = [], [], []
+        rows_all, picks, stats, extras = [], [], [], []
         for i, p in enumerate(params["blocks"]):
-            qn, qp, row = mla_project(p, h, cos, sin, arch)
-            att = attend_absorbed(p, qn, qp, row, gather(pool, i, table),
-                                  positions, arch)
-            h, pk, st = layer_finish(p, h, att, arch, active)
-            rows_all.append(row)
+            q, rows = att.project(p, h, rope)
+            a, extra = att.attend_step(p, pools, i, table, q, rows,
+                                       positions, active)
+            h, pk, st = layer_finish(p, h, a, arch, active)
+            rows_all.append(rows)
+            extras.append(extra)
             if pk is not None:
                 picks.append(pk)
                 stats.append(st)
         page_idx = table[jnp.arange(s_n), at // page_size]
-        pool = write_rows(pool, page_idx, at % page_size, rows_all)
-        return pool, none, _logits(params, h, arch), \
-            _join_aux(picks, stats, arch, (s_n,))
+        first, rest = write_rows(pools, page_idx, at % page_size, rows_all)
+        return first, rest, _logits(params, h, arch), \
+            join(picks, stats, extras, (s_n,))
 
-    def step_multi(params, pool, none, page_table, tokens, positions, active,
-                   temps, top_ks, top_ps, seeds, steps, budgets, eos_id,
-                   horizon):
+    def step_multi(params, first, rest, page_table, tokens, positions,
+                   active, temps, top_ks, top_ps, seeds, steps, budgets,
+                   eos_id, horizon):
         """``horizon.shape[0]`` decode steps in one program: a scan of
         ``step`` with the sampling on the device (the engine's own
         ``ops.sampling.sample_token``, keyed ``fold_in(seed, steps + j)``
         as its per-step sampler is, so fusion changes no token).  A slot
         that stops (EOS, budget, a non-finite row) leaves ``alive``: its
         later rows go to the scratch page and its picks are not
-        counted.  The counts are summed over the steps; the chosen
-        experts come back for every step."""
+        counted.  The counts are summed over the steps; what was chosen
+        comes back for every step."""
         from ..ops.sampling import sample_token
 
+        summed = ("expert_stats",) + tuple(n for n, _ in att.stats)
+        names = summed + ("expert_picks",) + att.extras
+
         def body(carry, j):
-            pool, tok, alive = carry
-            pool, _, lgs, aux = step(params, pool, none, page_table, tok,
-                                     positions + j, alive)
+            first, rest, tok, alive = carry
+            first, rest, lgs, aux = step(params, first, rest, page_table,
+                                         tok, positions + j, alive)
             nxt, fin = jax.vmap(
                 lambda l, t, k, p, sd, st: sample_token(
                     l, t, k, p, sd, st, arch.vocab_size)
             )(lgs, temps, top_ks, top_ps, seeds, steps + j)
             alive = alive & fin & (nxt != eos_id) & (j + 1 < budgets)
-            return (pool, nxt, alive), (nxt, fin, lgs, aux["expert_stats"],
-                                        aux["expert_picks"])
+            return (first, rest, nxt, alive), (
+                nxt, fin, lgs, *(aux[n] for n in names))
 
-        (pool, _, _), (toks, fins, lgs, stats, picks) = jax.lax.scan(
-            body, (pool, tokens, active), horizon)
-        return pool, none, toks, fins, lgs, {
-            "expert_stats": jnp.sum(stats, axis=0), "expert_picks": picks}
+        (first, rest, _, _), (toks, fins, lgs, *outs) = jax.lax.scan(
+            body, (first, rest, tokens, active), horizon)
+        return first, rest, toks, fins, lgs, {
+            n: jnp.sum(o, axis=0) if n in summed else o
+            for n, o in zip(names, outs)}
 
     def reencode(params, tokens):
-        return forward(params, tokens, arch)
+        return family_module(arch).forward(params, tokens, arch)
 
     return DecodeProgram(
         prefill=prefill, step=step, reencode=reencode, n_layers=n_layers,
-        n_heads=arch.n_heads, d_head=arch.qk_head_dim,
+        n_heads=arch.n_heads, d_head=att.d_head,
         vocab_size=arch.vocab_size, max_len=L, page_size=page_size,
         pages_per_slot=pps, prefill_at=prefill_at, step_multi=step_multi,
-        pool_row=(arch.latent_lanes,), pool_dtype=jnp.dtype(arch.param_dtype),
-        pool_sides=1, aux=True)
+        pool_rows=att.pool_rows, pool_dtype=jnp.dtype(arch.param_dtype),
+        aux=True, aux_stats=(("expert_stats", EXPERT_STATS),) + att.stats,
+        held_pages=att.held_pages)
+
+
+def family_module(arch: LMArch):
+    """The module of ``arch.block`` (``init_params``, ``forward``,
+    ``decode_program``)."""
+    import importlib
+    return importlib.import_module(f"{__package__}.{arch.block}")

@@ -155,20 +155,23 @@ def alloc_cache(n_layers: int, n_pages: int, page_size: int, n_heads: int,
 def alloc_pools(prog: "DecodeProgram", n_pages: int,
                 kv_dtype: Optional[str] = None) -> KVCache:
     """The pools ``prog`` threads, zero-filled: shape and dtype are the
-    program's own (``pool_row`` / ``pool_dtype`` / ``pool_sides``)."""
-    if prog.pool_row is None and prog.pool_sides == 2:
+    program's own (``pool_rows`` / ``pool_dtype``).  A program that names
+    its rows gets one pool a row kind: the first where the engine's
+    contract has ``k_pages``, the others as a tuple where it has
+    ``v_pages`` (empty for a single latent pool), so every pool lives,
+    is donated, scrubbed and reset with the same pages."""
+    if prog.pool_rows is None:
         return alloc_cache(prog.n_layers, n_pages, prog.page_size,
                            prog.n_heads, prog.d_head,
                            dtype=prog.pool_dtype or jnp.float32,
                            kv_dtype=kv_dtype)
     if kv_dtype in ("int8", "i8"):
         raise ValueError("int8 KV is not carried by this decode program")
-    row = prog.pool_row or (
-        prog.n_heads * head_lanes(prog.n_heads, prog.d_head),)
-    shape = (prog.n_layers, n_pages, prog.page_size) + tuple(row)
     dtype = prog.pool_dtype or jnp.float32
-    return KVCache(*(jnp.zeros(shape, dtype) if i < prog.pool_sides else ()
-                     for i in range(2)))
+    first, *rest = (jnp.zeros((prog.n_layers, n_pages, prog.page_size)
+                              + tuple(row), dtype)
+                    for row in prog.pool_rows)
+    return KVCache(first, tuple(rest))
 
 
 def pool_nbytes(cache) -> int:
@@ -515,22 +518,27 @@ class DecodeProgram(NamedTuple):
     # fns are shard_map'd over the mesh's "data" axis (heads + page pool
     # sharded, logits replicated) — see parallel/transformer.py
     tp: int = 1
-    # what one cached row is, for a program whose row is not n_heads
-    # heads of d_head: the trailing dims of a pool after [layers, pages,
-    # page] (None = (n_heads * head_lanes(n_heads, d_head),)) and the
-    # pool's dtype (None = float32).  A program with ``pool_sides == 1``
-    # keeps ONE pool (a latent cache: models/latent_moe.py) and the
-    # engine threads an empty tree where the second would go.
-    pool_row: Optional[tuple] = None
+    # the row kinds of a program whose cache is not a K and a V pool of
+    # n_heads heads of d_head (None): one entry a pool, the trailing dims
+    # after [layers, pages, page]; and the pools' dtype (None = float32).
+    # The engine threads the first pool as ``k_pages`` and a tuple of
+    # the others as ``v_pages``: one latent pool and an empty tuple
+    # (models/latent_moe.py), or K rows, then V rows and index rows
+    # (models/sparse_gqa.py).
+    pool_rows: Optional[tuple] = None
     pool_dtype: Any = None
-    pool_sides: int = 2
     # True: prefill / prefill_at / step return a fourth value, a dict of
     # small arrays the engine reads back with the tokens
-    # (``expert_stats`` int32 [len(parallel.moe.EXPERT_STATS)],
-    # ``expert_picks`` [..., expert layers, k])
+    # (``expert_picks`` [..., expert layers, k], and the int32 vectors
+    # of counts that ``aux_stats`` names: ``(key, names)`` pairs, each
+    # count read onto the step's or chunk's span and the engine's
+    # counter of its name)
     aux: bool = False
-    # True: step / spec_step / step_multi attend through
-    # ops/paged_attention.py, which reads only the pages a slot holds
-    # wherever its kernel takes the pool (``kept_path``); False:
-    # they read every slot's whole window
-    held_pages: bool = False
+    aux_stats: tuple = ()
+    # what ``kv_pages_read`` says of step / spec_step / step_multi.
+    # True: they attend through ops/paged_attention.py, which reads only
+    # the pages a slot holds wherever its kernel takes the pool
+    # (``kept_path``); False: they read every slot's whole window; None:
+    # they read rows, not pages, and count them in ``aux_stats``
+    # (models/sparse_gqa.py): the engine reports no ``kv_pages_read``
+    held_pages: Optional[bool] = False

@@ -181,11 +181,12 @@ EXPERT_STATS = ("expert_picks", "expert_picks_held", "expert_load_max",
 def init_held_experts(rng: Array, d_model: int, d_ff: int, n_experts: int,
                       experts_held: int, n_shared: int = 1,
                       std: float = 0.02, bias_std: float = 0.1,
-                      dtype=jnp.float32) -> Dict[str, Array]:
-    """Router over all ``n_experts`` (weights and the correction bias,
-    drawn non-zero so the choice-only path is worked), gated-SiLU
-    experts for the ``experts_held`` that live here, and the shared
-    expert (``n_shared`` experts' width in one)."""
+                      dtype=jnp.float32,
+                      router: str = "noaux_tc") -> Dict[str, Array]:
+    """Router over all ``n_experts`` (weights and, for ``noaux_tc``, the
+    correction bias, drawn non-zero so the choice-only path is worked),
+    gated-SiLU experts for the ``experts_held`` that live here, and the
+    shared expert (``n_shared`` experts' width in one)."""
     kg, kb, k1, k2, k3, k4, k5, k6 = jax.random.split(rng, 8)
 
     def normal(key, shape, s=std):
@@ -199,6 +200,8 @@ def init_held_experts(rng: Array, d_model: int, d_ff: int, n_experts: int,
         "e_up": normal(k2, (experts_held, d_model, d_ff)),
         "e_down": normal(k3, (experts_held, d_ff, d_model)),
     }
+    if router != "noaux_tc":
+        del p["router_b"]
     if n_shared:
         f = n_shared * d_ff
         p.update(s_gate=normal(k4, (d_model, f)), s_up=normal(k5, (d_model, f)),
@@ -222,6 +225,19 @@ def route_noaux_tc(x: Array, router_w: Array, router_b: Array, k: int,
     return idx.astype(jnp.int32), w
 
 
+def route_softmax_topk(x: Array, router_w: Array, k: int
+                       ) -> Tuple[Array, Array]:
+    """The softmax gate of the Qwen3-MoE family with ``norm_topk_prob``,
+    in float32: probabilities are ``softmax(x W)`` over all experts; the
+    ``k`` experts are its top k; their weights are those probabilities
+    divided by their sum.  No bias, no scaling.
+    ``x`` [N, d] -> (expert ids [N, k] int32, weights [N, k] f32)."""
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    w, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    return idx.astype(jnp.int32), w / jnp.sum(w, axis=-1, keepdims=True)
+
+
 def gated_silu(x: Array, w_gate: Array, w_up: Array, w_down: Array) -> Array:
     """``W_down(silu(W_gate x) * (W_up x))`` with the products' operands
     in the weights' type and float32 accumulation; float32 out."""
@@ -233,12 +249,15 @@ def gated_silu(x: Array, w_gate: Array, w_up: Array, w_down: Array) -> Array:
 
 
 def moe_forward_held(p: Dict[str, Array], x: Array, *, first_expert: int,
-                     k: int, scaling: float, valid: Optional[Array] = None,
-                     shared: bool = True):
+                     k: int, scaling: float = 1.0,
+                     valid: Optional[Array] = None, shared: bool = True,
+                     router: str = "noaux_tc"):
     """The part of a routed-expert layer that THIS chip gives.
 
     ``x`` [N, d].  Routes every row over all experts (``p["router_w"]``
-    is [d, n_experts]), keeps the picks that fall on the experts held
+    is [d, n_experts]) by ``router``: ``"noaux_tc"`` (sigmoid scores, the
+    correction bias ``p["router_b"]``, ``scaling``) or ``"softmax_topk"``
+    (neither); everything after the router is one code path.  Keeps the picks that fall on the experts held
     here (``p["e_gate"]`` is [held, d, f]; they are experts
     ``first_expert .. first_expert + held - 1``), sorts those picks by
     expert and runs ONE grouped product per projection over them
@@ -257,7 +276,10 @@ def moe_forward_held(p: Dict[str, Array], x: Array, *, first_expert: int,
     """
     n, d = x.shape
     held = p["e_gate"].shape[0]
-    idx, w = route_noaux_tc(x, p["router_w"], p["router_b"], k, scaling)
+    if router == "softmax_topk":
+        idx, w = route_softmax_topk(x, p["router_w"], k)
+    else:
+        idx, w = route_noaux_tc(x, p["router_w"], p["router_b"], k, scaling)
     if valid is None:
         valid = jnp.ones((n,), bool)
     local = idx - first_expert

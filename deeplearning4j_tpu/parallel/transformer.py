@@ -29,7 +29,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, set_mesh
 
-from ..models.arch import LMArch
+from ..models.arch import EXPERT_BLOCKS, LMArch
 from ..models.transformer import block_apply, block_params
 from ..nn.updaters import Adam
 from ..obs import trace as obs_trace
@@ -65,8 +65,9 @@ class ShardedTransformerLM:
     (e.g. ``LMArch.from_config(json.load(f))``) or the GPT-2 sizes by
     name as before, which build the same description.  ``params=`` hands
     in a ready tree (made elsewhere, e.g. on the device from a seed) in
-    place of the constructor's own draw.  A ``latent_moe`` architecture
-    (models/latent_moe.py) is SERVED (``decode_program``, ``logits``);
+    place of the constructor's own draw.  An architecture of the expert
+    family (``models/arch.EXPERT_BLOCKS``: models/latent_moe.py,
+    models/sparse_gqa.py) is SERVED (``decode_program``, ``logits``);
     its train step does not exist yet and ``fit_batch`` says so.
     """
 
@@ -148,8 +149,8 @@ class ShardedTransformerLM:
         self._jit_multi_step = None
         self._jit_logits = None
         self.token_sharding = NamedSharding(mesh, P("data", "seq"))
-        if arch.block == "latent_moe":
-            self._init_latent_moe(seed, params)
+        if arch.block in EXPERT_BLOCKS:
+            self._init_expert_family(seed, params)
             return
 
         rng = jax.random.PRNGKey(seed)
@@ -178,35 +179,35 @@ class ShardedTransformerLM:
         opt = self.updater.init_state(params)
         self.opt_state = jax.device_put(opt, self._opt_shardings(opt, shardings))
 
-    def _init_latent_moe(self, seed: int, params) -> None:
-        """A latent/expert architecture: replicated parameters in the
-        architecture's own type (bf16 weights are SERVED as bf16), no
+    def _init_expert_family(self, seed: int, params) -> None:
+        """An architecture of the expert family: replicated parameters in
+        the architecture's own type (bf16 weights are SERVED as bf16), no
         optimizer state — nothing here trains it yet."""
-        from ..models import latent_moe
+        from ..models.latent_moe import family_module
 
         arch, mesh = self.arch, self.mesh
         if any(mesh.shape.get(a, 1) > 1 for a in ("model", "seq", "pipe")):
             raise NotImplementedError(
-                "a latent_moe architecture is not sharded over model / seq "
+                f"a {arch.block} architecture is not sharded over model / seq "
                 f"/ pipe yet (got {dict(mesh.shape)}): the expert exchange "
                 "across chips is ROADMAP M4's open half")
         if self.compute_dtype is not None:
             raise NotImplementedError(
-                "compute_dtype does not apply to a latent_moe architecture: "
+                f"compute_dtype does not apply to a {arch.block} architecture: "
                 "state param_dtype in its LMArch")
         if params is None:
-            params = latent_moe.init_params(
+            params = family_module(arch).init_params(
                 jax.random.PRNGKey(seed), arch, jnp.dtype(arch.param_dtype))
         self.params = jax.device_put(params, NamedSharding(mesh, P()))
         self.opt_state = None
         self.block_specs = None
 
     def _refuse_training(self) -> None:
-        if self.arch.block == "latent_moe":
+        if self.arch.block in EXPERT_BLOCKS:
             raise NotImplementedError(
-                "training a latent_moe architecture is not implemented: "
-                "the train step of latent attention and of routed experts "
-                "(ROADMAP M4, M5) is open; this LM is served "
+                f"training a {self.arch.block} architecture is not "
+                "implemented: the train step of its attention and of routed "
+                "experts (ROADMAP M4, M5) is open; this LM is served "
                 "(decode_program, logits)")
 
     def _opt_shardings(self, opt, param_shardings):
@@ -222,9 +223,9 @@ class ShardedTransformerLM:
     # -- forward -----------------------------------------------------------
 
     def _forward(self, params, tokens):
-        if self.arch.block == "latent_moe":
-            from ..models import latent_moe
-            return latent_moe.forward(params, tokens, self.arch)
+        if self.arch.block in EXPERT_BLOCKS:
+            from ..models.latent_moe import family_module
+            return family_module(self.arch).forward(params, tokens, self.arch)
         cd = self.compute_dtype
         embed = params["embed"] if cd is None else params["embed"].astype(cd)
         pos = params["pos"] if cd is None else params["pos"].astype(cd)
@@ -418,14 +419,15 @@ class ShardedTransformerLM:
         quantization scale is an amax over ALL heads, which a head
         shard cannot compute locally (the engine enforces this).
         """
-        if self.arch.block == "latent_moe":
-            from ..models import latent_moe
+        if self.arch.block in EXPERT_BLOCKS:
+            from ..models.latent_moe import family_module
             if int(np.prod(list(self.mesh.shape.values()))) != 1:
                 raise NotImplementedError(
                     "tensor-parallel decode is not carried by the "
-                    "latent_moe decode program: serve it on a one-device "
-                    "mesh")
-            return latent_moe.decode_program(self.arch, page_size, max_len)
+                    f"{self.arch.block} decode program: serve it on a "
+                    "one-device mesh")
+            return family_module(self.arch).decode_program(self.arch, page_size,
+                                                     max_len)
         from ..models.transformer import paged_decode_program
         from ..nn.layers.normalization import layer_norm
         from ..ops.kv_cache import QuantPages

@@ -137,7 +137,8 @@ from typing import Any, Dict, List, Optional, Sequence
 import numpy as np
 
 from ..obs import trace as obs_trace
-from .batcher import ContinuousBatcher, pow2_buckets
+from .batcher import (ContinuousBatcher, DeadlineExceededError,
+                      pow2_buckets)
 from .engine import (ModelNotLoadedError, PoisonInputError,
                      ReplicaCrashError, _fail_safe, _set_safe)
 from .metrics import DecodeMetrics
@@ -171,6 +172,11 @@ class GenerationResult:
     # router chose at the position each token was taken from, where the
     # decode program has routed experts (models/latent_moe.py); else None
     expert_picks: Optional[np.ndarray] = None
+    # [n_tokens, layers, k] int32: the cached positions (-1 = none) each
+    # layer's attention selected at the position each token was taken
+    # from, for a request that asked ``echo_logits`` of a program with a
+    # learned sparse selection (models/sparse_gqa.py); else None
+    attn_rows: Optional[np.ndarray] = None
 
 
 @dataclass(frozen=True)
@@ -230,7 +236,8 @@ class _Slot:
     __slots__ = ("req", "spec", "tag", "page_ids", "n_prompt", "pos",
                  "last_token", "tokens", "n_out", "max_new", "deadline",
                  "t_first", "t_last", "logits", "shared_nodes", "n_matched",
-                 "n_prefilled", "picks", "logit_buf")
+                 "n_prefilled", "picks", "logit_buf", "rows_buf",
+                 "rows_next")
 
     def __init__(self, req, tag: str, page_ids: List[int], max_new: int):
         self.req = req
@@ -249,6 +256,12 @@ class _Slot:
         self.logits: Optional[List[np.ndarray]] = \
             [] if self.spec.echo_logits else None
         self.logit_buf: Optional[np.ndarray] = None   # the rows' storage
+        # the positions each layer's attention selected, a row a token
+        # beside ``logit_buf`` (a program that reports ``attn_rows``)
+        self.rows_buf: Optional[np.ndarray] = None
+        # the row of the token about to be recorded, set by the caller
+        # as ``picks`` is: ``_record_token`` keeps its five arguments
+        self.rows_next: Optional[np.ndarray] = None
         # the experts each token's layers chose, where the program says
         self.picks: List[np.ndarray] = []
         self.shared_nodes: List["_PrefixNode"] = []
@@ -265,7 +278,7 @@ class _Chunk:
 
     __slots__ = ("i", "slot", "offset", "take", "bucket", "padded", "last",
                  "lg", "aux", "tok", "fin", "picks_h", "tok_h", "fin_h",
-                 "lg_h", "t1")
+                 "lg_h", "rows_h", "t1")
 
 
 class _StepInputs:
@@ -503,9 +516,11 @@ class DecodeEngine:
                 "int8 KV + tensor-parallel decode is unsupported: the "
                 "per-row quantization scale is an amax over ALL heads "
                 "and cannot be computed inside one head shard")
-        if getattr(prog, "pool_sides", 2) != 2 or getattr(prog, "aux", False):
-            # a program with its own pool layout (one latent pool) carries
-            # the plain path and chunked prefill; the rest is not ported
+        if (getattr(prog, "pool_rows", None) is not None
+                or getattr(prog, "aux", False)):
+            # a program with its own pools (one latent pool; K, V and
+            # index rows) carries the plain path, chunked prefill and the
+            # fused horizon; the rest is not ported
             asked = [name for name, on in (
                 ("int8 KV (kv_dtype)", kv_dtype in ("int8", "i8")),
                 ("page transfer between hosts (role)", role != "unified"),
@@ -515,8 +530,8 @@ class DecodeEngine:
             ) if on]
             if asked:
                 raise ValueError(
-                    "this decode program keeps one latent pool and does "
-                    "not carry " + ", ".join(asked) + " yet")
+                    "this decode program keeps pools of its own rows and "
+                    "does not carry " + ", ".join(asked) + " yet")
         self._prefix_on = bool(prefix_cache)
         if self._prefix_on and prog.prefill_at is None:
             raise ValueError(
@@ -616,6 +631,7 @@ class DecodeEngine:
         # the device array, (buffer, first row, rows, slot) per request,
         # and the finished answers that wait for those rows
         self._echo_lgs = None
+        self._rows_shape: Optional[tuple] = None   # of ``attn_rows``, a token
         self._echo_rows: List[tuple] = []
         self._echo_results: List[tuple] = []
         self._echo_defer = False   # True while a fused step is recorded
@@ -705,10 +721,13 @@ class DecodeEngine:
                         np.zeros((s_n,), np.int32),
                         np.zeros((s_n,), np.int32),
                         np.zeros((s_n,), bool)).compile())
-                kp, vp, lgs = step_c(
+                kp, vp, lgs, *aux = step_c(
                     params, kp, vp, np.zeros((s_n, pps), np.int32),
                     np.zeros((s_n,), np.int32), np.zeros((s_n,), np.int32),
-                    np.zeros((s_n,), bool))[:3]
+                    np.zeros((s_n,), bool))
+                if aux and "attn_rows" in aux[0]:
+                    # [layers, k] a token: what an echoing request keeps
+                    self._rows_shape = tuple(aux[0]["attn_rows"].shape[1:])
                 self._compiled[("step",)] = step_c
 
                 if self.decode_horizon > 1:
@@ -1720,7 +1739,7 @@ class DecodeEngine:
             tok_h = int(np.asarray(tok))
             fin_h = bool(np.asarray(fin))
             lg_h = np.asarray(lg) if spec.echo_logits else None
-            picks_h = self._read_aux(sp, aux)
+            picks_h, rows_h = self._read_aux(sp, aux, spec.echo_logits)
             t1 = self.clock()
         self.metrics.inc("prefills")
         self.metrics.ttft.record((t1 - s.req.t_submit) * 1e3)
@@ -1733,6 +1752,7 @@ class DecodeEngine:
                 self._prefix_insert(s, t1)
         if picks_h is not None:
             s.picks.append(picks_h)
+        s.rows_next = rows_h
         self._record_token(i, tok_h, fin_h, lg_h, t1)
 
     def _prefill_chunk_step(self) -> bool:
@@ -1760,10 +1780,23 @@ class DecodeEngine:
         return True
 
     def _chunk_pick(self) -> Optional[_Chunk]:
-        """The next chunk of the round-robin, with its padded tokens."""
+        """The next chunk of the round-robin, with its padded tokens.  A
+        prompt whose deadline passed while it was being prefilled is
+        given up first, as one still queued would be: it has no token to
+        hand back, and its remaining chunks (up to seconds of the device
+        for a long prompt) would serve nobody."""
+        now = self.clock()
         with self._lock:
             pending = [i for i, s in enumerate(self._slots)
                        if s is not None and s.n_prefilled is not None]
+            late = [i for i in pending if now > self._slots[i].deadline]
+        for i in late:
+            s = self._slots[i]
+            self._finish(i, now, error=DeadlineExceededError(
+                f"deadline passed {s.n_prefilled} tokens into the prefill "
+                f"of a {s.n_prompt}-token prompt"))
+        with self._lock:
+            pending = [i for i in pending if i not in late]
             if not pending:
                 return None
             start = self._chunk_cursor
@@ -1803,7 +1836,8 @@ class DecodeEngine:
 
     def _chunk_wait(self, c: _Chunk, sp) -> None:
         """The blocking read-back of what the chunk left."""
-        c.picks_h = self._read_aux(sp, c.aux)
+        c.picks_h, c.rows_h = self._read_aux(
+            sp, c.aux, c.last and c.slot.spec.echo_logits)
         if c.last:
             c.tok_h = int(np.asarray(c.tok))
             c.fin_h = bool(np.asarray(c.fin))
@@ -1828,6 +1862,7 @@ class DecodeEngine:
                 self._prefix_insert(s, t1)
         if c.picks_h is not None:
             s.picks.append(c.picks_h)
+        s.rows_next = c.rows_h
         self._record_token(i, c.tok_h, c.fin_h, c.lg_h, t1)
 
     def _attach_handoff(self, i: int, transfer) -> None:
@@ -2015,7 +2050,7 @@ class DecodeEngine:
                     toks_h = np.asarray(toks)
                     fin_h = np.asarray(fin)
                     lgs_h = np.asarray(lgs) if inp.echo else None
-                    picks_h = self._read_aux(sp, aux)
+                    picks_h, rows_h = self._read_aux(sp, aux, inp.echo)
                 t1 = self.clock()
                 self._set_step_args(sp, inp, step_ms=(t_step - t0) * 1e3,
                                     sample_ms=(t1 - t_step) * 1e3)
@@ -2029,28 +2064,34 @@ class DecodeEngine:
                             s.pos += 1
                             if picks_h is not None:
                                 s.picks.append(picks_h[i])
+                            echo = lgs_h is not None and s.logits is not None
+                            if echo and rows_h is not None:
+                                s.rows_next = rows_h[i]
                             self._record_token(
                                 i, int(toks_h[i]), bool(fin_h[i]),
-                                lgs_h[i].copy() if (lgs_h is not None
-                                                    and s.logits is not None)
-                                else None, t1)
+                                lgs_h[i].copy() if echo else None, t1)
         return True
 
-    def _read_aux(self, sp, aux) -> Optional[np.ndarray]:
+    def _read_aux(self, sp, aux, rows: bool = False) -> tuple:
         """What a program with ``aux`` reports beside its logits, read
-        back with the tokens: the expert counts go onto the span and
-        the counters of the same names; the chosen experts are returned
-        for the requests' results.  None for any other program."""
+        back with the tokens: the expert counts (and a sparse
+        selection's) go onto the span and the counters of the same
+        names; returned for the requests' results are the chosen experts
+        and, where ``rows`` asks and the program reports them, the
+        positions each layer's attention selected (``attn_rows``, the
+        bulk of the tree: left on the device otherwise).  ``(None,
+        None)`` for any other program."""
         if not aux:
-            return None
-        from ..parallel.moe import EXPERT_STATS
+            return None, None
         import jax
-        host = jax.device_get(aux[0])       # both arrays in one round trip
-        counts = dict(zip(EXPERT_STATS, host["expert_stats"].tolist()))
+        host = jax.device_get({k: v for k, v in aux[0].items()
+                               if rows or k != "attn_rows"})   # one round trip
+        counts = {name: v for key, names in self.program.aux_stats
+                  for name, v in zip(names, host[key].tolist())}
         for name, v in counts.items():
             self.metrics.inc(name, v)
         sp.set(**counts)
-        return host["expert_picks"]
+        return host["expert_picks"], host.get("attn_rows")
 
     def _step_inputs(self, tag: str) -> Optional[_StepInputs]:
         """Assemble, under the lock, the arrays one dispatch takes for
@@ -2091,18 +2132,20 @@ class DecodeEngine:
         (``steps``: decode steps in the dispatch)."""
         prog = self.program
         tp = int(getattr(prog, "tp", 1))
+        more = {"shards": tp} if tp > 1 else {}
         if self._reads_held_pages:
             # each stepped slot's pages up to its new row, every step
             rows = (inp.pos[inp.group][:, None] + 1
                     + np.arange(steps, dtype=np.int64))
-            pages_read = int((-(-rows // prog.page_size)).sum())
-        else:
-            pages_read = steps * self.max_slots * prog.pages_per_slot
+            more["kv_pages_read"] = int((-(-rows // prog.page_size)).sum())
+        elif prog.held_pages is not None:
+            more["kv_pages_read"] = (steps * self.max_slots
+                                     * prog.pages_per_slot)
+        # else the program reads rows and counts them itself (``_read_aux``)
         sp.set(n_active=len(inp.group), step_ms=round(step_ms, 3),
                sample_ms=round(sample_ms, 3), queued=self.batcher.qsize(),
                pages_reserved=inp.pages_reserved,
-               pages_filled=inp.pages_filled, kv_pages_read=pages_read,
-               **({"shards": tp} if tp > 1 else {}))
+               pages_filled=inp.pages_filled, **more)
 
     def _step_fused_once(self) -> bool:
         """One FUSED dispatch per distinct active version tag: H =
@@ -2167,10 +2210,14 @@ class DecodeEngine:
                             inp.budgets, eos, np.arange(H, dtype=np.int32))
                     self._cache = (kp, vp)
                     # what the host waits for goes first, the bulk last
+                    attn = aux[0].get("attn_rows") if aux else None
                     for a in (toks, fins, *_leaves(aux)):
-                        a.copy_to_host_async()
+                        if a is not attn:
+                            a.copy_to_host_async()
                     if inp.echo:
                         lgs.copy_to_host_async()
+                        if attn is not None:
+                            attn.copy_to_host_async()
                 if chunk_due:
                     chunk_due = False
                     chunk = self._chunk_pick()
@@ -2183,7 +2230,7 @@ class DecodeEngine:
                 with obs_trace.span("serve/step_wait", cat="serve"):
                     toks_h = np.asarray(toks)      # [H, S]
                     fins_h = np.asarray(fins)
-                    picks_h = self._read_aux(sp, aux)   # [H, S, ...]
+                    picks_h, _ = self._read_aux(sp, aux)    # [H, S, ...]
                 t1 = self.clock()
                 if crash:
                     # "mid-horizon" from the host's view: the device has
@@ -2221,11 +2268,12 @@ class DecodeEngine:
                                 # rows n0.. of its buffer are column i of
                                 # this dispatch's logits
                                 self._echo_rows.append(
-                                    (s.logit_buf, n0, len(s.logits) - n0, i))
+                                    (s.logit_buf, s.rows_buf, n0,
+                                     len(s.logits) - n0, i))
                     finally:
                         self._echo_defer = False
                         if inp.echo:
-                            self._echo_lgs = lgs
+                            self._echo_lgs = (lgs, attn)
                 self.metrics.inc("tokens_per_dispatch", committed)
         # a chunk an earlier turn left running lies before this turn's
         # steps on the device: it is done, and reading it costs no wait
@@ -2266,9 +2314,12 @@ class DecodeEngine:
         self._echo_lgs, self._echo_rows, self._echo_results = None, [], []
         try:
             if rows:
-                lgs_h = np.asarray(lgs)                 # [H, S, V]
-                for buf, n0, n, i in rows:
+                lgs_h = np.asarray(lgs[0])              # [H, S, V]
+                attn_h = None if lgs[1] is None else np.asarray(lgs[1])
+                for buf, rows_buf, n0, n, i in rows:
                     buf[n0:n0 + n] = lgs_h[:n, i]
+                    if rows_buf is not None:
+                        rows_buf[n0:n0 + n] = attn_h[:n, i]
         except Exception as e:      # the device is gone: say so
             for fut, _ in results:
                 _fail_safe(fut, e)
@@ -2403,13 +2454,21 @@ class DecodeEngine:
             if s.logit_buf is None:
                 s.logit_buf = np.empty(
                     (s.max_new, self.program.vocab_size), np.float32)
+                if self._rows_shape is not None:
+                    s.rows_buf = np.empty((s.max_new,) + self._rows_shape,
+                                          np.int32)
             n = len(s.logits)
             if n == len(s.logit_buf):       # more rows than the budget
                 s.logit_buf = np.concatenate(
                     [s.logit_buf, np.empty_like(s.logit_buf)])
+                if s.rows_buf is not None:
+                    s.rows_buf = np.concatenate(
+                        [s.rows_buf, np.empty_like(s.rows_buf)])
             if logits_row is not None:
                 s.logit_buf[n] = logits_row
             # else the fused step's row, which ``_flush_echo`` copies in
+            if s.rows_next is not None:
+                s.rows_buf[n], s.rows_next = s.rows_next, None
             s.logits.append(s.logit_buf[n])
         self.metrics.inc("tokens_out")
         if self.eos_id is not None and token == self.eos_id:
@@ -2472,7 +2531,8 @@ class DecodeEngine:
             ttft_ms = (round((s.t_first - s.req.t_submit) * 1e3, 3)
                        if s.t_first else None)
             if error is not None:
-                self.metrics.inc("errors")
+                self.metrics.inc("deadline_missed" if isinstance(
+                    error, DeadlineExceededError) else "errors")
                 _fail_safe(s.req.future, error)
             else:
                 self.metrics.inc({"eos": "eos_stops",
@@ -2489,7 +2549,9 @@ class DecodeEngine:
                     logits=s.logit_buf[:len(s.logits)] if s.logits
                     else None,
                     request_id=request_id,
-                    expert_picks=np.stack(s.picks) if s.picks else None)
+                    expert_picks=np.stack(s.picks) if s.picks else None,
+                    attn_rows=s.rows_buf[:len(s.logits)]
+                    if s.logits and s.rows_buf is not None else None)
                 if s.logits and self._echo_defer:
                     # its last rows are still on the device
                     self._echo_results.append((s.req.future, result))

@@ -276,6 +276,11 @@ _DECODE_COUNTER_KEYS = (
     # experts held here, the fullest held expert's picks (summed over
     # layers and calls), held experts with at least one pick
     "expert_picks", "expert_picks_held", "expert_load_max", "experts_hit",
+    # a learned sparse selection (models/sparse_gqa.SPARSE_STATS; zero
+    # for a program without one), summed over layers and calls: context
+    # rows the indexer scored, K/V rows attention read after the
+    # selection, rows the stepped slots / the chunk's slot held
+    "index_rows_scored", "attn_rows_read", "rows_held",
 )
 
 

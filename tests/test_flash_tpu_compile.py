@@ -140,6 +140,81 @@ def test_latent_decode_program_compiles_for_v5e_without_a_pool_copy(
     assert mem.temp_size_in_bytes < 8 * 4097 * 16 * 640 * 2
 
 
+# -- the sparse decode programs at Keye-VL-2.0's widths ----------------------------
+
+@pytest.mark.parametrize("entry", ["step", "step_multi", "prefill_at"])
+def test_sparse_decode_program_compiles_for_v5e_without_a_pool_copy(
+        one_chip, entry):
+    """The decode step, the fused horizon and a prefill chunk (512
+    tokens) of the benchmark's ``keye-vl-2-30b-a3b`` configuration (8 slots,
+    16,384 positions, bf16) compile for one v5e chip, fit its memory,
+    update the three donated pools in place and keep far less in
+    temporaries than one pool holds.  In the compiled text every pool,
+    the 128-lane index pool among them, keeps its natural layout (a
+    64-lane row would be stored pages-minor and transposed in and out
+    of every call), and the steps hold no K or V temporary of a slot's
+    window: they gather the 2,048 chosen rows."""
+    import json
+    import re
+
+    from deeplearning4j_tpu.models import sparse_gqa
+    from deeplearning4j_tpu.models.arch import LMArch
+    from deeplearning4j_tpu.ops.kv_cache import alloc_pools
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "keye-vl-2-30b-a3b.json")) as f:
+        cfg = json.load(f)
+    slots, page = cfg["program"]["max_slots"], cfg["program"]["page_size"]
+    arch = LMArch.from_config(cfg, max_len=cfg["program"]["max_len"],
+                              param_dtype="bfloat16")
+    prog = sparse_gqa.decode_program(arch, page, arch.max_len)
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                             sharding=one_chip)
+    params = jax.tree_util.tree_map(on_chip, jax.eval_shape(
+        lambda: sparse_gqa.init_params(jax.random.PRNGKey(0), arch,
+                                       jnp.bfloat16)))
+    k_pool, rest = jax.tree_util.tree_map(on_chip, jax.eval_shape(
+        lambda: tuple(alloc_pools(prog, 1 + slots * prog.pages_per_slot))))
+    assert k_pool.shape == rest[0].shape == (7, 8193, 16, 512)
+    assert rest[1].shape == (7, 8193, 16, 128)
+    pool_bytes = 7 * 8193 * 16 * (512 + 512 + 128) * 2
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    flags = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip)
+    if entry == "step":
+        args = (i32(slots, prog.pages_per_slot), i32(slots), i32(slots),
+                flags)
+        fn = prog.step
+    elif entry == "step_multi":
+        f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32,
+                                              sharding=one_chip)
+        args = (i32(slots, prog.pages_per_slot), i32(slots), i32(slots),
+                flags, f32(slots), i32(slots), f32(slots),
+                jax.ShapeDtypeStruct((slots,), jnp.uint32, sharding=one_chip),
+                i32(slots), i32(slots), i32(),
+                i32(cfg["program"]["decode_horizon"]))
+        fn = prog.step_multi
+    else:
+        args = (i32(prog.pages_per_slot), i32(cfg["program"]["prefill_chunk"]),
+                i32(), i32())
+        fn = prog.prefill_at
+    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
+        params, k_pool, rest, *args).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
+    assert mem.alias_size_in_bytes >= pool_bytes            # all three in place
+    # a K or V pool is 940 MB: no copy of one (a 512-token chunk's key
+    # table and one block of scores are 43 MB, a step's 26 to 33)
+    assert mem.temp_size_in_bytes < 100e6
+    text = compiled.as_text()
+    layouts = set(re.findall(r"bf16\[7,8193,16,(?:512|128)\]\{([0-9,]*)", text))
+    assert layouts == {"3,2,1,0"}, layouts                  # never pages-minor
+    if entry != "prefill_at":
+        # a slot's window of K or V rows, flat or by heads
+        assert not re.search(r"bf16\[8,16384,(512|4,128)\]", text)
+        assert "bf16[8,2048,512]" in text                   # the chosen rows
+
+
 # -- the GPT-2 decode programs at gpt2-large's widths -----------------------------
 
 @pytest.fixture(scope="module")

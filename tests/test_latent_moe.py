@@ -21,10 +21,11 @@ from deeplearning4j_tpu.parallel.moe import (
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _reference():
+def _reference(config="kimi-k2-instruct"):
     path = os.path.join(ROOT, "benchmarks", "configs",
-                        "kimi-k2-instruct_reference.py")
-    spec = importlib.util.spec_from_file_location("kimi_reference", path)
+                        f"{config}_reference.py")
+    spec = importlib.util.spec_from_file_location(
+        config.replace("-", "_") + "_reference", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
@@ -184,27 +185,49 @@ def test_rows_that_are_no_tokens_route_nowhere():
     assert int(stats[0]) == 21
 
 
-def test_the_shares_add_up_to_the_uncut_layer():
+@pytest.mark.parametrize("router", ["noaux_tc", "softmax_topk"])
+def test_the_shares_add_up_to_the_uncut_layer(router):
     """The routed parts that all four shares of a 16-expert layer give
     (4 experts each), plus the shared expert ONCE, equal the uncut
-    reference's whole layer."""
+    reference's whole layer: behind the sigmoid gate with its bias
+    (Kimi's reference) and behind the softmax gate without one
+    (Keye's), one code path after the router."""
     whole_sizes = {**SIZES, "n_routed_experts": 16, "first_expert": 0}
     whole = _expert_layer(whole_sizes)
     x = jax.random.normal(jax.random.PRNGKey(7), (33, 64))
-    with ref.with_precision("float32"):
-        want, want_picks = ref.experts(whole, x, whole_sizes)
+    if router == "softmax_topk":
+        sparse = _reference("keye-vl-2-30b-a3b")
+        whole = {k: v for k, v in whole.items()
+                 if k != "router_b" and not k.startswith("s_")}
+        with sparse.with_precision("float32"):
+            want, want_picks = sparse.experts(whole, x, whole_sizes)
+    else:
+        with ref.with_precision("float32"):
+            want, want_picks = ref.experts(whole, x, whole_sizes)
     total = 0.0
     for share in range(4):
         lo = 4 * share
         p = {**whole, **{k: whole[k][lo:lo + 4]
                          for k in ("e_gate", "e_up", "e_down")}}
         y, picks, _ = moe_forward_held(p, x, first_expert=lo, k=3,
-                                       scaling=2.827, shared=share == 0)
+                                       scaling=2.827, shared=share == 0,
+                                       router=router)
         np.testing.assert_array_equal(np.asarray(picks),
                                       np.asarray(want_picks))
         total = total + y
     np.testing.assert_allclose(np.asarray(total), np.asarray(want),
                                rtol=2e-4, atol=2e-5)
+
+
+def test_softmax_router_weights_are_the_top_probabilities_over_their_sum():
+    from deeplearning4j_tpu.parallel.moe import route_softmax_topk
+    logits = np.array([[2.0, 1.0, 0.0, -1.0, -3.0]], np.float32)
+    idx, wt = route_softmax_topk(jnp.asarray(logits), jnp.eye(5), 2)
+    assert np.asarray(idx)[0].tolist() == [0, 1]
+    pr = np.exp(logits[0]) / np.exp(logits[0]).sum()
+    np.testing.assert_allclose(np.asarray(wt)[0], pr[:2] / pr[:2].sum(),
+                               rtol=1e-6)
+    assert float(np.asarray(wt).sum()) == pytest.approx(1.0, rel=1e-6)
 
 
 # -- attention: expanded, absorbed, reference ----------------------------------
@@ -351,7 +374,7 @@ def test_bf16_weights_are_served_as_bf16(mesh):
     assert all(a.dtype == jnp.bfloat16 for a in leaves
                if a.ndim >= 2), {a.dtype for a in leaves}
     prog = lm16.decode_program(page_size=8, max_len=128)
-    assert prog.pool_dtype == jnp.bfloat16 and prog.pool_row == (128,)
+    assert prog.pool_dtype == jnp.bfloat16 and prog.pool_rows == ((128,),)
     assert arch_of({**SIZES, "kv_lora_rank": 512, "qk_rope_head_dim": 64}).latent_lanes == 640
 
 
