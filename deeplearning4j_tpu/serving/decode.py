@@ -25,10 +25,29 @@ spans many device dispatches), and shape-bucketed in TWO dimensions
       one-shot engine).
 
   per-request stop conditions
-      EOS / max-tokens / deadline are checked after every sampled
-      token; a stopped request resolves immediately and its cache pages
-      go back to the free list the same step — the pool oversubscribes
-      slots when request lengths vary.
+      EOS / max-tokens / deadline are checked as every sampled token is
+      recorded; a stopped request resolves immediately and its cache
+      pages go back to the free list the same turn — the pool
+      oversubscribes slots when request lengths vary.
+
+  one decode step in flight (the plain loop, ``decode_horizon=1``)
+      A turn of the loop admits (a prefill blocks on its first token),
+      then QUEUES step n+1 and its sampler call, and only then reads
+      step n back and records it: the device runs n+1 while the host
+      reads, records, finishes, admits and builds n+2.  Step n+1's input
+      tokens are the sampler's output of step n, still on the device; a
+      slot that joined from a prefill since has its token on the host,
+      and one small program joins the two (``("join",)``).  Positions,
+      sampler counters and budget stops are known without the read; EOS,
+      a passed deadline and non-finite logits are not, so such a request
+      is stepped once too often: a write at its own next row, ordered on
+      the device before anything a later tenant of its pages does, and a
+      token that is dropped (``overrun_slot_steps``).  Slot state on the
+      host changes only at a read, so a crash retries from the last
+      recorded token.  While two model versions are alive each step is
+      read before the next is queued (``step_drains``); counters
+      ``steps_ahead`` / ``decode_steps`` say how often the device had
+      its next step waiting.
 
   resilience (the PR-7 supervisor patterns, decode-shaped)
       A crash anywhere in the decode loop fails or RETRIES every
@@ -87,7 +106,7 @@ Three stacked decode-side optimizations, each independently gated
       fixed HBM; changes bits, so it is gated by a top1-agree accuracy
       envelope in ``decode_speed_ab``, never by the identity gates.
 
-Two host-overhead eliminations ride on top (docs/SERVING.md
+Two more host-overhead eliminations ride on top (docs/SERVING.md
 "Host-overhead elimination"; both off by default, both bit-exact):
 
   fused multi-step decode (``decode_horizon=H``)
@@ -117,10 +136,11 @@ Two host-overhead eliminations ride on top (docs/SERVING.md
 
 TTFT and time-per-output-token are first-class (``DecodeMetrics``).
 Everything the loop thread does is a live ``obs.trace`` span under
-``serve/iteration`` (``serve/admit``, ``serve/prefill``,
-``serve/decode_step`` with a child where the host builds, dispatches,
-waits and records, ``serve/finish``), and the spans of one request carry
-its ``request_id`` — docs/OBSERVABILITY.md.
+``serve/iteration`` (``serve/admit``, ``serve/prefill``, a span each
+where the host builds and dispatches the step it queues,
+``serve/decode_step`` with a child where it waits for and one where it
+records the step it reads, ``serve/finish``), and the spans of one
+request carry its ``request_id`` — docs/OBSERVABILITY.md.
 """
 
 from __future__ import annotations
@@ -285,15 +305,22 @@ class _StepInputs:
     """One decode dispatch's host-built inputs: the per-slot arrays the
     compiled step and samplers take, and what the group holds."""
 
-    __slots__ = ("params", "group", "echo", "toks_in", "pos", "act",
-                 "temps", "tks", "tps", "seeds", "steps", "budgets",
-                 "pages_reserved", "pages_filled")
+    __slots__ = ("params", "tag", "group", "slots", "echo", "toks_in",
+                 "on_host", "pos", "act", "temps", "tks", "tps", "seeds",
+                 "steps", "budgets", "pages_reserved", "pages_filled")
 
-    def __init__(self, n_slots: int):
+    def __init__(self, n_slots: int, tag: str):
         self.params = None
+        self.tag = tag
         self.group: List[int] = []      # slot indices stepped together
+        # by slot, the request stepped there (None: not in the group):
+        # what the step returns is its, whoever holds the slot at the read
+        self.slots: List[Optional[_Slot]] = [None] * n_slots
         self.echo = False               # some slot wants its logits back
         self.toks_in = np.zeros((n_slots,), np.int32)
+        # False where the slot's input token is still on the device, in
+        # the sampler's output of the step in flight
+        self.on_host = np.ones((n_slots,), bool)
         self.pos = np.zeros((n_slots,), np.int32)
         self.act = np.zeros((n_slots,), bool)
         self.temps = np.zeros((n_slots,), np.float32)
@@ -304,6 +331,16 @@ class _StepInputs:
         self.budgets = np.ones((n_slots,), np.int32)
         self.pages_reserved = 0         # pages the group's slots hold
         self.pages_filled = 0           # ... of which hold >= 1 token
+
+
+class _Flight:
+    """One plain decode step on the device and not read back yet: its
+    inputs, what the step and the sampler left on the device (their
+    transfers to the host started at the dispatch), whether the step
+    before it was unread when it went, and the dispatch's times."""
+
+    __slots__ = ("inp", "toks", "fin", "lgs", "aux", "ahead", "t0",
+                 "step_ms", "sample_ms")
 
 
 class _PrefixNode:
@@ -637,6 +674,9 @@ class DecodeEngine:
         self._echo_defer = False   # True while a fused step is recorded
         # a chunk queued behind a fused step and not read back yet
         self._chunk_inflight: Optional[_Chunk] = None
+        # the plain loop's decode step on the device and not read back yet
+        self._flight: Optional[_Flight] = None
+        self._step_read_at = 0.0   # clock at the end of the last step's read
         self._request_ids = itertools.count(1)
         self._crash_next = False   # test hook: raise inside the next step
         self._thread: Optional[threading.Thread] = None
@@ -813,6 +853,20 @@ class DecodeEngine:
                              np.zeros((s_n,), np.int32))
                 np.asarray(toks)
                 self._compiled[("sample",)] = sb
+                if self.decode_horizon == 1 and self._draft_program is None:
+                    # the plain loop keeps a step in flight: a slot that
+                    # joins from a prefill has its token on the host, the
+                    # slots already stepping theirs on the device
+                    def _join_tokens(dev, host, on_host):
+                        import jax.numpy as jnp
+                        return jnp.where(on_host, host, dev)
+
+                    join_c = _get("join", lambda: jax.jit(_join_tokens).lower(
+                        toks, np.zeros((s_n,), np.int32),
+                        np.zeros((s_n,), bool)).compile())
+                    np.asarray(join_c(toks, np.zeros((s_n,), np.int32),
+                                      np.zeros((s_n,), bool)))
+                    self._compiled[("join",)] = join_c
 
             from ..ops.kv_cache import scrub_pool
 
@@ -1383,7 +1437,7 @@ class DecodeEngine:
             with self._lock:
                 leave = self._shutdown or gen != self._generation
             if leave:
-                self._chunk_inflight = None
+                self._chunk_inflight = self._flight = None
                 self._flush_echo()          # answers already finished
                 return
             # everything this thread does is under serve/iteration, so
@@ -2012,9 +2066,38 @@ class DecodeEngine:
                               request_id=spec.request_id)
 
     def _step_once(self) -> bool:
-        """One decode step per distinct active version tag (same
-        executable, that tag's params, that tag's slots active) — the
-        no-version-mixing hot-swap invariant lives here."""
+        """One turn of the plain loop: queue the NEXT decode step (and
+        its sampler call) on the device, then read back and record the
+        step queued a turn ago, which ran while the host recorded,
+        admitted and built.  The next step's input tokens are the
+        sampler's own output on the device, joined there with the
+        host's token of a slot that came from a prefill since
+        (``("join",)``), so nothing of a step has to reach the host
+        before the one after it is queued.
+
+        What the host knows without the read goes into the next step's
+        inputs (``_step_inputs``): a stepped slot's position and sampler
+        counter are one further, and a slot whose budget ends with the
+        step in flight is left out.  EOS, a passed deadline and
+        non-finite logits are known only at the read: such a slot has
+        then been stepped ONCE more.  That step's write lands at the
+        request's own next row, inside the pages it reserved, and any
+        later tenant's prefill, attach or scrub of those pages is queued
+        behind it on the device; its token is dropped
+        (``overrun_slot_steps``).  Host slot state still changes only at
+        a read, so a crash retries from the last recorded token.
+
+        The step runs once per distinct active version tag (same
+        executable, that tag's params, that tag's slots active): the
+        no-version-mixing hot-swap invariant lives here.  While two
+        tags are alive each step is read before the next is queued
+        (``step_drains``): a step takes its tokens from ONE sampler
+        output.
+
+        Spans: ``serve/step_build`` / ``serve/step_dispatch`` /
+        ``serve/sample_dispatch`` of the step that is queued lie under
+        ``serve/iteration``; ``serve/decode_step`` is the step that is
+        READ, with its ``serve/step_wait`` and ``serve/step_record``."""
         with self._lock:
             tags: List[str] = []
             for s in self._slots:
@@ -2025,52 +2108,110 @@ class DecodeEngine:
             self._crash_next = False
         if crash:
             raise ReplicaCrashError("injected decode-batch crash (test hook)")
-        if not tags:
+        prev, self._flight = self._flight, None
+        if prev is None and not tags:
             return False
-        for tag in tags:
-            with obs_trace.span("serve/decode_step", cat="serve",
-                                model=tag, tokens=1) as sp:
-                inp = self._step_inputs(tag)
-                if inp is None:
-                    continue
-                t0 = self.clock()
-                with obs_trace.span("serve/step_dispatch", cat="serve"):
-                    kp, vp = self._cache
-                    kp, vp, lgs, *aux = self._compiled[("step",)](
-                        inp.params, kp, vp, self._page_table, inp.toks_in,
-                        inp.pos, inp.act)
-                t_step = self.clock()
-                with obs_trace.span("serve/sample_dispatch", cat="serve"):
-                    toks, fin = self._compiled[("sample",)](
-                        lgs, inp.temps, inp.tks, inp.tps, inp.seeds,
-                        inp.steps)
-                    self._cache = (kp, vp)
-                # the blocking read-back: the device works inside it
-                with obs_trace.span("serve/step_wait", cat="serve"):
-                    toks_h = np.asarray(toks)
-                    fin_h = np.asarray(fin)
-                    lgs_h = np.asarray(lgs) if inp.echo else None
-                    picks_h, rows_h = self._read_aux(sp, aux, inp.echo)
-                t1 = self.clock()
-                self._set_step_args(sp, inp, step_ms=(t_step - t0) * 1e3,
-                                    sample_ms=(t1 - t_step) * 1e3)
-                self.metrics.inc("decode_steps")
-                self.metrics.step_time.record((t1 - t0) * 1e3)
-                with obs_trace.span("serve/step_record", cat="serve"):
-                    for i in inp.group:
-                        with self._lock:
-                            s = self._slots[i]
-                        if s is not None:
-                            s.pos += 1
-                            if picks_h is not None:
-                                s.picks.append(picks_h[i])
-                            echo = lgs_h is not None and s.logits is not None
-                            if echo and rows_h is not None:
-                                s.rows_next = rows_h[i]
-                            self._record_token(
-                                i, int(toks_h[i]), bool(fin_h[i]),
-                                lgs_h[i].copy() if echo else None, t1)
+        if len(tags) > 1:
+            if prev is not None:
+                self._step_read(prev)
+            for tag in tags:
+                f = self._step_queue(tag)
+                if f is not None:
+                    self.metrics.inc("step_drains")
+                    self._step_read(f)
+            return True
+        if tags:
+            self._flight = self._step_queue(tags[0], prev)
+        if prev is not None:
+            self._step_read(prev)
         return True
+
+    def _step_queue(self, tag: str, prev: Optional[_Flight] = None
+                    ) -> Optional[_Flight]:
+        """Queue one decode step of ``tag``'s slots and its sampler call
+        behind whatever the device holds (``prev``: the step in flight,
+        unread), and start the transfers of what the read will want;
+        nothing is waited for.  None where no slot is left to step."""
+        inp = self._step_inputs(tag, prev)
+        if inp is None:
+            return None
+        t0 = self.clock()
+        with obs_trace.span("serve/step_dispatch", cat="serve"):
+            toks_in = inp.toks_in
+            if not inp.on_host[inp.group].all():
+                # a slot of the step in flight: its token is on the device
+                toks_in = self._compiled[("join",)](prev.toks, toks_in,
+                                                    inp.on_host)
+            kp, vp = self._cache
+            kp, vp, lgs, *aux = self._compiled[("step",)](
+                inp.params, kp, vp, self._page_table, toks_in, inp.pos,
+                inp.act)
+        t_step = self.clock()
+        with obs_trace.span("serve/sample_dispatch", cat="serve"):
+            toks, fin = self._compiled[("sample",)](
+                lgs, inp.temps, inp.tks, inp.tps, inp.seeds, inp.steps)
+            self._cache = (kp, vp)
+            # what the read waits for goes first, the bulk last
+            attn = aux[0].get("attn_rows") if aux else None
+            for a in (toks, fin, *_leaves(aux)):
+                if a is not attn:
+                    a.copy_to_host_async()
+            if inp.echo:
+                lgs.copy_to_host_async()
+                if attn is not None:
+                    attn.copy_to_host_async()
+        self.metrics.inc("decode_steps")
+        if prev is not None:
+            self.metrics.inc("steps_ahead")
+        f = _Flight()
+        f.inp, f.toks, f.fin, f.aux = inp, toks, fin, aux
+        f.lgs = lgs if inp.echo else None
+        f.ahead = int(prev is not None)
+        f.t0, f.step_ms = t0, (t_step - t0) * 1e3
+        f.sample_ms = (self.clock() - t_step) * 1e3
+        return f
+
+    def _step_read(self, f: _Flight) -> None:
+        """The ``serve/decode_step`` span of step ``f``: the blocking
+        read-back (the device works inside it, on ``f`` and on whatever
+        was queued behind it) and the group's bookkeeping.  A slot whose
+        request stopped at the read before (EOS, deadline, poison) was
+        stepped for nothing: its token is dropped."""
+        inp = f.inp
+        with obs_trace.span("serve/decode_step", cat="serve",
+                            model=inp.tag, tokens=1) as sp:
+            t_wait = self.clock()
+            with obs_trace.span("serve/step_wait", cat="serve"):
+                toks_h = np.asarray(f.toks)
+                fin_h = np.asarray(f.fin)
+                lgs_h = np.asarray(f.lgs) if inp.echo else None
+                picks_h, rows_h = self._read_aux(sp, f.aux, inp.echo)
+            t1 = self.clock()
+            self._set_step_args(
+                sp, inp, step_ms=f.step_ms,
+                sample_ms=f.sample_ms + (t1 - t_wait) * 1e3)
+            sp.set(ahead=f.ahead)
+            # the device was on the step before until that one's read
+            # ended, where this one was queued behind it
+            self.metrics.step_time.record(
+                (t1 - max(f.t0, self._step_read_at)) * 1e3)
+            self._step_read_at = t1
+            with obs_trace.span("serve/step_record", cat="serve"):
+                for i in inp.group:
+                    s = inp.slots[i]
+                    with self._lock:
+                        gone = self._slots[i] is not s
+                    if gone:
+                        self.metrics.inc("overrun_slot_steps")
+                        continue
+                    s.pos += 1
+                    if picks_h is not None:
+                        s.picks.append(picks_h[i])
+                    echo = lgs_h is not None and s.logits is not None
+                    if echo and rows_h is not None:
+                        s.rows_next = rows_h[i]
+                    self._record_token(i, int(toks_h[i]), bool(fin_h[i]),
+                                       lgs_h[i] if echo else None, t1)
 
     def _read_aux(self, sp, aux, rows: bool = False) -> tuple:
         """What a program with ``aux`` reports beside its logits, read
@@ -2093,11 +2234,16 @@ class DecodeEngine:
         sp.set(**counts)
         return host["expert_picks"], host.get("attn_rows")
 
-    def _step_inputs(self, tag: str) -> Optional[_StepInputs]:
+    def _step_inputs(self, tag: str, ahead: Optional[_Flight] = None
+                     ) -> Optional[_StepInputs]:
         """Assemble, under the lock, the arrays one dispatch takes for
         the steppable slots serving ``tag``; None when the version is
-        gone or no slot is left to step."""
-        inp = _StepInputs(self.max_slots)
+        gone or no slot is left to step.  ``ahead`` is the step in
+        flight, not recorded yet: a slot it steps is taken one row and
+        one token further than the host has recorded, its input token is
+        the one that step leaves on the device, and it is left out where
+        that token is the last of its budget."""
+        inp = _StepInputs(self.max_slots, tag)
         with obs_trace.span("serve/step_build", cat="serve"), self._lock:
             inp.params = self._versions.get(tag)
             if inp.params is None:
@@ -2106,19 +2252,23 @@ class DecodeEngine:
             for i, s in enumerate(self._slots):
                 if s is None:
                     continue
-                filled = self._pages_filled(s)
+                d = int(ahead is not None and ahead.inp.slots[i] is s)
+                filled = self._pages_filled(s, d)
                 filled_all += filled
-                if s.tag != tag or s.n_prefilled is not None:
+                if (s.tag != tag or s.n_prefilled is not None
+                        or (d and s.n_out + 1 >= s.max_new)):
                     continue
                 inp.group.append(i)
+                inp.slots[i] = s
                 inp.toks_in[i] = s.last_token
-                inp.pos[i] = s.pos
+                inp.on_host[i] = not d
+                inp.pos[i] = s.pos + d
                 inp.act[i] = True
                 inp.temps[i] = s.spec.temperature
                 inp.tks[i] = s.spec.top_k
                 inp.tps[i] = s.spec.top_p
                 inp.seeds[i] = s.spec.seed
-                inp.steps[i] = s.n_out
+                inp.steps[i] = s.n_out + d
                 inp.budgets[i] = max(1, s.max_new - s.n_out)
                 inp.echo = inp.echo or s.logits is not None
                 inp.pages_reserved += len(s.page_ids) + len(s.shared_nodes)
@@ -2579,7 +2729,8 @@ class DecodeEngine:
         except Exception as e:      # their callers have been told
             obs_trace.instant("serve/replica_crash", cat="serve",
                               kind="echo_flush", error=type(e).__name__)
-        self._chunk_inflight = None     # its slot is wiped with the rest
+        # their slots are wiped with the rest, what they computed dropped
+        self._chunk_inflight = self._flight = None
         with self._lock:
             in_flight = [s for s in self._slots if s is not None]
             self._slots = [None] * self.max_slots
@@ -2629,9 +2780,10 @@ class DecodeEngine:
         self.metrics.pages_filled.set(
             sum(self._pages_filled(s) for s in self._slots if s is not None))
 
-    def _pages_filled(self, s: _Slot) -> int:
-        """Pages of ``s`` that hold at least one token."""
-        held = s.pos if s.n_prefilled is None else s.n_prefilled
+    def _pages_filled(self, s: _Slot, ahead: int = 0) -> int:
+        """Pages of ``s`` that hold at least one token (``ahead``: rows
+        a step in flight has written and the host not yet recorded)."""
+        held = s.pos + ahead if s.n_prefilled is None else s.n_prefilled
         return -(-held // self.program.page_size)
 
     def metrics_snapshot(self) -> dict:

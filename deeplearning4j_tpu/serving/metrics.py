@@ -271,6 +271,11 @@ _DECODE_COUNTER_KEYS = (
     # prompt/chunk counts
     "fused_dispatches", "tokens_per_dispatch",
     "chunked_prefills", "prefill_chunks",
+    # the plain loop's step in flight: decode steps queued while the step
+    # before was unread, steps read in the turn that queued them (two
+    # version tags alive), slot-steps computed for a request that the
+    # read before had stopped (EOS, deadline, poison)
+    "steps_ahead", "step_drains", "overrun_slot_steps",
     # routed experts (parallel/moe.EXPERT_STATS; zero for a program
     # without them): picks made by real tokens, those that fell on
     # experts held here, the fullest held expert's picks (summed over

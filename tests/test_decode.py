@@ -72,6 +72,15 @@ def _ctr(engine, key):
     return engine.metrics.snapshot()["counters"][key]
 
 
+def _ctr_reaches(engine, key, want, timeout=30.0):
+    """A counter the loop moves a turn AFTER a future resolved (the
+    step in flight is read then)."""
+    deadline = time.monotonic() + timeout
+    while _ctr(engine, key) < want and time.monotonic() < deadline:
+        time.sleep(0.001)
+    return _ctr(engine, key) == want
+
+
 class TestSamplingDeterminism:
     def test_greedy_repeatable(self, engine):
         a = _tokens(engine, [1, 2, 3], max_new_tokens=8)
@@ -395,6 +404,200 @@ class TestResilience:
         # scrub proof: the poisoned slot's recycled pages serve clean
         # (a NaN row left in the pool would contaminate via 0 * NaN)
         assert _tokens(engine, [12, 13], max_new_tokens=6) == ref
+
+
+class TestStepAhead:
+    """The plain loop keeps one decode step in flight: step n+1 is
+    queued, fed by the sampler's tokens on the device, before step n is
+    read.  Same tokens and stops as reading each step first; what only
+    the read can know (EOS, deadline, poison) costs the slot one step
+    too many, counted and dropped."""
+
+    @staticmethod
+    def _small(lm, **kw):
+        # scratch + 4 pages: one full-length request needs them all, so
+        # the next tenant can only run on the pages the last one freed
+        kw.setdefault("max_slots", 1)
+        return DecodeEngine(lm, page_size=8, **kw).load()
+
+    def test_eos_stops_at_the_same_token_and_counts_the_overrun(
+            self, engine, lm):
+        ref = _tokens(engine, [3, 4], max_new_tokens=12)
+        # the first token not seen before it, past the prefill's: the
+        # EOS is found at a decode step's read, with the next in flight
+        k = next(j for j in range(1, len(ref)) if ref[j] not in ref[:j])
+        ref_next = _tokens(engine, [5, 6, 7], max_new_tokens=29)
+        small = self._small(lm, eos_id=ref[k])
+        try:
+            assert small.total_pages == 5
+            res = small.generate([3, 4], max_new_tokens=12)
+            assert res.finish_reason == "eos" and res.tokens == ref[:k + 1]
+            assert _ctr_reaches(small, "overrun_slot_steps", 1)
+            assert _ctr(small, "decode_steps") == k + 1     # one too many
+            nxt = small.generate([5, 6, 7], max_new_tokens=29)
+            assert nxt.tokens == ref_next[:len(nxt.tokens)]
+            assert nxt.finish_reason == "eos" or nxt.tokens == ref_next
+            assert small.metrics_snapshot()["pages_in_use"] == 0
+        finally:
+            small.shutdown()
+
+    def test_deadline_stops_at_the_same_token_and_counts_the_overrun(
+            self, engine, lm):
+        ref = _tokens(engine, [2, 2], max_new_tokens=20)
+        ref_next = _tokens(engine, [5, 6, 7], max_new_tokens=29)
+        box = []
+        # the clock jumps once five tokens are recorded: the read that
+        # follows records the sixth and finds the deadline passed
+        late = lambda: (1e6 if box and box[0].metrics.counter_value(
+            "tokens_out") >= 5 else 0.0)
+        small = self._small(lm, clock=late)
+        box.append(small)
+        try:
+            res = small.generate_async([2, 2], max_new_tokens=20,
+                                       deadline=100.0).result(timeout=60)
+            assert res.finish_reason == "deadline" and res.tokens == ref[:6]
+            assert _ctr_reaches(small, "overrun_slot_steps", 1)
+            nxt = small.generate_async([5, 6, 7], max_new_tokens=29,
+                                       deadline=2e6).result(timeout=60)
+            assert nxt.tokens == ref_next
+        finally:
+            small.shutdown()
+
+    def test_poison_stops_its_request_alone_and_counts_the_overrun(
+            self, engine, lm):
+        ref_b = _tokens(engine, [14, 15], max_new_tokens=14)
+        ref_next = _tokens(engine, [5, 6, 7], max_new_tokens=12)
+        small = self._small(lm, max_slots=2, total_pages=5)
+        real, calls = small._compiled[("sample",)], []
+
+        def poisoned(lgs, *a):
+            toks, fin = real(lgs, *a)
+            calls.append(1)
+            if len(calls) == 3:         # slot 0's logits "were" non-finite
+                fin = fin.at[0].set(False)
+            return toks, fin
+
+        small._compiled[("sample",)] = poisoned
+        try:
+            bad = small.generate_async([12, 13], max_new_tokens=14)
+            good = small.generate_async([14, 15], max_new_tokens=14)
+            with pytest.raises(PoisonInputError):
+                bad.result(timeout=60)
+            assert good.result(timeout=60).tokens == ref_b
+            assert _ctr(small, "poison_isolated") == 1
+            assert _ctr(small, "overrun_slot_steps") == 1   # good ran on
+            # the freed (scrubbed) pages serve the next tenant clean
+            assert _tokens(small, [5, 6, 7], max_new_tokens=12) == ref_next
+            assert small.metrics_snapshot()["pages_in_use"] == 0
+        finally:
+            small.shutdown()
+
+    def test_crash_with_a_step_in_flight_retries_to_the_same_tokens(
+            self, engine):
+        prompts = [[1, 2], [3, 4, 5], [6]]
+        refs = [_tokens(engine, p, max_new_tokens=24) for p in prompts]
+        c0 = {k: _ctr(engine, k) for k in ("retries", "errors")}
+        in_flight, real = [], engine._drain_crashed
+
+        def spy(exc):
+            in_flight.append(engine._flight is not None)
+            return real(exc)
+
+        engine._drain_crashed = spy
+        try:
+            t0 = _ctr(engine, "tokens_out")
+            futs = [engine.generate_async(p, max_new_tokens=24)
+                    for p in prompts]
+            deadline = time.monotonic() + 30
+            while _ctr(engine, "tokens_out") < t0 + 6:
+                assert time.monotonic() < deadline, "decode never started"
+                time.sleep(0.0005)
+            engine._crash_next = True
+            got = [f.result(timeout=60) for f in futs]
+        finally:
+            engine._drain_crashed = real
+        assert in_flight == [True]          # its tokens were never read
+        assert [r.tokens for r in got] == refs
+        assert _ctr(engine, "retries") > c0["retries"]
+        assert _ctr(engine, "errors") == c0["errors"]
+
+    def test_two_versions_alive_drain_and_mix_nothing(self, engine, lm):
+        import jax
+
+        ref_v0 = _tokens(engine, [10, 11], max_new_tokens=24)
+        v1 = jax.tree_util.tree_map(
+            lambda a: (a * 1.37 + 0.05).astype(a.dtype), lm.params)
+        pre = _ctr(engine, "prefills")
+        fut_old = engine.generate_async([10, 11], max_new_tokens=24)
+        deadline = time.monotonic() + 30
+        while _ctr(engine, "prefills") <= pre:
+            assert time.monotonic() < deadline, "prefill never landed"
+            time.sleep(0.0005)
+        d0 = _ctr(engine, "step_drains")
+        try:
+            engine.swap_model(v1, "v1")
+            fut_new = engine.generate_async([10, 11], max_new_tokens=24)
+            r_old, r_new = fut_old.result(60), fut_new.result(60)
+            drained = _ctr(engine, "step_drains") - d0
+            a0 = _ctr(engine, "steps_ahead")
+            ref_v1 = _tokens(engine, [10, 11], max_new_tokens=24)  # pure v1
+        finally:
+            engine.swap_model(lm, "v0")
+        assert drained > 0                  # each tag's step read by itself
+        assert r_old.model_tag == "v0" and r_old.tokens == ref_v0
+        assert r_new.model_tag == "v1" and r_new.tokens == ref_v1 != ref_v0
+        # one version alive again: no drain, a step in flight
+        assert _ctr(engine, "step_drains") == d0 + drained
+        assert _ctr(engine, "steps_ahead") > a0
+
+    def test_a_saturated_engine_runs_ahead(self, lm):
+        eng = DecodeEngine(lm, max_slots=4, page_size=8).load()
+        try:
+            futs = [eng.generate_async([1 + i, 2 + i], max_new_tokens=12 + i,
+                                       temperature=0.8 * (i % 2), top_k=5,
+                                       seed=i) for i in range(12)]
+            assert [len(f.result(timeout=120).tokens)
+                    for f in futs] == [12 + i for i in range(12)]
+            c = eng.metrics_snapshot()["counters"]
+            assert c["steps_ahead"] / c["decode_steps"] >= 0.8
+            # every stop was a budget the host knew at the dispatch
+            assert c["overrun_slot_steps"] == 0 == c["step_drains"]
+        finally:
+            eng.shutdown()
+
+    def test_a_tensor_parallel_engine_steps_ahead_to_the_same_tokens(self):
+        """The sampler's output on four devices feeds the next step (and
+        the join) as compiled: no executable refuses its sharding."""
+        import jax
+
+        if len(jax.devices()) < 4:
+            pytest.skip("needs >= 4 devices")
+
+        def served(n):
+            mesh = build_mesh({"data": n, "model": 1, "seq": 1, "pipe": 1},
+                              jax.devices()[:n])
+            eng = DecodeEngine(ShardedTransformerLM(
+                vocab_size=VOCAB, n_layers=2, d_model=32, n_heads=4,
+                max_len=MAXLEN, mesh=mesh, seed=11),
+                max_slots=3, page_size=8).load()
+            try:
+                assert eng.program.tp == n
+                n0 = eng.compile_cache_size()
+                futs = [eng.generate_async(
+                    [1 + i, 2 + i], max_new_tokens=10 + i,
+                    temperature=0.8 * (i % 2), top_k=5, seed=i)
+                    for i in range(6)]
+                toks = [f.result(timeout=120).tokens for f in futs]
+                assert eng.compile_cache_size() == n0
+                return toks, eng.metrics_snapshot()["counters"]
+            finally:
+                eng.shutdown()
+
+        one, _ = served(1)
+        four, c = served(4)
+        assert four == one
+        # slots refill from prefills mid-run: joined with those in flight
+        assert c["steps_ahead"] / c["decode_steps"] >= 0.8
 
 
 class TestZeroServeTimeCompiles:

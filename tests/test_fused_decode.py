@@ -209,6 +209,34 @@ class TestFusedIdentity:
         assert _partition_ok(fused)
 
 
+class TestStepAheadAgainstFused:
+    def test_cobatched_mix_with_slots_refilling_matches_the_fused_loop(
+            self, plain, fused):
+        """The plain loop (a step in flight, its tokens fed on the
+        device) against the fused horizon, a path of its own: greedy
+        requests that echo their logits and top-k requests of different
+        lengths, twice as many as slots, so that slots finish and
+        refill mid-run beside slots that keep stepping."""
+        from _decode_checks import assert_logits_close
+
+        reqs = [(PROMPTS[i % 4], dict(max_new_tokens=5 + 3 * i,
+                                      echo_logits=True) if i % 2 == 0 else
+                 dict(max_new_tokens=4 + 2 * i, temperature=0.8, top_k=7,
+                      seed=100 + i)) for i in range(7)]
+        a0 = plain.metrics.counter_value("steps_ahead")
+        got, ref = ([f.result(timeout=120) for f in
+                     [e.generate_async(p, **kw) for p, kw in reqs]]
+                    for e in (plain, fused))
+        assert plain.metrics.counter_value("steps_ahead") > a0
+        for (p, kw), g, r in zip(reqs, got, ref):
+            assert g.tokens == r.tokens and len(g.tokens) == kw[
+                "max_new_tokens"]
+            assert g.finish_reason == r.finish_reason
+            if kw.get("echo_logits"):
+                assert_logits_close(g.logits, r.logits, f"prompt {p}")
+        assert _partition_ok(plain)
+
+
 class TestFusedSpans:
     def test_one_span_per_fused_dispatch_with_tokens_arg(self, fused):
         rec = obs_trace.TraceRecorder()

@@ -574,8 +574,12 @@ class TestOneSpanSystemTwoSinks:
         by_turn = [s for t in turns for s in t["children"]
                    if s["name"] == "serve/decode_step"]
         assert len(by_turn) == 2          # each directly under a turn
+        # a step's span is the turn that READS it: the wait and the
+        # record; its build and dispatch came a turn earlier
         for st in steps:
-            assert [c["name"] for c in st["children"]] == STEP_CHILDREN
+            assert [c["name"] for c in st["children"]] == STEP_CHILDREN[3:]
+        assert [st["event"]["args"]["ahead"] for st in steps] == [0, 1]
+        for st in steps:
             a = st["event"]["args"]
             assert a["tokens"] == 1 and a["n_active"] == 1
             assert {"model", "step_ms", "sample_ms", "queued"} <= set(a)
@@ -584,10 +588,13 @@ class TestOneSpanSystemTwoSinks:
             assert (a["pages_filled"] <= a["kv_pages_read"]
                     <= a["pages_filled"] + a["n_active"])
             assert "shards" not in a      # one device: not tensor-parallel
-        # admit and prefill are siblings in the first turn, in that order
+        # admit and prefill are siblings in the first turn, in that
+        # order, then the first step is queued: nothing is in flight to
+        # read.  Every later turn queues the next step, then reads.
         first = [c["name"] for c in turns[0]["children"]]
-        assert first[:3] == ["serve/admit", "serve/prefill",
-                             "serve/decode_step"]
+        assert first == ["serve/admit", "serve/prefill"] + STEP_CHILDREN[:3]
+        second = [c["name"] for c in turns[1]["children"]]
+        assert second == STEP_CHILDREN[:3] + ["serve/decode_step"]
         # the spans of one request share its identifier
         assert res.request_id > 0
         mine = lambda name: [
@@ -660,7 +667,7 @@ class TestOneSpanSystemTwoSinks:
         inside = [n for n, _, t0, d in spans
                   if n != "serve/decode_step" and step[2] <= t0
                   and t0 + d <= step[2] + step[3] and n in STEP_CHILDREN]
-        assert inside == STEP_CHILDREN
+        assert inside == STEP_CHILDREN[3:]      # the step that is read
 
     def test_instrumented_paths_are_bit_identical_in_every_mode(
             self, tiny_engine, profiler_session):
