@@ -22,6 +22,7 @@ import argparse
 import importlib.util
 import json
 import os
+import resource
 import sys
 import time
 from dataclasses import dataclass
@@ -104,13 +105,14 @@ class Cell:
     name: str
     chips: int
     seed: int
-    seconds: float
+    seconds: float                     # the whole window: windows x --seconds
     trace: bool
     config: dict
     mix: dict
     reference: Any
     devices: list
     rehearsal: bool = False
+    windows: int = 1                   # the mix's ``windows``
 
 
 @dataclass
@@ -189,6 +191,11 @@ def seeded_params(cell: Cell, out_shardings=None):
     return make(ref.seed_key(cell.seed))
 
 
+def host_peak_bytes() -> int:
+    """The most this process has held of the host's memory so far."""
+    return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
+
+
 def memory_bytes(devices) -> int:
     """Bytes held on the fullest chip: live arrays plus what the loaded
     programs keep reserved for their temporaries."""
@@ -241,7 +248,8 @@ def open_cell(workload: str, seed: int, seconds: float, trace: bool,
         config = {**config, **rehearsal.get("config", {})}
         config["program"] = {**config.get("program", {}),
                              **rehearsal.get("program", {})}
-        mix = {**mix, **rehearsal.get("mix", {})}
+        mix = traffic.check_mix({**mix, **rehearsal.get("mix", {})},
+                                "the rehearsal's mix")
 
     import jax
 
@@ -260,13 +268,17 @@ def open_cell(workload: str, seed: int, seconds: float, trace: bool,
     comp = Compilations()
     jax.monitoring.register_event_listener(comp.on_event)
     cache_dir = enable_compile_cache()
+    # the mix states how many times --seconds its cell's window lasts
+    windows = mix.get("windows", 1)
     cell = Cell(name=entry["name"], chips=entry["chips"], seed=seed,
-                seconds=seconds, trace=trace, config=config, mix=mix,
-                reference=load_reference(config), devices=devices,
-                rehearsal=rehearsal is not None)
+                seconds=seconds * windows, trace=trace, config=config,
+                mix=mix, reference=load_reference(config), devices=devices,
+                rehearsal=rehearsal is not None, windows=windows)
+    span = f"{cell.seconds}s" if windows == 1 \
+        else f"{windows} x {seconds}s = {cell.seconds}s"
     say(f"cell {cell.name}: config {config['name']}, traffic "
         f"{entry['traffic']}, {cell.chips} chip(s), seed {cell.seed}, window "
-        f"{cell.seconds}s, trace {int(cell.trace)}"
+        f"{span}, trace {int(cell.trace)}"
         + (" [REHEARSAL on " + dev.platform + ": no device metric]"
            if cell.rehearsal else ""))
     say(f"device {dev.platform} {dev.device_kind} x{len(devices)}; "
@@ -299,7 +311,7 @@ def main(argv, t_start: Optional[float] = None,
     state = runner.setup(cell, split)
     setup_s = time.time() - t_start
     split["import_and_start"] = round(split.pop("t_enter") - t_start, 3)
-    mem_setup = memory_bytes(devices)
+    mem_setup, host_setup = memory_bytes(devices), host_peak_bytes()
 
     comp.window_open = True
     try:
@@ -309,6 +321,7 @@ def main(argv, t_start: Optional[float] = None,
         comp.window_open = False
     counters = window.pop("counters", {})
     memory_peak = max(mem_setup, memory_bytes(devices))
+    host_window = host_peak_bytes()
     mem_detail = {str(d.id): d.memory_stats() for d in devices}
 
     served = runner.release(cell, state)       # frees the program's state
@@ -321,6 +334,8 @@ def main(argv, t_start: Optional[float] = None,
         f"{comp.hits}, misses {comp.misses}); compilations inside the "
         f"window: {comp.in_window}; peak memory {memory_peak} bytes")
     say(f"memory: {json.dumps(mem_detail)}")
+    say(f"host memory: peak {host_setup} bytes at set-up's end, {host_window} "
+        f"at the window's, {host_peak_bytes()} after the reference")
     say(f"window: {json.dumps(window.get('summary', {}))}")
     say(f"sizes: {json.dumps(runner.sizes(cell))}")
     correct = comp.in_window == 0 and bool(compared["numbers"])

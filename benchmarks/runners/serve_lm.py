@@ -119,7 +119,13 @@ def _annotate_engine(eng, tracer) -> None:
         setattr(eng, attr, wrapped)
 
 
-def window(cell, state, tracer) -> dict:
+def window(cell, state, tracer, may_be_drawn=None) -> dict:
+    """The measured window.  ``may_be_drawn(finished)``, where a runner
+    gives it, names those of the answers back so far that its comparison
+    may still draw: the echoed logits of the others are dropped as they
+    come back, so the host holds a few answers' logits however long the
+    window is.  Without it (a draw from the seed, which needs them all)
+    every answer is kept whole."""
     from deeplearning4j_tpu.serving import DeadlineExceededError
 
     eng, mix = state["eng"], cell.mix
@@ -132,19 +138,28 @@ def window(cell, state, tracer) -> dict:
     pending, finished, failed = {}, [], 0
     occupancy = []
     tokens_seen, stalled_since, longest_stall = before["tokens_out"], None, 0.0
+    # what a window of 1, 2, .. times --seconds would have read: the
+    # engine's count at each whole multiple inside the window
+    each_s = cell.seconds / cell.windows
+    tokens_at = []
 
     def collect(fut, req) -> int:
         """An answer that came back: 1 if it is a failure."""
         if fut.exception() is None:
             res = fut.result()
             finished.append((req, list(res.tokens), res.logits))
+            if may_be_drawn is not None:
+                keep = {id(f) for f in may_be_drawn(finished)}
+                finished[:] = [f if id(f) in keep else (f[0], f[1], None)
+                               for f in finished]
             return 0
         print(f"bench: request {req.index} failed: {fut.exception()!r}",
               flush=True)
         return 1
 
     t0 = time.perf_counter()
-    deadline = t0 + cell.seconds
+    marks = [t0 + each_s * k for k in range(1, cell.windows + 1)]
+    deadline = marks[-1]
     close_at = eng.clock() + cell.seconds       # on the engine's own clock
     for _ in range(int(mix["clients"])):
         req = next(stream)
@@ -153,7 +168,8 @@ def window(cell, state, tracer) -> dict:
         now = time.perf_counter()
         if now >= deadline:
             break
-        done, _ = wait(list(pending), timeout=min(POLL_S, deadline - now),
+        stop = marks[len(tokens_at)]      # the next multiple, or the close
+        done, _ = wait(list(pending), timeout=min(POLL_S, stop - now),
                        return_when=FIRST_COMPLETED)
         now = time.perf_counter()
         if now >= deadline:   # woken late: the engine may have cut or expired
@@ -165,6 +181,8 @@ def window(cell, state, tracer) -> dict:
         if not done:          # a periodic wake-up: one at a completion would
             occupancy.append(eng.metrics.active_slots.value())  # see a freed slot
         seen = counter("tokens_out")
+        if now >= stop:
+            tokens_at.append(seen - before["tokens_out"])
         if seen != tokens_seen:
             tokens_seen, stalled_since = seen, None
         else:
@@ -189,6 +207,7 @@ def window(cell, state, tracer) -> dict:
     window_s = t_last - t0              # to the last answer that came back
 
     after = {k: counter(k) for k in before}
+    tokens_out = after["tokens_out"] - before["tokens_out"]
     state["finished"] = finished
     tokens = sum(len(f[1]) for f in finished)
     return {
@@ -206,7 +225,8 @@ def window(cell, state, tracer) -> dict:
             "unresolved_after_the_close": len(left),
             "decode_steps": after["decode_steps"] - before["decode_steps"],
             "prefills": after["prefills"] - before["prefills"],
-            "tokens_out_by_engine": after["tokens_out"] - before["tokens_out"],
+            "tokens_out_by_engine": tokens_out,
+            "tokens_out_at_window": tokens_at + [tokens_out],
             "longest_no_progress_s": longest_stall,
             "executables_before_and_after": [state["executables"],
                                              eng.compile_cache_size()],

@@ -46,7 +46,8 @@ def window(cell, state, tracer) -> dict:
     """``serve_lm.window`` with the engine's answers also kept whole (the
     loop submits its requests one after another from one thread, so the
     n-th call is the stream's n-th request); of the requests the
-    comparison will not draw only the tokens are kept."""
+    comparison will not draw only the tokens are kept, from the moment
+    they come back."""
     eng = state["eng"]
     results = {}
     submit = eng.generate_async
@@ -62,9 +63,18 @@ def window(cell, state, tracer) -> dict:
         fut.add_done_callback(keep)
         return fut
 
+    def may_be_drawn(finished):
+        """``_sample`` of the answers back so far: one it leaves out now
+        it leaves out at the close too (a fixed order, a growing set),
+        so its logits and rows can go at once."""
+        keep = [f for kind in _sample(cell, finished) for f in kind]
+        for n in {f[0].index for f in finished} - {f[0].index for f in keep}:
+            results.pop(n, None)
+        return keep
+
     eng.generate_async = keeping
     try:
-        out = serve_lm.window(cell, state, tracer)
+        out = serve_lm.window(cell, state, tracer, may_be_drawn)
     finally:
         del eng.generate_async
     finished = [(req, toks, lg, *results.get(req.index, (None, None)))

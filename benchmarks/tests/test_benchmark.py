@@ -3,12 +3,14 @@
     JAX_PLATFORMS=cpu python -m pytest benchmarks/tests -q
 """
 
+import copy
 import json
 import os
 import re
 import statistics
 import subprocess
 import sys
+import types
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -74,6 +76,76 @@ def test_a_mix_that_is_not_a_closed_loop_is_refused():
     for loop in ("open", None):
         with pytest.raises(ValueError):
             traffic.check_mix({"kind": "requests", "loop": loop}, "x")
+
+
+# -- how long a cell's window is, as the mix's data --------------------------------
+
+@pytest.mark.parametrize("bad", [0, 1.5, "2", True, -1], ids=repr)
+def test_a_mix_whose_windows_are_no_whole_number_from_1_up_is_refused(bad):
+    with pytest.raises(ValueError) as e:
+        traffic.check_mix({"kind": "train", "windows": bad},
+                          "traffic/made-up.json")
+    assert "traffic/made-up.json" in str(e.value) and "windows" in str(e.value)
+    assert repr(bad) in str(e.value)
+
+
+@pytest.mark.parametrize("kind", ["train", "requests"])
+def test_a_mix_may_state_its_windows(kind):
+    mix = {"kind": kind, "loop": "closed", "windows": 3}
+    assert traffic.check_mix(mix, "x") is mix
+
+
+MIXES = sorted(f[:-len(".json")] for f in
+               os.listdir(os.path.join(ROOT, "benchmarks", "traffic")))
+
+
+@pytest.mark.parametrize("name", MIXES)
+def test_a_traffic_file_reads_as_it_is_written(name):
+    """``load_mix`` adds nothing: a file without the keys has none, and
+    the harness then runs one window of ``--seconds``, as before there
+    was the key."""
+    with open(os.path.join(ROOT, "benchmarks", "traffic", name + ".json")) as f:
+        written = json.load(f)
+    assert traffic.load_mix(name) == written
+    assert written.get("windows", 1) >= 1
+
+
+def _rehearsal_with(cell, **mix):
+    """The cell's tiny sizes with ``mix`` laid over its traffic."""
+    reh = rehearsal.CELLS[cell]
+    return {**reh, "mix": {**reh["mix"], **mix}}
+
+
+def _opened(cell, seconds, **mix):
+    return harness.open_cell(cell, 4294967311, seconds, False,
+                             _rehearsal_with(cell, **mix))[1]
+
+
+def test_open_cell_multiplies_the_commands_seconds_by_the_mixs_windows(capsys):
+    cell = _opened("gpt2-large.chat-closed8", 51.0, windows=3)
+    assert (cell.seconds, cell.windows) == (153.0, 3)
+    assert "window 3 x 51.0s = 153.0s, trace 0" in capsys.readouterr().out
+    cell = _opened("gpt2-medium.train-1k", 51.0)
+    assert (cell.seconds, cell.windows) == (51.0, 1)
+    assert "window 51.0s, trace 0" in capsys.readouterr().out
+    for bad in (0, 1.5, "2", True):
+        with pytest.raises(ValueError):
+            _opened("gpt2-medium.train-1k", 51.0, windows=bad)
+
+
+def test_a_longer_window_is_traced_for_the_same_three_seconds(monkeypatch):
+    import jax
+    calls = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda d: calls.append(("start", d)))
+    monkeypatch.setattr(jax.profiler, "stop_trace",
+                        lambda: calls.append(("stop",)))
+    tr = harness.Tracer(True, "d")
+    for t in (0.0, 9.9, 10.0, 12.9, 13.0, 30.0):
+        tr.tick(t, 102.0)
+        assert tr.stopped == (t >= 13.0)
+    tr.stop()
+    assert calls == [("start", "d"), ("stop",)] and tr.started_at == 10.0
 
 
 def test_train_batches_repeat_for_a_seed_and_rows_differ():
@@ -205,72 +277,135 @@ def test_hlo_event_names():
 
 # -- BENCHMARK.json: everything named is found as a file -------------------------------
 
-def test_manifest_keeps_to_the_contract():
-    assert set(MANIFEST) == {"command", "paths", "run_seconds", "configs",
+def check_manifest(manifest):
+    assert set(manifest) == {"command", "paths", "run_seconds", "configs",
                              "workloads", "end_to_end", "per_layer"}
-    assert MANIFEST["paths"] == ["benchmarks"] and 1 <= MANIFEST["run_seconds"] <= 51
+    assert manifest["paths"] == ["benchmarks"] and 1 <= manifest["run_seconds"] <= 51
     names = [x["name"] for sec in ("configs", "workloads", "end_to_end",
-                                   "per_layer") for x in MANIFEST[sec]]
+                                   "per_layer") for x in manifest[sec]]
     assert all(NAME.match(n) for n in names)
     for sec in ("configs", "workloads"):
-        ns = [x["name"] for x in MANIFEST[sec]]
+        ns = [x["name"] for x in manifest[sec]]
         assert len(ns) == len(set(ns))
-    metrics = [m["name"] for m in MANIFEST["end_to_end"] + MANIFEST["per_layer"]]
+    metrics = [m["name"] for m in manifest["end_to_end"] + manifest["per_layer"]]
     assert len(metrics) == len(set(metrics))
-    for m in MANIFEST["end_to_end"] + MANIFEST["per_layer"]:
+    for m in manifest["end_to_end"] + manifest["per_layer"]:
         assert UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
-    for m in MANIFEST["end_to_end"]:
+    for m in manifest["end_to_end"]:
         assert set(m) - {"workloads"} == {"name", "unit", "better", "bound", "source"}
         assert m["source"] in ("host_clock", "device_trace") and 0.01 <= m["bound"] <= 0.1
-    four = [w for w in MANIFEST["workloads"] if w["chips"] == 4]
-    assert len(four) <= max(1, len(MANIFEST["workloads"]) // 4)
-    for w in MANIFEST["workloads"]:
+    four = [w for w in manifest["workloads"] if w["chips"] == 4]
+    assert len(four) <= max(1, len(manifest["workloads"]) // 4)
+    for w in manifest["workloads"]:
         assert w["chips"] in (1, 4) and len(w["why"]) <= 200
-    assert "setup_s" in [m["name"] for m in MANIFEST["end_to_end"]]
+    assert "setup_s" in [m["name"] for m in manifest["end_to_end"]]
 
 
-@pytest.mark.parametrize("config", [c["name"] for c in MANIFEST["configs"]])
-def test_configuration_files(config):
-    entry = harness.find(MANIFEST["configs"], config, "config")
+def test_manifest_keeps_to_the_contract():
+    check_manifest(MANIFEST)
+
+
+def check_configuration(manifest, config, cfg):
+    entry = harness.find(manifest["configs"], config, "config")
     assert entry["file"] == f"benchmarks/configs/{config}.json"
-    cfg = harness.load_config(config)
     assert cfg["name"] == config and cfg["source"] == entry["source"]
     assert cfg["reduced"] == entry["reduced"]
     assert os.path.exists(os.path.join(ROOT, "benchmarks", "runners",
                                        cfg["runner"] + ".py"))
     ref = harness.load_reference(cfg)       # the plain reference, beside it
     assert ref.CONTROL_PRECISION in ref.PRECISIONS
-    assert cfg["n_inner"] == 4 * cfg["n_embd"]      # no width is cut
+    if "n_inner" in cfg:      # the GPT-2 family's own key: no width is cut
+        assert cfg["n_inner"] == 4 * cfg["n_embd"]
     assert cfg["limits"] and all(v > 0 for v in cfg["limits"].values())
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in MANIFEST["configs"]])
+def test_configuration_files(config):
+    check_configuration(MANIFEST, config, harness.load_config(config))
+
+
+def check_cell(manifest, cell, mix):
+    w = harness.find(manifest["workloads"], cell, "workload")
+    harness.find(manifest["configs"], w["config"], "config")
+    assert mix["kind"] in ("train", "requests")
+    e2e = [m["name"] for m in harness.metrics_of_cell(manifest, "end_to_end", cell)]
+    assert "setup_s" in e2e and len(e2e) >= 2
+    assert harness.metrics_of_cell(manifest, "per_layer", cell)
 
 
 @pytest.mark.parametrize("cell", [w["name"] for w in MANIFEST["workloads"]])
 def test_cell_files_and_what_each_cell_reports(cell):
     w = harness.find(MANIFEST["workloads"], cell, "workload")
-    harness.find(MANIFEST["configs"], w["config"], "config")
-    mix = traffic.load_mix(w["traffic"])
-    assert mix["kind"] in ("train", "requests")
-    e2e = [m["name"] for m in harness.metrics_of_cell(MANIFEST, "end_to_end", cell)]
-    assert "setup_s" in e2e and len(e2e) >= 2
-    assert harness.metrics_of_cell(MANIFEST, "per_layer", cell)
+    check_cell(MANIFEST, cell, traffic.load_mix(w["traffic"]))
 
 
-@pytest.mark.parametrize("metric", [m["name"] for m in MANIFEST["per_layer"]])
-def test_per_layer_metric_files(metric):
-    m = harness.find(MANIFEST["per_layer"], metric, "metric")
-    reader = harness.load_layer_metric(metric)
+def check_per_layer_metric(manifest, metric, reader):
+    m = harness.find(manifest["per_layer"], metric, "metric")
     assert (reader.NAME, reader.UNIT, reader.LAYER, reader.MOVES, reader.SOURCE) \
         == (m["name"], m["unit"], m["layer"], m["moves"], m["source"])
     assert m["source"] in ("device_trace", "program_span", "program_counter",
                            "host_clock")
-    cells = m.get("workloads") or [w["name"] for w in MANIFEST["workloads"]]
+    cells = m.get("workloads") or [w["name"] for w in manifest["workloads"]]
     for cell in cells:
         reported = [x["name"] for x in
-                    harness.metrics_of_cell(MANIFEST, "end_to_end", cell)]
+                    harness.metrics_of_cell(manifest, "end_to_end", cell)]
         assert m["moves"] in reported, (metric, cell)
     # a reader that finds nothing to read returns nothing
     nothing = harness.Observed(cell=None, window={}, counters={})
     assert reader.read(nothing) is None
+
+
+@pytest.mark.parametrize("metric", [m["name"] for m in MANIFEST["per_layer"]])
+def test_per_layer_metric_files(metric):
+    check_per_layer_metric(MANIFEST, metric, harness.load_layer_metric(metric))
+
+
+def test_a_later_pr_can_append_a_configuration_a_cell_and_a_metric():
+    """The guard against tests that pin the END of the manifest's lists
+    (three of this directory failed by design for it, PRs 27 to 35): a
+    made-up configuration, cell and per-layer metric are appended to a
+    copy of the manifest, the cell also to every list that holds the
+    newest served cell, and every assertion this directory makes about
+    the manifest has to hold on the copy as it holds on the file."""
+    from benchmarks.tests import test_keye_vl_2_30b_a3b as keye
+    from benchmarks.tests import test_kimi_k2_instruct as kimi
+
+    later = copy.deepcopy(MANIFEST)
+    name, cell = "made-up-1b", "made-up-1b.notes-closed4"
+    source = "https://example.org/made-up-1b/blob/main/config.json"
+    later["configs"].append({
+        "name": name, "source": source, "reduced": ["num_hidden_layers"],
+        "file": f"benchmarks/configs/{name}.json",
+        "why": "made up: the configuration a later PR appends"})
+    later["workloads"].append({
+        "name": cell, "config": name, "traffic": "notes-closed4", "chips": 1,
+        "why": "made up: the cell a later PR appends"})
+    for m in later["end_to_end"] + later["per_layer"]:
+        if keye.CELL in m.get("workloads", []) or m["name"] in kimi.READERS:
+            m["workloads"].append(cell)
+    later["per_layer"].append({
+        "name": "made_up_share", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "decode scheduler",
+        "moves": "serve_tokens_per_s", "workloads": [cell]})
+
+    check_manifest(later)
+    keye.check_this_configurations_entries(later)
+    kimi.check_this_configurations_entries(later)
+    for entry in later["configs"] + later["workloads"]:
+        keye.test_every_why_and_source_is_one_printable_ascii_line(entry)
+    # the made-up configuration's file: the newest one's, renamed; it has
+    # no n_inner, as no configuration outside the GPT-2 family has
+    cfg = {**keye.CONFIG, "name": name, "source": source,
+           "reduced": ["num_hidden_layers"]}
+    assert "n_inner" not in cfg
+    check_configuration(later, name, cfg)
+    check_cell(later, cell, {"kind": "requests", "loop": "closed"})
+    check_per_layer_metric(later, "made_up_share", types.SimpleNamespace(
+        NAME="made_up_share", UNIT="%", LAYER="decode scheduler",
+        MOVES="serve_tokens_per_s", SOURCE="program_counter",
+        read=lambda observed: None))
+    for metric in [m["name"] for m in MANIFEST["per_layer"]]:
+        check_per_layer_metric(later, metric, harness.load_layer_metric(metric))
 
 
 def test_nothing_under_benchmarks_imports_bench_or_scripts():
@@ -307,6 +442,27 @@ def test_rehearsal_drives_a_run_and_prints_no_device_metric(cell, trace, capsys)
             harness.metrics_of_cell(MANIFEST, "end_to_end", cell)}
     assert any("compilations inside the window: 0" in ln for ln in lines)
     assert sum(ln.startswith("bench: compared: ") for ln in lines) >= 2
+
+
+@pytest.mark.parametrize("cell,seconds", [("gpt2-large.chat-closed8", "1.5"),
+                                          ("gpt2-medium.train-1k", "1")])
+def test_rehearsal_of_two_windows_measures_twice_the_seconds(cell, seconds,
+                                                             capsys):
+    rc = harness.main(["--workload", cell, "--seed", "4294967311",
+                       "--seconds", seconds, "--trace", "0"],
+                      rehearsal=_rehearsal_with(cell, windows=2))
+    result, lines = last_line(capsys)
+    assert rc == 0 and result["correct"] is True and result["failed"] == 0
+    whole = 2 * float(seconds)
+    said = next(ln for ln in lines if ln.startswith("bench: cell "))
+    assert f"window 2 x {float(seconds)}s = {whole}s" in said
+    summary = json.loads(next(ln for ln in lines if ln.startswith(
+        "bench: window: "))[len("bench: window: "):])
+    assert summary["window_s"] >= whole - 1e-6
+    if "train" not in cell:
+        at = summary["tokens_out_at_window"]
+        assert len(at) == 2 and 0 < at[0] < at[1]
+        assert at[1] == summary["tokens_out_by_engine"]
 
 
 def test_the_command_refuses_without_the_chip():

@@ -127,29 +127,42 @@ def test_a_configurations_file_says_what_its_entry_says(entry):
     assert list(cfg.get("reduced", [])) == list(entry["reduced"])
 
 
-def test_the_manifest_gained_one_configuration_one_cell_and_two_metrics():
-    entry = harness.find(MANIFEST["configs"], NAME, "config")
-    assert entry == MANIFEST["configs"][-1]
+def check_this_configurations_entries(manifest):
+    """What PR 32 added, found by name.  Where an entry stands in its list
+    is not this configuration's to say: a later PR appends to all four."""
+    entry = harness.find(manifest["configs"], NAME, "config")
     assert entry["file"] == f"benchmarks/configs/{NAME}.json"
-    cell = harness.find(MANIFEST["workloads"], CELL, "workload")
-    assert cell == MANIFEST["workloads"][-1]
+    cell = harness.find(manifest["workloads"], CELL, "workload")
     assert (cell["config"], cell["traffic"], cell["chips"]) == \
         (NAME, "doc-closed16", 1)
-    assert [m["name"] for m in MANIFEST["per_layer"][-2:]] == [
-        "sparse_rows_read_share", "sparse_decode_bytes_roofline"]
-    for m in MANIFEST["per_layer"][-2:]:
-        assert m["workloads"] == [CELL] and m["moves"] == "serve_tokens_per_s"
+    own = ["sparse_rows_read_share", "sparse_decode_bytes_roofline"]
+    assert [m["name"] for m in manifest["per_layer"]
+            if m["name"] in own] == own              # in this order
+    for name in own:
+        m = harness.find(manifest["per_layer"], name, "metric")
+        assert CELL in m["workloads"] and m["moves"] == "serve_tokens_per_s"
     reported = {m["name"] for section in ("end_to_end", "per_layer")
-                for m in harness.metrics_of_cell(MANIFEST, section, CELL)}
-    assert reported == {
+                for m in harness.metrics_of_cell(manifest, section, CELL)}
+    assert reported >= {
         "serve_tokens_per_s", "setup_s", "prefill_time_share",
         "slot_occupancy.closed", "device_idle_share.closed",
         "decode_host_ms.closed", "queue_wait_ms.closed",
         "kv_pages_filled_share.closed", "expert_load_max_over_mean",
         "sparse_rows_read_share", "sparse_decode_bytes_roofline"}
-    for m in MANIFEST["end_to_end"] + MANIFEST["per_layer"]:
-        if CELL in m.get("workloads", []):
-            assert m["workloads"][-1] == CELL          # appended, at the end
+
+
+def test_the_manifest_holds_one_configuration_one_cell_and_two_metrics():
+    check_this_configurations_entries(MANIFEST)
+
+
+def test_the_cells_why_states_the_window_its_traffic_file_gives():
+    """The window is the traffic file's ``windows`` times ``run_seconds``
+    (PR 36: one window's rate followed the order of the seed's sizes)."""
+    from benchmarks import traffic
+    windows = traffic.load_mix("doc-closed16")["windows"]
+    cell = harness.find(MANIFEST["workloads"], CELL, "workload")
+    assert windows > 1
+    assert f"window {windows} x {MANIFEST['run_seconds']} s" in cell["why"]
 
 
 # -- the file ------------------------------------------------------------------
@@ -367,6 +380,33 @@ def test_the_fp8_control_fails_and_the_program_passes():
                             control_precision="bfloat16")
     for name in ("router_flip_share", "served_logit_mse", "index_miss_share"):
         assert 0 < stated["control"][name] <= limits[name], name
+
+
+def test_the_window_keeps_the_logits_of_the_answers_it_may_draw_and_no_others(
+        monkeypatch):
+    _, cell, _ = harness.open_cell(CELL, 2147483659, 3.0, False, REHEARSAL)
+    runner = harness.load_runner(cell.config["runner"])
+    n, sample, held = cell.mix["compare_requests"], runner._sample, []
+
+    def counting(cell, finished):
+        held.append(sum(f[2] is not None for f in finished))
+        return sample(cell, finished)
+
+    monkeypatch.setattr(runner, "_sample", counting)
+    state = runner.setup(cell, {})
+    runner.window(cell, state, harness.Tracer(False, ""))
+    finished = state["finished"]
+    runner.release(cell, state)
+    greedy = [f for f in finished if f[0].greedy and f[1]]
+    assert len(greedy) > n                 # there was something to drop
+    assert max(held) <= n + 1              # the drawable and the newcomer
+    # and the draw is what it is with every answer kept: most tokens first
+    most = sorted(greedy, key=lambda f: (-len(f[1]), f[0].index))[:n]
+    assert [f[0].index for f in sample(cell, finished)[0]] == \
+        [f[0].index for f in most]
+    for f in greedy:
+        whole = all(x is not None and len(x) == len(f[1]) for x in f[2:])
+        assert whole == any(f is m for m in most)
 
 
 def _breaks():

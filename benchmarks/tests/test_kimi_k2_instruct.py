@@ -178,17 +178,29 @@ def test_step_bytes_on_a_worked_example():
     assert decode_bytes.latent_row_bytes(CONFIG) == 8 * 576 * 2
 
 
+READERS = ("expert_picks_held_share", "expert_load_max_over_mean",
+           "decode_bytes_roofline")
+
+
 def test_the_readers_return_nothing_when_given_nothing():
     observed = harness.Observed(
         cell=harness.Cell(name=CELL, chips=1, seed=1, seconds=1.0,
                           trace=True, config=CONFIG, mix={}, reference=None,
                           devices=[]),
         window={}, counters={})
-    for name in ("expert_picks_held_share", "expert_load_max_over_mean",
-                 "decode_bytes_roofline"):
+    for name in READERS:
         assert harness.load_layer_metric(name).read(observed) is None
-        entry = harness.find(MANIFEST["per_layer"], name, "metric")
-        assert entry["workloads"] == [CELL]
+    check_this_configurations_entries(MANIFEST)
+
+
+def check_this_configurations_entries(manifest):
+    """What PR 27 added, found by name: its cell is IN each of its
+    metrics' lists, which a later configuration may join."""
+    cell = harness.find(manifest["workloads"], CELL, "workload")
+    assert (cell["config"], cell["chips"]) == (CONFIG["name"], 1)
+    for name in READERS:
+        entry = harness.find(manifest["per_layer"], name, "metric")
+        assert CELL in entry["workloads"]
         assert entry["moves"] == "serve_tokens_per_s"
 
 

@@ -12,6 +12,10 @@ Two kinds of mix:
               deals them to the clients of a closed loop.  So two seeds
               do the same work in another order.  An open loop (arrivals
               at a rate, bursts) comes with the cell that first needs it.
+
+Either kind may state ``windows`` (a whole number from 1 up, default 1):
+how many times the command's ``--seconds`` the cell's one window lasts,
+for a mix whose rate over one follows the order of its sizes.
 """
 
 from __future__ import annotations
@@ -39,6 +43,10 @@ def check_mix(mix: dict, path: str) -> dict:
         raise ValueError(f"{path}: kind must be 'train' or 'requests'")
     if mix["kind"] == "requests" and mix.get("loop") != "closed":
         raise ValueError(f"{path}: loop must be 'closed'")
+    windows = mix.get("windows", 1)
+    if type(windows) is not int or windows < 1:
+        raise ValueError(f"{path}: windows must be a whole number from 1 up, "
+                         f"not {windows!r}")
     return mix
 
 
