@@ -13,7 +13,11 @@ and the grouped-query block with a learned sparse selection
 free ``head_dim``, RMSNorm on each head's query and key, plain rotary
 over three position streams, an indexer that scores the context and
 keeps ``index_topk`` rows of it, every layer an expert layer behind a
-softmax router.
+softmax router; and the block whose layers are of TWO kinds
+(``models/linear_gqa.py``): gated grouped-query attention without
+positions at the ``gqa_layers`` indices, a gated delta-rule linear
+attention with a per-slot recurrent state everywhere else, every layer an
+expert layer with a shared expert.
 
 ``from_config`` reads a configuration file's keys (the published
 ``config.json`` names of each family), so a model is a data file and
@@ -26,10 +30,13 @@ import dataclasses
 import math
 from typing import Any, Dict
 
-BLOCKS = ("gpt2", "latent_moe", "sparse_gqa")
+BLOCKS = ("gpt2", "latent_moe", "sparse_gqa", "linear_gqa")
 #: the blocks of the expert family (``models/<block>.py``): served through
 #: one decode-program builder, not trained yet
-EXPERT_BLOCKS = ("latent_moe", "sparse_gqa")
+EXPERT_BLOCKS = ("latent_moe", "sparse_gqa", "linear_gqa")
+#: what a layer remembers (``LMArch.layer_types``): rows in the paged pools
+#: a token, or a per-slot recurrent state
+LAYER_TYPES = ("gqa", "linear")
 ROUTERS = ("noaux_tc", "softmax_topk")
 
 
@@ -63,6 +70,11 @@ class LMArch:
     index_n_heads: int = 0         # the indexer's query heads ...
     index_head_dim: int = 0        # ... over ONE index key a token
     index_topk: int = 0            # context rows a query keeps
+    # -- layers of two kinds (linear_gqa) ---------------------------------
+    layer_types: tuple = ()        # per layer, of LAYER_TYPES
+    linear_n_heads: int = 0        # the linear layers' heads (q, k and v) ...
+    linear_head_dim: int = 0       # ... of this width: state [dim, dim] a head
+    conv_kernel: int = 0           # taps of their causal depthwise convolution
     # -- experts ------------------------------------------------------------
     n_dense_layers: int = 0        # leading layers with a dense feed-forward
     moe_d_ff: int = 0
@@ -106,6 +118,22 @@ class LMArch:
                     f"head_dim / 2 = {self.head_dim // 2}")
             if self.n_dense_layers:
                 raise ValueError("every sparse_gqa layer is an expert layer")
+        if self.block == "linear_gqa":
+            for k in ("n_kv_heads", "head_dim", "linear_n_heads",
+                      "linear_head_dim"):
+                if getattr(self, k) < 1:
+                    raise ValueError(f"linear_gqa needs {k} >= 1")
+            if self.conv_kernel < 2:
+                raise ValueError("linear_gqa needs conv_kernel >= 2")
+            if self.n_heads % self.n_kv_heads:
+                raise ValueError("n_heads must be a multiple of n_kv_heads")
+            if (len(self.layer_types) != self.n_layers
+                    or any(t not in LAYER_TYPES for t in self.layer_types)):
+                raise ValueError(
+                    f"layer_types must name one of {LAYER_TYPES} for each of "
+                    f"the {self.n_layers} layers, got {self.layer_types!r}")
+            if self.n_dense_layers:
+                raise ValueError("every linear_gqa layer is an expert layer")
         if self.router not in ROUTERS:
             raise ValueError(f"router must be one of {ROUTERS}, got "
                              f"{self.router!r}")
@@ -174,8 +202,11 @@ class LMArch:
         ``num_key_value_heads`` is the grouped-query block over a
         learned selection (Qwen3-MoE's names plus the indexer's):
         ``num_experts`` is the router's width, and ``n_routed_experts``,
-        when stated, the count held from ``first_expert`` on.  ``over``
-        replaces any field."""
+        when stated, the count held from ``first_expert`` on.  One with
+        ``linear_attn_config`` beside ``gqa_layers`` is the block of two
+        layer kinds (DeepSeek-V3's expert names, as the first family):
+        ``gqa_layers`` is kept as published and read up to
+        ``num_hidden_layers``.  ``over`` replaces any field."""
         if "n_embd" in cfg:
             kw = dict(vocab_size=cfg["vocab_size"], n_layers=cfg["n_layer"],
                       d_model=cfg["n_embd"], n_heads=cfg["n_head"],
@@ -274,12 +305,59 @@ class LMArch:
                     f"config key rope_scaling={rs!r} is not expressible by "
                     "the sparse_gqa block (supported: plain rotary, "
                     "rope_type default)")
+        elif "linear_attn_config" in cfg and "gqa_layers" in cfg:
+            la = cfg["linear_attn_config"]
+            n_layers = int(cfg["num_hidden_layers"])
+            held = int(cfg["n_routed_experts"])
+            gqa = {int(i) for i in cfg["gqa_layers"]}
+            kw = dict(
+                block="linear_gqa", vocab_size=cfg["vocab_size"],
+                n_layers=n_layers, d_model=cfg["hidden_size"],
+                n_heads=cfg["num_attention_heads"],
+                n_kv_heads=cfg["num_key_value_heads"],
+                head_dim=int(cfg.get("head_dim") or cfg["hidden_size"]
+                             // cfg["num_attention_heads"]),
+                d_ff=cfg.get("intermediate_size") or 0,
+                max_len=cfg["max_position_embeddings"],
+                rms_eps=cfg.get("rms_norm_eps", 1e-6),
+                # the published list, read up to the depth held
+                layer_types=tuple("gqa" if i in gqa else "linear"
+                                  for i in range(n_layers)),
+                linear_n_heads=la["num_heads"],
+                linear_head_dim=la["head_dim"],
+                conv_kernel=la["short_conv_kernel_size"],
+                moe_d_ff=cfg["moe_intermediate_size"],
+                n_experts=int(cfg.get("n_routed_experts_published", held)),
+                experts_held=held,
+                first_expert=int(cfg.get("first_expert", 0)),
+                experts_per_token=cfg["num_experts_per_tok"],
+                n_shared_experts=cfg.get("n_shared_experts", 0),
+                routed_scaling_factor=cfg.get("routed_scaling_factor", 1.0),
+                init_std=cfg.get("initializer_range", 0.02))
+            unsupported = {
+                "use_rope": (False,), "use_gqa_gate": (True,),
+                "kda_use_full_proj": (False,),
+                "kda_allow_neg_eigval": (True,),
+                "first_k_dense_replace": (0,), "norm_topk_prob": (True,),
+                "tie_word_embeddings": (False,)}
+            for k, ok in unsupported.items():
+                if k in cfg and cfg[k] not in ok:
+                    raise ValueError(
+                        f"config key {k}={cfg[k]!r} is not expressible by "
+                        f"the linear_gqa block (supported: {ok})")
+            if la.get("num_kv_heads") not in (None, la["num_heads"]):
+                raise ValueError(
+                    "config key linear_attn_config.num_kv_heads="
+                    f"{la['num_kv_heads']!r} is not expressible by the "
+                    "linear_gqa block (supported: as many key and value "
+                    "heads as query heads)")
         else:
             raise ValueError(
                 "configuration names neither a GPT-2 block (n_embd), a "
-                "latent/expert block (kv_lora_rank) nor a grouped-query "
+                "latent/expert block (kv_lora_rank), a grouped-query "
                 "block over a learned selection (sa_config with "
-                "num_key_value_heads)")
+                "num_key_value_heads) nor a block of linear-attention and "
+                "grouped-query layers (linear_attn_config with gqa_layers)")
         kw.update(over)
         return cls(**kw)
 

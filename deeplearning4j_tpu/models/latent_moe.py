@@ -410,6 +410,21 @@ class CachedAttention(NamedTuple):
     # what the step reads of a slot's window
     # (``ops/kv_cache.DecodeProgram.held_pages``)
     held_pages: Optional[bool] = False
+    # layers of two kinds (models/linear_gqa.py).  ``kinds`` says of each
+    # layer whether it leaves rows in the pools ("pool": the callables
+    # above, with its index among the pool layers) or keeps per-slot state
+    # ("state"); None = every layer has rows in the pools.  ``slot_state``
+    # names a state layer's arrays (``DecodeProgram.slot_state``).
+    # ``state_chunk(p, h, state, offset, n_real) -> (att [T, .], state,
+    # extra)``: one slot's ``T`` new rows from that slot's own ``state``
+    # (from zero where ``offset`` is 0), the state returned as the last
+    # REAL row leaves it.  ``state_step(p, h, state, active) -> (att
+    # [S, .], state, extra)``: one row a slot over ``state`` [S, ...],
+    # which a slot that is not active keeps as it was.
+    kinds: Optional[tuple] = None
+    slot_state: tuple = ()
+    state_chunk: Optional[Callable] = None
+    state_step: Optional[Callable] = None
 
 
 def mla_attention(arch: LMArch, page_size: int, pps: int) -> CachedAttention:
@@ -477,8 +492,16 @@ def expert_decode_program(arch: LMArch, page_size: int,
     pool, so the donated pools are updated in place and never copied.
     Each returns one value more than the engine's contract names, the
     aux tree of ``_join_aux``; ``step_multi`` fuses steps and sampling.
+
+    Where the attention names layers that keep per-slot state
+    (``CachedAttention.kinds``), that rule holds for the pools only: the
+    state rides beside the pools after the first
+    (``ops/kv_cache.PoolsAndState``), a chunk takes its slot's part (the
+    slot's index is ``prefill`` / ``prefill_at``'s last argument) and
+    puts back what its last real row left, a step replaces every active
+    slot's; both in place, the arrays being donated with the pools.
     """
-    from ..ops.kv_cache import SCRATCH_PAGE, DecodeProgram
+    from ..ops.kv_cache import SCRATCH_PAGE, DecodeProgram, PoolsAndState
 
     if max_len is None:
         max_len = (arch.max_len // page_size) * page_size
@@ -490,6 +513,20 @@ def expert_decode_program(arch: LMArch, page_size: int,
     pps = L // page_size
     att = attention(arch, page_size, pps)
     n_layers = arch.n_layers
+    kinds = att.kinds or ("pool",) * n_layers
+    # a layer's index among those of its kind
+    nth = [kinds[:i].count(k) for i, k in enumerate(kinds)]
+
+    def split(first, rest):
+        """(the pools, the per-slot state) of what a call was handed."""
+        if att.slot_state:
+            return (first,) + tuple(rest.pools), rest.state
+        return (first,) + tuple(rest), ()
+
+    def joined(first, rest, state):
+        """What a call hands back where it took ``first`` and ``rest``."""
+        return first, (PoolsAndState(rest, tuple(state)) if att.slot_state
+                       else rest)
 
     def write_rows(pools, page_idx, in_page, rows_all):
         """Every layer's new rows into the pools, after the last read of
@@ -515,12 +552,15 @@ def expert_decode_program(arch: LMArch, page_size: int,
         return aux
 
     def prefill_at(params, first, rest, page_table_row, tokens, n_real,
-                   offset):
+                   offset, slot=None):
         """One slot's rows at positions offset..offset+Tb-1 (the first
         ``n_real`` real) attending over the ``offset`` rows the pools
         already hold and over themselves; their cache rows are written
-        by one scatter a pool; the last real position's logits."""
-        pools = (first,) + tuple(rest)
+        by one scatter a pool; the last real position's logits.
+        ``slot``: whose per-slot state the chunk carries on (a program
+        that keeps any)."""
+        pools, state = split(first, rest)
+        state = list(state)
         tb = tokens.shape[0]
         pos = offset + jnp.arange(tb, dtype=jnp.int32)
         at = jnp.clip(pos, 0, L - 1)
@@ -529,11 +569,18 @@ def expert_decode_program(arch: LMArch, page_size: int,
         h = _embed(params, tokens)
         rows_all, picks, stats, extras = [], [], [], []
         for i, p in enumerate(params["blocks"]):
-            q, rows = att.project(p, h, rope)
-            a, extra = att.attend_chunk(p, pools, i, page_table_row, q, rows,
-                                        offset, n_real)
+            if kinds[i] == "state":
+                a, new, extra = att.state_chunk(
+                    p, h, tuple(s[slot] for s in state[nth[i]]), offset,
+                    n_real)
+                state[nth[i]] = tuple(s.at[slot].set(n) for s, n
+                                      in zip(state[nth[i]], new))
+            else:
+                q, rows = att.project(p, h, rope)
+                a, extra = att.attend_chunk(p, pools, nth[i], page_table_row,
+                                            q, rows, offset, n_real)
+                rows_all.append(rows)
             h, pk, st = layer_finish(p, h, a, arch, valid)
-            rows_all.append(rows)
             extras.append(extra)
             if pk is not None:
                 picks.append(pk[n_real - 1])
@@ -543,17 +590,20 @@ def expert_decode_program(arch: LMArch, page_size: int,
                              page_table_row[jnp.clip(idx, 0, pps - 1)],
                              SCRATCH_PAGE)
         first, rest = write_rows(pools, page_idx, pos % page_size, rows_all)
-        return first, rest, _logits(params, h[n_real - 1], arch), \
+        return *joined(first, rest, state), \
+            _logits(params, h[n_real - 1], arch), \
             join(picks, stats, extras, ())
 
-    def prefill(params, first, rest, page_table_row, tokens, n_real):
+    def prefill(params, first, rest, page_table_row, tokens, n_real,
+                slot=None):
         return prefill_at(params, first, rest, page_table_row, tokens,
-                          n_real, jnp.int32(0))
+                          n_real, jnp.int32(0), slot)
 
     def step(params, first, rest, page_table, tokens, positions, active):
         """One token for every slot.  Idle slots' rows go to the scratch
-        page and their picks are not counted."""
-        pools = (first,) + tuple(rest)
+        page, their picks are not counted and their state stays."""
+        pools, state = split(first, rest)
+        state = list(state)
         s_n = tokens.shape[0]
         at = jnp.clip(positions, 0, L - 1)
         rope = jax.tree_util.tree_map(lambda t: t[at], att.tables)
@@ -561,18 +611,22 @@ def expert_decode_program(arch: LMArch, page_size: int,
         h = _embed(params, tokens)
         rows_all, picks, stats, extras = [], [], [], []
         for i, p in enumerate(params["blocks"]):
-            q, rows = att.project(p, h, rope)
-            a, extra = att.attend_step(p, pools, i, table, q, rows,
-                                       positions, active)
+            if kinds[i] == "state":
+                a, state[nth[i]], extra = att.state_step(
+                    p, h, state[nth[i]], active)
+            else:
+                q, rows = att.project(p, h, rope)
+                a, extra = att.attend_step(p, pools, nth[i], table, q, rows,
+                                           positions, active)
+                rows_all.append(rows)
             h, pk, st = layer_finish(p, h, a, arch, active)
-            rows_all.append(rows)
             extras.append(extra)
             if pk is not None:
                 picks.append(pk)
                 stats.append(st)
         page_idx = table[jnp.arange(s_n), at // page_size]
         first, rest = write_rows(pools, page_idx, at % page_size, rows_all)
-        return first, rest, _logits(params, h, arch), \
+        return *joined(first, rest, state), _logits(params, h, arch), \
             join(picks, stats, extras, (s_n,))
 
     def step_multi(params, first, rest, page_table, tokens, positions,
@@ -619,7 +673,8 @@ def expert_decode_program(arch: LMArch, page_size: int,
         pages_per_slot=pps, prefill_at=prefill_at, step_multi=step_multi,
         pool_rows=att.pool_rows, pool_dtype=jnp.dtype(arch.param_dtype),
         aux=True, aux_stats=(("expert_stats", EXPERT_STATS),) + att.stats,
-        held_pages=att.held_pages)
+        held_pages=att.held_pages,
+        kinds=att.kinds, slot_state=att.slot_state)
 
 
 def family_module(arch: LMArch):

@@ -286,6 +286,54 @@ INDEX_BLOCK_ROWS, KV_BLOCK_ROWS = 2048, 1024
 
 # -- attention ---------------------------------------------------------------------
 
+def attend_blocks(q: Array, k_new: Array, v_new: Array, arch: LMArch,
+                  mask_new, read_kv=None, mask_old=None, n_old=0,
+                  kv_block: int = 0) -> Array:
+    """Grouped-query attention of ``T`` new rows (``q`` [T, H, D] float32,
+    ``k_new`` / ``v_new`` [T, KV * D] as cached) over themselves under
+    ``mask_new()`` [T, T] and over the ``n_old`` cached rows before them,
+    read a block at a time (``read_kv(j)`` gives the K and V rows ``j *
+    kv_block ..``, ``mask_old(j)`` what each query may see of them,
+    broadcastable to [T, kv_block]): a loop over the blocks that hold any
+    carries the softmax's running maximum, sum and weighted values, so no
+    score matrix wider than a block exists and the work follows the rows
+    held.  Returns ``([T, H * D] float32, the mask mask_new gave)``."""
+    T = q.shape[0]
+    H, KV, D = arch.n_heads, arch.n_kv_heads, arch.head_dim
+    cd = k_new.dtype
+    qg = q.reshape(T, KV, H // KV, D).astype(cd)
+
+    def scored(k_rows, v_rows, mask):
+        """Masked scores [KV, G, T, rows] and values [rows, KV, D]."""
+        s = jnp.einsum("tgqd,lgd->gqtl", qg,
+                       k_rows.reshape(-1, KV, D).astype(cd),
+                       preferred_element_type=jnp.float32) * D ** -0.5
+        return (jnp.where(mask[None, None], s, NEG_INF),
+                v_rows.reshape(-1, KV, D).astype(cd))
+
+    new = mask_new()
+    s, v = scored(k_new, v_new, new)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    e = jnp.exp(s - m)
+    z = jnp.sum(e, axis=-1, keepdims=True)
+    acc = jnp.einsum("gqtl,lgd->gqtd", e.astype(cd), v,
+                     preferred_element_type=jnp.float32)
+    if read_kv is not None:
+        def body(j, carry):
+            m, z, acc = carry
+            mask = mask_old(j)
+            s, v = scored(*read_kv(j), mask)
+            m2 = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            e, keep = jnp.exp(s - m2), jnp.exp(m - m2)
+            return (m2, z * keep + jnp.sum(e, axis=-1, keepdims=True),
+                    acc * keep + jnp.einsum(
+                        "gqtl,lgd->gqtd", e.astype(cd), v,
+                        preferred_element_type=jnp.float32))
+        m, z, acc = jax.lax.fori_loop(
+            0, (n_old + kv_block - 1) // kv_block, body, (m, z, acc))
+    return jnp.moveaxis(acc / z, 2, 0).reshape(T, H * D), new
+
+
 def attend_chunk(q_side, rows, arch: LMArch, offset=0, n_real=None,
                  read_index=None, read_kv=None, n_old: int = 0,
                  index_block: int = 0, kv_block: int = 0):
@@ -302,8 +350,6 @@ def attend_chunk(q_side, rows, arch: LMArch, offset=0, n_real=None,
     the new rows [T, T]."""
     (q, q_i, w), (k_new, v_new, i_new) = q_side, rows
     T = q.shape[0]
-    H, KV, D = arch.n_heads, arch.n_kv_heads, arch.head_dim
-    cd = k_new.dtype
     key_new = jnp.where(causal(T),
                         sortable_keys(index_scores(q_i, w, i_new, arch)), 0)
     keys, cols = [key_new], [offset + jnp.arange(T)]
@@ -320,40 +366,15 @@ def attend_chunk(q_side, rows, arch: LMArch, offset=0, n_real=None,
     thr, cut = topk_threshold(keys, cols, arch.index_topk,
                               (n_old + T).bit_length())
 
-    qg = q.reshape(T, KV, H // KV, D).astype(cd)
+    def old_mask(j):
+        kb = jax.lax.dynamic_slice(keys[1], (0, j * kv_block), (T, kv_block))
+        return chosen(kb, (j * kv_block + jnp.arange(kv_block))[None, :],
+                      thr, cut)
 
-    def scored(k_rows, v_rows, mask):
-        """Masked scores [KV, G, T, rows] and values [rows, KV, D]."""
-        s = jnp.einsum("tgqd,lgd->gqtl", qg,
-                       k_rows.reshape(-1, KV, D).astype(cd),
-                       preferred_element_type=jnp.float32) * D ** -0.5
-        return (jnp.where(mask[None, None], s, NEG_INF),
-                v_rows.reshape(-1, KV, D).astype(cd))
-
-    mask_new = chosen(key_new, cols[0][None, :], thr, cut)
-    s, v = scored(k_new, v_new, mask_new)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    e = jnp.exp(s - m)
-    z = jnp.sum(e, axis=-1, keepdims=True)
-    acc = jnp.einsum("gqtl,lgd->gqtd", e.astype(cd), v,
-                     preferred_element_type=jnp.float32)
-    if read_kv is not None:
-        def body(j, carry):
-            m, z, acc = carry
-            kb = jax.lax.dynamic_slice(keys[1], (0, j * kv_block),
-                                       (T, kv_block))
-            mask = chosen(kb, (j * kv_block + jnp.arange(kv_block))[None, :],
-                          thr, cut)
-            s, v = scored(*read_kv(j), mask)
-            m2 = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-            e, keep = jnp.exp(s - m2), jnp.exp(m - m2)
-            return (m2, z * keep + jnp.sum(e, axis=-1, keepdims=True),
-                    acc * keep + jnp.einsum(
-                        "gqtl,lgd->gqtd", e.astype(cd), v,
-                        preferred_element_type=jnp.float32))
-        m, z, acc = jax.lax.fori_loop(
-            0, (offset + kv_block - 1) // kv_block, body, (m, z, acc))
-    att = jnp.moveaxis(acc / z, 2, 0).reshape(T, H * D)
+    att, mask_new = attend_blocks(
+        q, k_new, v_new, arch,
+        lambda: chosen(key_new, cols[0][None, :], thr, cut), read_kv,
+        old_mask, offset, kv_block)
     if n_real is None:
         return att, mask_new
     r = n_real - 1

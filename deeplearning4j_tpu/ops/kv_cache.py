@@ -152,14 +152,33 @@ def alloc_cache(n_layers: int, n_pages: int, page_size: int, n_heads: int,
     return KVCache(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
 
 
+class PoolsAndState(NamedTuple):
+    """What rides where the engine's contract has ``v_pages`` for a
+    program with per-slot state (``DecodeProgram.slot_state``): the
+    pools after the first, and beside them the state that no page table
+    reaches.  ``state`` holds, for each layer that keeps one, a tuple of
+    arrays ``[slots, ...]`` (one an entry of ``slot_state``): an array a
+    layer and not a layer axis, so that a step replaces each whole, in
+    place, and a chunk updates one slot's part of it.  Donated, reset
+    and handed on with the pools; a page scrub leaves it alone."""
+
+    pools: tuple
+    state: tuple
+
+
 def alloc_pools(prog: "DecodeProgram", n_pages: int,
-                kv_dtype: Optional[str] = None) -> KVCache:
+                kv_dtype: Optional[str] = None,
+                slots: Optional[int] = None) -> KVCache:
     """The pools ``prog`` threads, zero-filled: shape and dtype are the
     program's own (``pool_rows`` / ``pool_dtype``).  A program that names
     its rows gets one pool a row kind: the first where the engine's
     contract has ``k_pages``, the others as a tuple where it has
     ``v_pages`` (empty for a single latent pool), so every pool lives,
-    is donated, scrubbed and reset with the same pages."""
+    is donated, scrubbed and reset with the same pages.  The pools'
+    layer axis is as long as the layers that have rows in them (the
+    ``"pool"`` entries of ``kinds``).  A program with per-slot state gets
+    it, for every ``"state"`` layer and ``slots`` slots, beside those
+    pools (:class:`PoolsAndState`)."""
     if prog.pool_rows is None:
         return alloc_cache(prog.n_layers, n_pages, prog.page_size,
                            prog.n_heads, prog.d_head,
@@ -168,10 +187,26 @@ def alloc_pools(prog: "DecodeProgram", n_pages: int,
     if kv_dtype in ("int8", "i8"):
         raise ValueError("int8 KV is not carried by this decode program")
     dtype = prog.pool_dtype or jnp.float32
-    first, *rest = (jnp.zeros((prog.n_layers, n_pages, prog.page_size)
+    kinds = prog.kinds or ("pool",) * prog.n_layers
+    layers = kinds.count("pool")
+    first, *rest = (jnp.zeros((layers, n_pages, prog.page_size)
                               + tuple(row), dtype)
                     for row in prog.pool_rows)
-    return KVCache(first, tuple(rest))
+    if not prog.slot_state:
+        return KVCache(first, tuple(rest))
+    if slots is None:
+        raise ValueError("a program with per-slot state needs the number "
+                         "of slots to allocate it for")
+    state = tuple(tuple(jnp.zeros((slots,) + tuple(shape), dt)
+                        for shape, dt in prog.slot_state)
+                  for _ in range(kinds.count("state")))
+    return KVCache(first, PoolsAndState(tuple(rest), state))
+
+
+def state_nbytes(cache) -> int:
+    """Resident bytes of the per-slot state a cache carries (0 without)."""
+    rest = cache[1]
+    return pool_nbytes(rest.state) if isinstance(rest, PoolsAndState) else 0
 
 
 def pool_nbytes(cache) -> int:
@@ -307,7 +342,10 @@ def gather_layer(pages: KVPool, layer: int, page_table: Array,
 
 def scrub_pool(pages: KVPool, ids: Array) -> KVPool:
     """Zero the given page ids — values AND scales for int8 pools (a
-    stale scale would re-scale the next tenant's rows)."""
+    stale scale would re-scale the next tenant's rows).  Per-slot state
+    beside the pools has no pages and stays."""
+    if isinstance(pages, PoolsAndState):
+        return pages._replace(pools=scrub_pool(pages.pools, ids))
     return jax.tree_util.tree_map(lambda a: a.at[:, ids].set(0), pages)
 
 
@@ -542,3 +580,17 @@ class DecodeProgram(NamedTuple):
     # they read rows, not pages, and count them in ``aux_stats``
     # (models/sparse_gqa.py): the engine reports no ``kv_pages_read``
     held_pages: Optional[bool] = False
+    # which layers have rows in the pools (``"pool"``) and which keep
+    # state that is PER SLOT and not per token (``"state"``,
+    # models/linear_gqa.py), one entry a layer (None: every layer has
+    # rows); ``slot_state`` names a state layer's arrays, one ``(shape
+    # after [slots], dtype)`` each.  ``alloc_pools`` makes them beside the
+    # pools
+    # (:class:`PoolsAndState`, where the contract has ``v_pages``); every
+    # token replaces them, so ``prefill`` / ``prefill_at`` take the slot's
+    # index as one argument more (a chunk at offset 0 starts from zero
+    # state whatever the slot held, a later one from what the chunk
+    # before left at its last REAL row), and ``step`` / ``step_multi``
+    # leave the state of a slot that is not active as it was
+    kinds: Optional[tuple] = None
+    slot_state: tuple = ()

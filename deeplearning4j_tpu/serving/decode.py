@@ -133,6 +133,11 @@ Two more host-overhead eliminations ride on top (docs/SERVING.md
       dispatch's echoed logits are copied out one dispatch late
       (``_step_fused_once``, ``_flush_echo``): between two programs the
       host then only reads tokens, records them and dispatches.
+      Whose chunk goes next (``prefill_order``): ``"round_robin"`` over
+      the slots mid-prefill, or ``"nearest_end"``, the prompt with the
+      fewest tokens left first (``_nearest_end``): under a closed loop
+      of long prompts round-robin finishes every waiting prompt late
+      and together, so a third of the slots hold half-read prompts.
 
 TTFT and time-per-output-token are first-class (``DecodeMetrics``).
 Everything the loop thread does is a live ``obs.trace`` span under
@@ -164,6 +169,10 @@ from .engine import (ModelNotLoadedError, PoisonInputError,
 from .metrics import DecodeMetrics
 
 FINISH_REASONS = ("eos", "max_tokens", "deadline")
+
+#: ``prefill_order="nearest_end"``: rounds of a full engine's round-robin
+#: a prompt mid-prefill may be passed over before it goes first
+PASSED_OVER_ROUNDS = 4
 
 
 def _leaves(tree) -> list:
@@ -197,6 +206,14 @@ class GenerationResult:
     # from, for a request that asked ``echo_logits`` of a program with a
     # learned sparse selection (models/sparse_gqa.py); else None
     attn_rows: Optional[np.ndarray] = None
+    # what the slot held of every layer that keeps per-slot state
+    # (``DecodeProgram.slot_state``: one tuple of arrays a state layer)
+    # when the answer stopped, for a request that asked ``echo_state``
+    # and ran to ``max_tokens``: the state as the LAST token fed left it,
+    # which is every token of prompt and answer but the answer's last
+    # (served, and never fed back).  Jax arrays, copied out of the pools
+    # on the device and still there: ``np.asarray`` them.  Else None
+    slot_state: Optional[tuple] = None
 
 
 @dataclass(frozen=True)
@@ -211,6 +228,7 @@ class _GenSpec:
     seed: int
     echo_logits: bool
     request_id: int = 0       # drawn at submit; joins the request's spans
+    echo_state: bool = False
 
 
 @dataclass
@@ -257,7 +275,7 @@ class _Slot:
                  "last_token", "tokens", "n_out", "max_new", "deadline",
                  "t_first", "t_last", "logits", "shared_nodes", "n_matched",
                  "n_prefilled", "picks", "logit_buf", "rows_buf",
-                 "rows_next")
+                 "rows_next", "passed_over")
 
     def __init__(self, req, tag: str, page_ids: List[int], max_new: int):
         self.req = req
@@ -290,6 +308,8 @@ class _Slot:
         # the cache (None once prefill completes / for unchunked slots);
         # a slot with n_prefilled set is NOT steppable yet
         self.n_prefilled: Optional[int] = None
+        # chunk picks since this prompt's last chunk that went to another
+        self.passed_over = 0
 
 
 class _Chunk:
@@ -511,7 +531,8 @@ class DecodeEngine:
                  speculate_k: int = 4, kv_dtype: Optional[str] = None,
                  role: str = "unified", tenants=None,
                  decode_horizon: int = 1,
-                 prefill_chunk: Optional[int] = None):
+                 prefill_chunk: Optional[int] = None,
+                 prefill_order: str = "round_robin"):
         if max_slots < 1:
             raise ValueError("max_slots must be >= 1")
         if decode_horizon < 1:
@@ -533,6 +554,12 @@ class DecodeEngine:
             raise ValueError(
                 "chunked prefill + speculative decoding is unsupported "
                 "(the draft pool's mirror prefill is not chunked)")
+        if prefill_order not in ("round_robin", "nearest_end"):
+            raise ValueError(f"prefill_order {prefill_order!r} not supported "
+                             "(round_robin or nearest_end)")
+        if prefill_order != "round_robin" and prefill_chunk is None:
+            raise ValueError("prefill_order orders the chunks of a chunked "
+                             "prefill: it needs prefill_chunk")
         if kv_dtype not in (None, "f32", "float32", "int8", "i8"):
             raise ValueError(f"kv_dtype {kv_dtype!r} not supported "
                              "(float32 or int8)")
@@ -566,9 +593,17 @@ class DecodeEngine:
                 ("tensor-parallel decode", getattr(prog, "tp", 1) > 1),
             ) if on]
             if asked:
+                # per-slot state has no pages to share, hand over or roll
+                # back: a prefix hit, a transfer and a rejected proposal
+                # would each need a snapshot of it
+                own = ("pools of its own rows and a per-slot recurrent state"
+                       if prog.slot_state else "pools of its own rows")
                 raise ValueError(
-                    "this decode program keeps pools of its own rows and "
+                    f"this decode program keeps {own} and "
                     "does not carry " + ", ".join(asked) + " yet")
+        # the program keeps state per slot beside its pools (``load``
+        # allocates it with them; ``_slot_arg``)
+        self._slot_state = bool(getattr(prog, "slot_state", ()))
         self._prefix_on = bool(prefix_cache)
         if self._prefix_on and prog.prefill_at is None:
             raise ValueError(
@@ -585,6 +620,7 @@ class DecodeEngine:
             raise ValueError(
                 "prefill_chunk needs a decode program with a prefill_at "
                 "entry point (offset prefill drives each chunk)")
+        self.prefill_order = prefill_order
         self._kv_dtype = kv_dtype
         self.speculate_k = int(speculate_k)
         self._draft_program = None
@@ -705,20 +741,24 @@ class DecodeEngine:
         cold load."""
         import jax
 
-        from ..ops.kv_cache import alloc_pools, pool_nbytes
+        from ..ops.kv_cache import alloc_pools, pool_nbytes, state_nbytes
         from ..ops.paged_attention import kept_path
         from .warmcache import load_bundle
 
         prog = self.program
         params = self._versions[self._serve_tag]
         s_n, pps, v_n = self.max_slots, prog.pages_per_slot, prog.vocab_size
-        kp, vp = alloc_pools(prog, self.total_pages, self._kv_dtype)
+        kp, vp = alloc_pools(prog, self.total_pages, self._kv_dtype,
+                             slots=s_n)
+        slot0 = self._slot_arg(0)
         # what ``kv_pages_read`` counts: the program says it attends over
         # the pages held, the kernel's own rule whether it takes this pool
         self._reads_held_pages = (
             prog.held_pages and kept_path(kp, pps, prog.tp) is None)
+        self.metrics.recurrent_state_bytes.set(state_nbytes((kp, vp)))
         self.metrics.kv_bytes_per_token.set(
-            pool_nbytes((kp, vp)) / (self.total_pages * prog.page_size))
+            (pool_nbytes((kp, vp)) - state_nbytes((kp, vp)))
+            / (self.total_pages * prog.page_size))
         bundle_mesh = self._mesh if getattr(prog, "tp", 1) > 1 else None
         if bundle_mesh is not None:
             # the pool is head-sharded from its first byte (the program's
@@ -804,11 +844,12 @@ class DecodeEngine:
                           if self.prefill_chunk is None else ()):
                     pf = _get(f"prefill:{b}", lambda b=b: prefill_jit.lower(
                         params, kp, vp, np.zeros((pps,), np.int32),
-                        np.zeros((b,), np.int32), np.int32(1)).compile())
+                        np.zeros((b,), np.int32), np.int32(1),
+                        *slot0).compile())
                     kp, vp, lg1 = pf(params, kp, vp,
                                      np.zeros((pps,), np.int32),
                                      np.zeros((b,), np.int32),
-                                     np.int32(1))[:3]
+                                     np.int32(1), *slot0)[:3]
                     self._compiled[("prefill", b)] = pf
 
                 if self._prefix_on or self.prefill_chunk is not None:
@@ -823,11 +864,12 @@ class DecodeEngine:
                                       params, kp, vp,
                                       np.zeros((pps,), np.int32),
                                       np.zeros((b,), np.int32), np.int32(1),
-                                      np.int32(0)).compile())
+                                      np.int32(0), *slot0).compile())
                         kp, vp, lg1 = pf(params, kp, vp,
                                          np.zeros((pps,), np.int32),
                                          np.zeros((b,), np.int32),
-                                         np.int32(1), np.int32(0))[:3]
+                                         np.int32(1), np.int32(0),
+                                         *slot0)[:3]
                         self._compiled[("prefill_at", b)] = pf
 
             one, batch = _make_samplers(v_n)
@@ -867,6 +909,16 @@ class DecodeEngine:
                     np.asarray(join_c(toks, np.zeros((s_n,), np.int32),
                                       np.zeros((s_n,), bool)))
                     self._compiled[("join",)] = join_c
+            if self._slot_state:
+                # an answer that asked for its slot's state takes a copy
+                # of it out of the pools at its finish
+                def _state_of(state, i):
+                    return jax.tree_util.tree_map(lambda a: a[i], state)
+
+                so_c = _get("slot_state", lambda: jax.jit(_state_of).lower(
+                    vp.state, np.int32(0)).compile())
+                jax.block_until_ready(so_c(vp.state, np.int32(0)))
+                self._compiled[("slot_state",)] = so_c
 
             from ..ops.kv_cache import scrub_pool
 
@@ -1045,6 +1097,12 @@ class DecodeEngine:
         self._draft_cache = (dkp, dvp)
         return kp, vp
 
+    def _slot_arg(self, i: int) -> tuple:
+        """What ``prefill`` / ``prefill_at`` take after the contract's
+        arguments: the slot's index where the program keeps per-slot
+        state (its chunk carries that slot's on), nothing otherwise."""
+        return (np.int32(i),) if self._slot_state else ()
+
     def _zero_payload(self, pool):
         """A zero host-side payload with the shape
         ``gather_pages(pool, ids)`` produces for a full pages-per-slot id
@@ -1089,11 +1147,15 @@ class DecodeEngine:
                        slo_ms: Optional[float] = None,
                        deadline: Optional[float] = None,
                        echo_logits: bool = False,
+                       echo_state: bool = False,
                        model: Optional[str] = None,
                        tenant: Optional[str] = None) -> Future:
         """Enqueue one generation; the Future resolves to a
         ``GenerationResult`` (or a typed serving error).  Joins the
-        running decode batch at the next step boundary.  ``model``
+        running decode batch at the next step boundary.  ``echo_state``
+        asks for the slot's per-slot state at the answer's end
+        (``GenerationResult.slot_state``; a program that keeps none
+        gives None).  ``model``
         routes to a placed named model (``add_model``; None = the
         default); ``tenant`` tags the request for fair-share scheduling
         and quota accounting."""
@@ -1136,7 +1198,8 @@ class DecodeEngine:
                         temperature=float(temperature), top_k=int(top_k),
                         top_p=float(top_p), seed=int(seed),
                         echo_logits=bool(echo_logits),
-                        request_id=next(self._request_ids))
+                        request_id=next(self._request_ids),
+                        echo_state=bool(echo_state))
         return self.batcher.submit_request(spec, slo_ms=slo_ms,
                                            deadline=deadline,
                                            tenant=tenant, model=model)
@@ -1772,7 +1835,9 @@ class DecodeEngine:
                 padded[:n] = spec.prompt
                 kp, vp, lg, *aux = self._compiled[("prefill", bucket)](
                     self._versions[s.tag], kp, vp, self._page_table[i], padded,
-                    np.int32(n))
+                    np.int32(n), *self._slot_arg(i))
+                if self._slot_state:
+                    self.metrics.inc("recurrent_state_resets")
             tok, fin = self._compiled[("sample1",)](
                 lg, np.float32(spec.temperature), np.int32(spec.top_k),
                 np.float32(spec.top_p), np.uint32(spec.seed), np.int32(0))
@@ -1813,7 +1878,8 @@ class DecodeEngine:
         """Advance ONE pending chunked prefill by one chunk (at most
         ``prefill_chunk`` prompt tokens through the ``prefill_at``
         offset entry point), round-robin across slots mid-prefill so no
-        single long prompt starves another.  The final chunk runs the
+        single long prompt starves another (or in the order
+        ``prefill_order`` names).  The final chunk runs the
         ``_prefill_slot`` tail — sample token 0, TTFT, prefix insert —
         and the slot becomes steppable.  Chunk rows attend over all
         earlier rows already in the pool (same per-row math as a cold
@@ -1834,7 +1900,7 @@ class DecodeEngine:
         return True
 
     def _chunk_pick(self) -> Optional[_Chunk]:
-        """The next chunk of the round-robin, with its padded tokens.  A
+        """The next chunk in ``prefill_order``, with its padded tokens.  A
         prompt whose deadline passed while it was being prefilled is
         given up first, as one still queued would be: it has no token to
         hand back, and its remaining chunks (up to seconds of the device
@@ -1853,9 +1919,12 @@ class DecodeEngine:
             pending = [i for i in pending if i not in late]
             if not pending:
                 return None
-            start = self._chunk_cursor
-            i = min(pending, key=lambda x: (x - start) % self.max_slots)
-            self._chunk_cursor = (i + 1) % self.max_slots
+            if self.prefill_order == "nearest_end":
+                i = self._nearest_end(pending)
+            else:
+                start = self._chunk_cursor
+                i = min(pending, key=lambda x: (x - start) % self.max_slots)
+                self._chunk_cursor = (i + 1) % self.max_slots
             s = self._slots[i]
         c = _Chunk()
         c.i, c.slot, c.offset = i, s, s.n_prefilled
@@ -1865,6 +1934,25 @@ class DecodeEngine:
         c.padded[:c.take] = s.spec.prompt[c.offset:c.offset + c.take]
         c.last = c.offset + c.take >= s.n_prompt
         return c
+
+    def _nearest_end(self, pending: List[int]) -> int:
+        """Of the slots mid-prefill the one with the fewest prompt tokens
+        left (the lowest slot among equals): its request decodes soonest,
+        so fewer slots are held by half-read prompts.  A prompt passed
+        over ``PASSED_OVER_ROUNDS * max_slots`` picks in a row goes
+        first, the longest-waiting one: a full engine's round-robin gives
+        every prompt a chunk each ``max_slots`` picks, this order at
+        worst that many times later.  Caller holds ``_lock``."""
+        slots = self._slots
+        late = max(pending, key=lambda x: slots[x].passed_over)
+        if slots[late].passed_over >= PASSED_OVER_ROUNDS * self.max_slots:
+            i = late
+        else:
+            i = min(pending, key=lambda x: (
+                slots[x].n_prompt - slots[x].n_prefilled, x))
+        for x in pending:
+            slots[x].passed_over = 0 if x == i else slots[x].passed_over + 1
+        return i
 
     def _chunk_span(self, c: _Chunk):
         s = c.slot
@@ -1880,8 +1968,11 @@ class DecodeEngine:
         kp, vp = self._cache
         kp, vp, c.lg, *c.aux = self._compiled[("prefill_at", c.bucket)](
             self._versions[s.tag], kp, vp, self._page_table[c.i], c.padded,
-            np.int32(c.take), np.int32(c.offset))
+            np.int32(c.take), np.int32(c.offset), *self._slot_arg(c.i))
         self._cache = (kp, vp)
+        if c.offset == 0 and self._slot_state:
+            # the chunk at offset 0 starts the slot from zero state
+            self.metrics.inc("recurrent_state_resets")
         if c.last:
             # final chunk — the _prefill_slot tail
             c.tok, c.fin = self._compiled[("sample1",)](
@@ -2692,6 +2783,12 @@ class DecodeEngine:
                         if s.n_out > 1 else None)
                 if tpot is not None:
                     self.metrics.tpot.record(tpot)
+                # the device stopped the slot itself, so the state is
+                # the last fed token's (an EOS is fed by the step ahead)
+                held = (self._compiled[("slot_state",)](
+                            self._cache[1].state, np.int32(i))
+                        if s.spec.echo_state and self._slot_state
+                        and reason == "max_tokens" else None)
                 result = GenerationResult(
                     tokens=list(s.tokens), n_prompt=s.n_prompt,
                     finish_reason=reason, model_tag=s.tag, ttft_ms=ttft_ms,
@@ -2701,7 +2798,8 @@ class DecodeEngine:
                     request_id=request_id,
                     expert_picks=np.stack(s.picks) if s.picks else None,
                     attn_rows=s.rows_buf[:len(s.logits)]
-                    if s.logits and s.rows_buf is not None else None)
+                    if s.logits and s.rows_buf is not None else None,
+                    slot_state=held)
                 if s.logits and self._echo_defer:
                     # its last rows are still on the device
                     self._echo_results.append((s.req.future, result))
@@ -2811,6 +2909,7 @@ class DecodeEngine:
         snap["tp"] = int(getattr(self.program, "tp", 1))
         snap["decode_horizon"] = self.decode_horizon
         snap["prefill_chunk"] = self.prefill_chunk
+        snap["prefill_order"] = self.prefill_order
         return snap
 
     def health_snapshot(self) -> dict:

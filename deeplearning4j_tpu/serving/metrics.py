@@ -286,6 +286,15 @@ _DECODE_COUNTER_KEYS = (
     # rows the indexer scored, K/V rows attention read after the
     # selection, rows the stepped slots / the chunk's slot held
     "index_rows_scored", "attn_rows_read", "rows_held",
+    # layers that keep a per-slot recurrent state beside grouped-query
+    # layers over paged K and V (models/linear_gqa.STATE_STATS; zero for a
+    # program without them), summed over layers and calls: active slots
+    # whose state a step replaced, real rows a chunk scanned into a
+    # slot's state, K rows the stepped slots / the chunk's slot held,
+    # rows the block walk read of them; and admissions that started a
+    # slot from zero state
+    "state_slots_stepped", "state_rows_scanned", "kv_rows_held",
+    "kv_rows_read", "recurrent_state_resets",
 )
 
 
@@ -331,6 +340,11 @@ class DecodeMetrics:
         # bytes one cached token holds in the pool, all layers (set at load)
         self.kv_bytes_per_token = self.registry.gauge("kv_bytes_per_token")
         self.kv_bytes_per_token.set(0)
+        # bytes of the per-slot recurrent state beside the pools, all
+        # slots and layers (set at load; 0 for a program without any)
+        self.recurrent_state_bytes = self.registry.gauge(
+            "recurrent_state_bytes")
+        self.recurrent_state_bytes.set(0)
         self._t0 = time.monotonic()
         self.global_name = get_registry().register_collector(
             "decode", self.snapshot, unique=True)
@@ -370,6 +384,7 @@ class DecodeMetrics:
             "free_pages": int(self.free_pages.value()),
             "free_slots": int(self.free_slots.value()),
             "kv_bytes_per_token": int(self.kv_bytes_per_token.value()),
+            "recurrent_state_bytes": int(self.recurrent_state_bytes.value()),
             "accepted_tokens_per_step": round(
                 c["spec_committed"] / c["spec_steps"], 4)
             if c.get("spec_steps") else None,

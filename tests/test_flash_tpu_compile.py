@@ -215,6 +215,71 @@ def test_sparse_decode_program_compiles_for_v5e_without_a_pool_copy(
         assert "bf16[8,2048,512]" in text                   # the chosen rows
 
 
+# -- the two-kind decode programs at Solar-Open2's widths ---------------------------
+
+@pytest.mark.parametrize("entry", ["step_multi", "prefill_at"])
+def test_linear_gqa_decode_program_compiles_for_v5e_in_place(one_chip, entry):
+    """The fused horizon and a prefill chunk (512 tokens) of the
+    benchmark's ``solar-open2-250b`` configuration (32 slots, 19,456
+    positions, bf16 weights, float32 state) compile for one v5e chip, fit
+    its memory and update the two donated pools AND the per-slot state in
+    place.  The step's block walk gathers K and V blocks of FOUR slots
+    (``linear_gqa.STEP_GROUP``), never of all 32, and keeps under a third
+    of the 160 MB that one walk of every slot kept in temporaries."""
+    import json
+
+    from deeplearning4j_tpu.models import linear_gqa
+    from deeplearning4j_tpu.models.arch import LMArch
+    from deeplearning4j_tpu.ops.kv_cache import alloc_pools
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "solar-open2-250b.json")) as f:
+        cfg = json.load(f)
+    slots, page = cfg["program"]["max_slots"], cfg["program"]["page_size"]
+    arch = LMArch.from_config(cfg, max_len=cfg["program"]["max_len"],
+                              param_dtype="bfloat16")
+    prog = linear_gqa.decode_program(arch, page, arch.max_len)
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                             sharding=one_chip)
+    params = jax.tree_util.tree_map(on_chip, jax.eval_shape(
+        lambda: linear_gqa.init_params(jax.random.PRNGKey(0), arch,
+                                       jnp.bfloat16)))
+    k_pool, rest = jax.tree_util.tree_map(on_chip, jax.eval_shape(
+        lambda: tuple(alloc_pools(prog, 1 + slots * prog.pages_per_slot,
+                                  slots=slots))))
+    assert k_pool.shape == (1, 1 + 32 * 1216, 16, 1024)
+    held = sum(a.size * a.dtype.itemsize
+               for a in jax.tree_util.tree_leaves((k_pool, rest)))
+    assert 2.9e9 < held < 3.0e9         # 2.55 GB of K and V, 0.42 of state
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    if entry == "step_multi":
+        f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32,
+                                              sharding=one_chip)
+        args = (i32(slots, prog.pages_per_slot), i32(slots), i32(slots),
+                jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip),
+                f32(slots), i32(slots), f32(slots),
+                jax.ShapeDtypeStruct((slots,), jnp.uint32, sharding=one_chip),
+                i32(slots), i32(slots), i32(),
+                i32(cfg["program"]["decode_horizon"]))
+        fn = prog.step_multi
+    else:
+        args = (i32(prog.pages_per_slot), i32(cfg["program"]["prefill_chunk"]),
+                i32(), i32(), i32())
+        fn = prog.prefill_at
+    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
+        params, k_pool, rest, *args).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
+    assert mem.alias_size_in_bytes >= held              # pools and state in place
+    if entry == "step_multi":
+        assert mem.temp_size_in_bytes < 55e6
+        text = compiled.as_text()
+        group = linear_gqa.STEP_GROUP
+        assert f"bf16[{group},64,16,1024]" in text      # four slots' block
+        assert "bf16[32,64,16,1024]" not in text        # never all 32
+
+
 # -- the GPT-2 decode programs at gpt2-large's widths -----------------------------
 
 @pytest.fixture(scope="module")

@@ -133,6 +133,13 @@ class TestConstruction:
         with pytest.raises(ValueError):
             DecodeEngine(lm, max_slots=3, page_size=PAGE, prefill_chunk=0)
 
+    @pytest.mark.parametrize("kw", [
+        {"prefill_chunk": CHUNK, "prefill_order": "shortest"},
+        {"prefill_order": "nearest_end"}])      # nothing is chunked
+    def test_prefill_order_rejected(self, lm, kw):
+        with pytest.raises(ValueError, match="prefill_order"):
+            DecodeEngine(lm, max_slots=3, page_size=PAGE, **kw)
+
     def test_chunk_requires_unified_role(self, lm):
         with pytest.raises(ValueError):
             DecodeEngine(lm, max_slots=3, page_size=PAGE,
@@ -441,6 +448,51 @@ class TestChunkedPrefill:
                         == plain.generate(p, max_new_tokens=8).tokens)
         finally:
             eng.shutdown()
+
+    def test_nearest_end_serves_what_round_robin_serves(self, lm, plain):
+        """The order of the chunks is no part of an answer: three prompts
+        of two, one and two chunks at once, each the plain engine's
+        tokens."""
+        eng = _make(lm, prefill_chunk=CHUNK, prefill_order="nearest_end")
+        try:
+            assert eng.metrics_snapshot()["prefill_order"] == "nearest_end"
+            prompts = [[1 + (i * k) % (VOCAB - 1) for i in range(n)]
+                       for k, n in ((3, 32), (5, 9), (7, 21))]
+            futs = [eng.generate_async(p, max_new_tokens=6) for p in prompts]
+            for p, f in zip(prompts, futs):
+                assert (f.result(timeout=120).tokens
+                        == plain.generate(p, max_new_tokens=6).tokens)
+            assert _partition_ok(eng)
+        finally:
+            eng.shutdown()
+
+    def test_nearest_end_picks_the_least_left_and_lets_none_wait_for_ever(
+            self, chunk):
+        """``_nearest_end`` over slots mid-prefill: the fewest prompt
+        tokens left first (the lowest slot among equals); a prompt passed
+        over ``PASSED_OVER_ROUNDS * max_slots`` picks in a row goes
+        first, once, and waits again."""
+        from types import SimpleNamespace
+
+        from deeplearning4j_tpu.serving.decode import PASSED_OVER_ROUNDS
+
+        slots = [SimpleNamespace(n_prompt=n, n_prefilled=0, passed_over=0)
+                 for n in (500, 40, 40)]
+        eng = SimpleNamespace(_slots=slots, max_slots=3)
+        pick = lambda pending: DecodeEngine._nearest_end(eng, pending)
+        assert pick([0, 1, 2]) == 1
+        assert [s.passed_over for s in slots] == [1, 0, 1]
+        slots[1].n_prefilled = 16
+        assert pick([0, 1, 2]) == 1         # 24 left against 40 and 500
+        slots[1].n_prefilled = None         # its last chunk: it decodes
+        assert pick([0, 2]) == 2 and slots[0].passed_over == 3
+        limit = PASSED_OVER_ROUNDS * 3
+        for _ in range(limit - 3):          # new short prompts keep coming
+            slots[2].n_prefilled = 0
+            assert pick([0, 2]) == 2
+        assert slots[0].passed_over == limit
+        assert pick([0, 2]) == 0 and slots[0].passed_over == 0
+        assert pick([0, 2]) == 2
 
     def test_interacts_with_prefix_cache(self, lm, plain, oracle):
         # a prefix hit resumes the chunk walk at matched-pages (24 =
