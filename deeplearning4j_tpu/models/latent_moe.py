@@ -634,13 +634,13 @@ def expert_decode_program(arch: LMArch, page_size: int,
                    eos_id, horizon):
         """``horizon.shape[0]`` decode steps in one program: a scan of
         ``step`` with the sampling on the device (the engine's own
-        ``ops.sampling.sample_token``, keyed ``fold_in(seed, steps + j)``
+        ``ops.sampling.sample_tokens``, keyed ``fold_in(seed, steps + j)``
         as its per-step sampler is, so fusion changes no token).  A slot
         that stops (EOS, budget, a non-finite row) leaves ``alive``: its
         later rows go to the scratch page and its picks are not
         counted.  The counts are summed over the steps; what was chosen
         comes back for every step."""
-        from ..ops.sampling import sample_token
+        from ..ops.sampling import sample_tokens
 
         summed = ("expert_stats",) + tuple(n for n, _ in att.stats)
         names = summed + ("expert_picks",) + att.extras
@@ -649,10 +649,8 @@ def expert_decode_program(arch: LMArch, page_size: int,
             first, rest, tok, alive = carry
             first, rest, lgs, aux = step(params, first, rest, page_table,
                                          tok, positions + j, alive)
-            nxt, fin = jax.vmap(
-                lambda l, t, k, p, sd, st: sample_token(
-                    l, t, k, p, sd, st, arch.vocab_size)
-            )(lgs, temps, top_ks, top_ps, seeds, steps + j)
+            nxt, fin = sample_tokens(lgs, temps, top_ks, top_ps, seeds,
+                                     steps + j)
             alive = alive & fin & (nxt != eos_id) & (j + 1 < budgets)
             return (first, rest, nxt, alive), (
                 nxt, fin, lgs, *(aux[n] for n in names))

@@ -62,6 +62,7 @@ import numpy as np
 
 from ..nn.layers.normalization import layer_norm
 from ..ops.kv_cache import LANES
+from ..ops.select import kth_key, sortable_keys, walk
 from ..parallel.moe import init_held_experts
 from .arch import LMArch
 from .latent_moe import (NEG_INF, CachedAttention, _embed, _join_aux,
@@ -77,9 +78,6 @@ Array = jax.Array
 #: ``index_topk`` rows a slot; a chunk's blocks, which the mask covers
 #: whole), rows the stepped slots (or the chunk's slot) held
 SPARSE_STATS = ("index_rows_scored", "attn_rows_read", "rows_held")
-
-#: bits of the key the threshold walk settles a pass
-WALK_BITS = 2
 
 
 def index_lanes(arch: LMArch) -> int:
@@ -191,48 +189,22 @@ def project(p: Dict[str, Array], h: Array, rope, arch: LMArch):
 
 # -- the selection ----------------------------------------------------------------
 
-def sortable_keys(score: Array) -> Array:
-    """float32 -> uint32 whose unsigned order is the floats' order; never
-    0, which marks a column that is no candidate."""
-    bits = jax.lax.bitcast_convert_type(score.astype(jnp.float32), jnp.uint32)
-    u = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(0x80000000))
-    return jnp.maximum(u, jnp.uint32(1))
-
-
 def topk_threshold(keys, cols, k: int, n_pos_bits: int):
     """The exact top ``k`` of each row of a table of sortable keys given
     in parts (``keys[i]`` [T, n_i] uint32, 0 = no candidate; ``cols[i]``
     [n_i] the positions of its columns), as a pair ``(thr, cut)`` [T]:
     chosen are the keys above ``thr`` and, of those equal to it, the
     positions up to ``cut`` (``chosen``).  ``thr`` is the largest value
-    that at least ``k`` keys reach, built from the top ``WALK_BITS`` bits
-    a pass: a pass counts the keys that reach each of the ``2^WALK_BITS
-    - 1`` candidates in one read of the table (32 / ``WALK_BITS`` passes
-    of compare-and-count, no sort); ``cut`` likewise over the position's
-    ``n_pos_bits``.  A row with fewer than ``k`` candidates gets ``thr``
-    0: all of them."""
+    that at least ``k`` keys reach, found by ``ops.select``'s walk
+    (passes of compare-and-count over the table, no sort); ``cut``
+    likewise over the position's ``n_pos_bits``.  A row with fewer than
+    ``k`` candidates gets ``thr`` 0: all of them."""
     def count(pred):
         return sum(jnp.sum(pred(kk, cc[None, :]), axis=-1, dtype=jnp.int32)
                    for kk, cc in zip(keys, cols))
 
-    def walk(n_bits, start, fits):
-        """Digit by digit from the top: the largest value ``v`` of
-        ``n_bits`` bits for which ``fits(v)`` holds (it holds for 0 and
-        fails from some value on)."""
-        passes = -(-n_bits // WALK_BITS)
-
-        def digit(i, v):
-            shift = ((passes - 1 - i) * WALK_BITS).astype(v.dtype)
-            out = v
-            for d in range(1, 1 << WALK_BITS):
-                cand = v | (jnp.asarray(d, v.dtype) << shift)
-                out = jnp.where(fits(cand), cand, out)
-            return out
-        return jax.lax.fori_loop(0, passes, digit, start)
-
     rows = keys[0].shape[0]
-    thr = walk(32, jnp.zeros((rows,), jnp.uint32),
-               lambda c: count(lambda kk, cc: kk >= c[:, None]) >= k)
+    thr = kth_key(lambda c: count(lambda kk, cc: kk >= c[:, None]), rows, k)
     need = k - count(lambda kk, cc: kk > thr[:, None])
     cut = walk(n_pos_bits, jnp.zeros((rows,), jnp.int32),
                lambda c: count(lambda kk, cc: (kk == thr[:, None])

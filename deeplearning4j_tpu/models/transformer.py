@@ -16,7 +16,6 @@ the two is structural rather than tested-for.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 from typing import Callable, Dict, Optional
 
@@ -145,7 +144,7 @@ def paged_decode_program(*, embed: Callable, pos: Callable, blocks: Callable,
         write_tokens,
     )
     from ..ops.paged_attention import paged_attention, window_attention
-    from ..ops.sampling import sample_token
+    from ..ops.sampling import sample_tokens
 
     if max_len is None:
         max_len = (pos_rows // page_size) * page_size
@@ -240,9 +239,6 @@ def paged_decode_program(*, embed: Callable, pos: Callable, blocks: Callable,
                                               kv.transpose(0, 2, 1, 3)),
             held(pt, jnp.where(active, positions + tokens.shape[1], 0)))
 
-    sample_rows = jax.vmap(
-        functools.partial(sample_token, vocab_size=vocab_size))
-
     def step_multi(params, k_pages, v_pages, page_table, tokens, positions,
                    active, temps, top_ks, top_ps, seeds, steps, budgets,
                    eos_id, horizon):
@@ -264,8 +260,8 @@ def paged_decode_program(*, embed: Callable, pos: Callable, blocks: Callable,
                                                 kv[:, :, 0]),
                 held(pt, jnp.where(alive, pos_j + 1, 0)))
             lgs = lgs[:, 0]
-            nxt, fin = sample_rows(lgs, temps, top_ks, top_ps, seeds,
-                                   steps + j)
+            nxt, fin = sample_tokens(lgs, temps, top_ks, top_ps, seeds,
+                                     steps + j)
             alive = alive & fin & (nxt != eos_id) & (j + 1 < budgets)
             return (k_pages, v_pages, nxt, alive), (nxt, fin, lgs)
 

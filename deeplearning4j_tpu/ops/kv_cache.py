@@ -530,7 +530,7 @@ class DecodeProgram(NamedTuple):
           fused multi-step decode: ``lax.scan`` of the step body over
           ``horizon`` (an int32 arange whose LENGTH is the fused
           horizon H), with sampling device-resident
-          (``ops.sampling.sample_token`` keyed ``fold_in(seed,
+          (``ops.sampling.sample_tokens`` keyed ``fold_in(seed,
           steps + j)``) so the host syncs once per H tokens.  Per-slot
           EOS (token == eos_id; pass -1 to disable) / token-budget /
           poison masking runs on device: a finished slot's page-table

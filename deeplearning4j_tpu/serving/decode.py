@@ -386,25 +386,24 @@ class _PrefixNode:
         self.detached = False
 
 
-def _make_samplers(vocab_size: int):
+def _make_samplers():
     """(sample_one, sample_batch) pure fns.  Deterministic: the key is
     ``fold_in(PRNGKey(seed), step)`` — same (seed, step) → same draw.
     temperature <= 0 is greedy; top_k == 0 and top_p >= 1 disable those
     filters.  Also returns the all-finite flag the poison check reads.
 
-    The math lives in ``ops.sampling.sample_token`` so the fused
-    ``step_multi`` programs trace the SAME function — that shared
-    source is what makes horizon fusion bit-identical to step-by-step.
+    The math lives in ``ops.sampling`` so the fused ``step_multi``
+    programs trace the SAME function — that shared source is what makes
+    horizon fusion bit-identical to step-by-step.
     """
-    import jax
+    from ..ops.sampling import sample_token, sample_tokens
 
-    from ..ops.sampling import sample_token
-
+    # the names the executables carry in a trace (``jit_sample_batch``)
     def sample_one(lg, t, k, p, seed, step):
-        return sample_token(lg, t, k, p, seed, step, vocab_size)
+        return sample_token(lg, t, k, p, seed, step)
 
     def sample_batch(lgs, ts, ks, ps, seeds, steps):
-        return jax.vmap(sample_one)(lgs, ts, ks, ps, seeds, steps)
+        return sample_tokens(lgs, ts, ks, ps, seeds, steps)
 
     return sample_one, sample_batch
 
@@ -437,38 +436,30 @@ def _make_spec_fns(vocab_size: int, n_spec: int):
     import jax
     import jax.numpy as jnp
 
+    from ..ops.sampling import scale_and_filter
+
     def _key(seed, stream, step):
         return jax.random.fold_in(
             jax.random.fold_in(jax.random.PRNGKey(seed), stream), step)
 
-    def _warped(lg, t, k, p):
-        # the sample_one filter, expressed as a distribution
-        scaled = lg / jnp.maximum(t, 1e-6)
-        srt = jnp.sort(scaled)[::-1]
-        kk = jnp.clip(jnp.where(k > 0, k, vocab_size), 1, vocab_size)
-        thr_k = srt[kk - 1]
-        probs = jax.nn.softmax(srt)
-        cum_excl = jnp.cumsum(probs) - probs
-        keep = cum_excl < jnp.clip(p, 1e-6, 1.0)
-        thr_p = jnp.min(jnp.where(keep, srt, jnp.inf))
-        thr = jnp.maximum(thr_k, thr_p)
-        masked = jnp.where(scaled >= thr, scaled, -jnp.inf)
-        onehot = jax.nn.one_hot(jnp.argmax(lg), vocab_size,
+    def _warped(lgs, ts, ks, ps):
+        # the samplers' filter (one source), expressed as distributions
+        onehot = jax.nn.one_hot(jnp.argmax(lgs, axis=-1), vocab_size,
                                 dtype=jnp.float32)
-        return jnp.where(t <= 0.0, onehot, jax.nn.softmax(masked))
+        return jnp.where((ts <= 0.0)[:, None], onehot,
+                         jax.nn.softmax(scale_and_filter(lgs, ts, ks, ps)))
 
-    def propose_one(lg, t, k, p, seed, step):
-        dist = _warped(lg, t, k, p)
+    def propose_one(lg, dist, t, seed, step):
         g = jax.random.gumbel(_key(seed, _DRAFT_STREAM, step), lg.shape)
         sampled = jnp.argmax(jnp.log(jnp.maximum(dist, 1e-30)) + g)
         tok = jnp.where(t <= 0.0, jnp.argmax(lg), sampled)
-        return tok.astype(jnp.int32), dist
+        return tok.astype(jnp.int32)
 
-    def accept_one(tlgs, dtoks, dprobs, t, k, p, seed, step0):
-        # tlgs [n_spec+1, V] target logits; dtoks [n_spec] draft tokens;
-        # dprobs [n_spec, V] warped draft distributions
+    def accept_one(tlgs, targ, dtoks, dprobs, t, seed, step0):
+        # tlgs [n_spec+1, V] target logits, targ their warped
+        # distributions; dtoks [n_spec] draft tokens; dprobs [n_spec, V]
+        # warped draft distributions
         finite = jnp.all(jnp.isfinite(tlgs))
-        targ = jax.vmap(lambda lg: _warped(lg, t, k, p))(tlgs)
         j = jnp.arange(n_spec)
         p_t_d = targ[j, dtoks]
         p_d_d = dprobs[j, dtoks]
@@ -500,11 +491,17 @@ def _make_spec_fns(vocab_size: int, n_spec: int):
         return (a + 1).astype(jnp.int32), commit, finite
 
     def propose(lgs, ts, ks, ps, seeds, steps):
-        return jax.vmap(propose_one)(lgs, ts, ks, ps, seeds, steps)
+        dists = _warped(lgs, ts, ks, ps)
+        return jax.vmap(propose_one)(lgs, dists, ts, seeds, steps), dists
 
     def accept(tlgs, dtoks, dprobs, ts, ks, ps, seeds, steps):
-        return jax.vmap(accept_one)(tlgs, dtoks, dprobs, ts, ks, ps,
-                                    seeds, steps)
+        # every row of a slot under the slot's spec, in ONE batch
+        targ = _warped(
+            tlgs.reshape(-1, vocab_size),
+            *(jnp.repeat(a, n_spec + 1) for a in (ts, ks, ps))
+        ).reshape(tlgs.shape)
+        return jax.vmap(accept_one)(tlgs, targ, dtoks, dprobs, ts, seeds,
+                                    steps)
 
     return propose, accept
 
@@ -872,7 +869,7 @@ class DecodeEngine:
                                          *slot0)[:3]
                         self._compiled[("prefill_at", b)] = pf
 
-            one, batch = _make_samplers(v_n)
+            one, batch = _make_samplers()
             if self.role != "decode":
                 s1 = _get("sample1", lambda: jax.jit(one).lower(
                     lg1, np.float32(0), np.int32(0), np.float32(1),
@@ -2252,6 +2249,7 @@ class DecodeEngine:
                 if attn is not None:
                     attn.copy_to_host_async()
         self.metrics.inc("decode_steps")
+        self._count_sorted(inp)
         if prev is not None:
             self.metrics.inc("steps_ahead")
         f = _Flight()
@@ -2366,6 +2364,15 @@ class DecodeEngine:
                 inp.pages_filled += filled
             self.metrics.pages_filled.set(filled_all)
         return inp if inp.group else None
+
+    def _count_sorted(self, inp: _StepInputs, steps: int = 1) -> None:
+        """Count ``steps`` decode steps under ``sampler_sorted_steps`` if
+        their batch takes the sampler's sorted path: the predicate the
+        program branches by on the device, read here of the host's own
+        arrays."""
+        from ..ops.sampling import needs_sort
+        if needs_sort(inp.temps, inp.tps):
+            self.metrics.inc("sampler_sorted_steps", steps)
 
     def _set_step_args(self, sp, inp: _StepInputs, step_ms: float,
                        sample_ms: float, steps: int = 1) -> None:
@@ -2482,6 +2489,7 @@ class DecodeEngine:
                 self._set_step_args(sp, inp, step_ms=(t1 - t0) * 1e3,
                                     sample_ms=0.0, steps=H)
                 self.metrics.inc("decode_steps")
+                self._count_sorted(inp, steps=H)
                 self.metrics.inc("fused_dispatches")
                 self.metrics.step_time.record((t1 - t0) * 1e3)
                 committed = 0
@@ -2632,6 +2640,7 @@ class DecodeEngine:
                 lgs_h = np.asarray(lgs) if inp.echo else None
                 t1 = self.clock()
             self.metrics.inc("decode_steps")
+            self._count_sorted(inp)
             self.metrics.step_time.record((t1 - t0) * 1e3)
             self.metrics.inc("spec_steps")
             self.metrics.inc("spec_proposed", k * len(group))

@@ -276,6 +276,11 @@ _DECODE_COUNTER_KEYS = (
     # version tags alive), slot-steps computed for a request that the
     # read before had stopped (EOS, deadline, poison)
     "steps_ahead", "step_drains", "overrun_slot_steps",
+    # decode steps (each step of a fused dispatch) whose batch held a
+    # sampled top-p row, so the sampler sorted every row's vocabulary
+    # (ops/sampling.needs_sort); the others found the top-k threshold by
+    # selection
+    "sampler_sorted_steps",
     # routed experts (parallel/moe.EXPERT_STATS; zero for a program
     # without them): picks made by real tokens, those that fell on
     # experts held here, the fullest held expert's picks (summed over

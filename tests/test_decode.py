@@ -96,6 +96,35 @@ class TestSamplingDeterminism:
                   top_p=top_p, seed=13)
         assert _tokens(engine, [4, 5], **kw) == _tokens(engine, [4, 5], **kw)
 
+    def test_sorted_steps_count_the_steps_a_top_p_request_decodes(
+            self, engine):
+        """``sampler_sorted_steps`` stays where it was over greedy and
+        top-k requests (and a greedy one that names a top-p), and moves
+        with ``decode_steps`` while a sampled top-p request decodes: the
+        host counts by the predicate the sampler branches on."""
+        def moved(**kw):
+            c0 = engine.metrics_snapshot()["counters"]
+            engine.generate([4, 5], max_new_tokens=8, **kw)
+            c1 = engine.metrics_snapshot()["counters"]
+            return (c1["sampler_sorted_steps"] - c0["sampler_sorted_steps"],
+                    c1["decode_steps"] - c0["decode_steps"])
+
+        assert moved() == (0, 7)        # token 1 comes from the prefill
+        assert moved(temperature=0.9, top_k=5, seed=2) == (0, 7)
+        assert moved(top_p=0.5) == (0, 7)
+        assert moved(temperature=0.8, top_p=0.9, seed=2) == (7, 7)
+        # co-batched, the top-k neighbour's steps sort with it
+        c0 = engine.metrics_snapshot()["counters"]
+        futs = [engine.generate_async([4, 5], max_new_tokens=8, seed=2,
+                                      temperature=0.8, **kw)
+                for kw in (dict(top_p=0.9), dict(top_k=5))]
+        for f in futs:
+            f.result(timeout=120)
+        c1 = engine.metrics_snapshot()["counters"]
+        assert 7 <= (c1["sampler_sorted_steps"]
+                     - c0["sampler_sorted_steps"]) <= (
+            c1["decode_steps"] - c0["decode_steps"])
+
     def test_seed_changes_sampled_text(self, engine):
         runs = {tuple(_tokens(engine, [7, 8, 9], max_new_tokens=8,
                               temperature=1.5, seed=s)) for s in range(4)}

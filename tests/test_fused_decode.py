@@ -171,6 +171,31 @@ class TestFusedIdentity:
             assert (fused.generate(p, **kw).tokens
                     == plain.generate(p, **kw).tokens)
 
+    @pytest.mark.parametrize("kw", [
+        dict(temperature=0.8, top_k=5), dict(temperature=0.8, top_p=0.9),
+        dict(temperature=1.2, top_k=7, top_p=0.85),
+    ], ids=["top_k", "top_p", "top_k_and_top_p"])
+    def test_both_sampler_paths_identical(self, fused, plain, kw):
+        """The selection (no top-p in the batch) and the sort (a top-p
+        row) each draw in a scan what they draw step by step."""
+        for seed, p in enumerate(PROMPTS):
+            spec = dict(max_new_tokens=8, seed=40 + seed, **kw)
+            assert (fused.generate(p, **spec).tokens
+                    == plain.generate(p, **spec).tokens)
+
+    def test_a_top_p_row_cobatched_with_top_k_rows_identical(self, fused,
+                                                             plain):
+        """While the top-p request decodes its neighbours take the sorted
+        path with it, and draw the tokens they draw alone."""
+        specs = [dict(temperature=0.8, top_p=0.9, seed=1),
+                 dict(temperature=0.8, top_k=5, seed=2), dict()]
+        alone = [plain.generate(p, max_new_tokens=8, **kw).tokens
+                 for p, kw in zip(PROMPTS, specs)]
+        for eng in (fused, plain):
+            futs = [eng.generate_async(p, max_new_tokens=8, **kw)
+                    for p, kw in zip(PROMPTS, specs)]
+            assert [f.result(timeout=120).tokens for f in futs] == alone
+
     def test_budget_not_a_horizon_multiple(self, fused, plain):
         # 6 = H + 2: the second dispatch must stop mid-horizon and the
         # device overrun (routed to the scratch page) is never recorded
@@ -634,6 +659,23 @@ class TestMetricsAndBundle:
         # token 1 comes from prefill: the two fused dispatches commit 7
         assert c1["tokens_per_dispatch"] == c0["tokens_per_dispatch"] + 7
         assert snap["decode_horizon"] == H
+
+    def test_sorted_steps_count_each_step_of_a_top_p_dispatch(self, fused):
+        """``sampler_sorted_steps``: nothing for greedy and top-k
+        requests; every step of every dispatch (H a dispatch) while a
+        sampled top-p request decodes; nothing for a GREEDY request that
+        names a top-p."""
+        def moved(**kw):
+            c0 = fused.metrics_snapshot()["counters"]
+            fused.generate(PROMPTS[0], max_new_tokens=8, **kw)
+            c1 = fused.metrics_snapshot()["counters"]
+            return (c1["sampler_sorted_steps"] - c0["sampler_sorted_steps"],
+                    c1["fused_dispatches"] - c0["fused_dispatches"])
+
+        assert moved() == (0, 2)
+        assert moved(temperature=0.8, top_k=5, seed=3) == (0, 2)
+        assert moved(top_p=0.9) == (0, 2)
+        assert moved(temperature=0.8, top_p=0.9, seed=3) == (2 * H, 2)
 
     def test_warm_bundle_covers_fused_executable(self, lm, fused,
                                                  tmp_path):

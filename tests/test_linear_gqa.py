@@ -654,15 +654,19 @@ def _texts(block):
 
 
 #: the digests of the tree before this block joined the builder (PR 36's
-#: commit), on this repository's one installation (jax 0.9.0)
+#: commit), on this repository's one installation (jax 0.9.0).  The two
+#: ``step_multi`` digests are PR 38's: the scan's body holds the sampler,
+#: which finds its top-k threshold by selection since (ops/sampling.py);
+#: ``sparse_gqa``'s other four still pin the threshold walk's move to
+#: ops/select.py as a pure one
 BEFORE = {
     "latent_moe": {"prefill_at": "9e523187ef514400",
                    "prefill": "75f7e2286e3f0566", "step": "3b3858ebf0e144ec",
-                   "step_multi": "5f551b2a4d46aab3",
+                   "step_multi": "eb46206caa61b7e9",
                    "reencode": "055df8d5d251b425"},
     "sparse_gqa": {"prefill_at": "99861492a1a1c19d",
                    "prefill": "32c32972e353bb69", "step": "bb4e166c8adad74e",
-                   "step_multi": "4cdccf65ca08e765",
+                   "step_multi": "3b3917727229b5cf",
                    "reencode": "b6327a003d8beba3"},
 }
 
