@@ -2,7 +2,7 @@
 
 These encode cross-artifact invariants the soaks only catch indirectly:
 
-- GC401: every ``span()``/``instant()``/``complete_at()`` name must
+- GC401: every ``span()``/``instant()``/``complete_at()``/``phase()`` name must
   appear in the docs/OBSERVABILITY.md taxonomy table (wildcard rows
   like ``launcher/*`` cover f-string names).  The golden test in
   tests/test_static_analysis.py checks the reverse direction too, so
@@ -101,7 +101,7 @@ def collect_span_emissions(graph: CallGraph):
             if fname is None:
                 continue
             leaf = fname.split(".")[-1]
-            if leaf not in ("span", "instant", "complete_at"):
+            if leaf not in ("span", "instant", "complete_at", "phase"):
                 continue
             norm = mod.normalize(fname)
             if "obs" not in norm and "trace" not in norm.split(".")[0]:

@@ -311,10 +311,6 @@ class MetricsRegistry:
             self._collectors[name] = ref
         return name
 
-    def unregister_collector(self, name: str) -> None:
-        with self._lock:
-            self._collectors.pop(name, None)
-
     # -- snapshot ----------------------------------------------------------
 
     def snapshot(self) -> dict:

@@ -32,6 +32,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, set_mesh
 from ..models.arch import EXPERT_BLOCKS, LMArch
 from ..models.transformer import block_apply, block_params
 from ..nn.updaters import Adam
+from ..obs import startup as obs_startup
 from ..obs import trace as obs_trace
 from .pipeline import SCHEDULES, pipeline_apply, stack_stage_params
 from .ring import ring_attention
@@ -51,6 +52,10 @@ def _block_tp_specs(pipe: str = "pipe", model: str = "model"):
         "W1": P(pipe, None, model), "b1": P(pipe, model),
         "W2": P(pipe, model, None), "b2": P(pipe, None),
     }
+
+
+#: what JAX calls ``fit_batch``'s and ``fit_batches``' programs
+_STEP_PROGRAMS = ("jit(step)", "jit(multi)")
 
 
 class ShardedTransformerLM:
@@ -149,10 +154,24 @@ class ShardedTransformerLM:
         self._jit_multi_step = None
         self._jit_logits = None
         self.token_sharding = NamedSharding(mesh, P("data", "seq"))
-        if arch.block in EXPERT_BLOCKS:
-            self._init_expert_family(seed, params)
-            return
+        # the weights and their placement, as the phase ``lm/init`` of the
+        # start-up account (obs/startup.py)
+        obs_startup.watch_compiles()
+        with obs_startup.phase("lm/init", cat="train",
+                               drew_params=params is None) as ph:
+            if arch.block in EXPERT_BLOCKS:
+                self._init_expert_family(seed, params)
+            else:
+                self._init_gpt2_family(seed, params)
+            ph.set(leaves=len(jax.tree_util.tree_leaves(self.params)))
 
+    def _init_gpt2_family(self, seed: int, params) -> None:
+        """The GPT-2 family: parameters sharded over the mesh, and the
+        optimizer state beside them."""
+        arch, mesh = self.arch, self.mesh
+        vocab_size, n_layers = arch.vocab_size, arch.n_layers
+        d_model, n_heads = arch.d_model, arch.n_heads
+        d_ff, max_len = arch.d_ff, arch.max_len
         rng = jax.random.PRNGKey(seed)
         if params is None:
             ke, kp, kh, *kb = jax.random.split(rng, 3 + n_layers)
@@ -309,10 +328,26 @@ class ShardedTransformerLM:
 
         return jax.jit(step, donate_argnums=(0, 1))
 
+    def _on_compile(self, event: dict) -> None:
+        """JAX compiled a step program (or read it from its cache) outside
+        every phase of the start-up account, so after this model's
+        ``train/first_step``: a batch of a new shape.  (Two models that
+        train in one process both hear of it, as two loaded engines both
+        count a compile after load.)"""
+        if event["fun_name"] in _STEP_PROGRAMS:
+            obs_trace.instant("train/recompile", cat="train",
+                              fun_name=event["fun_name"],
+                              iteration=self.iteration + 1,
+                              seconds=event["seconds"])
+
     def fit_batch(self, tokens: np.ndarray, targets: np.ndarray):
         self._refuse_training()
         if self._jit_step is None:
             self._jit_step = self._build_step()
+            obs_startup.on_unphased_compile(self._on_compile)
+            with obs_startup.phase("train/first_step", cat="train",
+                                   fun="fit_batch"):
+                return self.fit_batch(tokens, targets)
         with obs_trace.span("train/step", cat="train",
                             iteration=self.iteration + 1) as sp:
             with obs_trace.span("train/h2d", cat="train"):
@@ -360,6 +395,10 @@ class ShardedTransformerLM:
         self._refuse_training()
         if self._jit_multi_step is None:
             self._jit_multi_step = self._build_multi_step()
+            obs_startup.on_unphased_compile(self._on_compile)
+            with obs_startup.phase("train/first_step", cat="train",
+                                   fun="fit_batches"):
+                return self.fit_batches(tokens, targets)
         stacked = NamedSharding(self.mesh, P(None, "data", "seq"))
         with obs_trace.span("train/step", cat="train",
                             iteration=self.iteration + 1) as sp:

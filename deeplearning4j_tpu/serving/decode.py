@@ -161,6 +161,7 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from ..obs import startup as obs_startup
 from ..obs import trace as obs_trace
 from .batcher import (ContinuousBatcher, DeadlineExceededError,
                       pow2_buckets)
@@ -728,14 +729,48 @@ class DecodeEngine:
         executable: one prefill per prompt bucket, the decode step, the
         two samplers, the pool reset, and the page scrub.  After this,
         ``compile_cache_size()`` must not grow while serving — the
-        zero-serve-time-compiles contract.
+        zero-serve-time-compiles contract; what JAX itself compiles
+        after this returns is counted too (``serve_time_compiles``).
 
         ``warm_bundle`` points at a bundle written by
         :meth:`save_warmup_bundle` (serving/warmcache.py): each
         executable deserializes instead of compiling, with per-key
         fallback to compile on any miss.  Bundle hits are still executed
         once below, so the donated pool state flows identically to a
-        cold load."""
+        cold load.
+
+        The whole of it is the phase ``serve/load`` of the start-up
+        account (obs/startup.py), each executable a
+        ``serve/load_executable`` with its ``serve/lower``,
+        ``serve/compile`` and ``serve/first_run``."""
+        obs_startup.watch_compiles()
+        with obs_startup.phase("serve/load", cat="serve",
+                               tag=self._serve_tag, role=self.role) as ph:
+            misses = self._load(warm_bundle)
+            n = len(self._compiled)
+            ph.set(executables=n, bundle_hits=n - misses,
+                   bundle_misses=misses)
+        self._loaded = True
+        obs_startup.on_unphased_compile(self._on_compile_after_load)
+        self._start_loop()
+        self._supervisor = threading.Thread(
+            target=self._supervise, name="decode-supervisor", daemon=True)
+        self._supervisor.start()
+        return self
+
+    def _on_compile_after_load(self, event: dict) -> None:
+        """JAX compiled (or read its cache) outside every phase of the
+        start-up account while this engine serves."""
+        if self._shutdown:
+            return
+        self.metrics.inc("serve_time_compiles")
+        obs_trace.instant("serve/compile_after_load", cat="serve",
+                          fun_name=event["fun_name"], stage=event["stage"],
+                          seconds=event["seconds"])
+
+    def _load(self, warm_bundle: Optional[str]) -> int:
+        """The body of ``load``; returns how many executables the bundle
+        did not bring."""
         import jax
 
         from ..ops.kv_cache import alloc_pools, pool_nbytes, state_nbytes
@@ -744,7 +779,7 @@ class DecodeEngine:
 
         prog = self.program
         params = self._versions[self._serve_tag]
-        s_n, pps, v_n = self.max_slots, prog.pages_per_slot, prog.vocab_size
+        s_n, pps = self.max_slots, prog.pages_per_slot
         kp, vp = alloc_pools(prog, self.total_pages, self._kv_dtype,
                              slots=s_n)
         slot0 = self._slot_arg(0)
@@ -774,63 +809,65 @@ class DecodeEngine:
         bundle = (load_bundle(warm_bundle, mesh=bundle_mesh,
                               devices=bundle_devices)
                   if warm_bundle else {})
-        hits = misses = 0
+        built = []      # executables the bundle did not bring
 
-        def _get(key, build):
-            nonlocal hits, misses
-            exe = bundle.get(key)
-            if exe is not None:
-                hits += 1
-                return exe
-            misses += 1
-            return build()
+        def _warm(key: tuple, jitted, *args):
+            """One executable of the serve path: out of the bundle, or
+            lowered and compiled here; run once on ``args`` (the donated
+            pools thread through), kept under ``key``.  Returns what the
+            run returned, ready."""
+            name = ":".join(str(p) for p in key)   # its name in a bundle
+            with obs_startup.phase("serve/load_executable", cat="serve",
+                                   key=name) as ph:
+                exe = bundle.get(name)
+                ph.set(source="built" if exe is None else "bundle")
+                if exe is None:
+                    built.append(name)
+                    with obs_startup.phase("serve/lower", cat="serve",
+                                           key=name):
+                        lowered = jitted.lower(*args)
+                    with obs_startup.phase("serve/compile", cat="serve",
+                                           key=name):
+                        exe = lowered.compile()
+                with obs_startup.phase("serve/first_run", cat="serve",
+                                       key=name):
+                    out = jax.block_until_ready(exe(*args))
+            self._compiled[key] = exe
+            return out
 
         t0 = self.clock()
         with obs_trace.span("serve/warmup", cat="serve", kind="decode",
                             tag=self._serve_tag, role=self.role):
             lgs = None
+            zs_i = np.zeros((s_n,), np.int32)
+            table0 = np.zeros((s_n, pps), np.int32)
+            ids0 = np.zeros((pps,), np.int32)
             if self.role != "prefill":
                 # decode step + batch sampler — a prefill-role host never
                 # steps, so its warmup (and bundle) skips them entirely
-                step_c = _get("step", lambda: jax.jit(
-                    prog.step, donate_argnums=(1, 2)).lower(
-                        params, kp, vp, np.zeros((s_n, pps), np.int32),
-                        np.zeros((s_n,), np.int32),
-                        np.zeros((s_n,), np.int32),
-                        np.zeros((s_n,), bool)).compile())
-                kp, vp, lgs, *aux = step_c(
-                    params, kp, vp, np.zeros((s_n, pps), np.int32),
-                    np.zeros((s_n,), np.int32), np.zeros((s_n,), np.int32),
+                kp, vp, lgs, *aux = _warm(
+                    ("step",), jax.jit(prog.step, donate_argnums=(1, 2)),
+                    params, kp, vp, table0, zs_i, zs_i,
                     np.zeros((s_n,), bool))
                 if aux and "attn_rows" in aux[0]:
                     # [layers, k] a token: what an echoing request keeps
                     self._rows_shape = tuple(aux[0]["attn_rows"].shape[1:])
-                self._compiled[("step",)] = step_c
 
                 if self.decode_horizon > 1:
                     # fused multi-step decode: H is a compile-time
                     # constant (the scan length = horizon arange), so
                     # the executable lives in the bundle like any other
                     H = self.decode_horizon
-                    zs_i = np.zeros((s_n,), np.int32)
-                    sm_c = _get(f"step_multi:{H}", lambda: jax.jit(
-                        prog.step_multi, donate_argnums=(1, 2)).lower(
-                            params, kp, vp, np.zeros((s_n, pps), np.int32),
-                            zs_i, zs_i, np.zeros((s_n,), bool),
-                            np.zeros((s_n,), np.float32), zs_i,
-                            np.ones((s_n,), np.float32),
-                            np.zeros((s_n,), np.uint32), zs_i,
-                            np.ones((s_n,), np.int32), np.int32(-1),
-                            np.arange(H, dtype=np.int32)).compile())
-                    kp, vp = sm_c(
-                        params, kp, vp, np.zeros((s_n, pps), np.int32),
-                        zs_i, zs_i, np.zeros((s_n,), bool),
+                    kp, vp = _warm(
+                        ("step_multi", H),
+                        jax.jit(prog.step_multi, donate_argnums=(1, 2)),
+                        params, kp, vp, table0, zs_i, zs_i,
+                        np.zeros((s_n,), bool),
                         np.zeros((s_n,), np.float32), zs_i,
                         np.ones((s_n,), np.float32),
                         np.zeros((s_n,), np.uint32), zs_i,
                         np.ones((s_n,), np.int32), np.int32(-1),
                         np.arange(H, dtype=np.int32))[:2]
-                    self._compiled[("step_multi", H)] = sm_c
 
             lg1 = None
             if self.role != "decode":
@@ -839,15 +876,9 @@ class DecodeEngine:
                 # the whole-prompt programs would never run
                 for b in (self.prompt_buckets
                           if self.prefill_chunk is None else ()):
-                    pf = _get(f"prefill:{b}", lambda b=b: prefill_jit.lower(
-                        params, kp, vp, np.zeros((pps,), np.int32),
-                        np.zeros((b,), np.int32), np.int32(1),
-                        *slot0).compile())
-                    kp, vp, lg1 = pf(params, kp, vp,
-                                     np.zeros((pps,), np.int32),
-                                     np.zeros((b,), np.int32),
-                                     np.int32(1), *slot0)[:3]
-                    self._compiled[("prefill", b)] = pf
+                    kp, vp, lg1 = _warm(
+                        ("prefill", b), prefill_jit, params, kp, vp, ids0,
+                        np.zeros((b,), np.int32), np.int32(1), *slot0)[:3]
 
                 if self._prefix_on or self.prefill_chunk is not None:
                     # suffix prefill per bucket — prefix-cache HITS and
@@ -856,42 +887,21 @@ class DecodeEngine:
                     # both features are off
                     pa_jit = jax.jit(prog.prefill_at, donate_argnums=(1, 2))
                     for b in self.prompt_buckets:
-                        pf = _get(f"prefill_at:{b}",
-                                  lambda b=b: pa_jit.lower(
-                                      params, kp, vp,
-                                      np.zeros((pps,), np.int32),
-                                      np.zeros((b,), np.int32), np.int32(1),
-                                      np.int32(0), *slot0).compile())
-                        kp, vp, lg1 = pf(params, kp, vp,
-                                         np.zeros((pps,), np.int32),
-                                         np.zeros((b,), np.int32),
-                                         np.int32(1), np.int32(0),
-                                         *slot0)[:3]
-                        self._compiled[("prefill_at", b)] = pf
+                        kp, vp, lg1 = _warm(
+                            ("prefill_at", b), pa_jit, params, kp, vp, ids0,
+                            np.zeros((b,), np.int32), np.int32(1),
+                            np.int32(0), *slot0)[:3]
 
             one, batch = _make_samplers()
             if self.role != "decode":
-                s1 = _get("sample1", lambda: jax.jit(one).lower(
-                    lg1, np.float32(0), np.int32(0), np.float32(1),
-                    np.uint32(0), np.int32(0)).compile())
-                tok, _ = s1(lg1, np.float32(0), np.int32(0), np.float32(1),
-                            np.uint32(0), np.int32(0))
-                np.asarray(tok)
-                self._compiled[("sample1",)] = s1
+                _warm(("sample1",), jax.jit(one), lg1, np.float32(0),
+                      np.int32(0), np.float32(1), np.uint32(0), np.int32(0))
             if self.role != "prefill":
-                sb = _get("sample", lambda: jax.jit(batch).lower(
-                    lgs, np.zeros((s_n,), np.float32),
-                    np.zeros((s_n,), np.int32),
+                toks, _ = _warm(
+                    ("sample",), jax.jit(batch), lgs,
+                    np.zeros((s_n,), np.float32), zs_i,
                     np.ones((s_n,), np.float32),
-                    np.zeros((s_n,), np.uint32),
-                    np.zeros((s_n,), np.int32)).compile())
-                toks, _ = sb(lgs, np.zeros((s_n,), np.float32),
-                             np.zeros((s_n,), np.int32),
-                             np.ones((s_n,), np.float32),
-                             np.zeros((s_n,), np.uint32),
-                             np.zeros((s_n,), np.int32))
-                np.asarray(toks)
-                self._compiled[("sample",)] = sb
+                    np.zeros((s_n,), np.uint32), zs_i)
                 if self.decode_horizon == 1 and self._draft_program is None:
                     # the plain loop keeps a step in flight: a slot that
                     # joins from a prefill has its token on the host, the
@@ -900,22 +910,16 @@ class DecodeEngine:
                         import jax.numpy as jnp
                         return jnp.where(on_host, host, dev)
 
-                    join_c = _get("join", lambda: jax.jit(_join_tokens).lower(
-                        toks, np.zeros((s_n,), np.int32),
-                        np.zeros((s_n,), bool)).compile())
-                    np.asarray(join_c(toks, np.zeros((s_n,), np.int32),
-                                      np.zeros((s_n,), bool)))
-                    self._compiled[("join",)] = join_c
+                    _warm(("join",), jax.jit(_join_tokens), toks, zs_i,
+                          np.zeros((s_n,), bool))
             if self._slot_state:
                 # an answer that asked for its slot's state takes a copy
                 # of it out of the pools at its finish
                 def _state_of(state, i):
                     return jax.tree_util.tree_map(lambda a: a[i], state)
 
-                so_c = _get("slot_state", lambda: jax.jit(_state_of).lower(
-                    vp.state, np.int32(0)).compile())
-                jax.block_until_ready(so_c(vp.state, np.int32(0)))
-                self._compiled[("slot_state",)] = so_c
+                _warm(("slot_state",), jax.jit(_state_of), vp.state,
+                      np.int32(0))
 
             from ..ops.kv_cache import scrub_pool
 
@@ -932,16 +936,12 @@ class DecodeEngine:
             # keep_unused: the zeros do not read the pools, and an argument
             # the program drops is not donated: the reset then builds a
             # second pair of pools beside the first (12 GB at 16 slots)
-            reset_c = _get("reset", lambda: jax.jit(
+            kp, vp = _warm(("reset",), jax.jit(
                 _reset, donate_argnums=(0, 1), keep_unused=True,
-                out_shardings=pool_sh).lower(kp, vp).compile())
-            kp, vp = reset_c(kp, vp)
-            self._compiled[("reset",)] = reset_c
-            scrub_c = _get("scrub", lambda: jax.jit(
-                _scrub, donate_argnums=(0, 1), out_shardings=pool_sh).lower(
-                    kp, vp, np.zeros((pps,), np.int32)).compile())
-            kp, vp = scrub_c(kp, vp, np.zeros((pps,), np.int32))
-            self._compiled[("scrub",)] = scrub_c
+                out_shardings=pool_sh), kp, vp)
+            kp, vp = _warm(("scrub",), jax.jit(
+                _scrub, donate_argnums=(0, 1), out_shardings=pool_sh),
+                kp, vp, ids0)
 
             if self.role == "prefill":
                 # page export: gather one slot's pages out of the pool
@@ -951,11 +951,7 @@ class DecodeEngine:
                 def _extract(k, v, ids):
                     return gather_pages(k, ids), gather_pages(v, ids)
 
-                ex_c = _get("extract", lambda: jax.jit(_extract).lower(
-                    kp, vp, np.zeros((pps,), np.int32)).compile())
-                jax.block_until_ready(
-                    ex_c(kp, vp, np.zeros((pps,), np.int32)))
-                self._compiled[("extract",)] = ex_c
+                _warm(("extract",), jax.jit(_extract), kp, vp, ids0)
             if self.role == "decode":
                 # page attach: scatter an inbound transfer's rows into
                 # freshly-allocated pages in ONE donated dispatch
@@ -964,18 +960,15 @@ class DecodeEngine:
                 def _attach(k, v, ids, kpay, vpay):
                     return set_pages(k, ids, kpay), set_pages(v, ids, vpay)
 
-                zk_pay = self._zero_payload(kp)
-                zv_pay = self._zero_payload(vp)
-                at_c = _get("attach", lambda: jax.jit(
-                    _attach, donate_argnums=(0, 1)).lower(
-                        kp, vp, np.zeros((pps,), np.int32),
-                        zk_pay, zv_pay).compile())
-                kp, vp = at_c(kp, vp, np.zeros((pps,), np.int32),
-                              zk_pay, zv_pay)
-                self._compiled[("attach",)] = at_c
+                kp, vp = _warm(
+                    ("attach",), jax.jit(_attach, donate_argnums=(0, 1)),
+                    kp, vp, ids0, self._zero_payload(kp),
+                    self._zero_payload(vp))
 
             if self._draft_program is not None:
-                kp, vp = self._load_spec(_get, params, kp, vp)
+                kp, vp = self._load_spec(_warm, params, kp, vp)
+        misses = len(built)
+        hits = len(self._compiled) - misses
         self.metrics.inc("bundle_hits", hits)
         self.metrics.inc("bundle_misses", misses)
         self.metrics.inc("warmup_seconds_total", self.clock() - t0)
@@ -983,14 +976,9 @@ class DecodeEngine:
         self._cache = (kp, vp)
         with self._lock:
             self._refresh_pool_gauges_locked()
-        self._loaded = True
-        self._start_loop()
-        self._supervisor = threading.Thread(
-            target=self._supervise, name="decode-supervisor", daemon=True)
-        self._supervisor.start()
-        return self
+        return misses
 
-    def _load_spec(self, _get, params, kp, vp):
+    def _load_spec(self, _warm, params, kp, vp):
         """Warm the speculative-decoding executables: the draft pool's
         prefill/step/reset/scrub (draft dims, SAME page table), the
         target's fixed-[S, k+1] ``spec_step`` verify, and the
@@ -1006,71 +994,40 @@ class DecodeEngine:
         s_n, pps, v_n = self.max_slots, prog.pages_per_slot, prog.vocab_size
         k = self.speculate_k
         dkp, dvp = alloc_pools(dprog, self.total_pages, self._kv_dtype)
+        table0 = np.zeros((s_n, pps), np.int32)
+        ids0 = np.zeros((pps,), np.int32)
+        zj = np.zeros((s_n,), np.int32)
+        active0 = np.zeros((s_n,), bool)
 
         dp_jit = jax.jit(dprog.prefill, donate_argnums=(1, 2))
         for b in self.prompt_buckets:
-            pf = _get(f"draft_prefill:{b}", lambda b=b: dp_jit.lower(
-                dparams, dkp, dvp, np.zeros((pps,), np.int32),
-                np.zeros((b,), np.int32), np.int32(1)).compile())
-            dkp, dvp, _ = pf(dparams, dkp, dvp, np.zeros((pps,), np.int32),
-                             np.zeros((b,), np.int32), np.int32(1))
-            self._compiled[("draft_prefill", b)] = pf
+            dkp, dvp, _ = _warm(
+                ("draft_prefill", b), dp_jit, dparams, dkp, dvp, ids0,
+                np.zeros((b,), np.int32), np.int32(1))
         if self._prefix_on:
             dpa_jit = jax.jit(dprog.prefill_at, donate_argnums=(1, 2))
             for b in self.prompt_buckets:
-                pf = _get(f"draft_prefill_at:{b}",
-                          lambda b=b: dpa_jit.lower(
-                              dparams, dkp, dvp, np.zeros((pps,), np.int32),
-                              np.zeros((b,), np.int32), np.int32(1),
-                              np.int32(0)).compile())
-                dkp, dvp, _ = pf(dparams, dkp, dvp,
-                                 np.zeros((pps,), np.int32),
-                                 np.zeros((b,), np.int32), np.int32(1),
-                                 np.int32(0))
-                self._compiled[("draft_prefill_at", b)] = pf
+                dkp, dvp, _ = _warm(
+                    ("draft_prefill_at", b), dpa_jit, dparams, dkp, dvp,
+                    ids0, np.zeros((b,), np.int32), np.int32(1),
+                    np.int32(0))
 
-        dstep_c = _get("draft_step", lambda: jax.jit(
-            dprog.step, donate_argnums=(1, 2)).lower(
-                dparams, dkp, dvp, np.zeros((s_n, pps), np.int32),
-                np.zeros((s_n,), np.int32), np.zeros((s_n,), np.int32),
-                np.zeros((s_n,), bool)).compile())
-        dkp, dvp, dlgs = dstep_c(
-            dparams, dkp, dvp, np.zeros((s_n, pps), np.int32),
-            np.zeros((s_n,), np.int32), np.zeros((s_n,), np.int32),
-            np.zeros((s_n,), bool))
-        self._compiled[("draft_step",)] = dstep_c
-
-        spec_c = _get("spec_step", lambda: jax.jit(
-            prog.spec_step, donate_argnums=(1, 2)).lower(
-                params, kp, vp, np.zeros((s_n, pps), np.int32),
-                np.zeros((s_n, k + 1), np.int32), np.zeros((s_n,), np.int32),
-                np.zeros((s_n,), bool)).compile())
-        kp, vp, tlgs = spec_c(
-            params, kp, vp, np.zeros((s_n, pps), np.int32),
-            np.zeros((s_n, k + 1), np.int32), np.zeros((s_n,), np.int32),
-            np.zeros((s_n,), bool))
-        self._compiled[("spec_step",)] = spec_c
+        dkp, dvp, dlgs = _warm(
+            ("draft_step",), jax.jit(dprog.step, donate_argnums=(1, 2)),
+            dparams, dkp, dvp, table0, zj, zj, active0)
+        kp, vp, tlgs = _warm(
+            ("spec_step",), jax.jit(prog.spec_step, donate_argnums=(1, 2)),
+            params, kp, vp, table0, np.zeros((s_n, k + 1), np.int32), zj,
+            active0)
 
         propose, accept = _make_spec_fns(v_n, k)
         zt = np.zeros((s_n,), np.float32)
-        zk = np.zeros((s_n,), np.int32)
         zp = np.ones((s_n,), np.float32)
         zs = np.zeros((s_n,), np.uint32)
-        zj = np.zeros((s_n,), np.int32)
-        prop_c = _get("propose", lambda: jax.jit(propose).lower(
-            dlgs, zt, zk, zp, zs, zj).compile())
-        d_tok, d_probs = prop_c(dlgs, zt, zk, zp, zs, zj)
-        np.asarray(d_tok)
-        self._compiled[("propose",)] = prop_c
-        acc_c = _get("spec_accept", lambda: jax.jit(accept).lower(
-            tlgs, np.zeros((s_n, k), np.int32),
-            np.zeros((s_n, k, v_n), np.float32), zt, zk, zp, zs,
-            zj).compile())
-        nc, cm, fin = acc_c(tlgs, np.zeros((s_n, k), np.int32),
-                            np.zeros((s_n, k, v_n), np.float32),
-                            zt, zk, zp, zs, zj)
-        np.asarray(nc)
-        self._compiled[("spec_accept",)] = acc_c
+        _warm(("propose",), jax.jit(propose), dlgs, zt, zj, zp, zs, zj)
+        _warm(("spec_accept",), jax.jit(accept), tlgs,
+              np.zeros((s_n, k), np.int32),
+              np.zeros((s_n, k, v_n), np.float32), zt, zj, zp, zs, zj)
 
         def _dreset(dk, dv):
             import jax.numpy as jnp
@@ -1080,16 +1037,10 @@ class DecodeEngine:
         def _dscrub(dk, dv, ids):
             return scrub_pool(dk, ids), scrub_pool(dv, ids)
 
-        dreset_c = _get("draft_reset", lambda: jax.jit(
-            _dreset, donate_argnums=(0, 1),
-            keep_unused=True).lower(dkp, dvp).compile())
-        dkp, dvp = dreset_c(dkp, dvp)
-        self._compiled[("draft_reset",)] = dreset_c
-        dscrub_c = _get("draft_scrub", lambda: jax.jit(
-            _dscrub, donate_argnums=(0, 1)).lower(
-                dkp, dvp, np.zeros((pps,), np.int32)).compile())
-        dkp, dvp = dscrub_c(dkp, dvp, np.zeros((pps,), np.int32))
-        self._compiled[("draft_scrub",)] = dscrub_c
+        dkp, dvp = _warm(("draft_reset",), jax.jit(
+            _dreset, donate_argnums=(0, 1), keep_unused=True), dkp, dvp)
+        dkp, dvp = _warm(("draft_scrub",), jax.jit(
+            _dscrub, donate_argnums=(0, 1)), dkp, dvp, ids0)
 
         self._draft_cache = (dkp, dvp)
         return kp, vp

@@ -281,6 +281,11 @@ _DECODE_COUNTER_KEYS = (
     # (ops/sampling.needs_sort); the others found the top-k threshold by
     # selection
     "sampler_sorted_steps",
+    # backend compiles and compile-cache reads that JAX made outside every
+    # phase of the start-up account (obs/startup.py) while this engine
+    # was loaded: the zero-serve-time-compiles contract, watched from
+    # inside JAX and not only through the engine's own dictionary
+    "serve_time_compiles",
     # routed experts (parallel/moe.EXPERT_STATS; zero for a program
     # without them): picks made by real tokens, those that fell on
     # experts held here, the fullest held expert's picks (summed over

@@ -104,8 +104,12 @@ def enable_compile_cache() -> str:
     Whether the cache is on at all stays jax's own switch
     (``JAX_ENABLE_COMPILATION_CACHE=false`` turns it off).  The
     min-compile-time threshold is dropped to 0 so even small executables
-    persist.  Idempotent.
+    persist.  Also starts the compile watch (obs/startup.py): what the
+    cache saves and what it cannot is then counted.  Idempotent.
     """
+    from ..obs.startup import watch_compiles
+
+    watch_compiles()
     _harden_cache_writes()
     d = os.environ.get(CACHE_ENV) or DEFAULT_CACHE_DIR
     if jax.config.jax_compilation_cache_dir != d:
