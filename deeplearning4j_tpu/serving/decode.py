@@ -122,22 +122,38 @@ Two more host-overhead eliminations ride on top (docs/SERVING.md
 
   chunked prefill (``prefill_chunk=N``)
       Long prompts prefill in ≤ N-token chunks through ``prefill_at``
-      at increasing offsets, ONE chunk per loop iteration, so a long
-      prompt never serializes the decode step loop; the batcher's
-      token-budget admission rule paces a wall of prompts to the same
-      chunk budget.  Per-row attention math is unchanged, so the final
-      chunk's logits (and every sampled token) are bit-identical to an
-      unchunked prefill.  Under a fused horizon the iteration's chunk
-      is queued behind the step's dispatch, so the device runs it while
-      the host reads back and records the step's tokens, and a fused
-      dispatch's echoed logits are copied out one dispatch late
+      at increasing offsets, so a long prompt never serializes the
+      decode step loop.  The budget of a loop iteration is ONE CHUNK
+      FOR EACH SLOT MID-PREFILL at its start (``_chunk_budget``; no
+      option sets it): a prompt read at one chunk a turn whatever waits
+      holds its slot for as many turns as it has chunks, and a turn's
+      dispatch costs the same whether five slots decode or eight.  The
+      stall bound: between two of a decoding slot's dispatches lie at
+      most as many chunks as slots were mid-prefill, so at most
+      ``max_slots - 1`` chunks of ``prefill_chunk`` tokens, and that
+      many only when it is the one slot that decodes (nobody else is
+      stalled then); counters ``chunk_turns`` / ``chunk_turns_multi``,
+      the gauge ``chunk_turn_max`` and the ``chunks`` / ``mid_prefill``
+      arguments of ``serve/decode_step`` say how often and how far.
+      What stays one a turn is ADMISSION: the batcher's token-budget
+      rule lets one round take in no more prompt tokens than one chunk
+      (the head request always), which paces a wall of prompts.
+      Per-row attention math is unchanged, so the final chunk's logits
+      (and every sampled token) are bit-identical to an unchunked
+      prefill, and to any other number of chunks a turn.  Under a fused
+      horizon the iteration's chunks are queued behind the step's
+      dispatch, back to back, so the device runs them while the host
+      reads back and records the step's tokens, and a fused dispatch's
+      echoed logits are copied out one dispatch late
       (``_step_fused_once``, ``_flush_echo``): between two programs the
       host then only reads tokens, records them and dispatches.
-      Whose chunk goes next (``prefill_order``): ``"round_robin"`` over
-      the slots mid-prefill, or ``"nearest_end"``, the prompt with the
-      fewest tokens left first (``_nearest_end``): under a closed loop
-      of long prompts round-robin finishes every waiting prompt late
-      and together, so a third of the slots hold half-read prompts.
+      Whose chunk goes next (``prefill_order``, asked once a chunk):
+      ``"round_robin"`` over the slots mid-prefill, or
+      ``"nearest_end"``, the prompt with the fewest tokens left first
+      (``_nearest_end``; a turn may then hand several chunks to one
+      prompt, each starting where the last one ends): under a closed
+      loop of long prompts round-robin finishes every waiting prompt
+      late and together.
 
 TTFT and time-per-output-token are first-class (``DecodeMetrics``).
 Everything the loop thread does is a live ``obs.trace`` span under
@@ -706,8 +722,9 @@ class DecodeEngine:
         self._echo_rows: List[tuple] = []
         self._echo_results: List[tuple] = []
         self._echo_defer = False   # True while a fused step is recorded
-        # a chunk queued behind a fused step and not read back yet
-        self._chunk_inflight: Optional[_Chunk] = None
+        # chunks queued behind a fused step and not read back yet, in the
+        # order of their dispatch
+        self._chunk_inflight: List[_Chunk] = []
         # the plain loop's decode step on the device and not read back yet
         self._flight: Optional[_Flight] = None
         self._step_read_at = 0.0   # clock at the end of the last step's read
@@ -1448,7 +1465,7 @@ class DecodeEngine:
             with self._lock:
                 leave = self._shutdown or gen != self._generation
             if leave:
-                self._chunk_inflight = self._flight = None
+                self._chunk_inflight, self._flight = [], None
                 self._flush_echo()          # answers already finished
                 return
             # everything this thread does is under serve/iteration, so
@@ -1456,24 +1473,22 @@ class DecodeEngine:
             with obs_trace.span("serve/iteration", cat="serve") as it:
                 try:
                     worked = self._admit_some()
-                    # at most ONE chunk of prefill work per iteration,
-                    # so a decode dispatch never waits behind more than
-                    # prefill_chunk tokens
+                    # one admission round and at most ``_chunk_budget()``
+                    # chunks of prefill work per iteration: a chunk for
+                    # each slot mid-prefill, so a decode dispatch never
+                    # waits behind more than that many chunks
                     if self.decode_horizon > 1:
-                        # the fused step queues the chunk behind itself
-                        # and does its host work while the chunk runs
+                        # the fused step queues the chunks behind itself
+                        # and does its host work while they run
                         stepped = self._step_fused_once()
                         if not stepped:
                             # no slot to step: nothing else will cover
                             # the rows and results still owed
                             self._chunk_settle()
                             self._flush_echo()
-                            if self.prefill_chunk is not None:
-                                worked = (self._prefill_chunk_step()
-                                          or worked)
+                            worked = self._prefill_chunk_steps() or worked
                     else:
-                        if self.prefill_chunk is not None:
-                            worked = self._prefill_chunk_step() or worked
+                        worked = self._prefill_chunk_steps() or worked
                         if self._draft_program is not None:
                             stepped = self._spec_step_once()
                         else:
@@ -1822,6 +1837,49 @@ class DecodeEngine:
         s.rows_next = rows_h
         self._record_token(i, tok_h, fin_h, lg_h, t1)
 
+    def _chunk_budget(self) -> int:
+        """How many prefill chunks this turn may take: as many as slots
+        are mid-prefill at its start, one ``_chunk_pick`` each (so
+        ``prefill_order`` alone says whose).  A prompt read at one chunk
+        a turn whatever waits holds its slot for as many turns as it has
+        chunks while every dispatch is paid in full; read at this pace
+        the waiting prompts take one turn a chunk together.  What a
+        decoding slot pays is bounded by the same count: the turn's
+        dispatch and at most ``max_slots - 1`` chunks, and that many
+        only when it is the one slot that decodes."""
+        with self._lock:
+            return len(self._mid_prefill())
+
+    def _mid_prefill(self) -> List[int]:
+        """The slots with a chunk still to pick (a prompt whose last
+        chunk is on the device has none).  Caller holds ``_lock``."""
+        return [i for i, s in enumerate(self._slots)
+                if s is not None and s.n_prefilled is not None
+                and s.n_prefilled < s.n_prompt]
+
+    def _prefill_chunk_steps(self) -> bool:
+        """A turn's chunks where no fused dispatch is there to queue them
+        behind (the plain loop, or no slot decodes): ``_chunk_budget()``
+        of them, each waited for and committed before the next."""
+        if self.prefill_chunk is None:
+            return False
+        k = 0
+        for _ in range(self._chunk_budget()):
+            if not self._prefill_chunk_step():
+                break
+            k += 1
+        self._count_chunk_turn(k)
+        return k > 0
+
+    def _count_chunk_turn(self, k: int) -> None:
+        """``k`` chunks went to the device in one turn of the loop."""
+        if k:
+            self.metrics.inc("chunk_turns")
+            if k > 1:
+                self.metrics.inc("chunk_turns_multi")
+            if k > self.metrics.chunk_turn_max.value():
+                self.metrics.chunk_turn_max.set(k)
+
     def _prefill_chunk_step(self) -> bool:
         """Advance ONE pending chunked prefill by one chunk (at most
         ``prefill_chunk`` prompt tokens through the ``prefill_at``
@@ -1835,8 +1893,8 @@ class DecodeEngine:
         prefill of the whole prompt.
 
         Pick, dispatch, wait and commit are separate so that the fused
-        decode step can put the chunk's dispatch BEHIND its own on the
-        device and do its host work while the chunk runs
+        decode step can put its chunks' dispatches BEHIND its own on the
+        device and do its host work while they run
         (``_step_fused_once``); here they run back to back."""
         c = self._chunk_pick()
         if c is None:
@@ -1855,8 +1913,7 @@ class DecodeEngine:
         for a long prompt) would serve nobody."""
         now = self.clock()
         with self._lock:
-            pending = [i for i, s in enumerate(self._slots)
-                       if s is not None and s.n_prefilled is not None]
+            pending = self._mid_prefill()
             late = [i for i in pending if now > self._slots[i].deadline]
         for i in late:
             s = self._slots[i]
@@ -1911,13 +1968,17 @@ class DecodeEngine:
 
     def _chunk_dispatch(self, c: _Chunk) -> None:
         """Queue the chunk's program (and, after a final chunk, the
-        first token's sampler) on the device; nothing is read back."""
+        first token's sampler) on the device; nothing is read back.  The
+        slot's next chunk starts where this one ends, and may be picked
+        and queued behind it before either is read: the device runs them
+        in the order of their dispatch."""
         s, spec = c.slot, c.slot.spec
         kp, vp = self._cache
         kp, vp, c.lg, *c.aux = self._compiled[("prefill_at", c.bucket)](
             self._versions[s.tag], kp, vp, self._page_table[c.i], c.padded,
             np.int32(c.take), np.int32(c.offset), *self._slot_arg(c.i))
         self._cache = (kp, vp)
+        s.n_prefilled = c.offset + c.take
         if c.offset == 0 and self._slot_state:
             # the chunk at offset 0 starts the slot from zero state
             self.metrics.inc("recurrent_state_resets")
@@ -1942,7 +2003,6 @@ class DecodeEngine:
         s, i, t1 = c.slot, c.i, c.t1
         self.metrics.inc("prefill_chunks")
         if not c.last:
-            s.n_prefilled = c.offset + c.take
             return
         self.metrics.inc("prefills")
         if c.offset > s.n_matched * self.program.page_size:
@@ -2362,13 +2422,16 @@ class DecodeEngine:
         sampling).
 
         The host's work is kept off the device's critical path where it
-        can be.  With chunked prefill the iteration's one chunk is
-        queued BEHIND the first dispatch, so the device goes from the
-        steps into the chunk while the host reads the tokens back and
-        records them.  A final chunk is then waited for (its first token
-        joins the next step); any other is left running, the next
-        turn's dispatch is queued behind it, and its counts are read
-        after that turn's steps, when it is long done.  Echoed logits (``[H, slots, vocab]`` float32, the
+        can be.  With chunked prefill the iteration's chunks
+        (``_chunk_budget()``: one for each slot mid-prefill) are queued
+        BEHIND the first dispatch, back to back, so the device goes from
+        the steps into the chunks while the host reads the tokens back
+        and records them.  They are then read in the order of their
+        dispatch as far as the last FINAL chunk among them (its first
+        token joins the next step); those behind it are left running,
+        the next turn's dispatch is queued behind them, and their counts
+        are read after that turn's steps, when they are long done.
+        Echoed logits (``[H, slots, vocab]`` float32, the
         bulk of what a dispatch returns) ride one dispatch behind: their
         transfer is started at the dispatch, and the rows are copied to
         the requests' buffers after the NEXT dispatch is queued (or as
@@ -2390,8 +2453,8 @@ class DecodeEngine:
         if not tags:
             return False
         eos = np.int32(self.eos_id if self.eos_id is not None else -1)
-        chunk = None
-        chunk_due = self.prefill_chunk is not None
+        chunks: List[_Chunk] = []
+        chunks_due = self.prefill_chunk is not None
         for tag in tags:
             with obs_trace.span("serve/decode_step", cat="serve",
                                 model=tag, tokens=H) as sp:
@@ -2417,13 +2480,21 @@ class DecodeEngine:
                         lgs.copy_to_host_async()
                         if attn is not None:
                             attn.copy_to_host_async()
-                if chunk_due:
-                    chunk_due = False
-                    chunk = self._chunk_pick()
-                    if chunk is not None:
+                if chunks_due:
+                    chunks_due = False
+                    budget = self._chunk_budget()
+                    for _ in range(budget):
+                        chunk = self._chunk_pick()
+                        if chunk is None:
+                            break
                         with obs_trace.span("serve/prefill_dispatch",
                                             cat="serve", slot=chunk.i):
                             self._chunk_dispatch(chunk)
+                        chunks.append(chunk)
+                    self._count_chunk_turn(len(chunks))
+                    # queued behind this dispatch, of the slots that
+                    # were mid-prefill
+                    sp.set(chunks=len(chunks), mid_prefill=budget)
                 # the device is busy: the last dispatch's rows can land
                 self._flush_echo()
                 with obs_trace.span("serve/step_wait", cat="serve"):
@@ -2475,30 +2546,32 @@ class DecodeEngine:
                         if inp.echo:
                             self._echo_lgs = (lgs, attn)
                 self.metrics.inc("tokens_per_dispatch", committed)
-        # a chunk an earlier turn left running lies before this turn's
-        # steps on the device: it is done, and reading it costs no wait
+        # chunks an earlier turn left running lie before this turn's
+        # steps on the device: they are done, and reading them costs no
+        # wait
         self._chunk_settle()
-        if chunk is not None:
-            # the chunk is still running: this dispatch's rows land now
+        if chunks:
+            # the chunks are still running: this dispatch's rows land now
             self._flush_echo()
-            if chunk.last:
-                # its first token joins the next step: wait for it
-                self._chunk_inflight = chunk
-                self._chunk_settle()
-            else:
-                # nothing of it is needed before the next dispatch, so
-                # that is queued behind it with the device still busy;
-                # the slot's next chunk starts where this one ends
-                chunk.slot.n_prefilled = chunk.offset + chunk.take
-                self._chunk_inflight = chunk
-        elif chunk_due:             # no dispatch to queue it behind
-            self._prefill_chunk_step()
+            self._chunk_inflight = chunks
+            # a final chunk's first token joins the next step: wait as
+            # far as the last of them.  Nothing of the chunks behind it
+            # is needed before the next dispatch, so that is queued
+            # behind them with the device still busy
+            self._chunk_settle(max(
+                (k + 1 for k, c in enumerate(chunks) if c.last), default=0))
+        elif chunks_due:            # no dispatch to queue them behind
+            self._prefill_chunk_steps()
         return True
 
-    def _chunk_settle(self) -> None:
-        """Read back and commit the chunk left on the device, if any."""
-        c, self._chunk_inflight = self._chunk_inflight, None
-        if c is not None:
+    def _chunk_settle(self, n: Optional[int] = None) -> None:
+        """Read back and commit, in the order of their dispatch, the
+        first ``n`` chunks left on the device (all of them by default)."""
+        flight = self._chunk_inflight
+        if n is None:
+            n = len(flight)
+        done, self._chunk_inflight = flight[:n], flight[n:]
+        for c in done:
             with self._chunk_span(c) as sp:
                 self._chunk_wait(c, sp)
             self._chunk_commit(c)
@@ -2788,7 +2861,7 @@ class DecodeEngine:
             obs_trace.instant("serve/replica_crash", cat="serve",
                               kind="echo_flush", error=type(e).__name__)
         # their slots are wiped with the rest, what they computed dropped
-        self._chunk_inflight = self._flight = None
+        self._chunk_inflight, self._flight = [], None
         with self._lock:
             in_flight = [s for s in self._slots if s is not None]
             self._slots = [None] * self.max_slots

@@ -271,6 +271,10 @@ _DECODE_COUNTER_KEYS = (
     # prompt/chunk counts
     "fused_dispatches", "tokens_per_dispatch",
     "chunked_prefills", "prefill_chunks",
+    # turns of the loop that sent at least one chunk to the device, and
+    # those that sent more than one (a chunk for each slot mid-prefill:
+    # prefill_chunks / fused_dispatches = chunks a turn)
+    "chunk_turns", "chunk_turns_multi",
     # the plain loop's step in flight: decode steps queued while the step
     # before was unread, steps read in the turn that queued them (two
     # version tags alive), slot-steps computed for a request that the
@@ -347,6 +351,10 @@ class DecodeMetrics:
         self.free_pages.set(0)
         self.free_slots = self.registry.gauge("free_slots")
         self.free_slots.set(0)
+        # the most chunks one turn sent to the device (the stall a
+        # decoding slot met at worst, in chunks; at most max_slots)
+        self.chunk_turn_max = self.registry.gauge("chunk_turn_max")
+        self.chunk_turn_max.set(0)
         # bytes one cached token holds in the pool, all layers (set at load)
         self.kv_bytes_per_token = self.registry.gauge("kv_bytes_per_token")
         self.kv_bytes_per_token.set(0)
@@ -393,6 +401,7 @@ class DecodeMetrics:
             "shared_pages": int(self.shared_pages.value()),
             "free_pages": int(self.free_pages.value()),
             "free_slots": int(self.free_slots.value()),
+            "chunk_turn_max": int(self.chunk_turn_max.value()),
             "kv_bytes_per_token": int(self.kv_bytes_per_token.value()),
             "recurrent_state_bytes": int(self.recurrent_state_bytes.value()),
             "accepted_tokens_per_step": round(

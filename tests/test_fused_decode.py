@@ -397,7 +397,7 @@ class TestFusedChunkedEcho:
                  and "offset" in e["args"]]
         assert len(waits) >= len(queued)
         assert not any(within(w, s) for w in waits for s in steps)
-        assert eng._chunk_inflight is None
+        assert eng._chunk_inflight == []
         c = eng.metrics_snapshot()["counters"]
         assert c["prefill_chunks"] - c0["prefill_chunks"] == len(waits) == 3
 
@@ -433,6 +433,205 @@ class TestFusedChunkedEcho:
         eng.shutdown()
         assert all(len(r.logits) == len(r.tokens) for r in done)
         assert eng._echo_lgs is None and not eng._echo_results
+
+
+# -- a chunk a turn for each slot mid-prefill --------------------------------
+
+class TestChunkBudget:
+    """A fused turn queues a chunk for EACH slot mid-prefill behind its
+    dispatch (``_chunk_budget``), where it used to queue one whatever
+    waited: only the order of the programs on the device changes, so
+    every answer keeps its bits, and no turn takes more chunks than
+    slots were mid-prefill at its start."""
+
+    SLOTS = 4
+    CLOCK_OFFSET = [0.0]
+
+    @classmethod
+    def _clock(cls):
+        return time.monotonic() + cls.CLOCK_OFFSET[0]
+
+    @classmethod
+    def _engine(cls, lm, order, horizon=H, one_a_turn=False):
+        eng = _make(lm, max_slots=cls.SLOTS, prefill_chunk=CHUNK,
+                    decode_horizon=horizon, prefill_order=order,
+                    clock=cls._clock)
+        if one_a_turn:          # the schedule before: one chunk whatever waits
+            many = eng._chunk_budget
+            eng._chunk_budget = lambda: min(1, many())
+        return eng
+
+    @staticmethod
+    def _long_prompts():
+        """Three prompts of three or four chunks each."""
+        return [[1 + (i * k) % (VOCAB - 1) for i in range(n)]
+                for k, n in ((3, 50), (5, 41), (7, 47))]
+
+    @classmethod
+    def _serve(cls, eng, before_prompts=None):
+        """One request that decodes throughout and, once it does, three
+        long prompts at once; the answers in that order."""
+        t0 = eng.metrics.counter_value("tokens_out")
+        first = eng.generate_async([2, 7, 1], max_new_tokens=56,
+                                   echo_logits=True)
+        limit = time.monotonic() + 60
+        while eng.metrics.counter_value("tokens_out") == t0:
+            assert time.monotonic() < limit, "the first prefill never landed"
+            time.sleep(0.0005)
+        if before_prompts is not None:
+            before_prompts()
+        with eng._lock:         # the loop's next turn finds all three queued
+            futs = [eng.generate_async(p, slo_ms=3_600_000.0, **kw)
+                    for p, kw in zip(cls._long_prompts(), (
+                        dict(max_new_tokens=9, echo_logits=True),
+                        dict(max_new_tokens=7, temperature=0.8, top_k=5,
+                             seed=3),
+                        dict(max_new_tokens=12, echo_logits=True)))]
+        return [first] + futs
+
+    @staticmethod
+    def _turns(rec):
+        """(the ``serve/decode_step`` span, the ``serve/prefill_dispatch``
+        spans inside it) of every fused turn recorded."""
+        ev = [e for e in rec.export()["traceEvents"] if e.get("ph") == "X"]
+        steps = [e for e in ev if e["name"] == "serve/decode_step"]
+        queued = [e for e in ev if e["name"] == "serve/prefill_dispatch"]
+        inside = lambda q, s: (s["ts"] <= q["ts"] and q["ts"] + q["dur"]
+                               <= s["ts"] + s["dur"])
+        turns = [(s, [q for q in queued if inside(q, s)]) for s in steps]
+        assert sum(len(qs) for _, qs in turns) == len(queued)
+        return turns, ev
+
+    @pytest.mark.parametrize("order", ["round_robin", "nearest_end"])
+    def test_answers_keep_their_bits_and_no_turn_passes_its_bound(
+            self, lm, order):
+        got = {}
+        for name, kw in (("many", {}), ("one", {"one_a_turn": True}),
+                         ("steps", {"horizon": 1})):
+            eng = self._engine(lm, order, **kw)
+            rec = obs_trace.TraceRecorder()
+            old = obs_trace.set_recorder(rec)
+            try:
+                n0 = eng.compile_cache_size()
+                got[name] = [f.result(timeout=120) for f in self._serve(eng)]
+                assert eng.compile_cache_size() == n0
+                assert eng._chunk_inflight == [] and _partition_ok(eng)
+                snap = eng.metrics_snapshot()
+            finally:
+                obs_trace.set_recorder(old)
+                eng.shutdown()
+            if name == "steps":
+                continue
+            turns, ev = self._turns(rec)
+            c = snap["counters"]
+            chunks = [s["args"].get("chunks", 0) for s, _ in turns]
+            # (b) as many dispatches inside the turn's step as its span
+            # says, each read back after a step and never inside one
+            assert chunks == [len(qs) for _, qs in turns]
+            waits = [e for e in ev if e["name"] == "serve/prefill"
+                     and "offset" in e["args"]]
+            assert len(waits) == c["prefill_chunks"] == 1 + 4 + 3 + 3
+            for w in waits:
+                assert not any(s["ts"] <= w["ts"] and w["ts"] + w["dur"]
+                               <= s["ts"] + s["dur"] for s, _ in turns)
+            # (c) never more chunks than slots were mid-prefill, and with
+            # a slot decoding that is fewer than the engine has slots
+            mid = [s["args"]["mid_prefill"] for s, _ in turns]
+            assert all(k <= p <= self.SLOTS - 1 for k, p in zip(chunks, mid))
+            assert snap["chunk_turn_max"] >= max(chunks)
+            assert c["chunk_turns"] >= sum(k >= 1 for k in chunks)
+            multi = sum(k > 1 for k in chunks)
+            if name == "one":       # what the parent queued
+                assert max(chunks) == 1 and c["chunk_turns_multi"] == 0
+                assert snap["chunk_turn_max"] == 1
+            else:
+                # no deadline passes here, so every waiting prompt got
+                # its chunk: three prompts wait beside the one that decodes
+                assert chunks == mid and max(chunks) == 3
+                assert c["chunk_turns_multi"] == multi >= 2
+                assert snap["chunk_turn_max"] == 3
+        # (a) the same bits as under one chunk a turn; the step-by-step
+        # engine's tokens, and its logits as close as two horizons come
+        for many, one, steps in zip(got["many"], got["one"], got["steps"]):
+            assert many.tokens == one.tokens == steps.tokens
+            assert many.finish_reason == one.finish_reason == "max_tokens"
+            if one.logits is None:
+                assert many.logits is None
+                continue
+            np.testing.assert_array_equal(many.logits, one.logits)
+            np.testing.assert_allclose(many.logits, steps.logits,
+                                       rtol=1e-5, atol=1e-6)
+
+    def _at_pick(self, eng, n, then):
+        """Call ``then`` on the loop thread right before chunk pick ``n``
+        (the first pick of the third turn with prompts: two chunks of
+        the turn before are still on the device then)."""
+        picks, pick = [0], eng._chunk_pick
+
+        def counted():
+            picks[0] += 1
+            if picks[0] == n:
+                then()
+            return pick()
+        eng._chunk_pick = counted
+
+    def test_a_deadline_that_passes_with_chunks_in_flight_gives_them_up(
+            self, lm):
+        from deeplearning4j_tpu.serving.batcher import DeadlineExceededError
+
+        ref = self._engine(lm, "round_robin")
+        try:
+            whole = self._serve(ref)[0].result(timeout=120)
+        finally:
+            ref.shutdown()
+        eng = self._engine(lm, "round_robin")
+        try:
+            seen = []
+
+            def later():
+                seen.append(len(eng._chunk_inflight))
+                self.CLOCK_OFFSET[0] = 7200.0
+            futs = self._serve(eng, lambda: self._at_pick(eng, 4, later))
+            for f in futs[1:]:
+                with pytest.raises(DeadlineExceededError):
+                    f.result(timeout=120)
+            # the request beside them states the engine's own deadline,
+            # which the jump passed too: cut, with every row it had
+            first = futs[0].result(timeout=120)
+            assert seen == [2] and first.finish_reason == "deadline"
+            n = len(first.tokens)
+            assert 1 <= n < 56 and first.tokens == whole.tokens[:n]
+            np.testing.assert_array_equal(first.logits, whole.logits[:n])
+            assert eng._chunk_inflight == [] and _partition_ok(eng)
+        finally:
+            self.CLOCK_OFFSET[0] = 0.0
+            eng.shutdown()
+
+    def test_a_crash_with_chunks_in_flight_retries_to_the_same_bits(
+            self, lm):
+        ref = self._engine(lm, "nearest_end")
+        try:
+            want = [f.result(timeout=120) for f in self._serve(ref)]
+        finally:
+            ref.shutdown()
+        eng = self._engine(lm, "nearest_end")
+        try:
+            seen = []
+
+            def crash():
+                seen.append(len(eng._chunk_inflight))
+                eng._crash_next = True      # the next turn's dispatch
+            futs = self._serve(eng, lambda: self._at_pick(eng, 4, crash))
+            got = [f.result(timeout=120) for f in futs]
+            assert seen == [2]
+            assert eng.metrics.counter_value("replica_crashes") == 1
+            for g, w in zip(got, want):
+                assert g.tokens == w.tokens
+                if w.logits is not None:
+                    np.testing.assert_array_equal(g.logits, w.logits)
+            assert eng._chunk_inflight == [] and _partition_ok(eng)
+        finally:
+            eng.shutdown()
 
 
 # -- chunked prefill -------------------------------------------------------

@@ -540,6 +540,58 @@ def test_a_slot_admitted_a_second_request_serves_what_a_fresh_engine_serves(
     np.testing.assert_array_equal(got.logits, want.logits)
 
 
+def test_two_chunks_of_one_slot_in_one_turn_leave_the_state_of_one_a_turn(lm):
+    """``nearest_end`` hands a turn's second chunk to the prompt it gave
+    the first (the one nearest its end), queued behind it before either
+    is read: the slot's state rides from one to the next on the device,
+    and state, logits and tokens are those of one chunk a turn."""
+    import time
+
+    from deeplearning4j_tpu.obs import trace as obs_trace
+
+    def serve(one_a_turn):
+        eng = DecodeEngine(lm, max_slots=3, page_size=4, max_len=128,
+                           prompt_buckets=[8, 16], prefill_chunk=16,
+                           decode_horizon=4,
+                           prefill_order="nearest_end").load()
+        if one_a_turn:
+            many = eng._chunk_budget
+            eng._chunk_budget = lambda: min(1, many())
+        rec = obs_trace.enable_tracing(capacity=65536)
+        try:
+            beside = eng.generate_async(TOKENS[90:95], max_new_tokens=60)
+            while not eng.metrics.counter_value("tokens_out"):
+                time.sleep(0.0005)
+            with eng._lock:     # both queued before the loop's next turn
+                futs = [eng.generate_async(TOKENS[a:b], max_new_tokens=6,
+                                           **_ECHO)
+                        for a, b in ((0, 53), (20, 90))]
+            out = [f.result(timeout=300) for f in futs]
+            beside.result(timeout=300)
+            return out, rec.events(), eng.metrics_snapshot()["counters"]
+        finally:
+            obs_trace.disable_tracing()
+            eng.shutdown()
+
+    got, events, c = serve(False)
+    want, _, _ = serve(True)
+    steps = [e for e in events if e["name"] == "serve/decode_step"]
+    queued = [e for e in events if e["name"] == "serve/prefill_dispatch"]
+    twice = [s for s in steps if s["args"].get("chunks", 0) >= 2
+             and len({q["args"]["slot"] for q in queued
+                      if s["ts"] <= q["ts"] <= s["ts"] + s["dur"]}) == 1]
+    assert twice, "no turn queued two chunks of one slot"
+    assert c["recurrent_state_resets"] == 3 and c["chunk_turns_multi"] >= 1
+    assert c["state_rows_scanned"] == 3 * (5 + 53 + 70)
+    for g, w in zip(got, want):
+        assert g.tokens == w.tokens
+        np.testing.assert_array_equal(g.logits, w.logits)
+        for (S, tail), (S_w, tail_w) in zip(g.slot_state, w.slot_state):
+            np.testing.assert_array_equal(np.asarray(S), np.asarray(S_w))
+            np.testing.assert_array_equal(np.asarray(tail),
+                                          np.asarray(tail_w))
+
+
 def test_the_spans_and_counters_say_what_the_state_did(lm):
     from deeplearning4j_tpu.obs import trace as obs_trace
     rec = obs_trace.enable_tracing(capacity=65536)
