@@ -17,7 +17,12 @@ softmax router; and the block whose layers are of TWO kinds
 (``models/linear_gqa.py``): gated grouped-query attention without
 positions at the ``gqa_layers`` indices, a gated delta-rule linear
 attention with a per-slot recurrent state everywhere else, every layer an
-expert layer with a shared expert.
+expert layer with a shared expert; and the block of Mamba-2 state-space
+layers beside ungated NoPE grouped-query layers (``models/ssm_gqa.py``):
+one scalar decay a head from an input-dependent step, a per-slot state
+``[heads, d_head, d_state]``, every layer a softmax-routed expert layer
+with a shared MLP, scalars on embedding, residual, attention and logits,
+a tied head.
 
 ``from_config`` reads a configuration file's keys (the published
 ``config.json`` names of each family), so a model is a data file and
@@ -30,13 +35,17 @@ import dataclasses
 import math
 from typing import Any, Dict
 
-BLOCKS = ("gpt2", "latent_moe", "sparse_gqa", "linear_gqa")
+BLOCKS = ("gpt2", "latent_moe", "sparse_gqa", "linear_gqa", "ssm_gqa")
 #: the blocks of the expert family (``models/<block>.py``): served through
 #: one decode-program builder, not trained yet
-EXPERT_BLOCKS = ("latent_moe", "sparse_gqa", "linear_gqa")
+EXPERT_BLOCKS = ("latent_moe", "sparse_gqa", "linear_gqa", "ssm_gqa")
 #: what a layer remembers (``LMArch.layer_types``): rows in the paged pools
-#: a token, or a per-slot recurrent state
-LAYER_TYPES = ("gqa", "linear")
+#: a token, or a per-slot recurrent state (the delta rule's, or a
+#: state-space layer's)
+LAYER_TYPES = ("gqa", "linear", "mamba")
+#: which of them each block of two layer kinds may name
+BLOCK_LAYER_TYPES = {"linear_gqa": ("gqa", "linear"),
+                     "ssm_gqa": ("gqa", "mamba")}
 ROUTERS = ("noaux_tc", "softmax_topk")
 
 
@@ -75,6 +84,20 @@ class LMArch:
     linear_n_heads: int = 0        # the linear layers' heads (q, k and v) ...
     linear_head_dim: int = 0       # ... of this width: state [dim, dim] a head
     conv_kernel: int = 0           # taps of their causal depthwise convolution
+    # -- state-space layers beside grouped-query layers (ssm_gqa) ---------
+    mamba_n_heads: int = 0         # heads of the state-space layers ...
+    mamba_d_head: int = 0          # ... of this width (heads * width = inner)
+    mamba_d_state: int = 0         # state [d_head, d_state] a head
+    mamba_d_conv: int = 0          # taps of the convolution over x | B | C
+    mamba_expand: int = 0          # inner width / d_model
+    mamba_n_groups: int = 1        # groups that share B and C
+    mamba_chunk_size: int = 256    # rows of a chunk of the scan
+    mamba_conv_bias: bool = True   # the convolution's; the projections have none
+    embedding_multiplier: float = 1.0   # on the embedding's rows
+    residual_multiplier: float = 1.0    # on what a layer adds to the stream
+    logits_scaling: float = 1.0         # the logits are DIVIDED by it
+    attention_multiplier: float = 0.0   # the softmax scale; 0 = head_dim^-0.5
+    tie_embeddings: bool = False        # the head is the embedding, transposed
     # -- experts ------------------------------------------------------------
     n_dense_layers: int = 0        # leading layers with a dense feed-forward
     moe_d_ff: int = 0
@@ -118,22 +141,39 @@ class LMArch:
                     f"head_dim / 2 = {self.head_dim // 2}")
             if self.n_dense_layers:
                 raise ValueError("every sparse_gqa layer is an expert layer")
-        if self.block == "linear_gqa":
-            for k in ("n_kv_heads", "head_dim", "linear_n_heads",
-                      "linear_head_dim"):
+        if self.block in BLOCK_LAYER_TYPES:
+            needs = {"linear_gqa": ("linear_n_heads", "linear_head_dim"),
+                     "ssm_gqa": ("mamba_n_heads", "mamba_d_head",
+                                 "mamba_d_state", "mamba_chunk_size")}
+            for k in ("n_kv_heads", "head_dim") + needs[self.block]:
                 if getattr(self, k) < 1:
-                    raise ValueError(f"linear_gqa needs {k} >= 1")
-            if self.conv_kernel < 2:
-                raise ValueError("linear_gqa needs conv_kernel >= 2")
+                    raise ValueError(f"{self.block} needs {k} >= 1")
+            taps = "conv_kernel" if self.block == "linear_gqa" \
+                else "mamba_d_conv"
+            if getattr(self, taps) < 2:
+                raise ValueError(f"{self.block} needs {taps} >= 2")
             if self.n_heads % self.n_kv_heads:
                 raise ValueError("n_heads must be a multiple of n_kv_heads")
+            kinds = BLOCK_LAYER_TYPES[self.block]
             if (len(self.layer_types) != self.n_layers
-                    or any(t not in LAYER_TYPES for t in self.layer_types)):
+                    or any(t not in kinds for t in self.layer_types)):
                 raise ValueError(
-                    f"layer_types must name one of {LAYER_TYPES} for each of "
+                    f"layer_types must name one of {kinds} for each of "
                     f"the {self.n_layers} layers, got {self.layer_types!r}")
             if self.n_dense_layers:
-                raise ValueError("every linear_gqa layer is an expert layer")
+                raise ValueError(f"every {self.block} layer is an expert "
+                                 "layer")
+        if self.block == "ssm_gqa":
+            if self.mamba_n_groups != 1:
+                raise ValueError("ssm_gqa needs mamba_n_groups == 1 (B and C "
+                                 "shared by all heads)")
+            if self.mamba_n_heads * self.mamba_d_head \
+                    != self.mamba_expand * self.d_model:
+                raise ValueError(
+                    f"mamba_n_heads * mamba_d_head = "
+                    f"{self.mamba_n_heads * self.mamba_d_head} must be "
+                    f"mamba_expand * d_model = "
+                    f"{self.mamba_expand * self.d_model}")
         if self.router not in ROUTERS:
             raise ValueError(f"router must be one of {ROUTERS}, got "
                              f"{self.router!r}")
@@ -176,6 +216,22 @@ class LMArch:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
 
     @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels the state-space layers' convolution runs over:
+        ``x | B | C``."""
+        return self.mamba_d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
+
+    @property
+    def gqa_scale(self) -> float:
+        """The grouped-query layers' softmax scale: ``attention_multiplier``
+        where the file states one, else ``head_dim^-0.5``."""
+        return self.attention_multiplier or self.head_dim ** -0.5
+
+    @property
     def softmax_scale(self) -> float:
         s = self.qk_head_dim ** -0.5
         if self.rope_factor > 1.0:
@@ -206,7 +262,14 @@ class LMArch:
         ``linear_attn_config`` beside ``gqa_layers`` is the block of two
         layer kinds (DeepSeek-V3's expert names, as the first family):
         ``gqa_layers`` is kept as published and read up to
-        ``num_hidden_layers``.  ``over`` replaces any field."""
+        ``num_hidden_layers``.  One whose ``layer_types`` name ``mamba`` and
+        ``attention`` beside the ``mamba_*`` sizes is the block of
+        state-space and grouped-query layers (GraniteMoeHybrid's names):
+        ``layer_types`` is kept as published and read up to
+        ``num_hidden_layers``; ``num_local_experts`` counts the experts
+        HELD and ``num_local_experts_published``, when stated, is the
+        router's width; the shared MLP of ``shared_intermediate_size`` is
+        that many experts' width in one.  ``over`` replaces any field."""
         if "n_embd" in cfg:
             kw = dict(vocab_size=cfg["vocab_size"], n_layers=cfg["n_layer"],
                       d_model=cfg["n_embd"], n_heads=cfg["n_head"],
@@ -351,13 +414,75 @@ class LMArch:
                     f"{la['num_kv_heads']!r} is not expressible by the "
                     "linear_gqa block (supported: as many key and value "
                     "heads as query heads)")
+        elif "layer_types" in cfg and "mamba_n_heads" in cfg:
+            n_layers = int(cfg["num_hidden_layers"])
+            held = int(cfg["num_local_experts"])
+            kinds = {"mamba": "mamba", "attention": "gqa"}
+            named = list(cfg["layer_types"])[:n_layers]
+            if len(named) != n_layers or any(t not in kinds for t in named):
+                raise ValueError(
+                    f"config key layer_types={cfg['layer_types']!r} is not "
+                    "expressible by the ssm_gqa block (supported: one of "
+                    f"{tuple(kinds)} for each of the {n_layers} layers)")
+            moe_d_ff = int(cfg["intermediate_size"])
+            shared = int(cfg.get("shared_intermediate_size", 0))
+            if shared % moe_d_ff:
+                raise ValueError(
+                    f"config key shared_intermediate_size={shared!r} is not "
+                    "expressible by the ssm_gqa block (supported: a whole "
+                    f"multiple of intermediate_size {moe_d_ff})")
+            kw = dict(
+                block="ssm_gqa", vocab_size=cfg["vocab_size"],
+                n_layers=n_layers, d_model=cfg["hidden_size"],
+                n_heads=cfg["num_attention_heads"],
+                n_kv_heads=cfg["num_key_value_heads"],
+                head_dim=int(cfg.get("head_dim") or cfg["hidden_size"]
+                             // cfg["num_attention_heads"]),
+                max_len=cfg["max_position_embeddings"],
+                rms_eps=cfg.get("rms_norm_eps", 1e-6),
+                # the published list, read up to the depth held
+                layer_types=tuple(kinds[t] for t in named),
+                mamba_n_heads=cfg["mamba_n_heads"],
+                mamba_d_head=cfg["mamba_d_head"],
+                mamba_d_state=cfg["mamba_d_state"],
+                mamba_d_conv=cfg["mamba_d_conv"],
+                mamba_expand=cfg["mamba_expand"],
+                mamba_n_groups=cfg.get("mamba_n_groups", 1),
+                mamba_chunk_size=cfg.get("mamba_chunk_size", 256),
+                mamba_conv_bias=bool(cfg.get("mamba_conv_bias", True)),
+                embedding_multiplier=float(cfg.get("embedding_multiplier", 1)),
+                residual_multiplier=float(cfg.get("residual_multiplier", 1)),
+                logits_scaling=float(cfg.get("logits_scaling", 1)),
+                attention_multiplier=float(
+                    cfg.get("attention_multiplier") or 0.0),
+                tie_embeddings=bool(cfg.get("tie_word_embeddings", False)),
+                moe_d_ff=moe_d_ff,
+                n_experts=int(cfg.get("num_local_experts_published", held)),
+                experts_held=held,
+                first_expert=int(cfg.get("first_expert", 0)),
+                experts_per_token=cfg["num_experts_per_tok"],
+                n_shared_experts=shared // moe_d_ff,
+                router="softmax_topk",
+                init_std=cfg.get("initializer_range", 0.02))
+            unsupported = {
+                "position_embedding_type": ("nope",), "mamba_n_groups": (1,),
+                "attention_bias": (False,), "mamba_proj_bias": (False,),
+                "hidden_act": ("silu",), "rope_scaling": (None,),
+                "normalization_function": ("rmsnorm",)}
+            for k, ok in unsupported.items():
+                if k in cfg and cfg[k] not in ok:
+                    raise ValueError(
+                        f"config key {k}={cfg[k]!r} is not expressible by "
+                        f"the ssm_gqa block (supported: {ok})")
         else:
             raise ValueError(
                 "configuration names neither a GPT-2 block (n_embd), a "
                 "latent/expert block (kv_lora_rank), a grouped-query "
                 "block over a learned selection (sa_config with "
-                "num_key_value_heads) nor a block of linear-attention and "
-                "grouped-query layers (linear_attn_config with gqa_layers)")
+                "num_key_value_heads), a block of linear-attention and "
+                "grouped-query layers (linear_attn_config with gqa_layers) "
+                "nor a block of state-space and grouped-query layers "
+                "(layer_types naming mamba / attention with mamba_n_heads)")
         kw.update(over)
         return cls(**kw)
 

@@ -33,10 +33,18 @@ The feed-forward of the leading ``n_dense_layers`` is a gated SiLU
 experts held here, the shared expert.
 
 The decode program is the expert family's one builder
-(``expert_decode_program``, which ``models/sparse_gqa.py`` shares: the
-entry points, the layer loop, the write of the new rows, ``layer_finish``
-and the aux read-back), around the attention a block hands it
-(:class:`CachedAttention`).  This block's threads ONE latent pool
+(``expert_decode_program``, which ``models/sparse_gqa.py``,
+``models/linear_gqa.py`` and ``models/ssm_gqa.py`` share: the entry
+points, the layer loop, the write of the new rows, ``layer_finish`` and
+the aux read-back), around the attention a block hands it
+(:class:`CachedAttention`).  The builder scales embedding, residual and
+logits and ties the head where the architecture says so
+(``LMArch.embedding_multiplier`` / ``residual_multiplier`` /
+``logits_scaling`` / ``tie_embeddings``): only ``models/ssm_gqa.py``'s
+family may state those (``LMArch.from_config`` refuses
+``tie_word_embeddings: true`` for this block, ``sparse_gqa`` and
+``linear_gqa`` by name, and none of the three reads a multiplier), so
+their programs trace as they did.  This block's threads ONE latent pool
 ``[layers, pages, page, latent_lanes]`` through ``prefill`` /
 ``prefill_at`` / ``step``: a row is
 the ``latent_width`` cached values in the next multiple of 128 lanes,
@@ -319,25 +327,46 @@ def layer_finish(p, h: Array, att: Array, arch: LMArch,
                  valid: Optional[Array] = None):
     """Second half of the layer: output projection, residual, the
     feed-forward the tree holds (dense, or routed + shared experts).
-    ``h`` [N, d] float32.  Returns ``(h, picks or None, stats or
-    None)`` (``parallel/moe.moe_forward_held``'s)."""
-    h = h + _mm(att, p["W_o"])
+    ``h`` [N, d] float32.  What the mixer and the feed-forward add to
+    the stream is scaled by ``arch.residual_multiplier`` where the file
+    states one (``models/ssm_gqa.py``'s family; the others state none and
+    trace no product).  Returns ``(h, picks or None, stats or None)``
+    (``parallel/moe.moe_forward_held``'s)."""
+    r = arch.residual_multiplier
+    scaled = (lambda y: y) if r == 1.0 else (lambda y: r * y)
+    h = h + scaled(_mm(att, p["W_o"]))
     u = rms_norm(h, p["ln2_g"], arch.rms_eps)
     if "W_gate" in p:
-        return h + gated_silu(u, p["W_gate"], p["W_up"], p["W_down"]), \
-            None, None
+        return h + scaled(gated_silu(u, p["W_gate"], p["W_up"],
+                                     p["W_down"])), None, None
     y, picks, stats = moe_forward_held(
         p, u, first_expert=arch.first_expert, k=arch.experts_per_token,
         scaling=arch.routed_scaling_factor, valid=valid, router=arch.router)
-    return h + y, picks, stats
+    return h + scaled(y), picks, stats
 
 
-def _embed(params, tokens):
-    return params["embed"][tokens].astype(jnp.float32)
+def _embed(params, tokens, arch: LMArch):
+    """The tokens' rows of the embedding in float32, times
+    ``arch.embedding_multiplier`` where the file states one."""
+    h = params["embed"][tokens].astype(jnp.float32)
+    return h if arch.embedding_multiplier == 1.0 \
+        else arch.embedding_multiplier * h
 
 
 def _logits(params, h, arch: LMArch):
-    return _mm(rms_norm(h, params["lnf_g"], arch.rms_eps), params["head"])
+    """``RMSNorm(h)`` times the head, or times the embedding transposed
+    where the head is tied (contracted over the embedding's own minor
+    axis: no transposed copy of the table), divided by
+    ``arch.logits_scaling`` where the file states one."""
+    u = rms_norm(h, params["lnf_g"], arch.rms_eps)
+    if arch.tie_embeddings:
+        e = params["embed"]
+        out = jax.lax.dot_general(
+            u.astype(e.dtype), e, (((u.ndim - 1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    else:
+        out = _mm(u, params["head"])
+    return out if arch.logits_scaling == 1.0 else out / arch.logits_scaling
 
 
 def _join_aux(picks: List[Array], stats: List[Array], arch: LMArch,
@@ -359,7 +388,7 @@ def forward(params, tokens: Array, arch: LMArch, with_aux: bool = False):
     cos, sin = rope_tables(arch, tokens.shape[1])
 
     def one(seq):
-        h = _embed(params, seq)
+        h = _embed(params, seq, arch)
         picks, stats = [], []
         for p in params["blocks"]:
             qn, qp, rows = mla_project(p, h, cos, sin, arch)
@@ -566,7 +595,7 @@ def expert_decode_program(arch: LMArch, page_size: int,
         at = jnp.clip(pos, 0, L - 1)
         rope = jax.tree_util.tree_map(lambda t: t[at], att.tables)
         valid = jnp.arange(tb) < n_real
-        h = _embed(params, tokens)
+        h = _embed(params, tokens, arch)
         rows_all, picks, stats, extras = [], [], [], []
         for i, p in enumerate(params["blocks"]):
             if kinds[i] == "state":
@@ -608,7 +637,7 @@ def expert_decode_program(arch: LMArch, page_size: int,
         at = jnp.clip(positions, 0, L - 1)
         rope = jax.tree_util.tree_map(lambda t: t[at], att.tables)
         table = jnp.where(active[:, None], page_table, SCRATCH_PAGE)
-        h = _embed(params, tokens)
+        h = _embed(params, tokens, arch)
         rows_all, picks, stats, extras = [], [], [], []
         for i, p in enumerate(params["blocks"]):
             if kinds[i] == "state":

@@ -186,20 +186,22 @@ def gqa_project(p: Dict[str, Array], h: Array, arch: LMArch):
 
 
 def gqa_attend_step(q: Array, k_new: Array, v_new: Array, arch: LMArch,
-                    positions: Array, read_block, n_held, block: int
-                    ) -> Array:
+                    positions: Array, read_block, n_held, block: int,
+                    scale: Optional[float] = None) -> Array:
     """One new row a slot (``q`` [S, H, D], ``k_new`` / ``v_new`` [S, KV *
     D], not in the pools yet) over the ``positions[s]`` rows slot ``s``
     holds, read a block a slot at a time up to row ``n_held`` (the
     fullest slot's): ``read_block(j)`` gives the K and V rows ``j * block
     ..`` of every slot as [S, block, lanes].  The softmax starts from the
-    slot's own row and is carried from block to block.  [S, H * D]."""
+    slot's own row and is carried from block to block, at scale
+    ``head_dim^-0.5`` unless ``scale`` says otherwise.  [S, H * D]."""
     S = q.shape[0]
     H, KV, D = arch.n_heads, arch.n_kv_heads, arch.head_dim
+    scale = D ** -0.5 if scale is None else scale
     cd = k_new.dtype
     qg = q.reshape(S, KV, H // KV, D).astype(cd)
     m = jnp.einsum("sgqd,sgd->sgq", qg, k_new.reshape(S, KV, D),
-                   preferred_element_type=jnp.float32)[..., None] * D ** -0.5
+                   preferred_element_type=jnp.float32)[..., None] * scale
     z = jnp.ones_like(m)
     acc = jnp.broadcast_to(
         v_new.reshape(S, KV, 1, D).astype(jnp.float32), qg.shape)
@@ -208,7 +210,7 @@ def gqa_attend_step(q: Array, k_new: Array, v_new: Array, arch: LMArch,
         m, z, acc = carry
         k_rows, v_rows = read_block(j)
         s = jnp.einsum("sgqd,slgd->sgql", qg, k_rows.reshape(S, block, KV, D),
-                       preferred_element_type=jnp.float32) * D ** -0.5
+                       preferred_element_type=jnp.float32) * scale
         seen = j * block + jnp.arange(block)[None, :] < positions[:, None]
         s = jnp.where(seen[:, None, None, :], s, NEG_INF)
         m2 = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
@@ -408,7 +410,7 @@ def forward(params, tokens: Array, arch: LMArch, with_aux: bool = False):
     """Full forward of ``tokens`` [B, T] with nothing cached and zero
     state: logits [B, T, V] float32."""
     def one(seq):
-        h = _embed(params, seq)
+        h = _embed(params, seq, arch)
         picks, stats = [], []
         for p, kind in zip(params["blocks"], arch.layer_types):
             if kind == "gqa":
@@ -430,13 +432,18 @@ def forward(params, tokens: Array, arch: LMArch, with_aux: bool = False):
 
 # -- the decode program ---------------------------------------------------------
 
-def mixers(arch: LMArch, page_size: int, pps: int) -> CachedAttention:
-    """Both mixers for the builder: the grouped-query layers over a K
-    and a V pool ``[gqa layers, pages, page, KV * head_dim]``, the linear
-    layers over their per-slot state."""
-    kv_lanes = arch.n_kv_heads * arch.head_dim
+def gqa_over_pages(arch: LMArch, page_size: int, pps: int,
+                   scale: Optional[float] = None):
+    """``(attend_chunk, attend_step)`` of ``CachedAttention`` for
+    grouped-query layers over a K and a V pool ``[gqa layers, pages, page,
+    KV * head_dim]``, for a ``project`` that gives ``((q, gate), (k row, v
+    row))``: the output is multiplied by ``gate`` unless it is None (an
+    ungated mixer's, ``models/ssm_gqa.py``).  Both count ``STATE_STATS``."""
     kv_pages = block_pages(pps, page_size, KV_BLOCK_ROWS)
     block = kv_pages * page_size
+
+    def gated(att, gate):
+        return att if gate is None else att * gate
 
     def chunk(p, pools, layer, page_table_row, q, rows, offset, n_real):
         k_pool, v_pool = pools
@@ -452,9 +459,9 @@ def mixers(arch: LMArch, page_size: int, pps: int) -> CachedAttention:
         att, _ = attend_blocks(
             q, k_new, v_new, arch, lambda: causal(T), read_kv,
             lambda j: (j * block + jnp.arange(block) < offset)[None, :],
-            offset, block)
+            offset, block, scale)
         # whole blocks up to the rows held, and the chunk's own rows
-        return att * gate, {"state_stats": _counts(
+        return gated(att, gate), {"state_stats": _counts(
             held=offset + n_real, read=-(-offset // block) * block + T)}
 
     def step(p, pools, layer, table, q, rows, positions, active):
@@ -480,12 +487,22 @@ def mixers(arch: LMArch, page_size: int, pps: int) -> CachedAttention:
 
             att.append(gqa_attend_step(q[at], k_new[at], v_new[at], arch,
                                        positions[at], read_block, n_held,
-                                       block))
+                                       block, scale))
             read += size * (-(-n_held // block) * block + 1)
         att = jnp.concatenate(att)[jnp.argsort(order)]
         # every slot, stepped or not: blocks up to its group's fullest row
-        return att * gate, {"state_stats": _counts(
+        return gated(att, gate), {"state_stats": _counts(
             held=jnp.sum(jnp.where(active, positions + 1, 0)), read=read)}
+
+    return chunk, step
+
+
+def mixers(arch: LMArch, page_size: int, pps: int) -> CachedAttention:
+    """Both mixers for the builder: the grouped-query layers over a K
+    and a V pool ``[gqa layers, pages, page, KV * head_dim]``, the linear
+    layers over their per-slot state."""
+    kv_lanes = arch.n_kv_heads * arch.head_dim
+    chunk, step = gqa_over_pages(arch, page_size, pps)
 
     def state_chunk(p, h, state, offset, n_real):
         att, state = linear_chunk(p, h, state, arch, offset, n_real)

@@ -260,7 +260,7 @@ INDEX_BLOCK_ROWS, KV_BLOCK_ROWS = 2048, 1024
 
 def attend_blocks(q: Array, k_new: Array, v_new: Array, arch: LMArch,
                   mask_new, read_kv=None, mask_old=None, n_old=0,
-                  kv_block: int = 0) -> Array:
+                  kv_block: int = 0, scale: Optional[float] = None) -> Array:
     """Grouped-query attention of ``T`` new rows (``q`` [T, H, D] float32,
     ``k_new`` / ``v_new`` [T, KV * D] as cached) over themselves under
     ``mask_new()`` [T, T] and over the ``n_old`` cached rows before them,
@@ -269,9 +269,11 @@ def attend_blocks(q: Array, k_new: Array, v_new: Array, arch: LMArch,
     broadcastable to [T, kv_block]): a loop over the blocks that hold any
     carries the softmax's running maximum, sum and weighted values, so no
     score matrix wider than a block exists and the work follows the rows
-    held.  Returns ``([T, H * D] float32, the mask mask_new gave)``."""
+    held.  The softmax's scale is ``head_dim^-0.5`` unless ``scale`` says
+    otherwise.  Returns ``([T, H * D] float32, the mask mask_new gave)``."""
     T = q.shape[0]
     H, KV, D = arch.n_heads, arch.n_kv_heads, arch.head_dim
+    scale = D ** -0.5 if scale is None else scale
     cd = k_new.dtype
     qg = q.reshape(T, KV, H // KV, D).astype(cd)
 
@@ -279,7 +281,7 @@ def attend_blocks(q: Array, k_new: Array, v_new: Array, arch: LMArch,
         """Masked scores [KV, G, T, rows] and values [rows, KV, D]."""
         s = jnp.einsum("tgqd,lgd->gqtl", qg,
                        k_rows.reshape(-1, KV, D).astype(cd),
-                       preferred_element_type=jnp.float32) * D ** -0.5
+                       preferred_element_type=jnp.float32) * scale
         return (jnp.where(mask[None, None], s, NEG_INF),
                 v_rows.reshape(-1, KV, D).astype(cd))
 
@@ -458,7 +460,7 @@ def forward(params, tokens: Array, arch: LMArch, with_aux: bool = False,
     rope = rope_rows(arch, positions)
 
     def one(seq):
-        h = _embed(params, seq)
+        h = _embed(params, seq, arch)
         picks, stats, masks = [], [], []
         for p in params["blocks"]:
             q_side, rows = project(p, h, rope, arch)

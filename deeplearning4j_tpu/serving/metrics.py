@@ -301,7 +301,8 @@ _DECODE_COUNTER_KEYS = (
     # selection, rows the stepped slots / the chunk's slot held
     "index_rows_scored", "attn_rows_read", "rows_held",
     # layers that keep a per-slot recurrent state beside grouped-query
-    # layers over paged K and V (models/linear_gqa.STATE_STATS; zero for a
+    # layers over paged K and V (models/linear_gqa.STATE_STATS, which
+    # models/ssm_gqa.py reports under the same names; zero for a
     # program without them), summed over layers and calls: active slots
     # whose state a step replaced, real rows a chunk scanned into a
     # slot's state, K rows the stepped slots / the chunk's slot held,
@@ -309,6 +310,9 @@ _DECODE_COUNTER_KEYS = (
     # slot from zero state
     "state_slots_stepped", "state_rows_scanned", "kv_rows_held",
     "kv_rows_read", "recurrent_state_resets",
+    # rows a chunked scan computed for those it scanned (whole chunks of
+    # the bucket: models/ssm_gqa.SCAN_STATS; zero for any other program)
+    "state_rows_computed",
 )
 
 

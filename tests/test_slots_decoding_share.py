@@ -65,9 +65,12 @@ def test_the_manifest_lists_it_for_the_three_cells_of_the_fused_loop():
     manifest = harness.load_manifest()
     entry = harness.find(manifest["per_layer"], NAME, "metric")
     module = harness.load_layer_metric(NAME)
-    assert entry == {"name": NAME, "unit": module.UNIT, "better": "higher",
-                     "source": module.SOURCE, "layer": module.LAYER,
-                     "moves": module.MOVES, "workloads": CELLS}
+    # the three it was brought for, first and in order; a later cell of the
+    # fused loop is appended behind them (PR 41's is)
+    assert {**entry, "workloads": entry["workloads"][:len(CELLS)]} == {
+        "name": NAME, "unit": module.UNIT, "better": "higher",
+        "source": module.SOURCE, "layer": module.LAYER,
+        "moves": module.MOVES, "workloads": CELLS}
     assert (module.UNIT, module.LAYER, module.MOVES, module.SOURCE) == (
         "%", "decode scheduler", "serve_tokens_per_s", "program_counter")
     for cell in CELLS:
