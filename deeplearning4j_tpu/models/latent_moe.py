@@ -20,6 +20,11 @@ per-head key or value.  Two paths read it, the same mathematics:
 * *absorbed* (the decode step): ``W_kvb``'s key half is folded into the
   query (``q_nope_i W_kvb,k,i^T`` against ``c_kv``) and its value half
   into the output (the weighted sum of ``c_kv`` through ``W_kvb,v,i``).
+  The absorbed query meets the cached rows AS STORED, one 576-wide head
+  shared by all query heads: over the pages a slot holds, through the
+  page table, by one Mosaic call a layer (``ops/latent_attention.py``);
+  over every slot's whole gathered window (``attend_window``) only where
+  that kernel cannot take the pool (its ``kept_path``).
 
 Both are MXU products (bf16 operands where the weights are bf16,
 float32 accumulation, float32 softmax): the bitwise decode-vs-reencode
@@ -67,6 +72,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops import latent_attention
+from ..ops.pallas_support import fell_back
 from ..parallel.moe import (EXPERT_STATS, gated_silu, init_held_experts,
                             moe_forward_held)
 from .arch import LMArch, yarn_mscale
@@ -291,21 +298,25 @@ def attend_expanded(p, q_nope, q_pe, rows_new, arch: LMArch,
     return jnp.swapaxes(acc / z, 0, 1).reshape(t, -1)
 
 
-def attend_absorbed(p, q_nope, q_pe, row_new, rows_old, n_old,
-                    arch: LMArch) -> Array:
-    """One new row per slot against the cache, ``W_kvb`` ABSORBED:
-    ``q_nope`` [S, H, n], ``q_pe`` [S, H, r], ``row_new`` [S, c + r]
-    (the slot's own row, not yet in the pool), ``rows_old`` [S, L,
-    c + r] of which the first ``n_old[s]`` are earlier positions.  No
-    per-head key or value exists anywhere.  Returns [S, H * v]."""
-    c, nd = arch.kv_lora_rank, arch.qk_nope_head_dim
-    cd = p["W_kvb"].dtype
-    wkvb = _kvb(p, arch)
-    q_abs = jnp.einsum("shn,chn->shc", q_nope.astype(cd), wkvb[..., :nd],
+def absorb_query(wkvb, q_nope, q_pe, arch: LMArch) -> Array:
+    """The step's query against a cached row as stored: ``q_nope`` [S, H,
+    n] through the key half of ``wkvb`` (``_kvb``), ``q_pe`` [S, H, r]
+    beside it, zero in the padding lanes: [S, H, latent_lanes] in the
+    weights' type."""
+    q_abs = jnp.einsum("shn,chn->shc", q_nope.astype(wkvb.dtype),
+                       wkvb[..., :arch.qk_nope_head_dim],
                        preferred_element_type=jnp.float32)
     pad = jnp.zeros(q_pe.shape[:-1] + (arch.latent_lanes
                                        - arch.latent_width,))
-    q_lat = jnp.concatenate([q_abs, q_pe, pad], axis=-1).astype(cd)
+    return jnp.concatenate([q_abs, q_pe, pad], axis=-1).astype(wkvb.dtype)
+
+
+def attend_window(q_lat, row_new, rows_old, n_old, arch: LMArch) -> Array:
+    """``q_lat`` [S, H, lanes] over ``rows_old`` [S, L, lanes], of which
+    the first ``n_old[s]`` are earlier positions, and over the slot's own
+    ``row_new`` [S, lanes]: the weighted sum of ``c_kv`` a head, [S, H, c]
+    float32."""
+    c, cd = arch.kv_lora_rank, q_lat.dtype
     s_old = jnp.einsum("shx,slx->shl", q_lat, rows_old.astype(cd),
                        preferred_element_type=jnp.float32)
     seen = jnp.arange(rows_old.shape[1])[None, :] < n_old[:, None]
@@ -314,13 +325,31 @@ def attend_absorbed(p, q_nope, q_pe, row_new, rows_old, n_old,
                        preferred_element_type=jnp.float32
                        )[..., None] * arch.softmax_scale
     a_old, a_new = _softmax_pair(s_old, s_new)
-    o_lat = jnp.einsum("shl,slc->shc", a_old.astype(cd),
-                       rows_old[..., :c].astype(cd),
-                       preferred_element_type=jnp.float32) \
+    return jnp.einsum("shl,slc->shc", a_old.astype(cd),
+                      rows_old[..., :c].astype(cd),
+                      preferred_element_type=jnp.float32) \
         + a_new * row_new[:, None, :c].astype(jnp.float32)
-    o = jnp.einsum("shc,chv->shv", o_lat.astype(cd), wkvb[..., nd:],
+
+
+def expand_values(wkvb, o_lat, arch: LMArch) -> Array:
+    """``o_lat`` [S, H, c] through the value half of ``wkvb``: [S, H * v]."""
+    o = jnp.einsum("shc,chv->shv", o_lat.astype(wkvb.dtype),
+                   wkvb[..., arch.qk_nope_head_dim:],
                    preferred_element_type=jnp.float32)
     return o.reshape(o.shape[0], -1)
+
+
+def attend_absorbed(p, q_nope, q_pe, row_new, rows_old, n_old,
+                    arch: LMArch) -> Array:
+    """One new row per slot against the cache, ``W_kvb`` ABSORBED:
+    ``q_nope`` [S, H, n], ``q_pe`` [S, H, r], ``row_new`` [S, c + r]
+    (the slot's own row, not yet in the pool), ``rows_old`` [S, L,
+    c + r] of which the first ``n_old[s]`` are earlier positions.  No
+    per-head key or value exists anywhere.  Returns [S, H * v]."""
+    wkvb = _kvb(p, arch)
+    o_lat = attend_window(absorb_query(wkvb, q_nope, q_pe, arch), row_new,
+                          rows_old, n_old, arch)
+    return expand_values(wkvb, o_lat, arch)
 
 
 def layer_finish(p, h: Array, att: Array, arch: LMArch,
@@ -436,9 +465,11 @@ class CachedAttention(NamedTuple):
     d_head: int
     stats: tuple = ()
     extras: tuple = ()
-    # what the step reads of a slot's window
-    # (``ops/kv_cache.DecodeProgram.held_pages``)
+    # what the step reads of a slot's window, and the rule of the kernel
+    # it reads through (``ops/kv_cache.DecodeProgram.held_pages`` /
+    # ``kept_path``)
     held_pages: Optional[bool] = False
+    kept_path: Optional[Callable] = None
     # layers of two kinds (models/linear_gqa.py).  ``kinds`` says of each
     # layer whether it leaves rows in the pools ("pool": the callables
     # above, with its index among the pool layers) or keeps per-slot state
@@ -459,7 +490,10 @@ class CachedAttention(NamedTuple):
 def mla_attention(arch: LMArch, page_size: int, pps: int) -> CachedAttention:
     """Latent attention over ONE latent pool ``[layers, pages, page,
     latent_lanes]``: chunks by the expanded path a block of pages at a
-    time, the step by the absorbed path over the gathered window."""
+    time, the step by the absorbed path over the pages a slot holds, read
+    from the pool as stored (``ops/latent_attention.py``, one Mosaic call
+    a layer), or over the gathered window where that kernel's
+    ``kept_path`` says it cannot take the pool."""
     L = pps * page_size
 
     def gather(pool, layer, table):
@@ -490,13 +524,25 @@ def mla_attention(arch: LMArch, page_size: int, pps: int) -> CachedAttention:
                                block_rows), None
 
     def attend_step(p, pools, layer, table, q, rows, positions, active):
-        return attend_absorbed(p, *q, rows[0], gather(pools[0], layer, table),
-                               positions, arch), None
+        (pool,), (row,) = pools, rows
+        why = latent_attention.kept_path(pool, pps)
+        if why:
+            fell_back(f"latent_attention[L={L},lanes={pool.shape[-1]}]", why)
+            return attend_absorbed(p, *q, row, gather(pool, layer, table),
+                                   positions, arch), None
+        wkvb = _kvb(p, arch)
+        # a slot that is not active holds nothing: its table is scratch
+        o_lat = latent_attention.latent_attention(
+            absorb_query(wkvb, *q, arch), row, pool, layer, table,
+            jnp.where(active, positions, 0), arch.kv_lora_rank,
+            arch.softmax_scale)
+        return expand_values(wkvb, o_lat, arch), None
 
     return CachedAttention(
         pool_rows=((arch.latent_lanes,),), tables=rope_tables(arch, L),
         project=project, attend_chunk=attend_chunk, attend_step=attend_step,
-        d_head=arch.qk_head_dim)
+        d_head=arch.qk_head_dim, held_pages=True,
+        kept_path=latent_attention.kept_path)
 
 
 def decode_program(arch: LMArch, page_size: int, max_len: Optional[int]):
@@ -700,7 +746,7 @@ def expert_decode_program(arch: LMArch, page_size: int,
         pages_per_slot=pps, prefill_at=prefill_at, step_multi=step_multi,
         pool_rows=att.pool_rows, pool_dtype=jnp.dtype(arch.param_dtype),
         aux=True, aux_stats=(("expert_stats", EXPERT_STATS),) + att.stats,
-        held_pages=att.held_pages,
+        held_pages=att.held_pages, kept_path=att.kept_path,
         kinds=att.kinds, slot_state=att.slot_state)
 
 

@@ -574,12 +574,16 @@ class DecodeProgram(NamedTuple):
     aux: bool = False
     aux_stats: tuple = ()
     # what ``kv_pages_read`` says of step / spec_step / step_multi.
-    # True: they attend through ops/paged_attention.py, which reads only
-    # the pages a slot holds wherever its kernel takes the pool
-    # (``kept_path``); False: they read every slot's whole window; None:
-    # they read rows, not pages, and count them in ``aux_stats``
-    # (models/sparse_gqa.py): the engine reports no ``kv_pages_read``
+    # True: they attend through a kernel that reads only the pages a
+    # slot holds wherever it takes the pool: ops/paged_attention.py, or
+    # the kernel whose rule ``kept_path`` names (``(pool, pages_per_slot,
+    # tp) -> why the gathered window runs instead, or None``:
+    # ops/latent_attention.py for models/latent_moe.py); False: they read
+    # every slot's whole window; None: they read rows, not pages, and
+    # count them in ``aux_stats`` (models/sparse_gqa.py): the engine
+    # reports no ``kv_pages_read``
     held_pages: Optional[bool] = False
+    kept_path: Optional[Callable[..., Optional[str]]] = None
     # which layers have rows in the pools (``"pool"``) and which keep
     # state that is PER SLOT and not per token (``"state"``,
     # models/linear_gqa.py), one entry a layer (None: every layer has

@@ -801,9 +801,9 @@ class DecodeEngine:
                              slots=s_n)
         slot0 = self._slot_arg(0)
         # what ``kv_pages_read`` counts: the program says it attends over
-        # the pages held, the kernel's own rule whether it takes this pool
-        self._reads_held_pages = (
-            prog.held_pages and kept_path(kp, pps, prog.tp) is None)
+        # the pages held, its kernel's own rule whether it takes this pool
+        self._reads_held_pages = bool(prog.held_pages) and (
+            prog.kept_path or kept_path)(kp, pps, prog.tp) is None
         self.metrics.recurrent_state_bytes.set(state_nbytes((kp, vp)))
         self.metrics.kv_bytes_per_token.set(
             (pool_nbytes((kp, vp)) - state_nbytes((kp, vp)))

@@ -1,5 +1,6 @@
 """The Mosaic kernels (flash attention's three, the decode step's paged
-attention) compiled for a DESCRIBED TPU v5e, without one.
+attention and latent attention) compiled for a DESCRIBED TPU v5e, without
+one.
 
 Interpret mode (tests/test_attention.py) cannot see what Mosaic refuses: a
 slice not aligned to the tiling, more VMEM than a kernel may use.  The
@@ -19,7 +20,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from deeplearning4j_tpu.ops import attention, paged_attention
+from deeplearning4j_tpu.ops import (attention, latent_attention,
+                                    paged_attention)
 
 
 @pytest.fixture(scope="module")
@@ -40,6 +42,7 @@ def as_on_tpu(monkeypatch):
     branch (the interpreter) here: steer it in the test."""
     monkeypatch.setattr(attention, "interpret", lambda: False)
     monkeypatch.setattr(paged_attention, "interpret", lambda: False)
+    monkeypatch.setattr(latent_attention, "interpret", lambda: False)
 
 
 # B, H, T, S, D, dtype, causal, kmask
@@ -83,14 +86,19 @@ def test_forward_and_backward_compile_for_v5e(one_chip, as_on_tpu, case):
 
 @pytest.mark.parametrize("entry", ["step", "step_multi", "prefill_at"])
 def test_latent_decode_program_compiles_for_v5e_without_a_pool_copy(
-        one_chip, entry):
+        one_chip, as_on_tpu, entry):
     """The decode step and a 512-token prefill chunk of the benchmark's
     ``kimi-k2-instruct`` configuration (16 slots, 4,096 positions, bf16)
     compile for one v5e chip, fit its memory, update the donated pool in
     place, and keep less in temporaries than one pool holds, so no copy
     of the pool is among them: a 576-lane row, ``pool[layer][table]``
     and a scatter a layer each cost whole-pool copies a call (PERF.md
-    §6, PR 27)."""
+    §6, PR 27).
+
+    The step holds ONE Mosaic call a layer (ops/latent_attention.py),
+    which reads the pool in place, and nothing shaped like the gathered
+    window, 84 MB written a layer a step (PERF.md section 6, PR 42); the
+    chunk walks the pages held in blocks and holds no Mosaic call."""
     import json
 
     from deeplearning4j_tpu.models import latent_moe
@@ -138,6 +146,21 @@ def test_latent_decode_program_compiles_for_v5e_without_a_pool_copy(
     assert mem.alias_size_in_bytes >= 8 * 4097 * 16 * 640 * 2   # in place
     # the program's temporaries are smaller than the pool: no copy of it
     assert mem.temp_size_in_bytes < 8 * 4097 * 16 * 640 * 2
+    import re
+    hlo = compiled.as_text()
+    # (XLA's own grouped products are Mosaic calls too: by the name)
+    mosaic_calls = len(re.findall(
+        r"%latent_attention[.\d]* = \S+ custom-call\(.*tpu_custom_call", hlo))
+    if entry == "prefill_at":
+        assert mosaic_calls == 0
+        return
+    # (a fused horizon's scan holds its body once)
+    assert mosaic_calls == prog.n_layers == 8
+    for gathered in ("bf16[%d,%d,640]" % (slots, arch.max_len),
+                     "bf16[%d,%d,640]" % (arch.max_len, slots),
+                     "bf16[%d,16,640]" % (slots * prog.pages_per_slot),
+                     "f32[%d,64,%d]" % (slots, arch.max_len)):
+        assert gathered not in hlo, gathered
 
 
 # -- the sparse decode programs at Keye-VL-2.0's widths ----------------------------
