@@ -22,7 +22,12 @@ layers beside ungated NoPE grouped-query layers (``models/ssm_gqa.py``):
 one scalar decay a head from an input-dependent step, a per-slot state
 ``[heads, d_head, d_state]``, every layer a softmax-routed expert layer
 with a shared MLP, scalars on embedding, residual, attention and logits,
-a tied head.
+a tied head; and the block of gated short-convolution layers beside
+rotary grouped-query layers (``models/conv_gqa.py``): a mixer whose whole
+memory is the last ``conv_L_cache - 1`` rows of its convolution's input,
+RMSNorm on each head's query and key BEFORE plain rotary, leading dense
+layers followed by expert layers behind the ``noaux_tc`` router with the
+file's own epsilon, no shared expert, a tied head.
 
 ``from_config`` reads a configuration file's keys (the published
 ``config.json`` names of each family), so a model is a data file and
@@ -35,17 +40,20 @@ import dataclasses
 import math
 from typing import Any, Dict
 
-BLOCKS = ("gpt2", "latent_moe", "sparse_gqa", "linear_gqa", "ssm_gqa")
+BLOCKS = ("gpt2", "latent_moe", "sparse_gqa", "linear_gqa", "ssm_gqa",
+          "conv_gqa")
 #: the blocks of the expert family (``models/<block>.py``): served through
 #: one decode-program builder, not trained yet
-EXPERT_BLOCKS = ("latent_moe", "sparse_gqa", "linear_gqa", "ssm_gqa")
+EXPERT_BLOCKS = ("latent_moe", "sparse_gqa", "linear_gqa", "ssm_gqa",
+                 "conv_gqa")
 #: what a layer remembers (``LMArch.layer_types``): rows in the paged pools
 #: a token, or a per-slot recurrent state (the delta rule's, or a
-#: state-space layer's)
-LAYER_TYPES = ("gqa", "linear", "mamba")
+#: state-space layer's), or only the tail of a short convolution's input
+LAYER_TYPES = ("gqa", "linear", "mamba", "conv")
 #: which of them each block of two layer kinds may name
 BLOCK_LAYER_TYPES = {"linear_gqa": ("gqa", "linear"),
-                     "ssm_gqa": ("gqa", "mamba")}
+                     "ssm_gqa": ("gqa", "mamba"),
+                     "conv_gqa": ("gqa", "conv")}
 ROUTERS = ("noaux_tc", "softmax_topk")
 
 
@@ -98,6 +106,9 @@ class LMArch:
     logits_scaling: float = 1.0         # the logits are DIVIDED by it
     attention_multiplier: float = 0.0   # the softmax scale; 0 = head_dim^-0.5
     tie_embeddings: bool = False        # the head is the embedding, transposed
+    # -- gated short convolutions beside rotary grouped-query (conv_gqa) --
+    conv_L_cache: int = 0          # taps of the short convolution
+    conv_bias: bool = False        # on the convolution and its projections
     # -- experts ------------------------------------------------------------
     n_dense_layers: int = 0        # leading layers with a dense feed-forward
     moe_d_ff: int = 0
@@ -108,6 +119,7 @@ class LMArch:
     n_shared_experts: int = 0
     routed_scaling_factor: float = 1.0
     router: str = "noaux_tc"       # parallel/moe.py ROUTERS
+    router_eps: float = 1e-20      # added to the chosen scores' sum (noaux_tc)
     init_std: float = 0.02
     param_dtype: str = "float32"
 
@@ -144,12 +156,13 @@ class LMArch:
         if self.block in BLOCK_LAYER_TYPES:
             needs = {"linear_gqa": ("linear_n_heads", "linear_head_dim"),
                      "ssm_gqa": ("mamba_n_heads", "mamba_d_head",
-                                 "mamba_d_state", "mamba_chunk_size")}
+                                 "mamba_d_state", "mamba_chunk_size"),
+                     "conv_gqa": ()}
             for k in ("n_kv_heads", "head_dim") + needs[self.block]:
                 if getattr(self, k) < 1:
                     raise ValueError(f"{self.block} needs {k} >= 1")
-            taps = "conv_kernel" if self.block == "linear_gqa" \
-                else "mamba_d_conv"
+            taps = {"linear_gqa": "conv_kernel", "ssm_gqa": "mamba_d_conv",
+                    "conv_gqa": "conv_L_cache"}[self.block]
             if getattr(self, taps) < 2:
                 raise ValueError(f"{self.block} needs {taps} >= 2")
             if self.n_heads % self.n_kv_heads:
@@ -160,7 +173,16 @@ class LMArch:
                 raise ValueError(
                     f"layer_types must name one of {kinds} for each of "
                     f"the {self.n_layers} layers, got {self.layer_types!r}")
-            if self.n_dense_layers:
+            if self.block == "conv_gqa":
+                if self.head_dim % 2:
+                    raise ValueError("head_dim must be even")
+                if self.conv_bias:
+                    raise ValueError("conv_gqa has no bias anywhere "
+                                     "(conv_bias)")
+                if not 0 <= self.n_dense_layers <= self.n_layers:
+                    raise ValueError("n_dense_layers must lie in "
+                                     "[0, n_layers]")
+            elif self.n_dense_layers:
                 raise ValueError(f"every {self.block} layer is an expert "
                                  "layer")
         if self.block == "ssm_gqa":
@@ -269,7 +291,15 @@ class LMArch:
         ``num_hidden_layers``; ``num_local_experts`` counts the experts
         HELD and ``num_local_experts_published``, when stated, is the
         router's width; the shared MLP of ``shared_intermediate_size`` is
-        that many experts' width in one.  ``over`` replaces any field."""
+        that many experts' width in one.  One with ``conv_L_cache`` beside
+        ``layer_types`` naming ``conv`` and ``full_attention`` is the
+        block of gated short-convolution and rotary grouped-query layers
+        (Lfm2Moe's names): ``layer_types`` is kept as published and read
+        up to ``num_hidden_layers``; ``num_experts`` is the router's
+        width and ``n_routed_experts``, when stated, the count held from
+        ``first_expert`` on; the head is tied unless ``tie_embedding``
+        (or ``tie_word_embeddings``) says otherwise.  ``over`` replaces
+        any field."""
         if "n_embd" in cfg:
             kw = dict(vocab_size=cfg["vocab_size"], n_layers=cfg["n_layer"],
                       d_model=cfg["n_embd"], n_heads=cfg["n_head"],
@@ -474,15 +504,72 @@ class LMArch:
                     raise ValueError(
                         f"config key {k}={cfg[k]!r} is not expressible by "
                         f"the ssm_gqa block (supported: {ok})")
+        elif "layer_types" in cfg and "conv_L_cache" in cfg:
+            n_layers = int(cfg["num_hidden_layers"])
+            kinds = {"conv": "conv", "full_attention": "gqa"}
+            named = list(cfg["layer_types"])[:n_layers]
+            if len(named) != n_layers or any(t not in kinds for t in named):
+                raise ValueError(
+                    f"config key layer_types={cfg['layer_types']!r} is not "
+                    "expressible by the conv_gqa block (supported: one of "
+                    f"{tuple(kinds)} for each of the {n_layers} layers)")
+            rp = cfg.get("rope_parameters") or {}
+            n_experts = int(cfg["num_experts"])
+            kw = dict(
+                block="conv_gqa", vocab_size=cfg["vocab_size"],
+                n_layers=n_layers, d_model=cfg["hidden_size"],
+                n_heads=cfg["num_attention_heads"],
+                n_kv_heads=cfg["num_key_value_heads"],
+                head_dim=int(cfg.get("head_dim") or cfg["hidden_size"]
+                             // cfg["num_attention_heads"]),
+                d_ff=cfg["intermediate_size"],
+                max_len=cfg["max_position_embeddings"],
+                rms_eps=cfg.get("norm_eps", 1e-5),
+                rope_theta=float(rp.get("rope_theta",
+                                        cfg.get("rope_theta", 1000000.0))),
+                # the published list, read up to the depth held
+                layer_types=tuple(kinds[t] for t in named),
+                conv_L_cache=int(cfg["conv_L_cache"]),
+                conv_bias=bool(cfg.get("conv_bias", False)),
+                tie_embeddings=bool(cfg.get(
+                    "tie_embedding", cfg.get("tie_word_embeddings", True))),
+                n_dense_layers=min(int(cfg.get("num_dense_layers", 0)),
+                                   n_layers),
+                moe_d_ff=cfg["moe_intermediate_size"],
+                n_experts=n_experts,
+                experts_held=int(cfg.get("n_routed_experts", n_experts)),
+                first_expert=int(cfg.get("first_expert", 0)),
+                experts_per_token=cfg["num_experts_per_tok"],
+                routed_scaling_factor=float(
+                    cfg.get("routed_scaling_factor", 1.0)),
+                router="noaux_tc",
+                router_eps=float(cfg.get("router_eps", 1e-6)),
+                init_std=cfg.get("initializer_range", 0.02))
+            unsupported = {
+                "conv_bias": (False,), "use_expert_bias": (True,),
+                "norm_topk_prob": (True,)}
+            for k, ok in unsupported.items():
+                if k in cfg and cfg[k] not in ok:
+                    raise ValueError(
+                        f"config key {k}={cfg[k]!r} is not expressible by "
+                        f"the conv_gqa block (supported: {ok})")
+            if rp.get("rope_type", rp.get("type", "default")) != "default":
+                raise ValueError(
+                    f"config key rope_parameters={rp!r} is not expressible "
+                    "by the conv_gqa block (supported: plain rotary, "
+                    "rope_type default)")
         else:
             raise ValueError(
                 "configuration names neither a GPT-2 block (n_embd), a "
                 "latent/expert block (kv_lora_rank), a grouped-query "
                 "block over a learned selection (sa_config with "
                 "num_key_value_heads), a block of linear-attention and "
-                "grouped-query layers (linear_attn_config with gqa_layers) "
-                "nor a block of state-space and grouped-query layers "
-                "(layer_types naming mamba / attention with mamba_n_heads)")
+                "grouped-query layers (linear_attn_config with gqa_layers), "
+                "a block of state-space and grouped-query layers "
+                "(layer_types naming mamba / attention with mamba_n_heads) "
+                "nor a block of short-convolution and grouped-query layers "
+                "(layer_types naming conv / full_attention with "
+                "conv_L_cache)")
         kw.update(over)
         return cls(**kw)
 

@@ -39,17 +39,21 @@ experts held here, the shared expert.
 
 The decode program is the expert family's one builder
 (``expert_decode_program``, which ``models/sparse_gqa.py``,
-``models/linear_gqa.py`` and ``models/ssm_gqa.py`` share: the entry
+``models/linear_gqa.py``, ``models/ssm_gqa.py`` and
+``models/conv_gqa.py`` share: the entry
 points, the layer loop, the write of the new rows, ``layer_finish`` and
 the aux read-back), around the attention a block hands it
 (:class:`CachedAttention`).  The builder scales embedding, residual and
 logits and ties the head where the architecture says so
 (``LMArch.embedding_multiplier`` / ``residual_multiplier`` /
 ``logits_scaling`` / ``tie_embeddings``): only ``models/ssm_gqa.py``'s
-family may state those (``LMArch.from_config`` refuses
-``tie_word_embeddings: true`` for this block, ``sparse_gqa`` and
-``linear_gqa`` by name, and none of the three reads a multiplier), so
-their programs trace as they did.  This block's threads ONE latent pool
+family may state those, and ``models/conv_gqa.py``'s the tied head
+(``LMArch.from_config`` refuses ``tie_word_embeddings: true`` for this
+block, ``sparse_gqa`` and ``linear_gqa`` by name, and none of the three
+reads a multiplier), so their programs trace as they did.  A leading
+dense layer may be of either kind: one whose mixer keeps per-slot state
+(``models/conv_gqa.py``'s) threads its state as an expert layer's does
+and reports no picks.  This block's threads ONE latent pool
 ``[layers, pages, page, latent_lanes]`` through ``prefill`` /
 ``prefill_at`` / ``step``: a row is
 the ``latent_width`` cached values in the next multiple of 128 lanes,
@@ -91,13 +95,14 @@ def rms_norm(x: Array, g: Array, eps: float) -> Array:
     return x * jax.lax.rsqrt(var + eps) * g.astype(jnp.float32)
 
 
-def yarn_inv_freq(arch: LMArch) -> np.ndarray:
-    """The rotary inverse frequencies [qk_rope_head_dim / 2] (float64 on
-    the host): plain ``theta^(-2i/d)`` when ``rope_factor`` is 1,
-    otherwise each blended between ``1/f`` and ``1/(factor f)`` by the
-    published code's linear ramp between its two correction bounds (it
-    widens the upper by 0.001 where they coincide)."""
-    dim, base = arch.qk_rope_head_dim, arch.rope_theta
+def yarn_inv_freq(arch: LMArch, dim: int = 0) -> np.ndarray:
+    """The rotary inverse frequencies [dim / 2] (float64 on the host;
+    ``dim`` 0: ``qk_rope_head_dim``): plain ``theta^(-2i/d)`` when
+    ``rope_factor`` is 1, otherwise each blended between ``1/f`` and
+    ``1/(factor f)`` by the published code's linear ramp between its two
+    correction bounds (it widens the upper by 0.001 where they
+    coincide)."""
+    dim, base = dim or arch.qk_rope_head_dim, arch.rope_theta
     pos_freq = base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
     extra = 1.0 / pos_freq
     if arch.rope_factor <= 1.0:
@@ -119,10 +124,11 @@ def yarn_inv_freq(arch: LMArch) -> np.ndarray:
     return inter * (1.0 - mask) + extra * mask
 
 
-def rope_tables(arch: LMArch, n_pos: int):
-    """(cos, sin) float32 [n_pos, qk_rope_head_dim]: the two halves
-    repeat the frequencies; both carry ``mscale / mscale_all_dim``."""
-    inv = yarn_inv_freq(arch)
+def rope_tables(arch: LMArch, n_pos: int, dim: int = 0):
+    """(cos, sin) float32 [n_pos, dim] (``dim`` 0: ``qk_rope_head_dim``):
+    the two halves repeat the frequencies; both carry ``mscale /
+    mscale_all_dim``."""
+    inv = yarn_inv_freq(arch, dim)
     ang = np.outer(np.arange(n_pos, dtype=np.float64), inv)
     emb = np.concatenate([ang, ang], axis=-1)
     m = 1.0
@@ -370,7 +376,8 @@ def layer_finish(p, h: Array, att: Array, arch: LMArch,
                                      p["W_down"])), None, None
     y, picks, stats = moe_forward_held(
         p, u, first_expert=arch.first_expert, k=arch.experts_per_token,
-        scaling=arch.routed_scaling_factor, valid=valid, router=arch.router)
+        scaling=arch.routed_scaling_factor, valid=valid, router=arch.router,
+        router_eps=arch.router_eps)
     return h + scaled(y), picks, stats
 
 

@@ -210,18 +210,20 @@ def init_held_experts(rng: Array, d_model: int, d_ff: int, n_experts: int,
 
 
 def route_noaux_tc(x: Array, router_w: Array, router_b: Array, k: int,
-                   scaling: float) -> Tuple[Array, Array]:
+                   scaling: float, eps: float = 1e-20
+                   ) -> Tuple[Array, Array]:
     """The ``noaux_tc`` gate with one group, in float32: scores are
     ``sigmoid(x W)``; the ``k`` experts are the top k of ``scores +
     bias``; their weights are the SCORES (without the bias) of those k,
-    divided by their sum (+1e-20), times ``scaling``.
+    divided by their sum ``+ eps`` (1e-20 in DeepSeek-V3's gate, which
+    Kimi's and Solar's files reuse; LFM2's adds 1e-6), times ``scaling``.
     ``x`` [N, d] -> (expert ids [N, k] int32, weights [N, k] f32)."""
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     scores = jax.nn.sigmoid(logits)
     _, idx = jax.lax.top_k(scores + router_b.astype(jnp.float32), k)
     w = jnp.take_along_axis(scores, idx, axis=-1)
-    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * scaling
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps) * scaling
     return idx.astype(jnp.int32), w
 
 
@@ -251,12 +253,13 @@ def gated_silu(x: Array, w_gate: Array, w_up: Array, w_down: Array) -> Array:
 def moe_forward_held(p: Dict[str, Array], x: Array, *, first_expert: int,
                      k: int, scaling: float = 1.0,
                      valid: Optional[Array] = None, shared: bool = True,
-                     router: str = "noaux_tc"):
+                     router: str = "noaux_tc", router_eps: float = 1e-20):
     """The part of a routed-expert layer that THIS chip gives.
 
     ``x`` [N, d].  Routes every row over all experts (``p["router_w"]``
     is [d, n_experts]) by ``router``: ``"noaux_tc"`` (sigmoid scores, the
-    correction bias ``p["router_b"]``, ``scaling``) or ``"softmax_topk"``
+    correction bias ``p["router_b"]``, ``scaling``, ``router_eps`` in
+    the weights' normalisation) or ``"softmax_topk"``
     (neither); everything after the router is one code path.  Keeps the picks that fall on the experts held
     here (``p["e_gate"]`` is [held, d, f]; they are experts
     ``first_expert .. first_expert + held - 1``), sorts those picks by
@@ -279,7 +282,8 @@ def moe_forward_held(p: Dict[str, Array], x: Array, *, first_expert: int,
     if router == "softmax_topk":
         idx, w = route_softmax_topk(x, p["router_w"], k)
     else:
-        idx, w = route_noaux_tc(x, p["router_w"], p["router_b"], k, scaling)
+        idx, w = route_noaux_tc(x, p["router_w"], p["router_b"], k, scaling,
+                                router_eps)
     if valid is None:
         valid = jnp.ones((n,), bool)
     local = idx - first_expert

@@ -469,6 +469,9 @@ class ContinuousBatcher(DynamicBatcher):
     opaque ``payload`` (the generation spec) instead of an input array.
     """
 
+    #: whether the token budget ended the last ``admit`` round
+    last_admit_budget_bound = False
+
     def submit_request(self, payload, slo_ms: Optional[float] = None,
                        deadline: Optional[float] = None,
                        tenant: Optional[str] = None,
@@ -508,7 +511,10 @@ class ContinuousBatcher(DynamicBatcher):
         head request is always admitted even when it alone exceeds the
         budget (an oversized prompt cannot be split at admission; the
         engine chunks its prefill instead), so the rule bounds pacing
-        without ever starving."""
+        without ever starving.  ``last_admit_budget_bound`` says of the
+        call just made whether the budget is what ended it: requests
+        still waited and ``limit`` was not reached."""
+        self.last_admit_budget_bound = False
         if limit <= 0:
             return []
 
@@ -526,6 +532,7 @@ class ContinuousBatcher(DynamicBatcher):
                     break
                 if (token_budget is not None and out
                         and spent + _cost(self._lanes[t][0]) > token_budget):
+                    self.last_admit_budget_bound = True
                     break
                 r = self._pop_one_locked(t)
                 spent += _cost(r)

@@ -1472,7 +1472,7 @@ class DecodeEngine:
             # no device gap is left without a span of the program's
             with obs_trace.span("serve/iteration", cat="serve") as it:
                 try:
-                    worked = self._admit_some()
+                    worked = self._admit_some(it)
                     # one admission round and at most ``_chunk_budget()``
                     # chunks of prefill work per iteration: a chunk for
                     # each slot mid-prefill, so a decode dispatch never
@@ -1606,12 +1606,14 @@ class DecodeEngine:
                 "trie": sorted(nd.page_id for nd in self._iter_trie()),
             }
 
-    def _admit_some(self) -> bool:
+    def _admit_some(self, turn=None) -> bool:
         """Join queued requests to the running batch: allocate pages +
         a slot (attaching the longest matching prefix read-only when the
         prefix cache is on), prefill, sample the first token (TTFT).
         Stops at the first request the pool cannot hold yet (FIFO order
-        preserved)."""
+        preserved).  ``turn`` is the loop's ``serve/iteration`` span: a
+        round that the token budget ended with slots free and requests
+        waiting is counted there and in ``admit_rounds_budget_bound``."""
         from ..ops.kv_cache import pages_for
 
         with self._lock:
@@ -1624,6 +1626,12 @@ class DecodeEngine:
         # can interleave (the head request is still always admitted)
         reqs = self.batcher.admit(len(free),
                                   token_budget=self.prefill_chunk)
+        if self.batcher.last_admit_budget_bound:
+            # it counts; it changes no decision
+            self.metrics.inc("admit_rounds_budget_bound")
+            if turn is not None:
+                turn.set(admit_budget_bound=True, free_slots=len(free),
+                         admitted=len(reqs))
         if not reqs:
             return False
         prog = self.program

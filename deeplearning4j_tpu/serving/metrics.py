@@ -275,6 +275,9 @@ _DECODE_COUNTER_KEYS = (
     # those that sent more than one (a chunk for each slot mid-prefill:
     # prefill_chunks / fused_dispatches = chunks a turn)
     "chunk_turns", "chunk_turns_multi",
+    # admission rounds that the token budget ended (one chunk's worth of
+    # prompt tokens a round) with slots still free and requests waiting
+    "admit_rounds_budget_bound",
     # the plain loop's step in flight: decode steps queued while the step
     # before was unread, steps read in the turn that queued them (two
     # version tags alive), slot-steps computed for a request that the

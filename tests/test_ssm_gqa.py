@@ -372,15 +372,17 @@ def _texts(block):
     import tests.test_latent_moe as tl
     import tests.test_linear_gqa as tg
     import tests.test_sparse_gqa as ts
-    mod, arch = {"latent_moe": (latent_moe, tl.arch_of()),
-                 "sparse_gqa": (sparse_gqa, ts.arch_of()),
-                 "linear_gqa": (linear_gqa, tg.arch_of())}[block]
+    mod, arch = {"latent_moe": (latent_moe, tl.arch_of),
+                 "sparse_gqa": (sparse_gqa, ts.arch_of),
+                 "linear_gqa": (linear_gqa, tg.arch_of),
+                 "ssm_gqa": (ssm_gqa, arch_of)}[block]
+    arch = arch()
     params = jax.eval_shape(lambda: mod.init_params(jax.random.PRNGKey(0),
                                                     arch))
     prog = mod.decode_program(arch, 4, 32)
     s_n, pps = 2, prog.pages_per_slot
     first, rest = jax.eval_shape(lambda: alloc_pools(prog, 17, slots=s_n))
-    assert isinstance(rest, PoolsAndState) == (block == "linear_gqa")
+    assert isinstance(rest, PoolsAndState) == bool(prog.slot_state)
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
     slot = (i32(),) if prog.slot_state else ()
     step_args = (params, first, rest, i32(s_n, pps), i32(s_n), i32(s_n),
