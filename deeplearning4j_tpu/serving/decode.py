@@ -135,9 +135,12 @@ Two more host-overhead eliminations ride on top (docs/SERVING.md
       stalled then); counters ``chunk_turns`` / ``chunk_turns_multi``,
       the gauge ``chunk_turn_max`` and the ``chunks`` / ``mid_prefill``
       arguments of ``serve/decode_step`` say how often and how far.
-      What stays one a turn is ADMISSION: the batcher's token-budget
-      rule lets one round take in no more prompt tokens than one chunk
-      (the head request always), which paces a wall of prompts.
+      ADMISSION keeps that pace: the batcher's token-budget rule lets
+      one round take in no more prompt tokens than one chunk FOR EACH
+      FREE SLOT (the head request always), so short prompts fill every
+      free slot at once and a wall of long ones enters at a chunk a
+      slot a turn (``admit_rounds_budget_bound`` counts the rounds the
+      budget ended).
       Per-row attention math is unchanged, so the final chunk's logits
       (and every sampled token) are bit-identical to an unchunked
       prefill, and to any other number of chunks a turn.  Under a fused
@@ -1611,7 +1614,9 @@ class DecodeEngine:
         a slot (attaching the longest matching prefix read-only when the
         prefix cache is on), prefill, sample the first token (TTFT).
         Stops at the first request the pool cannot hold yet (FIFO order
-        preserved).  ``turn`` is the loop's ``serve/iteration`` span: a
+        preserved).  With a ``prefill_chunk`` a round's prompt tokens
+        are budgeted: one chunk's worth for each free slot, none
+        otherwise.  ``turn`` is the loop's ``serve/iteration`` span: a
         round that the token budget ended with slots free and requests
         waiting is counted there and in ``admit_rounds_budget_bound``."""
         from ..ops.kv_cache import pages_for
@@ -1620,12 +1625,17 @@ class DecodeEngine:
             free = [i for i, s in enumerate(self._slots) if s is None]
         if not free:
             return False
-        # chunked prefill's batch-formation rule: one admit round never
-        # pulls in more prompt tokens than one chunk budget, so a wall
-        # of long prompts enters the engine at the pace the chunk loop
-        # can interleave (the head request is still always admitted)
-        reqs = self.batcher.admit(len(free),
-                                  token_budget=self.prefill_chunk)
+        # chunked prefill's batch-formation rule: a round takes in one
+        # chunk's worth of prompt tokens for EACH FREE SLOT, the pace at
+        # which the loop reads (``_chunk_budget``: a chunk a turn for
+        # every slot mid-prefill), so short prompts fill every slot that
+        # stands free in one round.  What it still ends is a round whose
+        # long prompts would take more chunks than the slots they fill
+        # give the loop: a 2,048-token head on one free slot is admitted
+        # (the head always is), a second one behind it waits a turn
+        budget = (None if self.prefill_chunk is None
+                  else self.prefill_chunk * len(free))
+        reqs = self.batcher.admit(len(free), token_budget=budget)
         if self.batcher.last_admit_budget_bound:
             # it counts; it changes no decision
             self.metrics.inc("admit_rounds_budget_bound")

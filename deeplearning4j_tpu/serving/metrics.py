@@ -276,7 +276,8 @@ _DECODE_COUNTER_KEYS = (
     # prefill_chunks / fused_dispatches = chunks a turn)
     "chunk_turns", "chunk_turns_multi",
     # admission rounds that the token budget ended (one chunk's worth of
-    # prompt tokens a round) with slots still free and requests waiting
+    # prompt tokens for each free slot) with slots still free and
+    # requests waiting
     "admit_rounds_budget_bound",
     # the plain loop's step in flight: decode steps queued while the step
     # before was unread, steps read in the turn that queued them (two

@@ -74,3 +74,37 @@ def save_bundle_or_skip(engine, path):
         if "is not serializable on the" not in str(e):
             raise
         pytest.skip(str(e))
+
+
+def queued_together(eng, requests, budget_of=None, timeout=300):
+    """``requests`` (``(prompt, generate_async's keywords)`` each) queued
+    TOGETHER (the loop waits for the engine's lock until all are in):
+    their results in order, the admission rounds that took them in as
+    ``(limit, token_budget, [prompt lengths])``, and how many rounds the
+    engine counted as ended by the budget meanwhile.  ``budget_of(limit)``
+    puts another budget in the engine's place (the rule before a round's
+    budget followed the free slots: one chunk's worth whatever is free)."""
+    rounds, admit = [], eng.batcher.admit
+
+    def watched(limit, token_budget=None):
+        if budget_of is not None:
+            token_budget = budget_of(limit)
+        out = admit(limit, token_budget=token_budget)
+        if out:
+            rounds.append((limit, token_budget,
+                           [len(r.payload.prompt) for r in out]))
+        return out
+
+    def bound():
+        return eng.metrics_snapshot()["counters"]["admit_rounds_budget_bound"]
+
+    n0 = bound()
+    try:
+        with eng._lock:
+            eng.batcher.admit = watched
+            futs = [eng.generate_async(prompt, **kw)
+                    for prompt, kw in requests]
+        res = [f.result(timeout=timeout) for f in futs]
+    finally:
+        eng.batcher.admit = admit
+    return res, rounds, bound() - n0

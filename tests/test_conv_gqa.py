@@ -469,6 +469,35 @@ def test_a_round_that_the_token_budget_ends_is_counted(lm):
     assert alone["counters"]["admit_rounds_budget_bound"] == 0
 
 
+@pytest.mark.parametrize("sampling", [
+    {}, {"temperature": 0.8, "top_k": 5}], ids=["greedy", "sampled"])
+def test_slots_filled_in_one_round_say_what_they_say_filled_one_a_round(
+        lm, sampling):
+    """A round's budget is a chunk's worth of prompt tokens for each free
+    slot: four short prompts fill four free slots at once, where one
+    chunk's worth a round (the rule it replaces) takes them in one or two
+    a turn.  The schedule decides WHEN a tail and its pages are written,
+    and nothing a request says: tokens and chosen experts are equal."""
+    from _decode_checks import queued_together
+    requests = [(TOKENS[a:b], {"max_new_tokens": 7, "seed": j, **sampling})
+                for j, (a, b) in enumerate([(0, 12), (20, 25), (30, 46),
+                                            (50, 59)])]
+    eng = DecodeEngine(lm, max_slots=4, page_size=4, max_len=128,
+                       prompt_buckets=[8, 16], prefill_chunk=16,
+                       decode_horizon=4).load()
+    try:
+        now, rounds, bound = queued_together(eng, requests)
+        then, before, _ = queued_together(eng, requests,
+                                          budget_of=lambda limit: 16)
+    finally:
+        eng.shutdown()
+    assert rounds == [(4, 4 * 16, [12, 5, 16, 9])] and bound == 0
+    assert before[0] == (4, 16, [12]) and len(before) > 1
+    for a, b in zip(now, then):
+        assert list(a.tokens) == list(b.tokens) and len(a.tokens) == 7
+        np.testing.assert_array_equal(a.expert_picks, b.expert_picks)
+
+
 @pytest.mark.parametrize("what,kw", [
     ("prefix", {"prefix_cache": True}), ("int8", {"kv_dtype": "int8"}),
     ("page transfer", {"role": "prefill"})])

@@ -36,7 +36,8 @@ from types import SimpleNamespace
 
 import numpy as np
 import pytest
-from _decode_checks import assert_greedy_echo, save_bundle_or_skip
+from _decode_checks import (assert_greedy_echo, queued_together,
+                            save_bundle_or_skip)
 
 from deeplearning4j_tpu.obs import trace as obs_trace
 from deeplearning4j_tpu.parallel.mesh import build_mesh
@@ -793,6 +794,109 @@ class TestChunkedPrefill:
         for r in out:
             r.future.set_result(None)
         b.close()
+
+
+# -- the token budget of an admission round --------------------------------
+
+def _prompt(n, k=7):
+    return [1 + (i * k) % (VOCAB - 1) for i in range(n)]
+
+
+def _queued(eng, lengths, budget_of=None, **kw):
+    """Seeded prompts of ``lengths`` through ``queued_together``."""
+    return queued_together(
+        eng, [(_prompt(n, 3 + 2 * j),
+               {"max_new_tokens": 6, "seed": j, **kw})
+              for j, n in enumerate(lengths)], budget_of, timeout=120)
+
+
+@pytest.fixture(scope="module", params=[1, H], ids=["plain", "fused"])
+def budgeted(lm, request):
+    """Three slots read in chunks, under the plain loop and the fused."""
+    eng = _make(lm, prefill_chunk=CHUNK, decode_horizon=request.param)
+    yield eng
+    eng.shutdown()
+
+
+class TestAdmissionBudget:
+    """A round takes in one chunk's worth of prompt tokens for EACH FREE
+    SLOT: short prompts fill every free slot at once, a long head still
+    ends the round, and with one slot free the round is what it was."""
+
+    # (3, 30, 9): a two-chunk prompt between short ones fits as well,
+    # 42 of 3 x 16 tokens
+    @pytest.mark.parametrize("lengths", [(10, 16, 5), (16, 16, 16),
+                                         (3, 30, 9)])
+    def test_short_prompts_fill_every_free_slot_in_one_round(self, budgeted,
+                                                             lengths):
+        res, rounds, bound = _queued(budgeted, lengths)
+        assert rounds == [(3, 3 * CHUNK, list(lengths))] and bound == 0
+        assert all(len(r.tokens) == 6 for r in res)
+        assert _partition_ok(budgeted)
+
+    def test_one_free_slot_takes_the_head_whatever_its_length(self, lm):
+        eng = _make(lm, prefill_chunk=CHUNK, decode_horizon=H, max_slots=1)
+        try:
+            for head in (5, 16, 40):
+                _, rounds, bound = _queued(eng, (head, 7))
+                assert rounds == [(1, CHUNK, [head]), (1, CHUNK, [7])]
+                assert bound == 0       # the SLOT ended both rounds
+        finally:
+            eng.shutdown()
+
+    @pytest.mark.parametrize("horizon", [1, H])
+    def test_a_long_head_ends_the_round_and_the_next_waits_a_turn(
+            self, lm, horizon):
+        eng = _make(lm, prefill_chunk=CHUNK, decode_horizon=horizon,
+                    max_slots=2)
+        rec = obs_trace.enable_tracing(capacity=65536)
+        try:
+            # a head of three chunks on two free slots: alone, counted;
+            # then one slot is free and its round is the slot's
+            _, rounds, bound = _queued(eng, (40, 6, 7))
+            events = rec.events()
+        finally:
+            obs_trace.disable_tracing()
+            eng.shutdown()
+        assert rounds == [(2, 2 * CHUNK, [40]), (1, CHUNK, [6]),
+                          (1, CHUNK, [7])]                  # FIFO kept
+        assert bound == 1
+        turns = sorted((e for e in events if e["name"] == "serve/iteration"),
+                       key=lambda e: e["ts"])
+        assert [(t["args"]["free_slots"], t["args"]["admitted"])
+                for t in turns if t["args"].get("admit_budget_bound")] \
+            == [(2, 1)]
+        assert turns[0]["args"].get("admit_budget_bound")
+        # the request behind the head is taken in by the very next turn
+        second = sorted(e["ts"] for e in events
+                        if e["name"] == "serve/admit")[1]
+        assert turns[1]["ts"] <= second <= turns[1]["ts"] + turns[1]["dur"]
+
+    @pytest.mark.parametrize("sampling", [
+        {}, {"temperature": 0.8, "top_k": 5}], ids=["greedy", "sampled"])
+    def test_the_schedule_changes_when_a_request_runs_not_what_it_says(
+            self, budgeted, plain, sampling):
+        """Requests admitted together return the tokens they return
+        admitted one chunk's worth a round, and the tokens the plain
+        engine gives each alone."""
+        lengths = (12, 5, 16, 21, 9, 3)
+        now, rounds, _ = _queued(budgeted, lengths, **sampling)
+        then, before, _ = _queued(budgeted, lengths,
+                                  budget_of=lambda limit: CHUNK, **sampling)
+        assert rounds[0] == (3, 3 * CHUNK, [12, 5, 16])
+        assert before[0] == (3, CHUNK, [12])
+        assert [r.tokens for r in now] == [r.tokens for r in then]
+        for j, (n, r) in enumerate(zip(lengths, now)):
+            alone = plain.generate(_prompt(n, 3 + 2 * j), max_new_tokens=6,
+                                   seed=j, **sampling)
+            assert r.tokens == alone.tokens
+
+    @pytest.mark.parametrize("which", ["plain", "fused"])
+    def test_an_engine_without_a_chunk_passes_no_budget(self, request,
+                                                        which):
+        _, rounds, bound = _queued(request.getfixturevalue(which),
+                                   (30, 30, 30))
+        assert rounds == [(3, None, [30, 30, 30])] and bound == 0
 
 
 # -- composition with disaggregation --------------------------------------
