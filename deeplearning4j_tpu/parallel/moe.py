@@ -263,8 +263,8 @@ def moe_forward_held(p: Dict[str, Array], x: Array, *, first_expert: int,
     (neither); everything after the router is one code path.  Keeps the picks that fall on the experts held
     here (``p["e_gate"]`` is [held, d, f]; they are experts
     ``first_expert .. first_expert + held - 1``), sorts those picks by
-    expert and runs ONE grouped product per projection over them
-    (``jax.lax.ragged_dot``: work follows the rows really routed, an
+    expert and runs ONE grouped feed-forward over them
+    (``held_experts_ffn``: work follows the rows really routed, an
     expert nobody picked is not read).  A pick on an expert that lives
     elsewhere contributes nothing.  No capacity: every held pick is
     computed whatever the skew, the shapes are static (N*k rows).  The
@@ -276,9 +276,13 @@ def moe_forward_held(p: Dict[str, Array], x: Array, *, first_expert: int,
     stats int32 [4])`` with stats in the order of ``EXPERT_STATS``:
     picks made by valid rows, those that fell on held experts, the
     fullest held expert's picks, held experts with at least one pick.
+
+    What follows the router is a function of its own under ``jit``
+    (``_held_picks``): a program's expert layers, all of one shape, are
+    traced once and lowered once, and called a layer (XLA inlines the
+    calls).
     """
-    n, d = x.shape
-    held = p["e_gate"].shape[0]
+    n = x.shape[0]
     if router == "softmax_topk":
         idx, w = route_softmax_topk(x, p["router_w"], k)
     else:
@@ -286,6 +290,44 @@ def moe_forward_held(p: Dict[str, Array], x: Array, *, first_expert: int,
                                 router_eps)
     if valid is None:
         valid = jnp.ones((n,), bool)
+    mine = {name: p[name] for name in _HELD_PARAMS
+            if name in p and (shared or not name.startswith("s_"))}
+    y, stats = _held_picks(mine, x, idx, w, valid, first_expert=first_expert)
+    return y, jnp.sort(idx, axis=-1), stats
+
+
+#: what of a layer's tree ``moe_forward_held`` reads after the router
+_HELD_PARAMS = ("e_gate", "e_up", "e_down", "s_gate", "s_up", "s_down")
+
+
+def held_experts_ffn(xs: Array, w_gate: Array, w_up: Array, w_down: Array,
+                     group_sizes: Array) -> Array:
+    """The gated feed-forward of rows ``xs`` [M, d] sorted by expert
+    (``group_sizes`` [held] rows each): operands in the weights' type,
+    float32 accumulation, ``silu(gate) * up`` cast to the weights' type
+    before the down projection; [M, d] float32, the rows past the last
+    group unspecified.  One Mosaic call (``ops/grouped_ffn.py``) where
+    the shapes and the type allow it, three ``jax.lax.ragged_dot``\\ s
+    where they do not."""
+    from ..ops import grouped_ffn, pallas_support
+
+    why = grouped_ffn.kept_path(xs, w_gate)
+    if why is None:
+        return grouped_ffn.grouped_ffn(xs, w_gate, w_up, w_down, group_sizes)
+    pallas_support.fell_back(grouped_ffn.call_name(xs, w_gate), why)
+    rd = functools.partial(jax.lax.ragged_dot, group_sizes=group_sizes,
+                           preferred_element_type=jnp.float32)
+    a = (jax.nn.silu(rd(xs, w_gate)) * rd(xs, w_up)).astype(w_down.dtype)
+    return rd(a, w_down)
+
+
+@functools.partial(jax.jit, static_argnames=("first_expert",))
+def _held_picks(p, x, idx, w, valid, *, first_expert):
+    """``moe_forward_held`` after its router: the picks ``idx`` [N, k]
+    with weights ``w`` -> ``(y, stats)``."""
+    n, d = x.shape
+    k = idx.shape[1]
+    held = p["e_gate"].shape[0]
     local = idx - first_expert
     on_held = (local >= 0) & (local < held) & valid[:, None]
     # picks elsewhere go to a last, empty-weighted group `held`
@@ -295,12 +337,8 @@ def moe_forward_held(p: Dict[str, Array], x: Array, *, first_expert: int,
     load = jnp.zeros((held + 1,), jnp.int32).at[flat_e].add(1)[:held]
     n_held = jnp.sum(load)
     in_group = jnp.arange(n * k) < n_held
-    cd = p["e_gate"].dtype
-    xs = x.astype(cd)[tok]
-    rd = functools.partial(jax.lax.ragged_dot, group_sizes=load,
-                           preferred_element_type=jnp.float32)
-    a = (jax.nn.silu(rd(xs, p["e_gate"])) * rd(xs, p["e_up"])).astype(cd)
-    o = rd(a, p["e_down"])
+    xs = x.astype(p["e_gate"].dtype)[tok]
+    o = held_experts_ffn(xs, p["e_gate"], p["e_up"], p["e_down"], load)
     # rows past the last group hold nothing of any expert's
     o = jnp.where(in_group[:, None], o * w.reshape(-1)[order][:, None], 0.0)
     # back to the picks' own order by a gather (a scatter-add of N*k rows
@@ -308,11 +346,11 @@ def moe_forward_held(p: Dict[str, Array], x: Array, *, first_expert: int,
     back = jnp.zeros((n * k,), jnp.int32).at[order].set(
         jnp.arange(n * k, dtype=jnp.int32))
     y = jnp.sum(o[back].reshape(n, k, d), axis=1)
-    if shared and "s_gate" in p:
+    if "s_gate" in p:
         y = y + gated_silu(x, p["s_gate"], p["s_up"], p["s_down"])
     stats = jnp.stack([jnp.sum(valid) * k, n_held, jnp.max(load),
                        jnp.sum(load > 0)]).astype(jnp.int32)
-    return y, jnp.sort(idx, axis=-1), stats
+    return y, stats
 
 
 # ---------------------------------------------------------------------------

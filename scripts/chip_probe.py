@@ -10,6 +10,11 @@ records about the installation:
   tiles      the three flash kernels alone at the benchmark's training
              shape over a handful of tile geometries (what the preferences
              in ``ops/attention.py`` were read from); not in the default run
+  experts    the expert layers' grouped feed-forward (``ops/grouped_ffn``)
+             alone against the three ``ragged_dot``s it replaced, at the
+             five expert cells' step and chunk shapes, then over tile
+             geometries put in place of ``ffn_tiles``' choice; not in the
+             default run
   precision  what an f32 matmul is on the MXU at default precision
   flash_xla  one LM train step each with attention_impl "flash" and "xla"
              at the chip_smoke width (one run each — a finding, not a
@@ -24,6 +29,7 @@ Writes ``chiprun_out/chip_probe.json``; the last stdout line is the same
 JSON.
 """
 import functools
+import glob
 import json
 import os
 import sys
@@ -247,6 +253,104 @@ def probe_tiles() -> list:
     return table
 
 
+def _expert_calls() -> dict:
+    """The expert cells' grouped products, from their configurations'
+    files: cell -> (rows a decode step sorts, rows of the largest prefill
+    chunk, d, f, experts held, experts in all, k)."""
+    calls = {}
+    for path in sorted(glob.glob(os.path.join(_REPO, "benchmarks", "configs",
+                                              "*.json"))):
+        with open(path) as f:
+            cfg = json.load(f)
+        if "n_routed_experts" not in cfg:
+            continue
+        k, prog = cfg["num_experts_per_tok"], cfg["program"]
+        held = cfg["n_routed_experts"]
+        calls[os.path.basename(path)[:-5]] = (
+            prog["max_slots"] * k, prog["prefill_chunk"] * k,
+            cfg["hidden_size"],
+            cfg.get("moe_intermediate_size", cfg["intermediate_size"]), held,
+            next(cfg[name] for name in (
+                "n_routed_experts_published", "num_local_experts_published",
+                "n_routed_experts") if name in cfg), k)
+    return calls
+
+
+def probe_experts() -> list:
+    """``ops/grouped_ffn`` against the three ``jax.lax.ragged_dot``\\ s it
+    replaced, alone, at the five expert cells' step and largest chunk
+    shapes (bf16), each token's k picks drawn evenly over all experts:
+    the two agree, the time of each, and the bandwidth the kernel reaches
+    over the weights of the experts that were hit.  Then the step shapes
+    again over tile geometries put in place of ``ffn_tiles``' choice."""
+    from deeplearning4j_tpu.ops import grouped_ffn as G
+
+    rng = np.random.default_rng(0)
+    table = []
+
+    def plain(xs, wg, wu, wd, sizes):
+        rd = functools.partial(jax.lax.ragged_dot, group_sizes=sizes,
+                               preferred_element_type=jnp.float32)
+        return rd((jax.nn.silu(rd(xs, wg)) * rd(xs, wu)).astype(wd.dtype), wd)
+
+    for cell, (m_step, m_chunk, d, f, held, n_all, k) in _expert_calls().items():
+        mk = lambda *s: jnp.asarray(0.05 * rng.standard_normal(s, np.float32),
+                                    jnp.bfloat16)
+        wg, wu, wd = mk(held, d, f), mk(held, d, f), mk(held, f, d)
+        for what, m in (("step", m_step), ("chunk", m_chunk)):
+            picks = np.stack([rng.choice(n_all, k, replace=False)
+                              for _ in range(m // k)]).reshape(-1)
+            sizes = np.bincount(picks[picks < held], minlength=held)
+            n, hit = int(sizes.sum()), int((sizes > 0).sum())
+            xs = 20 * mk(m, d)
+            sz = jnp.asarray(sizes, jnp.int32)
+            chosen = G.ffn_tiles(m, d, f, held, 2)
+            row = {"cell": cell, "call": what, "M": m, "d": d, "f": f,
+                   "held": held, "rows_held": n, "experts_hit": hit,
+                   "tiles": str(chosen)}
+            try:
+                want = np.asarray(jax.jit(plain)(xs, wg, wu, wd, sz))[:n]
+                got = np.asarray(jax.jit(G.grouped_ffn)(xs, wg, wu, wd, sz))[:n]
+                row["max_abs_gap"] = float(np.abs(got - want).max()) if n else 0.0
+                row["max_abs"] = float(np.abs(want).max()) if n else 0.0
+                row["ragged_dot_ms"] = round(_time(
+                    jax.jit(functools.partial(plain)), xs, wg, wu, wd, sz,
+                    n=20) * 1e3, 4)
+                ms = _time(jax.jit(functools.partial(G.grouped_ffn)), xs, wg,
+                           wu, wd, sz, n=20) * 1e3
+                row["grouped_ffn_ms"] = round(ms, 4)
+                row["hit_weights_gb_s"] = round(
+                    hit * 3 * d * f * 2 / (ms * 1e-3) / 1e9, 1)
+                row["tflop_s"] = round(n * 6 * d * f / (ms * 1e-3) / 1e12, 2)
+            except Exception as e:
+                traceback.print_exc()
+                row["refused"] = f"{type(e).__name__}: {e}"[:600]
+            print(f"chip_probe: {row}", flush=True)
+            table.append(row)
+            # other geometries at the same shapes
+            windows = (32, 64, 128) if what == "step" else (64, 128, 256)
+            for window in windows:
+                for cols in sorted({c for c in (128, 256, 384, 512, 768)
+                                    if f % c == 0 and d * c * 2 <= 6 << 20}):
+                    if (window, cols) == (chosen.window, chosen.cols):
+                        continue
+                    rows = -(-chosen.rows // window) * window
+                    t = G.FfnTiles(rows, -(-m // rows), window, cols,
+                                   2 * (6 * d * cols + 6 * rows * d))
+                    r = {"cell": cell, "call": what, "window": window,
+                         "cols": cols, "rows": rows}
+                    try:
+                        r["grouped_ffn_ms"] = round(_time(
+                            jax.jit(functools.partial(G.with_tiles, tiles=t)),
+                            xs, wg, wu, wd, sz, n=10) * 1e3, 4)
+                    except Exception as e:
+                        r["refused"] = f"{type(e).__name__}: {e}"[:300]
+                    print(f"chip_probe: {r}", flush=True)
+                    table.append(r)
+        del wg, wu, wd
+    return table
+
+
 def probe_precision() -> dict:
     """f32 x f32 matmul against a float64 host reference, per precision."""
     rng = np.random.default_rng(0)
@@ -387,7 +491,8 @@ def probe_gspmd() -> dict:
 SECTIONS = {"kernels": probe_kernels, "precision": probe_precision,
             "flash_xla": probe_flash_vs_xla, "bundle": probe_bundle,
             "gspmd": probe_gspmd}
-ON_REQUEST = {"tiles": probe_tiles}      # run only when named
+ON_REQUEST = {"tiles": probe_tiles,       # run only when named
+              "experts": probe_experts}
 
 
 def main() -> int:

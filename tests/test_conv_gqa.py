@@ -518,13 +518,26 @@ SSM_BEFORE = {"prefill_at": "f1ae8c387647600c", "prefill": "ca15d31f37485606",
               "reencode": "d47320ab4ba9d2df"}
 
 
+#: this tree's, since PR 46 put the expert layer under a ``jit`` of its
+#: own (tests/test_linear_gqa.py ``SINCE_PR46``)
+SSM_SINCE_PR46 = {"prefill_at": "4371400915c7ee87",
+                  "prefill": "2eeb2eb11fb852c7", "step": "da6fcda3567e94cd",
+                  "step_multi": "ce1786b02e63b01f",
+                  "reencode": "6282d1812de5dda7"}
+
+
+@pytest.mark.parametrize("expert_layer", ["its-own-function", "traced-in-line"])
 @pytest.mark.parametrize("block", ["latent_moe", "sparse_gqa", "linear_gqa",
                                    "ssm_gqa"])
-def test_the_other_blocks_programs_lower_to_what_they_did(block):
+def test_the_other_blocks_programs_lower_to_what_they_did(block, expert_layer,
+                                                          monkeypatch):
     """One builder for five blocks, whose router now takes the epsilon of
     its normalisation from the architecture and whose rotary tables take
     a width: Kimi's, Keye's, Solar's and Granite's programs are the
-    parent's, text for text."""
+    parent's, text for text, but for the expert layer's call."""
+    import tests.test_linear_gqa as tg
     import tests.test_ssm_gqa as tsm
-    before = {**tsm.BEFORE, "ssm_gqa": SSM_BEFORE}
-    assert tsm._texts(block) == before[block]
+    tg.pinned_texts(
+        tsm._texts, block, expert_layer, {**tsm.BEFORE, "ssm_gqa": SSM_BEFORE},
+        {**tg.SINCE_PR46, **tsm.SINCE_PR46, "ssm_gqa": SSM_SINCE_PR46},
+        monkeypatch)

@@ -1,6 +1,6 @@
 """The Mosaic kernels (flash attention's three, the decode step's paged
-attention and latent attention) compiled for a DESCRIBED TPU v5e, without
-one.
+attention and latent attention, the expert layers' grouped feed-forward)
+compiled for a DESCRIBED TPU v5e, without one.
 
 Interpret mode (tests/test_attention.py) cannot see what Mosaic refuses: a
 slice not aligned to the tiling, more VMEM than a kernel may use.  The
@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from deeplearning4j_tpu.ops import (attention, latent_attention,
+from deeplearning4j_tpu.ops import (attention, grouped_ffn, latent_attention,
                                     paged_attention)
 
 
@@ -43,6 +43,7 @@ def as_on_tpu(monkeypatch):
     monkeypatch.setattr(attention, "interpret", lambda: False)
     monkeypatch.setattr(paged_attention, "interpret", lambda: False)
     monkeypatch.setattr(latent_attention, "interpret", lambda: False)
+    monkeypatch.setattr(grouped_ffn, "interpret", lambda: False)
 
 
 # B, H, T, S, D, dtype, causal, kmask
@@ -163,22 +164,72 @@ def test_latent_decode_program_compiles_for_v5e_without_a_pool_copy(
         assert gathered not in hlo, gathered
 
 
-# -- the sparse decode programs at Keye-VL-2.0's widths ----------------------------
+# -- the expert layers' grouped feed-forward at the five expert cells' shapes -------
 
-@pytest.mark.parametrize("entry", ["step", "step_multi", "prefill_at"])
-def test_sparse_decode_program_compiles_for_v5e_without_a_pool_copy(
-        one_chip, entry):
-    """The decode step, the fused horizon and a prefill chunk (512
-    tokens) of the benchmark's ``keye-vl-2-30b-a3b`` configuration (8 slots,
-    16,384 positions, bf16) compile for one v5e chip, fit its memory,
-    update the three donated pools in place and keep far less in
-    temporaries than one pool holds.  In the compiled text every pool,
-    the 128-lane index pool among them, keeps its natural layout (a
-    64-lane row would be stored pages-minor and transposed in and out
-    of every call), and the steps hold no K or V temporary of a slot's
-    window: they gather the 2,048 chosen rows."""
+# cell -> (rows a decode step sorts (slots x k), rows of the largest
+# prefill chunk (512 x k), d, f, experts held)
+EXPERT_CALLS = {
+    "lfm2-24b-a2b": (512, 2048, 2048, 1536, 64),
+    "granite-4.0-h-small": (480, 5120, 4096, 768, 36),
+    "solar-open2-250b": (256, 4096, 4096, 1280, 40),
+    "kimi-k2-instruct": (128, 4096, 7168, 2048, 12),
+    "keye-vl-2-30b-a3b": (64, 4096, 2048, 768, 128),
+}
+
+
+def _expert_shapes(cell, call):
+    """What the configuration's file says, so that the table above cannot
+    drift from it."""
     import json
-    import re
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs", cell + ".json")) as f:
+        cfg = json.load(f)
+    step, chunk, d, f, held = EXPERT_CALLS[cell]
+    k, prog = cfg["num_experts_per_tok"], cfg["program"]
+    assert step == prog["max_slots"] * k and chunk == prog["prefill_chunk"] * k
+    assert d == cfg["hidden_size"] and held == cfg["n_routed_experts"]
+    assert f == cfg.get("moe_intermediate_size", cfg["intermediate_size"])
+    return (step if call == "step" else chunk), d, f, held
+
+
+@pytest.mark.parametrize("call", ["step", "chunk"])
+@pytest.mark.parametrize("cell", sorted(EXPERT_CALLS))
+def test_grouped_ffn_compiles_for_v5e_at_the_expert_cells_shapes(
+        one_chip, as_on_tpu, cell, call):
+    """Mosaic's alignment and VMEM limits show only here: the kernel at
+    each expert cell's decode step and largest prefill chunk (bf16), ONE
+    Mosaic call where three grouped products stood, within the VMEM its
+    tiles say."""
+    m, d, f, held = _expert_shapes(cell, call)
+    bf16 = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+    x = bf16(m, d)
+    assert grouped_ffn.kept_path(x, bf16(held, d, f)) is None
+    tiles = grouped_ffn.ffn_tiles(m, d, f, held, 2)
+    assert tiles.vmem_bytes <= 48 * 1024 * 1024
+    hlo = jax.jit(grouped_ffn.grouped_ffn).lower(
+        x, bf16(held, d, f), bf16(held, d, f), bf16(held, f, d),
+        jax.ShapeDtypeStruct((held,), jnp.int32, sharding=one_chip)
+    ).compile().as_text()
+    assert hlo.count("tpu_custom_call") == 1
+    assert "%grouped_ffn" in hlo and "ragged" not in hlo
+
+
+def test_grouped_ffn_compiles_for_v5e_with_float32_weights(one_chip,
+                                                           as_on_tpu):
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    hlo = jax.jit(grouped_ffn.grouped_ffn).lower(
+        f32(96, 512), f32(8, 512, 256), f32(8, 512, 256), f32(8, 256, 512),
+        jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
+    ).compile().as_text()
+    assert hlo.count("tpu_custom_call") == 1
+
+
+def _keye_entry(one_chip, entry):
+    """``(cfg, arch, prog, pools, lowered)`` of one entry point of the
+    benchmark's ``keye-vl-2-30b-a3b`` configuration (8 slots, 16,384
+    positions, bf16) on the described chip."""
+    import json
 
     from deeplearning4j_tpu.models import sparse_gqa
     from deeplearning4j_tpu.models.arch import LMArch
@@ -199,30 +250,69 @@ def test_sparse_decode_program_compiles_for_v5e_without_a_pool_copy(
                                        jnp.bfloat16)))
     k_pool, rest = jax.tree_util.tree_map(on_chip, jax.eval_shape(
         lambda: tuple(alloc_pools(prog, 1 + slots * prog.pages_per_slot))))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    flags = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip)
+    step_args = (i32(slots, prog.pages_per_slot), i32(slots), i32(slots),
+                 flags)
+    fn, args = {
+        "step": (prog.step, step_args),
+        "step_multi": (prog.step_multi, step_args + (
+            f32(slots), i32(slots), f32(slots),
+            jax.ShapeDtypeStruct((slots,), jnp.uint32, sharding=one_chip),
+            i32(slots), i32(slots), i32(),
+            i32(cfg["program"]["decode_horizon"]))),
+        "prefill_at": (prog.prefill_at, (
+            i32(prog.pages_per_slot), i32(cfg["program"]["prefill_chunk"]),
+            i32(), i32())),
+    }[entry]
+    lowered = jax.jit(fn, donate_argnums=(1, 2)).lower(
+        params, k_pool, rest, *args)
+    return cfg, arch, prog, (k_pool, rest), lowered
+
+
+@pytest.mark.parametrize("entry", ["step", "step_multi", "prefill_at"])
+def test_an_expert_program_lowers_the_grouped_ffn_once_a_shape(
+        one_chip, as_on_tpu, entry):
+    """ROADMAP A12: a kernel lowered at every call site is paid at every
+    load (PR 45's: three calls a layer, ten layers, five programs, 0.95 s
+    of the Granite cell's set-up and its refusal).  The Keye
+    configuration's programs hold seven expert layers of one shape: the
+    lowered text holds what follows the router ONCE, as a private
+    function called seven times, and in it ONE Mosaic call
+    (``step_multi`` scans its step: the same one)."""
+    import re
+
+    _, _, prog, _, lowered = _keye_entry(one_chip, entry)
+    text = lowered.as_text()
+    assert prog.n_layers == 7
+    assert len(re.findall(r"func\.func private @_held_picks", text)) == 1
+    assert len(re.findall(r"call @_held_picks", text)) == 7
+    assert text.count("@tpu_custom_call") == 1
+    assert text.count("ragged_dot") == 0
+
+
+# -- the sparse decode programs at Keye-VL-2.0's widths ----------------------------
+
+@pytest.mark.parametrize("entry", ["step", "step_multi", "prefill_at"])
+def test_sparse_decode_program_compiles_for_v5e_without_a_pool_copy(
+        one_chip, as_on_tpu, entry):
+    """The decode step, the fused horizon and a prefill chunk (512
+    tokens) of the benchmark's ``keye-vl-2-30b-a3b`` configuration (8 slots,
+    16,384 positions, bf16) compile for one v5e chip, fit its memory,
+    update the three donated pools in place and keep far less in
+    temporaries than one pool holds.  In the compiled text every pool,
+    the 128-lane index pool among them, keeps its natural layout (a
+    64-lane row would be stored pages-minor and transposed in and out
+    of every call), and the steps hold no K or V temporary of a slot's
+    window: they gather the 2,048 chosen rows."""
+    import re
+
+    _, _, _, (k_pool, rest), lowered = _keye_entry(one_chip, entry)
     assert k_pool.shape == rest[0].shape == (7, 8193, 16, 512)
     assert rest[1].shape == (7, 8193, 16, 128)
     pool_bytes = 7 * 8193 * 16 * (512 + 512 + 128) * 2
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
-    flags = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip)
-    if entry == "step":
-        args = (i32(slots, prog.pages_per_slot), i32(slots), i32(slots),
-                flags)
-        fn = prog.step
-    elif entry == "step_multi":
-        f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32,
-                                              sharding=one_chip)
-        args = (i32(slots, prog.pages_per_slot), i32(slots), i32(slots),
-                flags, f32(slots), i32(slots), f32(slots),
-                jax.ShapeDtypeStruct((slots,), jnp.uint32, sharding=one_chip),
-                i32(slots), i32(slots), i32(),
-                i32(cfg["program"]["decode_horizon"]))
-        fn = prog.step_multi
-    else:
-        args = (i32(prog.pages_per_slot), i32(cfg["program"]["prefill_chunk"]),
-                i32(), i32())
-        fn = prog.prefill_at
-    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
-        params, k_pool, rest, *args).compile()
+    compiled = lowered.compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
     assert mem.alias_size_in_bytes >= pool_bytes            # all three in place
@@ -241,7 +331,8 @@ def test_sparse_decode_program_compiles_for_v5e_without_a_pool_copy(
 # -- the two-kind decode programs at Solar-Open2's widths ---------------------------
 
 @pytest.mark.parametrize("entry", ["step_multi", "prefill_at"])
-def test_linear_gqa_decode_program_compiles_for_v5e_in_place(one_chip, entry):
+def test_linear_gqa_decode_program_compiles_for_v5e_in_place(
+        one_chip, as_on_tpu, entry):
     """The fused horizon and a prefill chunk (512 tokens) of the
     benchmark's ``solar-open2-250b`` configuration (32 slots, 19,456
     positions, bf16 weights, float32 state) compile for one v5e chip, fit
