@@ -30,24 +30,37 @@ spans many device dispatches), and shape-bucketed in TWO dimensions
       pages go back to the free list the same turn — the pool
       oversubscribes slots when request lengths vary.
 
-  one decode step in flight (the plain loop, ``decode_horizon=1``)
-      A turn of the loop admits (a prefill blocks on its first token),
-      then QUEUES step n+1 and its sampler call, and only then reads
-      step n back and records it: the device runs n+1 while the host
-      reads, records, finishes, admits and builds n+2.  Step n+1's input
-      tokens are the sampler's output of step n, still on the device; a
-      slot that joined from a prefill since has its token on the host,
-      and one small program joins the two (``("join",)``).  Positions,
-      sampler counters and budget stops are known without the read; EOS,
-      a passed deadline and non-finite logits are not, so such a request
-      is stepped once too often: a write at its own next row, ordered on
-      the device before anything a later tenant of its pages does, and a
-      token that is dropped (``overrun_slot_steps``).  Slot state on the
-      host changes only at a read, so a crash retries from the last
-      recorded token.  While two model versions are alive each step is
-      read before the next is queued (``step_drains``); counters
-      ``steps_ahead`` / ``decode_steps`` say how often the device had
-      its next step waiting.
+  one turn of the loop (``_turn``)
+      A turn admits (a whole prompt's prefill blocks on its first
+      token), runs the turn's prefill chunks, and then, per live model
+      version, QUEUES one decode dispatch and READS what is due
+      (``_step_once``: one dispatch function, one read, one record
+      loop).  Two predicates say how, each computed from what the engine
+      observes and written once, with its reason:
+
+      ``keep`` (``_step_once``): with a horizon of 1 and one live
+      version the new dispatch stays unread until the next turn, which
+      queues step n+1 and its sampler call and only then reads step n
+      back: the device runs n+1 while the host reads, records, finishes,
+      admits and builds n+2.  Step n+1's input tokens are the sampler's
+      output of step n, still on the device; a slot that joined from a
+      prefill since has its token on the host, and one small program
+      joins the two (``("join",)``).  Positions, sampler counters and
+      budget stops are known without the read; EOS, a passed deadline
+      and non-finite logits are not, so such a request is stepped once
+      too often: a write at its own next row, ordered on the device
+      before anything a later tenant of its pages does, and a token that
+      is dropped (``overrun_slot_steps``).  While two model versions are
+      alive each step is read before the next is queued
+      (``step_drains``); counters ``steps_ahead`` / ``decode_steps`` say
+      how often the device had its next step waiting.
+
+      ``_chunks_ride_behind``: under a fused horizon the turn's chunks
+      are queued behind the dispatch and read after it; otherwise they
+      run before it, each waited for (see "chunked prefill" below).
+
+      Slot state on the host changes only at a read, so a crash retries
+      from the last recorded token.
 
   resilience (the PR-7 supervisor patterns, decode-shaped)
       A crash anywhere in the decode loop fails or RETRIES every
@@ -144,12 +157,15 @@ Two more host-overhead eliminations ride on top (docs/SERVING.md
       Per-row attention math is unchanged, so the final chunk's logits
       (and every sampled token) are bit-identical to an unchunked
       prefill, and to any other number of chunks a turn.  Under a fused
-      horizon the iteration's chunks are queued behind the step's
-      dispatch, back to back, so the device runs them while the host
-      reads back and records the step's tokens, and a fused dispatch's
-      echoed logits are copied out one dispatch late
-      (``_step_fused_once``, ``_flush_echo``): between two programs the
-      host then only reads tokens, records them and dispatches.
+      horizon the turn's chunks are queued behind the step's dispatch,
+      back to back, so the device runs them while the host reads back
+      and records the step's tokens; they are read in the order of their
+      dispatch as far as the last FINAL chunk among them (its first
+      token joins the next step), the rest after the next turn's steps,
+      when they are long done (``_turn``).  A fused dispatch's echoed
+      logits are copied out one dispatch late (``_Flight.late``,
+      ``_flush_echo``): between two programs the host then only reads
+      tokens, records them and dispatches.
       Whose chunk goes next (``prefill_order``, asked once a chunk):
       ``"round_robin"`` over the slots mid-prefill, or
       ``"nearest_end"``, the prompt with the fewest tokens left first
@@ -169,6 +185,7 @@ request carry its ``request_id`` — docs/OBSERVABILITY.md.
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import os
 import threading
@@ -333,12 +350,15 @@ class _Slot:
 
 
 class _Chunk:
-    """One chunk of a chunked prefill between its pick and its commit:
-    the slot, the tokens, and what the dispatch left on the device."""
+    """One dispatch of a prompt program between its pick and its commit:
+    the slot, the tokens, and what the dispatch left on the device.
+    ``whole``: it is the prompt's whole unmatched part, prefilled at
+    admission in one program, and not a turn's chunk of a chunked
+    prefill."""
 
     __slots__ = ("i", "slot", "offset", "take", "bucket", "padded", "last",
-                 "lg", "aux", "tok", "fin", "picks_h", "tok_h", "fin_h",
-                 "lg_h", "rows_h", "t1")
+                 "whole", "lg", "aux", "tok", "fin", "picks_h", "tok_h",
+                 "fin_h", "lg_h", "rows_h", "t1")
 
 
 class _StepInputs:
@@ -374,13 +394,18 @@ class _StepInputs:
 
 
 class _Flight:
-    """One plain decode step on the device and not read back yet: its
-    inputs, what the step and the sampler left on the device (their
-    transfers to the host started at the dispatch), whether the step
-    before it was unread when it went, and the dispatch's times."""
+    """One decode dispatch on the device and not read back yet: its
+    inputs, the decode ``steps`` it holds, what it left on the device
+    (``[steps, slots, ...]``, a single step's without the leading axis;
+    the transfers to the host started at the dispatch), whether the
+    dispatch before it was unread when it went, and the dispatch's
+    times.  ``late``: its echoed logits are the bulk of ``steps`` steps
+    and are copied out a dispatch late (``_flush_echo``), not waited for
+    at the read.  ``sample_ms`` is None where the step's program samples
+    itself: the step's time is then the whole of dispatch to read."""
 
-    __slots__ = ("inp", "toks", "fin", "lgs", "aux", "ahead", "t0",
-                 "step_ms", "sample_ms")
+    __slots__ = ("inp", "steps", "toks", "fin", "lgs", "attn", "aux",
+                 "late", "ahead", "t0", "step_ms", "sample_ms")
 
 
 class _PrefixNode:
@@ -717,18 +742,19 @@ class DecodeEngine:
         self._shutdown = False
         self._generation = 0
         self._chunk_cursor = 0     # round-robin over chunked prefills
-        # echoed logits of the last fused dispatch, not copied out yet:
-        # the device array, (buffer, first row, rows, slot) per request,
-        # and the finished answers that wait for those rows
+        # echoed logits of the last dispatch that hands them over late
+        # (``_Flight.late``), not copied out yet: the device arrays,
+        # (buffer, first row, rows, slot) per request, and the finished
+        # answers that wait for those rows
         self._echo_lgs = None
         self._rows_shape: Optional[tuple] = None   # of ``attn_rows``, a token
         self._echo_rows: List[tuple] = []
         self._echo_results: List[tuple] = []
-        self._echo_defer = False   # True while a fused step is recorded
-        # chunks queued behind a fused step and not read back yet, in the
-        # order of their dispatch
+        self._echo_defer = False   # True while such a dispatch is recorded
+        # chunks queued behind a decode dispatch and not read back yet, in
+        # the order of their dispatch
         self._chunk_inflight: List[_Chunk] = []
-        # the plain loop's decode step on the device and not read back yet
+        # the decode dispatch a turn left unread for the next one
         self._flight: Optional[_Flight] = None
         self._step_read_at = 0.0   # clock at the end of the last step's read
         self._request_ids = itertools.count(1)
@@ -923,9 +949,9 @@ class DecodeEngine:
                     np.ones((s_n,), np.float32),
                     np.zeros((s_n,), np.uint32), zs_i)
                 if self.decode_horizon == 1 and self._draft_program is None:
-                    # the plain loop keeps a step in flight: a slot that
-                    # joins from a prefill has its token on the host, the
-                    # slots already stepping theirs on the device
+                    # a single step may stay in flight (``_step_once``): a
+                    # slot that joins from a prefill has its token on the
+                    # host, the slots already stepping theirs on the device
                     def _join_tokens(dev, host, on_host):
                         import jax.numpy as jnp
                         return jnp.where(on_host, host, dev)
@@ -1475,28 +1501,7 @@ class DecodeEngine:
             # no device gap is left without a span of the program's
             with obs_trace.span("serve/iteration", cat="serve") as it:
                 try:
-                    worked = self._admit_some(it)
-                    # one admission round and at most ``_chunk_budget()``
-                    # chunks of prefill work per iteration: a chunk for
-                    # each slot mid-prefill, so a decode dispatch never
-                    # waits behind more than that many chunks
-                    if self.decode_horizon > 1:
-                        # the fused step queues the chunks behind itself
-                        # and does its host work while they run
-                        stepped = self._step_fused_once()
-                        if not stepped:
-                            # no slot to step: nothing else will cover
-                            # the rows and results still owed
-                            self._chunk_settle()
-                            self._flush_echo()
-                            worked = self._prefill_chunk_steps() or worked
-                    else:
-                        worked = self._prefill_chunk_steps() or worked
-                        if self._draft_program is not None:
-                            stepped = self._spec_step_once()
-                        else:
-                            stepped = self._step_once()
-                    worked = stepped or worked
+                    worked = self._turn(it)
                 except Exception as e:
                     obs_trace.instant("serve/replica_crash", cat="serve",
                                       kind="decode_step",
@@ -1509,6 +1514,57 @@ class DecodeEngine:
                     it.drop()   # an idle engine must not fill the ring
             if not worked:
                 self.batcher.wait_for_work(0.05)
+
+    def _turn(self, it) -> bool:
+        """One turn of the loop: one admission round, the turn's prefill
+        chunks (``_chunk_budget()``: one for each slot mid-prefill, so a
+        decode dispatch never waits behind more than that many), and the
+        decode dispatches with their reads (``_step_once``).  Where the
+        chunks stand to the dispatch is ``_chunks_ride_behind``'s to
+        say; False when the turn found nothing to do."""
+        worked = self._admit_some(it)
+        if self._draft_program is not None:
+            return self._spec_step_once() or worked
+        behind = self._chunks_ride_behind()
+        if not behind:
+            # each chunk waited for, and a final chunk's slot steps with
+            # this turn's dispatch
+            worked = self._turn_chunks() > 0 or worked
+        old = len(self._chunk_inflight)
+        stepped = self._step_once()
+        if behind:
+            # the chunks an earlier turn left running lie before this
+            # turn's steps on the device: they are done, and reading them
+            # costs no wait
+            self._chunk_settle(old)
+            new = self._chunk_inflight
+            if new:
+                # they are still running: this dispatch's rows land now
+                self._flush_echo()
+                # a final chunk's first token joins the next step: wait
+                # as far as the last of them.  Nothing of the chunks
+                # behind it is needed before the next dispatch, so that
+                # is queued behind them with the device still busy
+                self._chunk_settle(max(
+                    (k + 1 for k, c in enumerate(new) if c.last), default=0))
+            elif not stepped:
+                # no dispatch to queue them behind, and nothing else will
+                # cover the rows and results still owed
+                self._flush_echo()
+                worked = self._turn_chunks() > 0 or worked
+        return stepped or worked
+
+    def _chunks_ride_behind(self) -> bool:
+        """The schedule's second predicate: whether a turn's chunks are
+        queued BEHIND its decode dispatch, back to back, and read after
+        it (as far as the last final chunk), or run before it, each
+        waited for.  Behind ⇔ ``decode_horizon > 1``: a fused dispatch
+        gives the host H tokens a slot to read back and record, and the
+        device goes from the steps into the chunks meanwhile; a single
+        step's read is short, and its successor is what the device has
+        waiting (``_step_once``), so the chunks go first and a prompt
+        whose final chunk ran decodes with this very turn's step."""
+        return self.decode_horizon > 1
 
     # -- radix prefix cache (host-side trie; loop thread + _lock) ----------
 
@@ -1723,10 +1779,6 @@ class DecodeEngine:
                     slot.shared_nodes = matched
                     slot.n_matched = m
                     self._slots[i] = slot
-                    self.metrics.active_slots.set(
-                        sum(1 for s in self._slots if s is not None))
-                    self.metrics.pages_in_use.set(
-                        self.total_pages - 1 - len(self._free_pages))
                     self._refresh_pool_gauges_locked()
                 queue_wait_ms = (now - r.t_submit) * 1e3
                 self.metrics.queue_wait.record(queue_wait_ms)
@@ -1791,69 +1843,10 @@ class DecodeEngine:
         return self.prompt_buckets[-1]
 
     def _prefill_slot(self, i: int) -> None:
-        s = self._slots[i]
-        spec = s.spec
-        n = s.n_prompt
-        m = s.n_matched * self.program.page_size   # matched prefix tokens
-        bucket = self._bucket_for(n - m)
-        with obs_trace.span("serve/prefill", cat="serve", slot=i,
-                            bucket=bucket, prompt_tokens=n, model=s.tag,
-                            request_id=spec.request_id) as sp:
-            kp, vp = self._cache
-            if m:
-                # prefix-cache hit: prefill ONLY the unmatched suffix; the
-                # shared pages already hold the prefix rows and the suffix
-                # rows attend over them (prefill_at) — same per-row math as
-                # a cold prefill, so the logits are bit-identical
-                suffix = n - m
-                padded = np.zeros((bucket,), np.int32)
-                padded[:suffix] = spec.prompt[m:]
-                kp, vp, lg, *aux = self._compiled[("prefill_at", bucket)](
-                    self._versions[s.tag], kp, vp, self._page_table[i], padded,
-                    np.int32(suffix), np.int32(m))
-            else:
-                padded = np.zeros((bucket,), np.int32)
-                padded[:n] = spec.prompt
-                kp, vp, lg, *aux = self._compiled[("prefill", bucket)](
-                    self._versions[s.tag], kp, vp, self._page_table[i], padded,
-                    np.int32(n), *self._slot_arg(i))
-                if self._slot_state:
-                    self.metrics.inc("recurrent_state_resets")
-            tok, fin = self._compiled[("sample1",)](
-                lg, np.float32(spec.temperature), np.int32(spec.top_k),
-                np.float32(spec.top_p), np.uint32(spec.seed), np.int32(0))
-            self._cache = (kp, vp)
-            if self._draft_program is not None:
-                # mirror the prompt into the draft pool (same page ids, the
-                # draft's dims) so proposals start from the right state
-                dkp, dvp = self._draft_cache
-                if m:
-                    dkp, dvp, _ = self._compiled[("draft_prefill_at", bucket)](
-                        self._draft_params, dkp, dvp, self._page_table[i],
-                        padded, np.int32(n - m), np.int32(m))
-                else:
-                    dkp, dvp, _ = self._compiled[("draft_prefill", bucket)](
-                        self._draft_params, dkp, dvp, self._page_table[i],
-                        padded, np.int32(n))
-                self._draft_cache = (dkp, dvp)
-            tok_h = int(np.asarray(tok))
-            fin_h = bool(np.asarray(fin))
-            lg_h = np.asarray(lg) if spec.echo_logits else None
-            picks_h, rows_h = self._read_aux(sp, aux, spec.echo_logits)
-            t1 = self.clock()
-        self.metrics.inc("prefills")
-        self.metrics.ttft.record((t1 - s.req.t_submit) * 1e3)
-        s.t_first = t1
-        if self._prefix_on and fin_h:
-            # insert BEFORE recording the token so a same-prompt request
-            # admitted next hits; gated on a finite first sample so a
-            # poisoned prefill's rows never enter the trie
-            with self._lock:
-                self._prefix_insert(s, t1)
-        if picks_h is not None:
-            s.picks.append(picks_h)
-        s.rows_next = rows_h
-        self._record_token(i, tok_h, fin_h, lg_h, t1)
+        """Admission's prefill of a whole prompt: ONE chunk, the part of
+        it no prefix match covers, dispatched, waited for and committed
+        (first token, TTFT) before the next request is admitted."""
+        self._chunk_run(self._chunk_of(i, self._slots[i], whole=True))
 
     def _chunk_budget(self) -> int:
         """How many prefill chunks this turn may take: as many as slots
@@ -1875,53 +1868,53 @@ class DecodeEngine:
                 if s is not None and s.n_prefilled is not None
                 and s.n_prefilled < s.n_prompt]
 
-    def _prefill_chunk_steps(self) -> bool:
-        """A turn's chunks where no fused dispatch is there to queue them
-        behind (the plain loop, or no slot decodes): ``_chunk_budget()``
-        of them, each waited for and committed before the next."""
+    def _turn_chunks(self, behind=None) -> int:
+        """Advance the chunked prefills by one turn: ``_chunk_budget()``
+        chunks, one ``_chunk_pick`` each (at most ``prefill_chunk``
+        prompt tokens through the ``prefill_at`` offset entry point),
+        and count the turn.  Each is dispatched, waited for and
+        committed before the next; or, given ``behind`` (the
+        ``serve/decode_step`` span of the dispatch the device is busy
+        with), they are queued behind it back to back, nothing read
+        back, and left in ``_chunk_inflight`` for the turn to settle.
+        Chunk rows attend over all earlier rows already in the pool
+        (same per-row math as a cold prefill), so the final logits are
+        bit-identical to an unchunked prefill of the whole prompt.
+        Returns the chunks sent."""
         if self.prefill_chunk is None:
-            return False
-        k = 0
-        for _ in range(self._chunk_budget()):
-            if not self._prefill_chunk_step():
+            return 0
+        budget, k = self._chunk_budget(), 0
+        for _ in range(budget):
+            c = self._chunk_pick()
+            if c is None:
                 break
+            if behind is None:
+                self._chunk_run(c)
+            else:
+                with obs_trace.span("serve/prefill_dispatch", cat="serve",
+                                    slot=c.i):
+                    self._chunk_dispatch(c)
+                self._chunk_inflight.append(c)
             k += 1
-        self._count_chunk_turn(k)
-        return k > 0
-
-    def _count_chunk_turn(self, k: int) -> None:
-        """``k`` chunks went to the device in one turn of the loop."""
         if k:
             self.metrics.inc("chunk_turns")
             if k > 1:
                 self.metrics.inc("chunk_turns_multi")
             if k > self.metrics.chunk_turn_max.value():
                 self.metrics.chunk_turn_max.set(k)
+        if behind is not None:
+            # queued behind this dispatch, of the slots that were
+            # mid-prefill
+            behind.set(chunks=k, mid_prefill=budget)
+        return k
 
-    def _prefill_chunk_step(self) -> bool:
-        """Advance ONE pending chunked prefill by one chunk (at most
-        ``prefill_chunk`` prompt tokens through the ``prefill_at``
-        offset entry point), round-robin across slots mid-prefill so no
-        single long prompt starves another (or in the order
-        ``prefill_order`` names).  The final chunk runs the
-        ``_prefill_slot`` tail — sample token 0, TTFT, prefix insert —
-        and the slot becomes steppable.  Chunk rows attend over all
-        earlier rows already in the pool (same per-row math as a cold
-        prefill), so the final logits are bit-identical to an unchunked
-        prefill of the whole prompt.
-
-        Pick, dispatch, wait and commit are separate so that the fused
-        decode step can put its chunks' dispatches BEHIND its own on the
-        device and do its host work while they run
-        (``_step_fused_once``); here they run back to back."""
-        c = self._chunk_pick()
-        if c is None:
-            return False
+    def _chunk_run(self, c: _Chunk) -> None:
+        """One prompt program from dispatch to commit, inside its
+        ``serve/prefill`` span."""
         with self._chunk_span(c) as sp:
             self._chunk_dispatch(c)
             self._chunk_wait(c, sp)
         self._chunk_commit(c)
-        return True
 
     def _chunk_pick(self) -> Optional[_Chunk]:
         """The next chunk in ``prefill_order``, with its padded tokens.  A
@@ -1949,9 +1942,20 @@ class DecodeEngine:
                 i = min(pending, key=lambda x: (x - start) % self.max_slots)
                 self._chunk_cursor = (i + 1) % self.max_slots
             s = self._slots[i]
+        return self._chunk_of(i, s)
+
+    def _chunk_of(self, i: int, s: _Slot, whole: bool = False) -> _Chunk:
+        """The slot's next dispatch of a prompt program, with its padded
+        tokens: the next ``prefill_chunk`` tokens of a chunked prefill,
+        or (``whole``) all of the prompt past its matched prefix."""
         c = _Chunk()
-        c.i, c.slot, c.offset = i, s, s.n_prefilled
-        c.take = min(self.prefill_chunk, s.n_prompt - c.offset)
+        c.i, c.slot, c.whole = i, s, whole
+        if whole:
+            c.offset = s.n_matched * self.program.page_size
+            c.take = s.n_prompt - c.offset
+        else:
+            c.offset = s.n_prefilled
+            c.take = min(self.prefill_chunk, s.n_prompt - c.offset)
         c.bucket = self._bucket_for(c.take)
         c.padded = np.zeros((c.bucket,), np.int32)
         c.padded[:c.take] = s.spec.prompt[c.offset:c.offset + c.take]
@@ -1979,32 +1983,49 @@ class DecodeEngine:
 
     def _chunk_span(self, c: _Chunk):
         s = c.slot
+        # a whole prompt's span carries its length; a chunk's, its own
+        # tokens and where they start
+        rows = ({"prompt_tokens": s.n_prompt} if c.whole
+                else {"prompt_tokens": c.take, "offset": c.offset})
         return obs_trace.span("serve/prefill", cat="serve", slot=c.i,
-                              bucket=c.bucket, prompt_tokens=c.take,
-                              offset=c.offset, model=s.tag,
-                              request_id=s.spec.request_id)
+                              bucket=c.bucket, model=s.tag,
+                              request_id=s.spec.request_id, **rows)
 
     def _chunk_dispatch(self, c: _Chunk) -> None:
         """Queue the chunk's program (and, after a final chunk, the
-        first token's sampler) on the device; nothing is read back.  The
-        slot's next chunk starts where this one ends, and may be picked
-        and queued behind it before either is read: the device runs them
-        in the order of their dispatch."""
+        first token's sampler) on the device; nothing is read back.  A
+        whole prompt from its row 0 runs ``prefill``; a chunk, and the
+        suffix past a prefix-cache hit (the shared pages already hold
+        the prefix rows and the suffix rows attend over them), runs
+        ``prefill_at``: the same per-row math, so the logits are
+        bit-identical.  A chunked slot's next chunk starts where this
+        one ends, and may be picked and queued behind it before either
+        is read: the device runs them in the order of their dispatch."""
         s, spec = c.slot, c.slot.spec
+        key, at = (("prefill", ()) if c.whole and c.offset == 0
+                   else ("prefill_at", (np.int32(c.offset),)))
         kp, vp = self._cache
-        kp, vp, c.lg, *c.aux = self._compiled[("prefill_at", c.bucket)](
+        kp, vp, c.lg, *c.aux = self._compiled[(key, c.bucket)](
             self._versions[s.tag], kp, vp, self._page_table[c.i], c.padded,
-            np.int32(c.take), np.int32(c.offset), *self._slot_arg(c.i))
+            np.int32(c.take), *at, *self._slot_arg(c.i))
         self._cache = (kp, vp)
-        s.n_prefilled = c.offset + c.take
+        if not c.whole:
+            s.n_prefilled = c.offset + c.take
         if c.offset == 0 and self._slot_state:
-            # the chunk at offset 0 starts the slot from zero state
+            # the rows at offset 0 start the slot from zero state
             self.metrics.inc("recurrent_state_resets")
         if c.last:
-            # final chunk — the _prefill_slot tail
             c.tok, c.fin = self._compiled[("sample1",)](
                 c.lg, np.float32(spec.temperature), np.int32(spec.top_k),
                 np.float32(spec.top_p), np.uint32(spec.seed), np.int32(0))
+        if self._draft_program is not None:
+            # mirror the prompt into the draft pool (same page ids, the
+            # draft's dims) so proposals start from the right state
+            dkp, dvp = self._draft_cache
+            dkp, dvp, _ = self._compiled[("draft_" + key, c.bucket)](
+                self._draft_params, dkp, dvp, self._page_table[c.i],
+                c.padded, np.int32(c.take), *at)
+            self._draft_cache = (dkp, dvp)
 
     def _chunk_wait(self, c: _Chunk, sp) -> None:
         """The blocking read-back of what the chunk left."""
@@ -2018,23 +2039,36 @@ class DecodeEngine:
         c.t1 = self.clock()
 
     def _chunk_commit(self, c: _Chunk) -> None:
+        """Count the chunk; after a prompt's last one record its first
+        token (TTFT): the slot becomes steppable."""
         s, i, t1 = c.slot, c.i, c.t1
-        self.metrics.inc("prefill_chunks")
+        if not c.whole:
+            self.metrics.inc("prefill_chunks")
         if not c.last:
             return
-        self.metrics.inc("prefills")
         if c.offset > s.n_matched * self.program.page_size:
             self.metrics.inc("chunked_prefills")   # took >= 2 chunks
-        self.metrics.ttft.record((t1 - s.req.t_submit) * 1e3)
-        s.t_first = t1
         s.n_prefilled = None
-        if self._prefix_on and c.fin_h:
-            with self._lock:
-                self._prefix_insert(s, t1)
         if c.picks_h is not None:
             s.picks.append(c.picks_h)
         s.rows_next = c.rows_h
-        self._record_token(i, c.tok_h, c.fin_h, c.lg_h, t1)
+        self._first_token(i, c.tok_h, c.fin_h, c.lg_h, t1)
+
+    def _first_token(self, i: int, token: int, finite: bool,
+                     logits_row: Optional[np.ndarray], t1: float) -> None:
+        """A request's first token, from its prompt's last rows here or
+        handed over by a prefill host: TTFT, and the slot decodes."""
+        s = self._slots[i]
+        self.metrics.inc("prefills")
+        self.metrics.ttft.record((t1 - s.req.t_submit) * 1e3)
+        s.t_first = t1
+        if self._prefix_on and finite:
+            # insert BEFORE recording the token so a same-prompt request
+            # admitted next hits; gated on a finite first sample so a
+            # poisoned prefill's rows never enter the trie
+            with self._lock:
+                self._prefix_insert(s, t1)
+        self._record_token(i, token, finite, logits_row, t1)
 
     def _attach_handoff(self, i: int, transfer) -> None:
         """Decode-stage admission: scatter the prefill host's exported
@@ -2071,20 +2105,13 @@ class DecodeEngine:
                 kp, vp, ids, _pad(transfer.k), _pad(transfer.v))
             self._cache = (kp, vp)
             t1 = self.clock()
-        self.metrics.inc("prefills")
         self.metrics.inc("handoffs_in")
         self.metrics.inc("pages_attached", p_pro - m)
         if m:
             self.metrics.inc("pages_deduped", m)
-        self.metrics.ttft.record((t1 - s.req.t_submit) * 1e3)
-        s.t_first = t1
-        fin_h = bool(h.finite)
-        if self._prefix_on and fin_h:
-            with self._lock:
-                self._prefix_insert(s, t1)
         lg_h = (np.asarray(h.logits0, np.float32)
                 if s.spec.echo_logits and h.logits0 is not None else None)
-        self._record_token(i, int(h.first_token), fin_h, lg_h, t1)
+        self._first_token(i, int(h.first_token), bool(h.finite), lg_h, t1)
 
     def _prefill_export(self, i: int) -> None:
         """Prefill-role terminal: run the standard prefill + first-token
@@ -2100,32 +2127,11 @@ class DecodeEngine:
         s = self._slots[i]
         spec = s.spec
         n = s.n_prompt
-        m = s.n_matched * self.program.page_size
-        bucket = self._bucket_for(n - m)
-        with obs_trace.span("serve/prefill", cat="serve", slot=i,
-                            bucket=bucket, prompt_tokens=n, model=s.tag,
-                            request_id=spec.request_id) as sp:
-            kp, vp = self._cache
-            if m:
-                suffix = n - m
-                padded = np.zeros((bucket,), np.int32)
-                padded[:suffix] = spec.prompt[m:]
-                kp, vp, lg = self._compiled[("prefill_at", bucket)](
-                    self._versions[s.tag], kp, vp, self._page_table[i], padded,
-                    np.int32(suffix), np.int32(m))
-            else:
-                padded = np.zeros((bucket,), np.int32)
-                padded[:n] = spec.prompt
-                kp, vp, lg = self._compiled[("prefill", bucket)](
-                    self._versions[s.tag], kp, vp, self._page_table[i], padded,
-                    np.int32(n))
-            tok, fin = self._compiled[("sample1",)](
-                lg, np.float32(spec.temperature), np.int32(spec.top_k),
-                np.float32(spec.top_p), np.uint32(spec.seed), np.int32(0))
-            self._cache = (kp, vp)
-            tok_h = int(np.asarray(tok))
-            fin_h = bool(np.asarray(fin))
-            t1 = self.clock()
+        c = self._chunk_of(i, s, whole=True)
+        with self._chunk_span(c) as sp:
+            self._chunk_dispatch(c)
+            self._chunk_wait(c, sp)
+        tok_h, fin_h, t1 = c.tok_h, c.fin_h, c.t1
         self.metrics.inc("prefills")
         self.metrics.ttft.record((t1 - s.req.t_submit) * 1e3)
         s.t_first = t1
@@ -2141,7 +2147,7 @@ class DecodeEngine:
                 self._prefix_insert(s, t1)
         p_pro = pages_for(n, self.program.page_size)
         k_pages, v_pages = self._compiled[("extract",)](
-            kp, vp, self._page_table[i])
+            *self._cache, self._page_table[i])
         k_np = jax.tree_util.tree_map(
             lambda a: np.asarray(a)[:, :p_pro].copy(), k_pages)
         v_np = jax.tree_util.tree_map(
@@ -2153,29 +2159,13 @@ class DecodeEngine:
             top_p=spec.top_p, seed=spec.seed,
             echo_logits=spec.echo_logits, first_token=tok_h, finite=True,
             n_pages=p_pro, pages=payload,
-            logits0=np.asarray(lg).copy() if spec.echo_logits else None,
+            logits0=c.lg_h.copy() if spec.echo_logits else None,
             model_tag=s.tag)
         self.metrics.inc("handoffs_out")
         self.metrics.inc("pages_exported", p_pro)
         now = self.clock()
         with self._lock:
-            self._slots[i] = None
-            self._free_pages.extend(s.page_ids)
-            for nd in reversed(s.shared_nodes):
-                nd.refs -= 1
-                nd.last_used = now
-            s.shared_nodes = []
-            self._page_table[i] = 0
-            live_tags = {sl.tag for sl in self._slots if sl is not None}
-            live_tags.add(self._serve_tag)
-            live_tags.update(self._model_tags.values())
-            for t in [t for t in self._versions if t not in live_tags]:
-                del self._versions[t]
-            self.metrics.active_slots.set(
-                sum(1 for sl in self._slots if sl is not None))
-            self.metrics.pages_in_use.set(
-                self.total_pages - 1 - len(self._free_pages))
-            self._refresh_pool_gauges_locked()
+            self._release_locked(i, s, now)
         _set_safe(s.req.future, handoff)
         obs_trace.complete_at("serve/request", s.req.t_submit, now,
                               cat="serve", kind="prefill_handoff",
@@ -2183,137 +2173,240 @@ class DecodeEngine:
                               request_id=spec.request_id)
 
     def _step_once(self) -> bool:
-        """One turn of the plain loop: queue the NEXT decode step (and
-        its sampler call) on the device, then read back and record the
-        step queued a turn ago, which ran while the host recorded,
-        admitted and built.  The next step's input tokens are the
-        sampler's own output on the device, joined there with the
-        host's token of a slot that came from a prefill since
-        (``("join",)``), so nothing of a step has to reach the host
-        before the one after it is queued.
+        """The decode part of a turn: per distinct live version tag ONE
+        dispatch of that tag's slots (same executable, that tag's
+        params: the no-version-mixing hot-swap invariant lives here),
+        the turn's chunks behind the first of them where
+        ``_chunks_ride_behind``, and the read of what is due.  False
+        when there was nothing to dispatch or read.
 
-        What the host knows without the read goes into the next step's
-        inputs (``_step_inputs``): a stepped slot's position and sampler
-        counter are one further, and a slot whose budget ends with the
-        step in flight is left out.  EOS, a passed deadline and
-        non-finite logits are known only at the read: such a slot has
-        then been stepped ONCE more.  That step's write lands at the
-        request's own next row, inside the pages it reserved, and any
-        later tenant's prefill, attach or scrub of those pages is queued
-        behind it on the device; its token is dropped
-        (``overrun_slot_steps``).  Host slot state still changes only at
-        a read, so a crash retries from the last recorded token.
+        The schedule's first predicate, ``keep``: whether the new
+        dispatch stays UNREAD until the next turn, which queues its
+        successor behind it before reading it, so the device runs that
+        while the host reads, records, finishes, admits and builds.
+        Kept ⇔ ``decode_horizon == 1`` and one live tag: a single step's
+        successor takes its input tokens from ONE sampler output on the
+        device (``("join",)`` puts in the host's token of a slot that
+        came from a prefill since), so with two tags alive each step is
+        read before the next is queued (``step_drains``); and a fused
+        dispatch is read in its own turn, with the turn's chunks to keep
+        the device busy meanwhile.
 
-        The step runs once per distinct active version tag (same
-        executable, that tag's params, that tag's slots active): the
-        no-version-mixing hot-swap invariant lives here.  While two
-        tags are alive each step is read before the next is queued
-        (``step_drains``): a step takes its tokens from ONE sampler
-        output.
+        What the host knows without the read goes into a kept
+        dispatch's successor (``_step_inputs``): a stepped slot's
+        position and sampler counter are one further, and a slot whose
+        budget ends with the step in flight is left out.  EOS, a passed
+        deadline and non-finite logits are known only at the read: such
+        a slot has then been stepped ONCE more.  That step's write lands
+        at the request's own next row, inside the pages it reserved, and
+        any later tenant's prefill, attach or scrub of those pages is
+        queued behind it on the device; its token is dropped
+        (``overrun_slot_steps``).  Host slot state changes only at a
+        read, so a crash retries from the last recorded token.
 
-        Spans: ``serve/step_build`` / ``serve/step_dispatch`` /
-        ``serve/sample_dispatch`` of the step that is queued lie under
-        ``serve/iteration``; ``serve/decode_step`` is the step that is
-        READ, with its ``serve/step_wait`` and ``serve/step_record``."""
+        Spans: a kept dispatch's ``serve/step_build`` /
+        ``serve/step_dispatch`` / ``serve/sample_dispatch`` lie under
+        ``serve/iteration`` in the turn that queues it, and so do a
+        drained step's; ``serve/decode_step`` is the dispatch that is
+        READ, with its ``serve/step_wait`` and ``serve/step_record``.
+        Where the chunks ride behind, the ``serve/decode_step`` of the
+        dispatch opens before it is built and holds its dispatch spans
+        and the chunks' ``serve/prefill_dispatch`` too, whose count it
+        carries."""
+        tags, crash = self._live_tags()
+        behind = self._chunks_ride_behind()
+        keep = self.decode_horizon == 1 and len(tags) == 1
+        if crash and not (behind and tags):
+            # the test hook: before a dispatch; behind a fused one, after
+            # its read and before any commit (``_step_read``)
+            raise ReplicaCrashError("injected decode-batch crash (test hook)")
+        prev, self._flight = self._flight, None
+        if prev is None and not tags:
+            return False
+        if keep:
+            self._flight = self._step_dispatch(tags[0], prev)
+        if prev is not None:
+            self._step_read(prev)
+        if keep:
+            return True
+        chunks_due = behind
+        for tag in tags:
+            with contextlib.ExitStack() as scope:
+                sp = (scope.enter_context(self._step_span(tag))
+                      if behind else None)
+                f = self._step_dispatch(tag)
+                if f is None:
+                    continue
+                if chunks_due:
+                    chunks_due = False
+                    self._turn_chunks(behind=sp)
+                # the device is busy: the last dispatch's rows can land
+                self._flush_echo()
+                if not behind:
+                    # a single step that two live versions keep from
+                    # staying in flight
+                    self.metrics.inc("step_drains")
+                self._step_read(f, sp, crash)
+        return True
+
+    def _live_tags(self) -> tuple:
+        """The version tags of the slots that can be stepped, in slot
+        order, and the test hook's flag (``_crash_next``), taken."""
         with self._lock:
             tags: List[str] = []
             for s in self._slots:
                 if (s is not None and s.n_prefilled is None
                         and s.tag not in tags):
                     tags.append(s.tag)
-            crash = self._crash_next
-            self._crash_next = False
-        if crash:
-            raise ReplicaCrashError("injected decode-batch crash (test hook)")
-        prev, self._flight = self._flight, None
-        if prev is None and not tags:
-            return False
-        if len(tags) > 1:
-            if prev is not None:
-                self._step_read(prev)
-            for tag in tags:
-                f = self._step_queue(tag)
-                if f is not None:
-                    self.metrics.inc("step_drains")
-                    self._step_read(f)
-            return True
-        if tags:
-            self._flight = self._step_queue(tags[0], prev)
-        if prev is not None:
-            self._step_read(prev)
-        return True
+            crash, self._crash_next = self._crash_next, False
+        return tags, crash
 
-    def _step_queue(self, tag: str, prev: Optional[_Flight] = None
-                    ) -> Optional[_Flight]:
-        """Queue one decode step of ``tag``'s slots and its sampler call
-        behind whatever the device holds (``prev``: the step in flight,
-        unread), and start the transfers of what the read will want;
-        nothing is waited for.  None where no slot is left to step."""
+    def _step_span(self, tag: str):
+        return obs_trace.span("serve/decode_step", cat="serve", model=tag)
+
+    def _step_dispatch(self, tag: str, prev: Optional[_Flight] = None
+                       ) -> Optional[_Flight]:
+        """Queue one decode dispatch of ``tag``'s slots behind whatever
+        the device holds (``prev``: the dispatch in flight, unread,
+        whose tokens on the device are the inputs of the slots it
+        steps), and start the transfers of what the read will want;
+        nothing is waited for.  A horizon of 1 is the step, its sampler
+        and, after ``prev``, the token join; a longer one is ONE
+        ``("step_multi", H)`` executable, H steps and their sampling in
+        a ``lax.scan`` of the step body: the ``fold_in(seed,
+        token_index)`` keying makes its stream bit-identical to
+        step-by-step, and a slot that ends mid-horizon (EOS, budget,
+        poison) has its remaining writes routed to the scratch page on
+        the device.  None where no slot is left to step."""
         inp = self._step_inputs(tag, prev)
         if inp is None:
             return None
-        t0 = self.clock()
+        H = self.decode_horizon
+        f = _Flight()
+        f.inp, f.steps, f.ahead = inp, H, int(prev is not None)
+        f.late = inp.echo and H > 1
+        f.step_ms = f.sample_ms = None
+        f.t0 = self.clock()
+        kp, vp = self._cache
         with obs_trace.span("serve/step_dispatch", cat="serve"):
-            toks_in = inp.toks_in
-            if not inp.on_host[inp.group].all():
-                # a slot of the step in flight: its token is on the device
-                toks_in = self._compiled[("join",)](prev.toks, toks_in,
-                                                    inp.on_host)
-            kp, vp = self._cache
-            kp, vp, lgs, *aux = self._compiled[("step",)](
-                inp.params, kp, vp, self._page_table, toks_in, inp.pos,
-                inp.act)
-        t_step = self.clock()
-        with obs_trace.span("serve/sample_dispatch", cat="serve"):
-            toks, fin = self._compiled[("sample",)](
-                lgs, inp.temps, inp.tks, inp.tps, inp.seeds, inp.steps)
-            self._cache = (kp, vp)
-            # what the read waits for goes first, the bulk last
-            attn = aux[0].get("attn_rows") if aux else None
-            for a in (toks, fin, *_leaves(aux)):
-                if a is not attn:
-                    a.copy_to_host_async()
-            if inp.echo:
-                lgs.copy_to_host_async()
-                if attn is not None:
-                    attn.copy_to_host_async()
+            if H > 1:
+                eos = np.int32(self.eos_id if self.eos_id is not None else -1)
+                kp, vp, f.toks, f.fin, lgs, *f.aux = \
+                    self._compiled[("step_multi", H)](
+                        inp.params, kp, vp, self._page_table, inp.toks_in,
+                        inp.pos, inp.act, inp.temps, inp.tks, inp.tps,
+                        inp.seeds, inp.steps, inp.budgets, eos,
+                        np.arange(H, dtype=np.int32))
+            else:
+                toks_in = inp.toks_in
+                if not inp.on_host[inp.group].all():
+                    # a slot of the step in flight: its token is on the
+                    # device
+                    toks_in = self._compiled[("join",)](
+                        prev.toks, toks_in, inp.on_host)
+                kp, vp, lgs, *f.aux = self._compiled[("step",)](
+                    inp.params, kp, vp, self._page_table, toks_in, inp.pos,
+                    inp.act)
+        if H == 1:
+            t_step = self.clock()
+            with obs_trace.span("serve/sample_dispatch", cat="serve"):
+                f.toks, f.fin = self._compiled[("sample",)](
+                    lgs, inp.temps, inp.tks, inp.tps, inp.seeds, inp.steps)
+        self._cache = (kp, vp)
+        # what the read waits for goes first, the bulk last
+        f.lgs = lgs if inp.echo else None
+        f.attn = f.aux[0].get("attn_rows") if f.aux else None
+        for a in (f.toks, f.fin, *_leaves(f.aux)):
+            if a is not f.attn:
+                a.copy_to_host_async()
+        if inp.echo:
+            lgs.copy_to_host_async()
+            if f.attn is not None:
+                f.attn.copy_to_host_async()
+        if H == 1:
+            f.step_ms = (t_step - f.t0) * 1e3
+            f.sample_ms = (self.clock() - t_step) * 1e3
+        else:
+            self.metrics.inc("fused_dispatches")
         self.metrics.inc("decode_steps")
-        self._count_sorted(inp)
+        self._count_sorted(inp, steps=H)
         if prev is not None:
             self.metrics.inc("steps_ahead")
-        f = _Flight()
-        f.inp, f.toks, f.fin, f.aux = inp, toks, fin, aux
-        f.lgs = lgs if inp.echo else None
-        f.ahead = int(prev is not None)
-        f.t0, f.step_ms = t0, (t_step - t0) * 1e3
-        f.sample_ms = (self.clock() - t_step) * 1e3
         return f
 
-    def _step_read(self, f: _Flight) -> None:
-        """The ``serve/decode_step`` span of step ``f``: the blocking
-        read-back (the device works inside it, on ``f`` and on whatever
-        was queued behind it) and the group's bookkeeping.  A slot whose
-        request stopped at the read before (EOS, deadline, poison) was
-        stepped for nothing: its token is dropped."""
+    def _step_read(self, f: _Flight, sp=None, crash: bool = False) -> None:
+        """Read dispatch ``f`` back and record it, inside its
+        ``serve/decode_step`` span (``sp`` where the turn opened it
+        before the dispatch): the blocking read-back (the device works
+        inside it, on ``f`` and on whatever was queued behind it) and
+        the group's bookkeeping, every step's (token, finite) pair
+        through ``_record_token`` exactly as so many single steps would
+        have been.  Echoed logits are read here with the tokens, or
+        (``f.late``) ride one dispatch behind: their transfer started at
+        the dispatch, and ``_flush_echo`` copies the rows to the
+        requests' buffers once the next dispatch keeps the device busy
+        (or a chunk does, or the loop has nothing to step); an answer
+        that echoes logits and finishes meanwhile is handed to its
+        caller right after its last rows land."""
         inp = f.inp
-        with obs_trace.span("serve/decode_step", cat="serve",
-                            model=inp.tag, tokens=1) as sp:
+
+        def by_step(a):     # [steps, slots, ...]: a single step has no axis
+            return a if a is None or f.steps > 1 else a[None]
+
+        with (self._step_span(inp.tag) if sp is None
+              else contextlib.nullcontext(sp)) as sp:
             t_wait = self.clock()
             with obs_trace.span("serve/step_wait", cat="serve"):
-                toks_h = np.asarray(f.toks)
-                fin_h = np.asarray(f.fin)
-                lgs_h = np.asarray(f.lgs) if inp.echo else None
-                picks_h, rows_h = self._read_aux(sp, f.aux, inp.echo)
+                toks_h = by_step(np.asarray(f.toks))    # [steps, S]
+                fin_h = by_step(np.asarray(f.fin))
+                inline = inp.echo and not f.late
+                lgs_h = by_step(np.asarray(f.lgs)) if inline else None
+                picks_h, rows_h = self._read_aux(sp, f.aux, inline)
             t1 = self.clock()
-            self._set_step_args(
-                sp, inp, step_ms=f.step_ms,
-                sample_ms=f.sample_ms + (t1 - t_wait) * 1e3)
-            sp.set(ahead=f.ahead)
-            # the device was on the step before until that one's read
+            if crash:
+                # "mid-horizon" from the host's view: the device has
+                # advanced but NOTHING is committed — recovery must retry
+                # from the last committed token
+                raise ReplicaCrashError(
+                    "injected decode-batch crash (test hook)")
+            if f.sample_ms is None:
+                step_ms, sample_ms = (t1 - f.t0) * 1e3, 0.0
+            else:
+                step_ms = f.step_ms
+                sample_ms = f.sample_ms + (t1 - t_wait) * 1e3
+            self._set_step_args(sp, inp, step_ms, sample_ms, f.steps)
+            sp.set(tokens=f.steps, ahead=f.ahead)
+            # the device was on the dispatch before until that one's read
             # ended, where this one was queued behind it
             self.metrics.step_time.record(
                 (t1 - max(f.t0, self._step_read_at)) * 1e3)
             self._step_read_at = t1
+            if f.late:
+                self._echo_lgs = (f.lgs, f.attn)
+            committed = self._record_steps(
+                inp, toks_h, fin_h, t1, picks=by_step(picks_h), lgs=lgs_h,
+                rows=by_step(rows_h), late=f.late)
+            if f.steps > 1:
+                self.metrics.inc("tokens_per_dispatch", committed)
+
+    def _record_steps(self, inp: _StepInputs, toks: np.ndarray,
+                      fins: np.ndarray, now: float, *, counts=None,
+                      picks=None, lgs=None, rows=None,
+                      late: bool = False) -> int:
+        """The record loop of every decode dispatch: slot ``i`` of the
+        group gets column ``i`` of ``toks`` / ``fins`` (``[steps,
+        slots]``; ``picks``, ``lgs``, ``rows`` likewise where there are
+        any), its first ``counts[i]`` steps where ``counts`` says, in
+        order, and none once it is no longer the request the dispatch
+        stepped: ended at the read before (EOS, deadline, poison: it was
+        stepped for nothing, ``overrun_slot_steps``) or by a token of
+        this very column (the device's overrun past it is dropped).
+        ``late``: the echoed rows are not here yet; the requests' buffers
+        keep their place (``_echo_rows``) for ``_flush_echo``.  Returns
+        the tokens committed."""
+        committed = 0
+        self._echo_defer = late
+        try:
             with obs_trace.span("serve/step_record", cat="serve"):
                 for i in inp.group:
                     s = inp.slots[i]
@@ -2322,14 +2415,30 @@ class DecodeEngine:
                     if gone:
                         self.metrics.inc("overrun_slot_steps")
                         continue
-                    s.pos += 1
-                    if picks_h is not None:
-                        s.picks.append(picks_h[i])
-                    echo = lgs_h is not None and s.logits is not None
-                    if echo and rows_h is not None:
-                        s.rows_next = rows_h[i]
-                    self._record_token(i, int(toks_h[i]), bool(fin_h[i]),
-                                       lgs_h[i] if echo else None, t1)
+                    echo = lgs is not None and s.logits is not None
+                    n0 = len(s.logits) if s.logits is not None else 0
+                    for j in range(len(toks) if counts is None
+                                   else counts[i]):
+                        if self._slots[i] is not s:
+                            break
+                        s.pos += 1
+                        fin = bool(fins[j, i])
+                        if picks is not None:
+                            s.picks.append(picks[j, i])
+                        if echo and rows is not None:
+                            s.rows_next = rows[j, i]
+                        self._record_token(i, int(toks[j, i]), fin,
+                                           lgs[j, i] if echo else None, now)
+                        committed += fin
+                    if late and s.logits is not None and len(s.logits) > n0:
+                        # rows n0.. of its buffer are column i of this
+                        # dispatch's logits
+                        self._echo_rows.append(
+                            (s.logit_buf, s.rows_buf, n0,
+                             len(s.logits) - n0, i))
+        finally:
+            self._echo_defer = False
+        return committed
 
     def _read_aux(self, sp, aux, rows: bool = False) -> tuple:
         """What a program with ``aux`` reports beside its logits, read
@@ -2424,170 +2533,10 @@ class DecodeEngine:
                pages_reserved=inp.pages_reserved,
                pages_filled=inp.pages_filled, **more)
 
-    def _step_fused_once(self) -> bool:
-        """One FUSED dispatch per distinct active version tag: H =
-        ``decode_horizon`` decode steps plus device-resident sampling
-        run inside the single ``("step_multi", H)`` executable, and the
-        host syncs once per H tokens.  Host bookkeeping then replays
-        the H (token, finite) pairs through ``_record_token`` exactly
-        as H plain steps would have — a slot that stops mid-horizon
-        (EOS / budget / poison / deadline) frees at the same token, and
-        the device's post-stop overrun (≤ H-1 tokens, routed to the
-        scratch page on device) is simply not recorded.  Host slot
-        state is only mutated AFTER the dispatch returns, so a crash
-        anywhere inside the horizon retries from the last committed
-        token and regenerates identical bits (seeded counter-based
-        sampling).
-
-        The host's work is kept off the device's critical path where it
-        can be.  With chunked prefill the iteration's chunks
-        (``_chunk_budget()``: one for each slot mid-prefill) are queued
-        BEHIND the first dispatch, back to back, so the device goes from
-        the steps into the chunks while the host reads the tokens back
-        and records them.  They are then read in the order of their
-        dispatch as far as the last FINAL chunk among them (its first
-        token joins the next step); those behind it are left running,
-        the next turn's dispatch is queued behind them, and their counts
-        are read after that turn's steps, when they are long done.
-        Echoed logits (``[H, slots, vocab]`` float32, the
-        bulk of what a dispatch returns) ride one dispatch behind: their
-        transfer is started at the dispatch, and the rows are copied to
-        the requests' buffers after the NEXT dispatch is queued (or as
-        soon as a chunk keeps the device busy, or the loop has nothing
-        to step); an answer that echoes logits and finishes meanwhile is
-        handed to its caller right after its last rows land
-        (``_flush_echo``)."""
-        H = self.decode_horizon
-        with self._lock:
-            tags: List[str] = []
-            for s in self._slots:
-                if (s is not None and s.n_prefilled is None
-                        and s.tag not in tags):
-                    tags.append(s.tag)
-            crash = self._crash_next
-            self._crash_next = False
-        if crash and not tags:
-            raise ReplicaCrashError("injected decode-batch crash (test hook)")
-        if not tags:
-            return False
-        eos = np.int32(self.eos_id if self.eos_id is not None else -1)
-        chunks: List[_Chunk] = []
-        chunks_due = self.prefill_chunk is not None
-        for tag in tags:
-            with obs_trace.span("serve/decode_step", cat="serve",
-                                model=tag, tokens=H) as sp:
-                inp = self._step_inputs(tag)
-                if inp is None:
-                    continue
-                t0 = self.clock()
-                with obs_trace.span("serve/step_dispatch", cat="serve"):
-                    kp, vp = self._cache
-                    kp, vp, toks, fins, lgs, *aux = \
-                        self._compiled[("step_multi", H)](
-                            inp.params, kp, vp, self._page_table,
-                            inp.toks_in, inp.pos, inp.act, inp.temps,
-                            inp.tks, inp.tps, inp.seeds, inp.steps,
-                            inp.budgets, eos, np.arange(H, dtype=np.int32))
-                    self._cache = (kp, vp)
-                    # what the host waits for goes first, the bulk last
-                    attn = aux[0].get("attn_rows") if aux else None
-                    for a in (toks, fins, *_leaves(aux)):
-                        if a is not attn:
-                            a.copy_to_host_async()
-                    if inp.echo:
-                        lgs.copy_to_host_async()
-                        if attn is not None:
-                            attn.copy_to_host_async()
-                if chunks_due:
-                    chunks_due = False
-                    budget = self._chunk_budget()
-                    for _ in range(budget):
-                        chunk = self._chunk_pick()
-                        if chunk is None:
-                            break
-                        with obs_trace.span("serve/prefill_dispatch",
-                                            cat="serve", slot=chunk.i):
-                            self._chunk_dispatch(chunk)
-                        chunks.append(chunk)
-                    self._count_chunk_turn(len(chunks))
-                    # queued behind this dispatch, of the slots that
-                    # were mid-prefill
-                    sp.set(chunks=len(chunks), mid_prefill=budget)
-                # the device is busy: the last dispatch's rows can land
-                self._flush_echo()
-                with obs_trace.span("serve/step_wait", cat="serve"):
-                    toks_h = np.asarray(toks)      # [H, S]
-                    fins_h = np.asarray(fins)
-                    picks_h, _ = self._read_aux(sp, aux)    # [H, S, ...]
-                t1 = self.clock()
-                if crash:
-                    # "mid-horizon" from the host's view: the device has
-                    # advanced H tokens but NONE are committed — recovery
-                    # must retry from the last committed token
-                    raise ReplicaCrashError(
-                        "injected decode-batch crash (test hook)")
-                self._set_step_args(sp, inp, step_ms=(t1 - t0) * 1e3,
-                                    sample_ms=0.0, steps=H)
-                self.metrics.inc("decode_steps")
-                self._count_sorted(inp, steps=H)
-                self.metrics.inc("fused_dispatches")
-                self.metrics.step_time.record((t1 - t0) * 1e3)
-                committed = 0
-                with obs_trace.span("serve/step_record", cat="serve"):
-                    self._echo_defer = inp.echo
-                    try:
-                        for i in inp.group:
-                            with self._lock:
-                                s = self._slots[i]
-                            if s is None:
-                                continue
-                            n0 = len(s.logits) if s.logits is not None else 0
-                            for j in range(H):
-                                if self._slots[i] is None:
-                                    break   # stopped mid-horizon; drop overrun
-                                s.pos += 1
-                                fin_j = bool(fins_h[j, i])
-                                if picks_h is not None:
-                                    s.picks.append(picks_h[j, i])
-                                self._record_token(i, int(toks_h[j, i]),
-                                                   fin_j, None, t1)
-                                if fin_j:
-                                    committed += 1
-                            if s.logits is not None and len(s.logits) > n0:
-                                # rows n0.. of its buffer are column i of
-                                # this dispatch's logits
-                                self._echo_rows.append(
-                                    (s.logit_buf, s.rows_buf, n0,
-                                     len(s.logits) - n0, i))
-                    finally:
-                        self._echo_defer = False
-                        if inp.echo:
-                            self._echo_lgs = (lgs, attn)
-                self.metrics.inc("tokens_per_dispatch", committed)
-        # chunks an earlier turn left running lie before this turn's
-        # steps on the device: they are done, and reading them costs no
-        # wait
-        self._chunk_settle()
-        if chunks:
-            # the chunks are still running: this dispatch's rows land now
-            self._flush_echo()
-            self._chunk_inflight = chunks
-            # a final chunk's first token joins the next step: wait as
-            # far as the last of them.  Nothing of the chunks behind it
-            # is needed before the next dispatch, so that is queued
-            # behind them with the device still busy
-            self._chunk_settle(max(
-                (k + 1 for k, c in enumerate(chunks) if c.last), default=0))
-        elif chunks_due:            # no dispatch to queue them behind
-            self._prefill_chunk_steps()
-        return True
-
-    def _chunk_settle(self, n: Optional[int] = None) -> None:
+    def _chunk_settle(self, n: int) -> None:
         """Read back and commit, in the order of their dispatch, the
-        first ``n`` chunks left on the device (all of them by default)."""
+        first ``n`` chunks left on the device."""
         flight = self._chunk_inflight
-        if n is None:
-            n = len(flight)
         done, self._chunk_inflight = flight[:n], flight[n:]
         for c in done:
             with self._chunk_span(c) as sp:
@@ -2595,8 +2544,8 @@ class DecodeEngine:
             self._chunk_commit(c)
 
     def _flush_echo(self) -> None:
-        """Copy the echoed logits of the last fused dispatch into the
-        requests' buffers and hand over the answers that waited for
+        """Copy the echoed logits of the last dispatch that left them on
+        the device (``_Flight.late``) into the requests' buffers and hand over the answers that waited for
         them.  Loop thread only; a no-op when nothing is owed."""
         lgs, rows, results = self._echo_lgs, self._echo_rows, \
             self._echo_results
@@ -2631,13 +2580,7 @@ class DecodeEngine:
         every fully-accepted round would degrade later proposals."""
         s_n = self.max_slots
         k = self.speculate_k
-        with self._lock:
-            tags: List[str] = []
-            for s in self._slots:
-                if s is not None and s.tag not in tags:
-                    tags.append(s.tag)
-            crash = self._crash_next
-            self._crash_next = False
+        tags, crash = self._live_tags()
         if crash:
             raise ReplicaCrashError("injected decode-batch crash (test hook)")
         if not tags:
@@ -2686,28 +2629,20 @@ class DecodeEngine:
             self.metrics.step_time.record((t1 - t0) * 1e3)
             self.metrics.inc("spec_steps")
             self.metrics.inc("spec_proposed", k * len(group))
-            committed = 0
+            self.metrics.inc("spec_accepted",
+                             int(nc_h[group].sum()) - len(group))
+            # a slot's column is its ``nc_h`` committed tokens, one
+            # finite flag for all of them
+            committed = self._record_steps(
+                inp, cm_h.T, np.broadcast_to(fin_h, cm_h.T.shape), t1,
+                counts=nc_h,
+                lgs=None if lgs_h is None else lgs_h.transpose(1, 0, 2))
             catchup = np.zeros((s_n,), bool)
             cu_tok = np.zeros((s_n,), np.int32)
-            with obs_trace.span("serve/step_record", cat="serve"):
+            with self._lock:
                 for i in group:
-                    c = int(nc_h[i])
-                    self.metrics.inc("spec_accepted", c - 1)
-                    for j in range(c):
-                        with self._lock:
-                            s = self._slots[i]
-                        if s is None:   # stopped mid-commit (eos/max/...)
-                            break
-                        s.pos += 1
-                        committed += 1
-                        self._record_token(
-                            i, int(cm_h[i, j]), bool(fin_h[i]),
-                            lgs_h[i, j].copy()
-                            if (lgs_h is not None and s.logits is not None)
-                            else None, t1)
-                    with self._lock:
-                        alive = self._slots[i] is not None
-                    if alive and c == k + 1:
+                    if (self._slots[i] is inp.slots[i]
+                            and nc_h[i] == k + 1):
                         catchup[i] = True
                         cu_tok[i] = d_toks[i, k - 1]
             self.metrics.inc("spec_committed", committed)
@@ -2758,7 +2693,7 @@ class DecodeEngine:
                         [s.rows_buf, np.empty_like(s.rows_buf)])
             if logits_row is not None:
                 s.logit_buf[n] = logits_row
-            # else the fused step's row, which ``_flush_echo`` copies in
+            # else a row that comes late, which ``_flush_echo`` copies in
             if s.rows_next is not None:
                 s.rows_buf[n], s.rows_next = s.rows_next, None
             s.logits.append(s.logit_buf[n])
@@ -2799,25 +2734,7 @@ class DecodeEngine:
                 s = self._slots[i]
                 if s is None:
                     return
-                self._slots[i] = None
-                self._free_pages.extend(s.page_ids)
-                for nd in reversed(s.shared_nodes):
-                    # decref, never free: trie pages stay resident for
-                    # the next shared-prefix request until LRU eviction
-                    nd.refs -= 1
-                    nd.last_used = now
-                s.shared_nodes = []
-                self._page_table[i] = 0
-                live_tags = {sl.tag for sl in self._slots if sl is not None}
-                live_tags.add(self._serve_tag)
-                live_tags.update(self._model_tags.values())
-                for t in [t for t in self._versions if t not in live_tags]:
-                    del self._versions[t]
-                self.metrics.active_slots.set(
-                    sum(1 for sl in self._slots if sl is not None))
-                self.metrics.pages_in_use.set(
-                    self.total_pages - 1 - len(self._free_pages))
-                self._refresh_pool_gauges_locked()
+                self._release_locked(i, s, now)
             request_id = s.spec.request_id
             # an error before the first token leaves no time to it
             ttft_ms = (round((s.t_first - s.req.t_submit) * 1e3, 3)
@@ -2866,6 +2783,27 @@ class DecodeEngine:
                               finish=reason or "error",
                               request_id=request_id)
 
+    def _release_locked(self, i: int, s: _Slot, now: float) -> None:
+        """Take ``s`` out of slot ``i``: its private pages go back to the
+        free list, its shared ones lose a reference, and a version no
+        slot, alias or placed model holds any more is dropped.  Caller
+        holds ``_lock``."""
+        self._slots[i] = None
+        self._free_pages.extend(s.page_ids)
+        for nd in reversed(s.shared_nodes):
+            # decref, never free: trie pages stay resident for the next
+            # shared-prefix request until LRU eviction
+            nd.refs -= 1
+            nd.last_used = now
+        s.shared_nodes = []
+        self._page_table[i] = 0
+        live_tags = {sl.tag for sl in self._slots if sl is not None}
+        live_tags.add(self._serve_tag)
+        live_tags.update(self._model_tags.values())
+        for t in [t for t in self._versions if t not in live_tags]:
+            del self._versions[t]
+        self._refresh_pool_gauges_locked()
+
     # -- crash recovery ----------------------------------------------------
 
     def _drain_crashed(self, exc: BaseException) -> None:
@@ -2892,8 +2830,6 @@ class DecodeEngine:
             self._prefix_root = _PrefixNode((), None, None)
             self._trie_pages = 0
             self.metrics.shared_pages.set(0)
-            self.metrics.active_slots.set(0)
-            self.metrics.pages_in_use.set(0)
             self._refresh_pool_gauges_locked()
         # the crash may have left non-finite rows anywhere — zero the pool
         kp, vp = self._cache
@@ -2920,12 +2856,16 @@ class DecodeEngine:
     # -- observability / shutdown ------------------------------------------
 
     def _refresh_pool_gauges_locked(self) -> None:
-        """Keep the free-capacity gauges live — the fleet router scores
-        decode sinks by them (docs/SERVING.md "Disaggregated and
-        sharded decode").  Caller holds ``self._lock``."""
+        """Keep the occupancy and free-capacity gauges live — the fleet
+        router scores decode sinks by them (docs/SERVING.md
+        "Disaggregated and sharded decode").  Caller holds
+        ``self._lock``."""
+        free = sum(1 for s in self._slots if s is None)
+        self.metrics.active_slots.set(self.max_slots - free)
+        self.metrics.free_slots.set(free)
+        self.metrics.pages_in_use.set(
+            self.total_pages - 1 - len(self._free_pages))
         self.metrics.free_pages.set(len(self._free_pages))
-        self.metrics.free_slots.set(
-            sum(1 for s in self._slots if s is None))
         self.metrics.pages_filled.set(
             sum(self._pages_filled(s) for s in self._slots if s is not None))
 
